@@ -15,7 +15,7 @@ classic compute/memory roofline):
 
 ``predicted_s = max(compute, memory, collective)`` — the terms overlap
 on real hardware (async collectives, prefetch), and the efficiency
-factors are *seeded from the repo's own bench trajectory* (BENCH_r01–r05
+factors are *seeded from the repo's own bench trajectory* (BENCH_r04–r05
 on TPU v5e: train steps sustain ~50% MFU, bandwidth-bound decode ~80%
 MBU), so each term is already an achieved-rate estimate, not a
 theoretical peak.
@@ -80,7 +80,7 @@ class Profile:
         return dataclasses.asdict(self)
 
 
-#: Seeded from the repo's own bench trajectory: BENCH_r01–r05 (TPU v5e)
+#: Seeded from the repo's own bench trajectory: BENCH_r04–r05 (TPU v5e)
 #: hold train at 49–50% MFU and bandwidth-bound decode at ~80% MBU, so
 #: those are the achieved-rate factors; ICI link bandwidth per 2211.05322
 #: §2 / public v5e specs (4 ICI links, ~45 GB/s effective per direction).

@@ -28,7 +28,8 @@ import math
 from typing import Any, Callable
 
 import jax
-from jax import core as jax_core
+from jax.core import DropVar
+from jax.extend import core as jax_core
 
 from learning_jax_sharding_tpu.analysis.findings import Finding
 
@@ -150,7 +151,7 @@ def _dead_eqns(jaxpr, path: str = "") -> list[Finding]:
     for i in reversed(range(len(jaxpr.eqns))):
         eqn = jaxpr.eqns[i]
         is_live = bool(getattr(eqn, "effects", None)) or any(
-            (not isinstance(v, jax_core.DropVar)) and v in live
+            (not isinstance(v, DropVar)) and v in live
             for v in eqn.outvars
         )
         if is_live:

@@ -116,7 +116,8 @@ class _SpecRecorder(_Interp):
 
     def run(self, jaxpr, in_specs: list[Spec],
             out_hint: list[Spec] | None = None) -> list[Spec]:
-        from jax import core as jax_core
+        from jax.core import DropVar
+        from jax.extend import core as jax_core
 
         env: dict[Any, Spec] = {}
 
@@ -128,7 +129,7 @@ class _SpecRecorder(_Interp):
             ))
 
         def write(v, spec: Spec):
-            if not isinstance(v, jax_core.DropVar):
+            if not isinstance(v, DropVar):
                 env[v] = spec
                 self.var_specs[v] = spec
 
@@ -241,7 +242,8 @@ class _Liveness:
 
     def walk(self, jaxpr, donated: frozenset = frozenset(),
              arg_names: Sequence[str] | None = None) -> _WalkResult:
-        from jax import core as jax_core
+        from jax.core import DropVar
+        from jax.extend import core as jax_core
 
         eqns = jaxpr.eqns
         n = len(eqns)
@@ -258,7 +260,7 @@ class _Liveness:
                     last.setdefault(v, i)
             for v in eqns[i].outvars:
                 if isinstance(v, jax_core.Var) and not isinstance(
-                        v, jax_core.DropVar):
+                        v, DropVar):
                     last.setdefault(v, i)
 
         live: dict[Any, int] = {}
@@ -323,7 +325,7 @@ class _Liveness:
                 ]
             virtual = (eqn.primitive.name in _VIRTUAL and inner is None)
             for v in eqn.outvars:
-                if isinstance(v, jax_core.DropVar):
+                if isinstance(v, DropVar):
                     continue
                 if virtual and v not in outset:
                     add(v, where, "intermediate", nbytes=0)

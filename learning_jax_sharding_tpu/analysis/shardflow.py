@@ -262,21 +262,18 @@ def _aval_bytes(v) -> int:
 
 
 def _source_line(eqn) -> str:
-    try:
-        from jax._src import source_info_util
+    # jax-internal, no public equivalent: the first frame of the eqn's
+    # traceback that is outside jax itself.
+    from jax._src import source_info_util
 
-        fr = source_info_util.user_frame(eqn.source_info)
-    except Exception:
-        # source_info_util is jax-internal; if it moves, attribution
-        # degrades to "<unknown>" rather than breaking the analysis.
-        return "<unknown>"
+    fr = source_info_util.user_frame(eqn.source_info.traceback)
     if fr is not None:
         return f"{fr.file_name}:{fr.start_line}"
     return "<unknown>"
 
 
 def _sub_jaxprs(eqn):
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
 
     out = []
     for k, v in eqn.params.items():
@@ -392,7 +389,8 @@ class _Interp:
 
     def run(self, jaxpr, in_specs: list[Spec],
             out_hint: list[Spec] | None = None) -> list[Spec]:
-        from jax import core as jax_core
+        from jax.core import DropVar
+        from jax.extend import core as jax_core
 
         env: dict[Any, Spec] = {}
 
@@ -404,7 +402,7 @@ class _Interp:
             ))
 
         def write(v, spec: Spec):
-            if not isinstance(v, jax_core.DropVar):
+            if not isinstance(v, DropVar):
                 env[v] = spec
 
         for v, s in zip(jaxpr.invars, in_specs):
@@ -923,7 +921,7 @@ class _Interp:
         self._call(eqn, read, write)
 
     def _p_scan(self, eqn, read, write):
-        from jax import core as jax_core
+        from jax.extend import core as jax_core
 
         closed = eqn.params["jaxpr"]
         body = closed.jaxpr if isinstance(
@@ -968,7 +966,7 @@ class _Interp:
             write(v, s)
 
     def _p_while(self, eqn, read, write):
-        from jax import core as jax_core
+        from jax.extend import core as jax_core
 
         body_closed = eqn.params["body_jaxpr"]
         cond_closed = eqn.params["cond_jaxpr"]
@@ -1009,7 +1007,7 @@ class _Interp:
             write(v, s)
 
     def _p_cond(self, eqn, read, write):
-        from jax import core as jax_core
+        from jax.extend import core as jax_core
 
         branches = eqn.params["branches"]
         in_specs = [read(v) for v in eqn.invars[1:]]
@@ -1025,7 +1023,7 @@ class _Interp:
         """Explicit-collective region: walk the body for psum/all_gather/
         ppermute/all_to_all and pass them through verbatim; outputs take
         the region's declared out_specs."""
-        from jax import core as jax_core
+        from jax.extend import core as jax_core
 
         closed = eqn.params.get("jaxpr")
         body = closed.jaxpr if isinstance(
@@ -1061,7 +1059,7 @@ class _Interp:
             write(v, spec)
 
     def _walk_explicit(self, jaxpr):
-        from jax import core as jax_core
+        from jax.extend import core as jax_core
 
         for eqn in jaxpr.eqns:
             prim = eqn.primitive.name

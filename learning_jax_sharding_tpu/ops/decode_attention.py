@@ -138,7 +138,11 @@ def _kernel(
             k_blk = merge(k_blk, kn_ref)
             v_blk = merge(v_blk, vn_ref)
             if quantized:
-                slot2 = slot[..., 0]
+                # A second iota, not ``slot[..., 0]``: Mosaic cannot
+                # squeeze a mask vector (i1 has no vreg bitcast).
+                slot2 = jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_k), 1
+                ) == s_ref[bi, 4]
                 ks_blk = jnp.where(
                     jnp.logical_and(here, slot2), ksn_ref[0], ks_blk
                 )

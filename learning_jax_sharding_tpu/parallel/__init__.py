@@ -1,40 +1,6 @@
 """Parallelism layers: mesh (L1), sharding placement (L2), logical axes (L3),
 explicit collectives, HLO introspection, and multi-host bootstrap."""
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # This runtime predates the public ``jax.shard_map`` (and its
-    # ``check_vma=`` / ``axis_names=`` spellings and the ``lax.pcast``
-    # varying-manual-axes cast). The framework is written against the
-    # public API; bridge to the experimental one here — one gated shim
-    # at the import root every layer goes through, a no-op on newer jax.
-    from jax.experimental.shard_map import shard_map as _experimental_sm
-
-    def _compat_shard_map(
-        f, *, mesh=None, in_specs=None, out_specs=None, check_vma=None,
-        axis_names=None, **kwargs,
-    ):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        if axis_names is not None:
-            # New API names the MANUAL axes; the experimental API names
-            # the complement (``auto``).
-            kwargs.setdefault(
-                "auto", frozenset(mesh.axis_names) - frozenset(axis_names)
-            )
-        return _experimental_sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-
-    _jax.shard_map = _compat_shard_map
-
-if not hasattr(_jax.lax, "pcast"):
-    # ``lax.pcast(x, axes, to="varying")`` is an identity on data — it
-    # only adjusts the new type system's varying-manual-axes annotation,
-    # which the experimental shard_map does not track.
-    _jax.lax.pcast = lambda x, axes, to=None: x
-
 from learning_jax_sharding_tpu.parallel.mesh import (  # noqa: F401
     DATA_AXIS,
     DEFAULT_AXIS_NAMES,

@@ -51,10 +51,6 @@ def force_emulated_devices(n: int, *, platform: str = "cpu") -> None:
     first device access — which lets this live in a function instead of a
     module preamble.
 
-    Note: in this environment a plugin intercepts platform selection, so the
-    ``jax.config`` update (not just the env var) is required to actually land
-    on the emulated CPU backend.
-
     Raises:
         RuntimeError: if the backend is already initialized with a different
             device count (the flag would be silently ignored).
@@ -142,21 +138,10 @@ def build_mesh(
             stacklevel=2,
         )
         devices = devices[:n]
-    try:
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except (ValueError, AssertionError, NotImplementedError) as e:
-        # create_device_mesh can reject odd topologies (e.g. emulated devices
-        # with no coords); a plain reshape is semantically identical but loses
-        # ICI-aware ordering, so on real accelerators that downgrade must be
-        # loud — collectives would silently hop hosts otherwise.
-        if devices[0].platform != "cpu":
-            warnings.warn(
-                f"create_device_mesh failed on {devices[0].platform} ({e}); "
-                "falling back to arbitrary device order — mesh axes may not "
-                "follow ICI topology",
-                stacklevel=2,
-            )
-        dev_array = np.asarray(devices).reshape(shape)
+    # No reshape fallback: on a TPU host a mesh that does not follow the ICI
+    # topology is an error, and for CPU devices create_device_mesh is itself
+    # a plain reshape.
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, tuple(axis_names))
 
 
@@ -206,14 +191,9 @@ def build_hybrid_mesh(
         dev_array = mesh_utils.create_hybrid_device_mesh(
             ici_shape, dcn_shape, devices=devices
         )
-    except (ValueError, AssertionError, NotImplementedError, KeyError) as e:
+    except (ValueError, AssertionError, NotImplementedError, KeyError):
         if devices[0].platform != "cpu":
-            warnings.warn(
-                f"create_hybrid_device_mesh failed on {devices[0].platform} "
-                f"({e}); falling back to index order — mesh axes may not "
-                "follow slice topology",
-                stacklevel=2,
-            )
+            raise
         # Slice-major by index: reshape to (dcn…, ici…), interleave each
         # (dcn_k, ici_k) pair, merge — mesh[k] then iterates slices outer,
         # in-slice devices inner, matching create_hybrid_device_mesh.

@@ -56,18 +56,8 @@ def initialize(
     # jax.devices(), …) before the distributed client exists —
     # jax.distributed.initialize refuses to run once any JAX computation has
     # initialized the runtime (caught by tests/test_distributed_cluster.py).
-    if getattr(jax.distributed, "is_initialized", None) is not None:
-        if jax.distributed.is_initialized():
-            return  # a cluster is already up
-    else:
-        # Older jax has no is_initialized(); the internal global state's
-        # live client is the same fact.
-        from jax._src import distributed as _dist
-
-        if getattr(
-            getattr(_dist, "global_state", None), "client", None
-        ) is not None:
-            return
+    if jax.distributed.is_initialized():
+        return  # a cluster is already up
     kwargs: dict[str, Any] = {}
     if coordinator_address is not None:
         kwargs["coordinator_address"] = coordinator_address

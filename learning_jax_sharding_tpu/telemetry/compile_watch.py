@@ -21,10 +21,10 @@ compilation first-class telemetry, three ways:
   (arXiv 2211.05322) say dominates scaled cost, now machine-readable
   per step.
 
-The monitoring hooks live in ``jax._src.monitoring`` in this JAX
-version; their absence degrades :class:`CompileWatch` to zeros with
-``monitoring_available = False`` instead of failing (no new
-dependencies, no hard version pin).
+The listeners are the public ``jax.monitoring`` ones. With the
+persistent compilation cache on, ``backend_compile`` still fires for a
+program found in the cache (its seconds are then the retrieval), and the
+cache's own hit/miss events are counted beside it.
 """
 
 from __future__ import annotations
@@ -33,31 +33,26 @@ import threading
 from typing import Any, Callable
 
 import jax
+from jax import monitoring
 
 from learning_jax_sharding_tpu.parallel.hlo import (
     collective_counts,
     collective_instructions,
 )
 
-try:  # the monitoring module is private API — gate, don't pin
-    from jax._src import monitoring as _monitoring
-
-    # Both halves must exist: registering without being able to
-    # unregister would make stop() raise after a full bench run.
-    _MON_OK = hasattr(
-        _monitoring, "register_event_duration_secs_listener"
-    ) and hasattr(
-        _monitoring, "_unregister_event_duration_listener_by_callback"
-    )
-except Exception:  # pragma: no cover - import-shape drift
-    _monitoring = None
-    _MON_OK = False
-
-#: Event keys observed from jax 0.4.x; unknown keys are kept under "other".
+#: Duration events; other ``/jax/core/compile`` keys are kept under "other".
 EVENT_KINDS = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
     "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+
+#: Plain events of the persistent compilation cache. JAX records a "miss"
+#: when it WRITES a freshly compiled program to the cache; a program that
+#: compiles faster than the cache's threshold is neither hit nor miss.
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hit",
+    "/jax/compilation_cache/cache_misses": "cache_miss",
 }
 
 
@@ -74,7 +69,6 @@ class CompileWatch:
     def __init__(
         self, registry: Any | None = None, *, recorder: Any | None = None
     ):
-        self.monitoring_available = _MON_OK
         self._lock = threading.Lock()
         self._counts: dict[str, int] = {}
         self._seconds: dict[str, float] = {}
@@ -103,22 +97,30 @@ class CompileWatch:
                 "seconds spent in compile events",
             ).inc(secs)
 
+    def _on_event(self, name: str, **kw) -> None:
+        kind = CACHE_EVENTS.get(name)
+        if kind is not None:
+            with self._lock:
+                self._counts[kind] = self._counts.get(kind, 0) + 1
+
     def start(self) -> "CompileWatch":
         self._active += 1
-        if self._active == 1 and _MON_OK:
-            _monitoring.register_event_duration_secs_listener(
+        if self._active == 1:
+            monitoring.register_event_duration_secs_listener(
                 self._on_duration
             )
+            monitoring.register_event_listener(self._on_event)
         return self
 
     def stop(self) -> None:
         if self._active == 0:
             return
         self._active -= 1
-        if self._active == 0 and _MON_OK:
-            _monitoring._unregister_event_duration_listener_by_callback(
+        if self._active == 0:
+            monitoring.unregister_event_duration_listener(
                 self._on_duration
             )
+            monitoring.unregister_event_listener(self._on_event)
 
     def __enter__(self) -> "CompileWatch":
         return self.start()
@@ -136,11 +138,15 @@ class CompileWatch:
 
     def report(self) -> dict:
         """``{kind: n, kind_seconds: s, ...}`` for trace / lower /
-        backend_compile, plus availability."""
-        out: dict = {"monitoring_available": self.monitoring_available}
+        backend_compile, plus the persistent cache's hits and misses.
+        ``monitoring_available`` is always True: the listeners are public
+        API, and a JAX without them fails at import, not with zeros."""
+        out: dict = {"monitoring_available": True}
         for kind in ("trace", "lower", "backend_compile", "other"):
             out[f"{kind}s"] = self._counts.get(kind, 0)
             out[f"{kind}_seconds"] = self._seconds.get(kind, 0.0)
+        out["cache_hits"] = self._counts.get("cache_hit", 0)
+        out["cache_misses"] = self._counts.get("cache_miss", 0)
         return out
 
 
@@ -203,8 +209,6 @@ def _cost_analysis_dict(compiled) -> dict:
         ca = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(ca, (list, tuple)):   # some backends: one dict per device
-        ca = ca[0] if ca else {}
     return dict(ca) if ca else {}
 
 

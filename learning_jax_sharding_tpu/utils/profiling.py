@@ -32,18 +32,10 @@ def trace(logdir: str | os.PathLike, *, host_tracer_level: int = 2) -> Iterator[
     at ``host_tracer_level``, and all :func:`annotate` spans.
     """
     os.makedirs(os.fspath(logdir), exist_ok=True)
-    # ProfileOptions landed in newer jax; this runtime (0.4.x) captures
-    # host activity by default — gate rather than pin the version.
-    if hasattr(jax.profiler, "ProfileOptions"):
-        options = jax.profiler.ProfileOptions()
-        options.host_tracer_level = host_tracer_level
-        with jax.profiler.trace(
-            os.fspath(logdir), profiler_options=options
-        ):
-            yield
-    else:
-        with jax.profiler.trace(os.fspath(logdir)):
-            yield
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = host_tracer_level
+    with jax.profiler.trace(os.fspath(logdir), profiler_options=options):
+        yield
 
 
 def annotate(name: str) -> jax.profiler.TraceAnnotation:

@@ -1,0 +1,200 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+
+No chip is attached here: the TPU compiler that ships with jaxlib compiles
+for a topology that is described, not present (``v5e:2x2``). Interpret-mode
+tests cannot see what Mosaic refuses — a misaligned slice, an unsupported
+vector cast, too much VMEM — so each kernel the trainer and the engine
+dispatch is compiled here with ``interpret=False`` at the widths the chip
+runs, and must come out as a ``tpu_custom_call``. Nothing executes: a
+compile that passes is not a chip run (``chip_smoke.py`` is).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library, and every xdist worker imports
+this file.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from learning_jax_sharding_tpu.ops.decode_attention import decode_attention
+from learning_jax_sharding_tpu.ops.flash_attention import flash_attention
+from learning_jax_sharding_tpu.ops.fused_norm import fused_residual_norm
+from learning_jax_sharding_tpu.ops.int4_ff import int4_ff
+from learning_jax_sharding_tpu.ops.int4_matmul import int4_matmul
+
+
+BF16, F32, I8, I32, U8 = (
+    jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32, jnp.uint8
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler / library held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A described-device compile can be written to the persistent cache
+    # but never read back without a chip; keep it off around these tests.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiles_to_kernel(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip and return how many Mosaic
+    kernels the optimized program holds (0 = it fell back to plain XLA)."""
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in shapes
+    ]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count("tpu_custom_call")
+
+
+@pytest.mark.parametrize(
+    "b,s,n,n_kv,h,window",
+    [
+        (8, 1024, 12, 12, 64, None),     # the 125M train step's shape
+        (2, 4096, 16, 16, 128, None),    # long context, head_dim 128
+        (2, 4096, 16, 4, 128, None),     # GQA 16/4
+        (2, 4096, 16, 16, 128, 1024),    # sliding window
+    ],
+    ids=["125m-hd64", "s4096-hd128", "gqa16-4", "window1024"],
+)
+@pytest.mark.parametrize("grads", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention(one_chip, b, s, n, n_kv, h, window, grads):
+    def fwd(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, window=window, interpret=False
+        )
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grads else fwd
+    q = ((b, s, n, h), BF16)
+    kv = ((b, s, n_kv, h), BF16)
+    # fwd is one kernel; the backward adds the dkv and dq kernels.
+    assert _compiles_to_kernel(fn, one_chip, q, kv, kv) >= (3 if grads else 1)
+
+
+# 125M serving shapes: 12 heads x 64, 8 rows, 1024-token rows.
+_B, _N, _H, _L = 8, 12, 64, 1024
+
+
+def _decode_case(*, s=1, page=None, fold=False, int8=False, window=None):
+    """(fn, shapes) for one decode_attention variant at the 125M widths."""
+    cache_dt = I8 if int8 else BF16
+    if page is None:
+        cache = ((_B, _N, _L, _H), cache_dt)
+        scales = ((_B, _N, _L), F32)
+    else:
+        pool = _B * (_L // page) + 1
+        cache = ((pool, _N, page, _H), cache_dt)
+        scales = ((pool, _N, page), F32)
+    names = ["q", "k_cache", "v_cache", "index"]
+    shapes = [((_B, s, _N, _H), BF16), cache, cache, ((_B,), I32)]
+    if int8:
+        names += ["k_scale", "v_scale"]
+        shapes += [scales, scales]
+    if fold:
+        names += ["k_new", "v_new"]
+        shapes += [((_B, _N, 1, _H), cache_dt)] * 2
+        if int8:
+            names += ["ks_new", "vs_new"]
+            shapes += [((_B, _N, 1), F32)] * 2
+        names += ["write_enable"]
+        shapes += [((_B,), I32)]
+    if page is not None:
+        names += ["block_table"]
+        shapes += [((_B, _L // page), I32)]
+
+    def fn(*args):
+        kw = dict(zip(names, args))
+        return decode_attention(
+            kw.pop("q"), kw.pop("k_cache"), kw.pop("v_cache"),
+            kw.pop("index"), window=window, interpret=False, **kw,
+        )
+
+    return fn, shapes
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        dict(),
+        dict(page=16),
+        dict(page=64),
+        dict(page=64, fold=True),
+        dict(page=64, s=128),
+        dict(int8=True),
+        dict(page=64, int8=True, fold=True),
+        dict(fold=True, int8=True),
+        dict(window=256),
+    ],
+    ids=[
+        "per-row", "paged16", "paged64", "paged64-fold", "paged64-chunk128",
+        "int8", "paged64-int8-fold", "int8-fold", "window256",
+    ],
+)
+def test_decode_attention(one_chip, case):
+    fn, shapes = _decode_case(**case)
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+def test_fused_residual_norm_fwd_bwd(one_chip):
+    def loss(x, resid, gamma, beta):
+        y, r = fused_residual_norm(x, resid, gamma, beta, interpret=False)
+        return (y.astype(jnp.float32) * r.astype(jnp.float32)).sum()
+
+    x = ((8, 1024, 768), BF16)
+    vec = ((768,), F32)
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3))
+    assert _compiles_to_kernel(fn, one_chip, x, x, vec, vec) >= 2
+
+
+_K, _NOUT, _G = 2048, 8192, 128
+
+
+@pytest.mark.parametrize("w4a8", [False, True], ids=["bf16", "w4a8"])
+def test_int4_matmul(one_chip, w4a8):
+    def fn(x, q4, scale):
+        return int4_matmul(
+            x, q4, scale, group=_G, w4a8=w4a8, interpret=False
+        )
+
+    shapes = [
+        ((8, _K), BF16), ((_K // 2, _NOUT), U8), ((_K // _G, _NOUT), F32),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+def test_int4_ff(one_chip):
+    def fn(x, q_up, s_up, q_dn, s_dn):
+        return int4_ff(x, q_up, s_up, q_dn, s_dn, group=_G, interpret=False)
+
+    shapes = [
+        ((8, _K), BF16),
+        ((_K // 2, _NOUT), U8), ((_K // _G, _NOUT), F32),
+        ((_NOUT // 2, _K), U8), ((_NOUT // _G, _K), F32),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
