@@ -38,11 +38,14 @@ structure matters:
   (``step`` / ``*dispatch*`` / ``_admit`` / ``_sweep_deadlines`` /
   ``_try_commit_swap`` / ``export_kv`` / ``ingest_kv``) that is NOT
   lexically inside a goodput-ledger frame (``with ...measure(...)`` /
-  ``with ..._led_device(...)``): time it spends escapes the
+  ``with ..._led_device(...)`` / ``_led_h2d()`` / ``_led_consume()``):
+  time it spends escapes the
   Σ buckets == wall reconciliation invariant
   (``telemetry/ledger.py``) — the static face of the accounting
   identity tier-1 gates at runtime. New engine code paths must open (or
-  sit inside) a bucket frame.
+  sit inside) a bucket frame; every frame the engine opens is also a
+  named span (``engine.h2d``, ``engine.consume``, ...; the table is
+  beside ``ContinuousEngine._led_device``).
 * ``unbounded-host-buffer`` — a ``.append(...)`` of a device-array
   value (a ``jnp.``/``jax.device_put``/``jax.random.`` result, direct
   or via a local name) onto a container inside a loop body of an
@@ -200,12 +203,15 @@ def _is_ledger_frame(item: ast.withitem) -> bool:
     Matches ``<anything>.measure(...)`` (GoodputLedger.measure — the
     lint deliberately also accepts utils.bench.measure, which times a
     region and is never an engine phase) and the engine's
-    ``self._led_device(...)`` compile-steal helper."""
+    ``self._led_*(...)`` helpers: ``_led_device`` (compile-steal) and the
+    named host frames ``_led_h2d`` / ``_led_consume``."""
     expr = item.context_expr
     if not isinstance(expr, ast.Call):
         return False
     name = _dotted(expr.func)
-    return name.endswith(".measure") or name.endswith("_led_device")
+    return name.endswith(".measure") or (
+        name.rpartition(".")[2].startswith("_led_")
+    )
 
 
 #: Fleet scale actions the ``unguarded-scale-decision`` rule polices:
@@ -369,7 +375,11 @@ class _Visitor(ast.NodeVisitor):
                 "goodput-ledger frame — its wall-clock escapes the "
                 "ledger's Σ buckets == wall reconciliation (gated in "
                 "tier-1); wrap it in `with self.ledger.measure(...)`"
-                " or `with self._led_device(...)`",
+                " or `with self._led_device(...)`, or put it inside "
+                "the frame it belongs to: `self._led_h2d()` (span "
+                "`engine.h2d`: host-to-device pushes ahead of an "
+                "enqueue) or `self._led_consume()` (`engine.consume`: "
+                "the loop after a readback)",
             ))
 
     def visit_Call(self, node: ast.Call):

@@ -75,6 +75,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import hashlib
 import time
 from collections import deque
@@ -121,7 +122,6 @@ from learning_jax_sharding_tpu.telemetry import (
     Tracer,
 )
 from learning_jax_sharding_tpu.telemetry.compile_watch import cache_size
-from learning_jax_sharding_tpu.utils.profiling import annotate
 
 #: Dispatch failures the engine RECOVERS from (quarantine/requeue)
 #: instead of propagating: the chaos harness's injected faults and the
@@ -135,6 +135,29 @@ _RECOVERABLE_DISPATCH = (InjectedFault, FloatingPointError)
 #: counters (cache_index, position, block_table) stay: a retained prefix
 #: page carries K/V only; the mapping is host state.
 _PAGE_LEAF_KEYS = ("cached_key", "cached_value", "key_scale", "value_scale")
+
+
+def _dispatch_span(kind):
+    """Wrap a dispatch method in the tracer span ``engine.<kind>``. The
+    event is kept only when the method dispatched a program of that
+    kind: not when nothing ran, a fault cut it short, or
+    ``_mixed_dispatch`` fell through to a split program (which wrote its
+    own span)."""
+    name = f"engine.{kind}"
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def dispatch(self, params, d_params, retired):
+            with self.tracer.span(name, keep=False) as sp:
+                ran = fn(self, params, d_params, retired)
+                if ran is True or ran == kind:
+                    sp.keep = True
+                    sp.args["retired"] = len(retired)
+            return ran
+
+        return dispatch
+
+    return deco
 
 
 class AdmissionError(RuntimeError):
@@ -1867,6 +1890,40 @@ class ContinuousEngine:
         self._h_swap_stall = r.histogram(
             "engine_swap_stall_seconds",
             "stage-to-commit latency of weight swaps (drain or preempt)")
+        # Host phases of a step, at the ledger frames' funnel (all
+        # cumulative; the frames' span names are tabled at _led_device).
+        self._c_step_s = r.counter(
+            "engine_step_seconds_total",
+            "wall seconds inside step() (the ledger's covered seconds of "
+            "its step frames): the denominator of the host-share metrics")
+        self._c_enqueue_s = r.counter(
+            "engine_enqueue_seconds_total",
+            "host seconds inside jitted calls (argument transfer, output "
+            "allocation, launch)")
+        self._c_wait_s = r.counter(
+            "engine_wait_seconds_total",
+            "seconds in the blocking readbacks that drain a dispatched "
+            "program")
+        self._c_h2d_s = r.counter(
+            "engine_h2d_seconds_total",
+            "host seconds pushing block tables and step inputs to the "
+            "device ahead of an enqueue")
+        self._c_table_leaves = r.counter(
+            "engine_table_push_leaves_total",
+            "block_table arrays pushed from the host (one per layer per "
+            "push)")
+        self._c_prefill_tok = r.counter(
+            "engine_prefill_tokens_total",
+            "prompt tokens consumed by refill dispatches")
+        self._c_decode_steps = r.counter(
+            "engine_decode_steps_total",
+            "decode row-steps advanced (tokens emitted after the first)")
+        self._c_decode_ctx = r.counter(
+            "engine_decode_context_tokens_total",
+            "sum over decode row-steps of the row's cache length at that "
+            "step: x 2 x layers x kv_heads x head_dim x bytes is the K,V "
+            "a decode kernel had to read")
+        self._span_names: dict[tuple[str, str], str] = {}  # (phase, family)
         # Goodput ledger (round 14): exhaustive wall-clock attribution
         # for the engine loop. step() is the top-level frame (its
         # unclaimed remainder is host scheduling, bucket "sched");
@@ -1875,7 +1932,20 @@ class ContinuousEngine:
         # recovery/telemetry paths open their own frames, and idle is
         # derived — reconcile() must hold after any run (tier-1 gated).
         # Meters into this registry as ledger_seconds_total{bucket=...}.
-        self.ledger = GoodputLedger(registry=r)
+        # Every frame is also a span on this engine's tracer, and the
+        # frames carry the empty-device clock
+        # (engine_device_starved_seconds_total).
+        self.ledger = GoodputLedger(registry=r, tracer=self.tracer)
+        # Programs dispatched whose results the host has not read: the
+        # empty-device clock runs only while this is 0.
+        self._in_flight = 0
+        self._step_n = 0
+        # What the last engine.dispatch event has accounted for.
+        self._starved_at_enqueue = 0.0
+        self._last_family = None
+        self._compiled = False
+        self._booked = dict.fromkeys(self._DISPATCH_DELTAS, 0.0)
+        self._booked["starved_s"] = 0.0
         # Request-scoped trace sink (telemetry.tracecontext.TraceStore).
         # The fleet router attaches its store (and the replica name) to
         # every replica; a solo driver may attach its own — legs are
@@ -1925,8 +1995,65 @@ class ContinuousEngine:
             self._fam_cache[id(fn)] = fam
         return fam
 
+    # Every goodput-ledger frame the engine opens is a span: a tracer
+    # event and a ``jax.profiler.TraceAnnotation`` of the same name, so
+    # each instant of ``step()`` lies in exactly one innermost span. A
+    # span is finer than its bucket, never a new bucket. The frame's
+    # ``label`` is its series of the empty-device clock
+    # (``engine_device_starved_seconds_total{span=...}``). Pinned by
+    # ``tests/test_engine_spans.py``; ``scripts/engine_breakdown.py``
+    # reads them back from a flight-recorder bundle and a capture.
+    #
+    # span                      bucket      label       wraps
+    # engine.step               sched       sched       one step(); annotation
+    #                                                   arguments step, ts_us
+    # engine.admission          admission   admission   deadline sweep, _admit
+    # engine.page_alloc         page_alloc  page_alloc  _ensure when it claims
+    #                                                   pages (no ring event)
+    # engine.h2d                sched       h2d         the block-table push
+    #                                                   (argument leaves), the
+    #                                                   jnp.asarray of inputs
+    # engine.enqueue.<family>   device      enqueue     the jitted call alone
+    #                           / compile
+    # engine.wait.<family>      device      wait        the blocking readback
+    # engine.consume            sched       consume     token / first-token /
+    #                                                   retire loop after it
+    # engine.plan               sched       plan        the horizon planner
+    # engine.telemetry          telemetry   telemetry   counters, recorder, SLO
+    # engine.recovery / engine.kv_handoff / engine.swap: their bucket
+    #
+    # ``<family>`` is ``_program_family``'s name. ``engine.refill`` /
+    # ``engine.decode`` / ``engine.mixed`` (``_dispatch_span``) are tracer
+    # spans around a whole dispatch, not frames: their own time is the
+    # step's.
+
+    def _led_h2d(self, **args):
+        """Ledger frame ``engine.h2d``: host-to-device pushes ahead of
+        an enqueue (block tables, step inputs)."""
+        return self.ledger.measure(
+            "sched", span="engine.h2d", label="h2d", counter=self._c_h2d_s,
+            **args,
+        )
+
+    def _led_consume(self):
+        """Ledger frame ``engine.consume``: the token / first-token /
+        retire loop after a readback (its telemetry frames nest)."""
+        return self.ledger.measure(
+            "sched", span="engine.consume", label="consume"
+        )
+
+    #: ``engine.dispatch`` event field -> the cumulative counter whose
+    #: growth since the previous event it reports.
+    _DISPATCH_DELTAS = {
+        "enqueue_s": "_c_enqueue_s", "wait_s": "_c_wait_s",
+        "h2d_s": "_c_h2d_s", "table_leaves": "_c_table_leaves",
+        "prefill_tokens": "_c_prefill_tok",
+        "decode_steps": "_c_decode_steps",
+        "context_tokens": "_c_decode_ctx",
+    }
+
     @contextlib.contextmanager
-    def _led_device(self, fn=None, family=None):
+    def _led_device(self, fn=None, family=None, in_flight=0):
         """Ledger frame for a dispatch or blocking readback: books to
         the ``device`` bucket (tagged with ``fn``'s program family for
         :meth:`overlap_report`), unless ``fn``'s executable cache GREW
@@ -1939,18 +2066,38 @@ class ContinuousEngine:
         microseconds, so the blocking readback that drains a program's
         in-flight seconds must carry the SAME family tag or the
         overlap_report attribution would book the device time as
-        unattributed."""
+        unattributed.
+
+        The two forms are the spans ``engine.enqueue.<family>`` (the
+        jitted call alone) and ``engine.wait.<family>``. ``in_flight``
+        is how many dispatched programs are still unread once this
+        readback returns (the device runs them in order); at 0 the chip
+        is empty until the next enqueue returns."""
         before = cache_size(fn) if fn is not None else None
+        fam = family if family is not None else self._program_family(fn)
+        phase = "enqueue" if fn is not None else "wait"
+        span = self._span_names.get((phase, fam))
+        if span is None:
+            span = self._span_names[phase, fam] = f"engine.{phase}.{fam}"
         with self.ledger.measure(
-            "device",
-            family=family if family is not None
-            else self._program_family(fn),
+            "device", family=fam, span=span, label=phase,
+            counter=self._c_enqueue_s if fn is not None else self._c_wait_s,
         ) as f:
             yield f
             if before is not None:
                 after = cache_size(fn)
                 if after is not None and (before is None or after > before):
                     f.rebucket("compile")
+                    self._compiled = True
+            if fn is not None:
+                self.ledger.device_busy()
+                self._starved_at_enqueue = self.ledger.starved_s
+                self._in_flight += 1
+                self._last_family = fam
+            else:
+                self._in_flight = in_flight
+                if not in_flight:
+                    self.ledger.device_empty()
 
     def _win_delta(self, counter):
         # The stats window (reset_stats → snapshot) over a cumulative
@@ -1994,6 +2141,7 @@ class ContinuousEngine:
         t_cap = self._cfg.max_seq_len // self._page_size
         self._table_np = np.zeros((b, t_cap), np.int32)
         self._tables_dirty = True
+        self._table_leaves = None      # block_table arrays in the cache tree
         # Prefix-cache state (the metrics registry is the separate,
         # public ``self.registry``): page-aligned token-prefix bytes →
         # the page holding that prefix's LAST page of K/V; refcounts for pages
@@ -2193,7 +2341,9 @@ class ContinuousEngine:
         need = -(-int(tokens_through) // self._page_size)
         if len(self._held[slot]) >= need:
             return   # steady-state decode mostly allocates nothing
-        with self.ledger.measure("page_alloc"):
+        with self.ledger.measure(
+            "page_alloc", span="engine.page_alloc", ring=False
+        ):
             while len(self._held[slot]) < need:
                 p = self._take_page()
                 self._table_np[slot, len(self._held[slot])] = p
@@ -2265,26 +2415,42 @@ class ContinuousEngine:
         self._tables_dirty = True
         self._update_high_water()
 
-    def _set_tables(self, cache):
+    def _set_tables(self, cache, frame=True):
         # Push the host tables into every layer's block_table leaf
         # (target AND draft trees; the draft's table may be narrower —
         # same prefix, same page ids). Skipped entirely when no
         # allocation changed since the last push — the steady-state
-        # decode loop mostly doesn't allocate.
+        # decode loop mostly doesn't allocate. The push is the frame
+        # ``engine.h2d``; ``frame=False`` is for a caller outside step().
         if not self._tables_dirty:
             return cache
         self._tables_dirty = False
         table_np = self._table_np
 
+        def is_table(path):
+            return getattr(path[-1], "key", None) == "block_table"
+
         def leaf(path, x):
-            if getattr(path[-1], "key", None) == "block_table":
+            if is_table(path):
                 # .copy(): the full-width slice is a contiguous view and
                 # jnp.asarray may alias it zero-copy — the host table is
                 # mutated in place by later allocations/releases.
                 return jnp.asarray(table_np[:, : x.shape[1]].copy())
             return x
 
-        return jax.tree_util.tree_map_with_path(leaf, cache)
+        if self._table_leaves is None:
+            # One array per layer (target and draft): counted once, the
+            # cache's tree never changes shape.
+            self._table_leaves = sum(
+                is_table(path)
+                for path, _ in jax.tree_util.tree_flatten_with_path(cache)[0]
+            )
+        self._c_table_leaves.inc(self._table_leaves)
+        with (
+            self._led_h2d(leaves=self._table_leaves) if frame
+            else contextlib.nullcontext()
+        ):
+            return jax.tree_util.tree_map_with_path(leaf, cache)
 
     # --- request lifecycle -------------------------------------------------
 
@@ -2435,7 +2601,7 @@ class ContinuousEngine:
             return out, int(stats["bytes"])
 
         t0 = time.perf_counter()
-        with self.ledger.measure("swap"):
+        with self.ledger.measure("swap", span="engine.swap", busy=True):
             try:
                 chaos_hook("engine.swap_stage", version=version, mode=mode)
                 cast = self._maybe_cast(new_params)
@@ -2483,7 +2649,7 @@ class ContinuousEngine:
         s = self._staged_swap
         if s is None or any(q >= 0 for q in self._req):
             return False
-        with self.ledger.measure("swap"):
+        with self.ledger.measure("swap", span="engine.swap", busy=True):
             if self._paged:
                 # Old-params K/V must not seed new-params requests; slots
                 # are empty, so every retained page is reference-free.
@@ -2726,7 +2892,8 @@ class ContinuousEngine:
             )
             _, self._cache = self._first_refill_fn(*first_args)
             if self._paged:
-                self._cache = self._set_tables(self._cache)
+                # Outside step(), which is what the ledger covers.
+                self._cache = self._set_tables(self._cache, frame=False)
         self.cache_creations += 1
         self._c_creations.inc()
         self.recorder.record("engine.cache_create", n=self.cache_creations)
@@ -2800,7 +2967,9 @@ class ContinuousEngine:
             )
         if self._cache is None:
             raise RuntimeError("export_kv: the engine holds no cache")
-        with self.ledger.measure("kv_handoff"):
+        with self.ledger.measure(
+            "kv_handoff", span="engine.kv_handoff", busy=True
+        ):
             slot_j = jnp.int32(slot)
             with activate(self._mesh, self._rules):
                 rows = self._kv_export_fn(self._cache, slot_j)
@@ -2839,7 +3008,9 @@ class ContinuousEngine:
         ``RuntimeError`` when no slot is free (the router holds the
         handoff until one is)."""
         self._check_handoff_supported("ingest_kv")
-        with self.ledger.measure("kv_handoff"):
+        with self.ledger.measure(
+            "kv_handoff", span="engine.kv_handoff", busy=True
+        ):
             p = np.asarray(prompt, np.int32).reshape(-1)
             self._validate_prompt(p)
             if (
@@ -3053,7 +3224,9 @@ class ContinuousEngine:
             plan_transfer,
         )
 
-        with self.ledger.measure("kv_handoff"):
+        with self.ledger.measure(
+            "kv_handoff", span="engine.kv_handoff", busy=True
+        ):
             pid_j = jnp.int32(pid)
             with activate(self._mesh, self._rules):
                 dev_rows = self._kv_page_spill_fn(self._cache, pid_j)
@@ -3132,8 +3305,12 @@ class ContinuousEngine:
             plan_transfer,
         )
 
-        with self.ledger.measure("kv_handoff"):
-            with self.ledger.measure("page_alloc"):
+        with self.ledger.measure(
+            "kv_handoff", span="engine.kv_handoff", busy=True
+        ):
+            with self.ledger.measure(
+                "page_alloc", span="engine.page_alloc", ring=False
+            ):
                 pid = self._take_page()
             codec = self._kv_codec
             ckey = (
@@ -3216,7 +3393,7 @@ class ContinuousEngine:
         # percentiles in latency_stats() stay sample-based (pinned). All
         # of this booking is the observability tax — it lands in the
         # ledger's telemetry bucket so perf_goodput.py can pin it.
-        with self.ledger.measure("telemetry"):
+        with self.ledger.measure("telemetry", span="engine.telemetry"):
             self._c_finished.inc()
             self._c_tokens.inc(n)
             self._h_wait.observe(rec["queue_wait"])
@@ -3325,7 +3502,7 @@ class ContinuousEngine:
         r.finish_t = now
         if tokens is not None:
             r.tokens = tokens
-        with self.ledger.measure("telemetry"):
+        with self.ledger.measure("telemetry", span="engine.telemetry"):
             self._c_req_failed.inc()
             if status == "rerouted":
                 self._c_rerouted.inc()
@@ -3397,7 +3574,7 @@ class ContinuousEngine:
             ):
                 self._any_req_deadline = False
                 return
-        with self.ledger.measure("admission"):
+        with self.ledger.measure("admission", span="engine.admission"):
             now = time.perf_counter()
 
             def expired(r):
@@ -3438,7 +3615,7 @@ class ContinuousEngine:
         ``_admit``) so the poison trips alone instead of striking its
         batchmates to death. The engine's device state needs no repair:
         re-admission resets every per-row counter."""
-        with self.ledger.measure("recovery"):
+        with self.ledger.measure("recovery", span="engine.recovery"):
             self._c_dispatch_faults.inc()
             self.recorder.record(
                 "engine.dispatch_fault",
@@ -3464,8 +3641,10 @@ class ContinuousEngine:
         # Append a decode dispatch's tokens for one slot; retire at
         # EOS or budget — ONE copy of the retirement rule for both
         # engine modes.
-        for t in tokens:
-            self._out[slot].append(int(t))
+        out = self._out[slot]
+        ctx = len(out)      # prompt + emitted: the cache length at the
+        for t in tokens:    # step that emits the row's next token
+            out.append(int(t))
             self._emitted[slot] += 1
             self._tok[slot] = int(t)
             self._ttimes[slot].append(now)
@@ -3474,6 +3653,9 @@ class ContinuousEngine:
             ):
                 self._retire(slot, now, retired)
                 break
+        k = len(out) - ctx
+        self._c_decode_steps.inc(k)
+        self._c_decode_ctx.inc(k * ctx + k * (k - 1) // 2)
 
     def _rid_arr(self):
         return jnp.asarray(np.maximum(self._req, 0), jnp.int32)
@@ -3553,7 +3735,7 @@ class ContinuousEngine:
             self._g_queue.set(len(self._queue))
             return
         b = self._b
-        with self.ledger.measure("admission"):
+        with self.ledger.measure("admission", span="engine.admission"):
             now = time.perf_counter()
             for slot in range(b):
                 if self._req[slot] < 0 and self._queue:
@@ -3665,6 +3847,7 @@ class ContinuousEngine:
                                     )
             self._g_queue.set(len(self._queue))
 
+    @_dispatch_span("refill")
     def _refill_dispatch(self, params, d_params, retired):
         # One refill chunk for every slot with pending prompt tokens
         # (fresh or continuing); decoding rows ride along with length 0.
@@ -3685,7 +3868,7 @@ class ContinuousEngine:
                     lengths[slot] = n
             if not lengths.any():
                 break
-            with self.ledger.measure("recovery"):
+            with self.ledger.measure("recovery", span="engine.recovery"):
                 # An armed chaos seam spends its injected delay (hang,
                 # slow) HERE — fault time is recovery, never device.
                 chaos_hook(
@@ -3740,13 +3923,12 @@ class ContinuousEngine:
                     self._last_first_refill_args = lambda: first_args
                 self._cache = self._set_tables(self._cache)
             if self._cache is None:
-                first_args = (
-                    params, d_params, jnp.asarray(chunk),
-                    jnp.asarray(lengths), self._rid_arr(), self.rng,
-                )
-                with self._led_device(self._first_refill_fn), annotate(
-                    "engine.first_refill"
-                ):
+                with self._led_h2d():
+                    first_args = (
+                        params, d_params, jnp.asarray(chunk),
+                        jnp.asarray(lengths), self._rid_arr(), self.rng,
+                    )
+                with self._led_device(self._first_refill_fn):
                     tok_new, self._cache = self._first_refill_fn(*first_args)
                 seg_fam = "first_refill"
                 self.cache_creations += 1
@@ -3763,14 +3945,13 @@ class ContinuousEngine:
                 # aliased clear would erase the admission resets
                 # mid-flight (observed as flaky stale-counter corruption
                 # on CPU).
-                chunk_d = jnp.asarray(chunk)
-                lengths_d = jnp.asarray(lengths)
-                reset_d = jnp.asarray(self._needs_reset.copy())
-                reset_to_d = jnp.asarray(self._reset_to.copy())
-                rid_d = self._rid_arr()
-                with self._led_device(self._refill_step_fn), annotate(
-                    "engine.refill_step"
-                ):
+                with self._led_h2d():
+                    chunk_d = jnp.asarray(chunk)
+                    lengths_d = jnp.asarray(lengths)
+                    reset_d = jnp.asarray(self._needs_reset.copy())
+                    reset_to_d = jnp.asarray(self._reset_to.copy())
+                    rid_d = self._rid_arr()
+                with self._led_device(self._refill_step_fn):
                     tok_new, self._cache = self._refill_step_fn(
                         params, d_params, self._cache, chunk_d, lengths_d,
                         reset_d, reset_to_d, rid_d, self.rng,
@@ -3800,32 +3981,38 @@ class ContinuousEngine:
                     ):
                         seg_completes.append(slot)
             segs.append((tok_new, seg_completes, seg_fam))
+            self._c_prefill_tok.inc(int(lengths.sum()))
         if not segs:
             return False
-        for tok_new, seg_completes, seg_fam in segs:
-            with self._led_device(family=seg_fam):
+        for i, (tok_new, seg_completes, seg_fam) in enumerate(segs):
+            with self._led_device(
+                family=seg_fam, in_flight=len(segs) - 1 - i
+            ):
                 tok_new = np.asarray(tok_new)   # each segment's own sync
             now = time.perf_counter()       # its host-visibility time
-            for slot in seg_completes:
-                # Prompt complete: its first token came from this
-                # chunk's last valid position.
-                t = int(tok_new[slot])
-                self._out[slot].append(t)
-                self._emitted[slot] = 1
-                self._tok[slot] = t
-                self._slot_req[slot].first_token_t = now
-                self._ttimes[slot].append(now)
-                self.tracer.instant(
-                    "request.first_token", rid=self._req[slot]
-                )
-                if (self._eos is not None and t == self._eos) or (
-                    self._max_new == 1
-                ):
-                    self._retire(slot, now, retired)
-                else:
-                    self._active[slot] = True
+            with self._led_consume():
+                self._first_tokens(seg_completes, tok_new, now, retired)
         return True
 
+    def _first_tokens(self, slots, tok_new, now, retired):
+        # Prompt complete: each slot's first token came from its last
+        # refill chunk's last valid position (``tok_new[slot]``).
+        for slot in slots:
+            t = int(tok_new[slot])
+            self._out[slot].append(t)
+            self._emitted[slot] = 1
+            self._tok[slot] = t
+            self._slot_req[slot].first_token_t = now
+            self._ttimes[slot].append(now)
+            self.tracer.instant("request.first_token", rid=self._req[slot])
+            if (self._eos is not None and t == self._eos) or (
+                self._max_new == 1
+            ):
+                self._retire(slot, now, retired)
+            else:
+                self._active[slot] = True
+
+    @_dispatch_span("decode")
     def _decode_dispatch(self, params, d_params, retired):
         # Up to ``decode_chain`` decode BLOCKS dispatched back-to-back —
         # the carries (tok/active/remaining[/pos]) flow device-to-device
@@ -3866,7 +4053,7 @@ class ContinuousEngine:
             (self._num_draft + 1) if spec else 1
         )
         chain = min(self.decode_chain, -(-worst // per_block))
-        with self.ledger.measure("recovery"):
+        with self.ledger.measure("recovery", span="engine.recovery"):
             # Armed chaos delay (hang/slow) books as recovery, not
             # device — the attribution the chaos tests pin.
             chaos_hook(
@@ -3915,28 +4102,28 @@ class ContinuousEngine:
             # to it would dispatch fully-frozen no-op blocks.
             worst = int(remaining[self._active].max())
             chain = min(self.decode_chain, -(-worst // per_block))
-        tok_d = jnp.asarray(self._tok)
-        active_d = jnp.asarray(self._active.astype(np.int32))
-        remaining_d = jnp.asarray(remaining)
-        rid = self._rid_arr()
-        if spec:
-            # Each row's current cache index: prompt + emitted - 1 (its
-            # pending token is not yet in the cache).
-            pos_d = jnp.asarray(
-                np.asarray(
-                    [
-                        max(0, p + e - 1)
-                        for p, e in zip(self._plen, self._emitted)
-                    ],
-                    np.int32,
+        with self._led_h2d():
+            tok_d = jnp.asarray(self._tok)
+            active_d = jnp.asarray(self._active.astype(np.int32))
+            remaining_d = jnp.asarray(remaining)
+            rid = self._rid_arr()
+            if spec:
+                # Each row's current cache index: prompt + emitted - 1
+                # (its pending token is not yet in the cache).
+                pos_d = jnp.asarray(
+                    np.asarray(
+                        [
+                            max(0, p + e - 1)
+                            for p, e in zip(self._plen, self._emitted)
+                        ],
+                        np.int32,
+                    )
                 )
-            )
+        if spec:
             t_cache, d_cache = self._cache
             segs = []
             for _ in range(chain):
-                with self._led_device(self._decode_block_spec_fn), annotate(
-                    "engine.decode_block_spec"
-                ):
+                with self._led_device(self._decode_block_spec_fn):
                     (buffer, counts, acc, prop, tok_d, pos_d, active_d,
                      remaining_d, t_cache, d_cache) = (
                         self._decode_block_spec_fn(
@@ -3957,18 +4144,19 @@ class ContinuousEngine:
                 ]
             now = time.perf_counter()
             was_active = self._active.copy()
-            for buffer, counts, acc, prop in segs:
-                self._c_spec_acc.inc(int(acc.sum()))
-                self._c_spec_prop.inc(int(prop.sum()))
-                for slot in range(b):
-                    # Consume segments chronologically; a slot retired in
-                    # an earlier segment (req < 0) emits nothing real in
-                    # later ones — its lane froze on device.
-                    if was_active[slot] and self._req[slot] >= 0:
-                        self._consume(
-                            slot, buffer[slot, : counts[slot]].tolist(),
-                            now, retired,
-                        )
+            with self._led_consume():
+                for buffer, counts, acc, prop in segs:
+                    self._c_spec_acc.inc(int(acc.sum()))
+                    self._c_spec_prop.inc(int(prop.sum()))
+                    for slot in range(b):
+                        # Consume segments chronologically; a slot retired
+                        # in an earlier segment (req < 0) emits nothing
+                        # real in later ones — its lane froze on device.
+                        if was_active[slot] and self._req[slot] >= 0:
+                            self._consume(
+                                slot, buffer[slot, : counts[slot]].tolist(),
+                                now, retired,
+                            )
         else:
             if self._speculative:
                 # Degraded: advance the TARGET cache only; the idle
@@ -3978,9 +4166,7 @@ class ContinuousEngine:
                 cache, d_cache = self._cache, None
             segs = []
             for _ in range(chain):
-                with self._led_device(self._decode_block_fn), annotate(
-                    "engine.decode_block"
-                ):
+                with self._led_device(self._decode_block_fn):
                     toks, active_d, remaining_d, cache = (
                         self._decode_block_fn(
                             params, cache, tok_d, active_d,
@@ -4007,12 +4193,13 @@ class ContinuousEngine:
                 segs = [np.asarray(t) for t in segs]   # ONE sync
             now = time.perf_counter()
             was_active = self._active.copy()
-            for toks in segs:
-                for slot in range(b):
-                    if was_active[slot] and self._req[slot] >= 0:
-                        self._consume(
-                            slot, toks[slot].tolist(), now, retired
-                        )
+            with self._led_consume():
+                for toks in segs:
+                    for slot in range(b):
+                        if was_active[slot] and self._req[slot] >= 0:
+                            self._consume(
+                                slot, toks[slot].tolist(), now, retired
+                            )
         return True
 
     def _schedule_refill(self, budget):
@@ -4062,6 +4249,7 @@ class ContinuousEngine:
             budget -= n
         return chunk, lengths, starved
 
+    @_dispatch_span("mixed")
     def _mixed_dispatch(self, params, d_params, retired):
         # The FUSED scheduler iteration (``mixed=True``): up to
         # ``decode_chain`` mixed links dispatched back-to-back, each
@@ -4202,32 +4390,36 @@ class ContinuousEngine:
             chain_dec = chain_cap(remaining, self._active)
         was_active = self._active.copy()
         n_active = int(was_active.sum())
-        tok_d = jnp.asarray(self._tok)
-        active_d = jnp.asarray(was_active.astype(np.int32))
-        remaining_d = jnp.asarray(remaining)
-        rid = self._rid_arr()
-        if self._speculative:
-            # Every row's CURRENT cache index: decoding rows at
-            # prompt + emitted - 1, refilling rows at their consumed
-            # count (the round's rollback broadcast must re-assert, never
-            # rewind, a refill advance — the device adds each link's
-            # chunk lengths on top of this).
-            pos_d = jnp.asarray(
-                np.asarray(
-                    [
-                        max(0, self._plen[s] + self._emitted[s] - 1)
-                        if was_active[s]
-                        else (
-                            self._plen[s] - self._pending[s].size
-                            if self._req[s] >= 0 else 0
-                        )
-                        for s in range(b)
-                    ],
-                    np.int32,
+        with self._led_h2d():
+            tok_d = jnp.asarray(self._tok)
+            active_d = jnp.asarray(was_active.astype(np.int32))
+            remaining_d = jnp.asarray(remaining)
+            rid = self._rid_arr()
+            if self._speculative:
+                # Every row's CURRENT cache index: decoding rows at
+                # prompt + emitted - 1, refilling rows at their consumed
+                # count (the round's rollback broadcast must re-assert,
+                # never rewind, a refill advance — the device adds each
+                # link's chunk lengths on top of this).
+                pos_d = jnp.asarray(
+                    np.asarray(
+                        [
+                            max(0, self._plen[s] + self._emitted[s] - 1)
+                            if was_active[s]
+                            else (
+                                self._plen[s] - self._pending[s].size
+                                if self._req[s] >= 0 else 0
+                            )
+                            for s in range(b)
+                        ],
+                        np.int32,
+                    )
                 )
-            )
+            if self._adapter_pool is not None:
+                aidx_d = jnp.asarray(self._aidx)
+        if self._speculative:
             t_cache, d_cache = self._cache
-        with self.ledger.measure("recovery"):
+        with self.ledger.measure("recovery", span="engine.recovery"):
             # Armed chaos delay books as recovery, never device.
             chaos_hook(
                 "engine.dispatch", phase="mixed",
@@ -4241,7 +4433,6 @@ class ContinuousEngine:
             # admission ran before this dispatch and nothing re-admits
             # mid-chain.
             pool_t = self._adapter_pool.tree
-            aidx_d = jnp.asarray(self._aidx)
         if horizon > 1:
             # Device-resident multi-step path: the horizon's plan is
             # staged host-side and ONE scanned program advances all of
@@ -4294,14 +4485,13 @@ class ContinuousEngine:
             # budget starved this link: the on-device counter reset is
             # idempotent and nothing advances a row before its first
             # chunk, so resetting early is safe and the flags can clear.
-            chunk_d = jnp.asarray(chunk)
-            lengths_d = jnp.asarray(lengths)
-            reset_d = jnp.asarray(self._needs_reset.copy())
-            reset_to_d = jnp.asarray(self._reset_to.copy())
+            with self._led_h2d():
+                chunk_d = jnp.asarray(chunk)
+                lengths_d = jnp.asarray(lengths)
+                reset_d = jnp.asarray(self._needs_reset.copy())
+                reset_to_d = jnp.asarray(self._reset_to.copy())
             if self._speculative and self._adapter_pool is not None:
-                with self._led_device(
-                    self._adapter_spec_mixed_step_fn
-                ), annotate("engine.adapter_spec_mixed_step"):
+                with self._led_device(self._adapter_spec_mixed_step_fn):
                     (first_tok, buffer, counts, acc, prop, tok_d, pos_d,
                      active_d, remaining_d, t_cache, d_cache) = (
                         self._adapter_spec_mixed_step_fn(
@@ -4318,9 +4508,7 @@ class ContinuousEngine:
                 )
                 link_fam = "adapter_mixed_step"
             elif self._speculative:
-                with self._led_device(
-                    self._spec_mixed_step_fn
-                ), annotate("engine.spec_mixed_step"):
+                with self._led_device(self._spec_mixed_step_fn):
                     (first_tok, buffer, counts, acc, prop, tok_d, pos_d,
                      active_d, remaining_d, t_cache, d_cache) = (
                         self._spec_mixed_step_fn(
@@ -4336,9 +4524,7 @@ class ContinuousEngine:
                 )
                 link_fam = "mixed_step"
             elif self._adapter_pool is not None:
-                with self._led_device(
-                    self._adapter_mixed_step_fn
-                ), annotate("engine.adapter_mixed_step"):
+                with self._led_device(self._adapter_mixed_step_fn):
                     first_tok, tok_d, active_d, remaining_d, self._cache = (
                         self._adapter_mixed_step_fn(
                             params, pool_t, aidx_d, self._cache, chunk_d,
@@ -4354,9 +4540,7 @@ class ContinuousEngine:
                 )
                 link_fam = "adapter_mixed_step"
             else:
-                with self._led_device(
-                    self._mixed_step_fn
-                ), annotate("engine.mixed_step"):
+                with self._led_device(self._mixed_step_fn):
                     first_tok, tok_d, active_d, remaining_d, self._cache = (
                         self._mixed_step_fn(
                             params, self._cache, chunk_d, lengths_d,
@@ -4392,6 +4576,7 @@ class ContinuousEngine:
             )
         if not segs:
             return False
+        self._c_prefill_tok.inc(refill_scheduled)
         if self._speculative:
             self._cache = (t_cache, d_cache)
         self.recorder.record(
@@ -4406,47 +4591,37 @@ class ContinuousEngine:
             self._c_adapter_rows.inc(
                 int(((self._aidx > 0) & occ).sum()) * len(segs)
             )
-        for first_tok, buffer, counts, acc, prop, seg_completes in segs:
-            with self._led_device(family=link_fam):
+        for i, (first_tok, buffer, counts, acc, prop, seg_completes) in (
+            enumerate(segs)
+        ):
+            left = len(segs) - 1 - i
+            with self._led_device(family=link_fam, in_flight=left):
                 first_np = np.asarray(first_tok)   # each link's own sync
             now = time.perf_counter()
-            for slot in seg_completes:
-                # Prompt complete: its first token came from this link's
-                # refill pick (same rule as _refill_dispatch).
-                t = int(first_np[slot])
-                self._out[slot].append(t)
-                self._emitted[slot] = 1
-                self._tok[slot] = t
-                self._slot_req[slot].first_token_t = now
-                self._ttimes[slot].append(now)
-                self.tracer.instant(
-                    "request.first_token", rid=self._req[slot]
-                )
-                if (self._eos is not None and t == self._eos) or (
-                    self._max_new == 1
-                ):
-                    self._retire(slot, now, retired)
-                else:
-                    self._active[slot] = True
             if self._speculative:
-                with self._led_device(family=link_fam):
+                with self._led_device(family=link_fam, in_flight=left):
                     counts_np = np.asarray(counts)
                     buffer_np = np.asarray(buffer)
                     acc_np = np.asarray(acc)
                     prop_np = np.asarray(prop)
                 self._c_spec_acc.inc(int(acc_np.sum()))
                 self._c_spec_prop.inc(int(prop_np.sum()))
-            for slot in range(b):
-                # Decode consumption: rows decoding at CHAIN START that
-                # are still live (a row retired while processing an
-                # earlier link froze on device — its later-link lanes
-                # carry no real tokens).
-                if was_active[slot] and self._req[slot] >= 0:
-                    if self._speculative:
-                        toks = buffer_np[slot, : counts_np[slot]].tolist()
-                    else:
-                        toks = [int(first_np[slot])]
-                    self._consume(slot, toks, now, retired)
+            with self._led_consume():
+                # Same first-token rule as _refill_dispatch.
+                self._first_tokens(seg_completes, first_np, now, retired)
+                for slot in range(b):
+                    # Decode consumption: rows decoding at CHAIN START
+                    # that are still live (a row retired while processing
+                    # an earlier link froze on device — its later-link
+                    # lanes carry no real tokens).
+                    if was_active[slot] and self._req[slot] >= 0:
+                        if self._speculative:
+                            toks = (
+                                buffer_np[slot, : counts_np[slot]].tolist()
+                            )
+                        else:
+                            toks = [int(first_np[slot])]
+                        self._consume(slot, toks, now, retired)
         return "mixed"
 
     def _plan_horizon_links(
@@ -4464,7 +4639,7 @@ class ContinuousEngine:
         cannot cover the plan — preemption is a BOUNDARY decision, so
         speculative staging aborts instead of un-admitting anyone."""
         b = self._b
-        with self.ledger.measure("sched"):
+        with self.ledger.measure("sched", span="engine.plan", label="plan"):
             offs = [0] * b
             links = []
             for link in range(n_links):
@@ -4629,15 +4804,14 @@ class ContinuousEngine:
                 t_cache, d_cache = self._cache
         live = np.zeros((n_links,), np.int32)
         live[:n_live] = 1
-        chunks_d = jnp.asarray(chunks)
-        lens_d = jnp.asarray(lens)
-        resets_d = jnp.asarray(resets)
-        reset_tos_d = jnp.asarray(reset_tos)
-        live_d = jnp.asarray(live)
+        with self._led_h2d():
+            chunks_d = jnp.asarray(chunks)
+            lens_d = jnp.asarray(lens)
+            resets_d = jnp.asarray(resets)
+            reset_tos_d = jnp.asarray(reset_tos)
+            live_d = jnp.asarray(live)
         if self._speculative and self._adapter_pool is not None:
-            with self._led_device(
-                self._adapter_spec_multi_step_fn
-            ), annotate("engine.adapter_spec_multi_step"):
+            with self._led_device(self._adapter_spec_multi_step_fn):
                 (first_toks, buffers, counts, accs, props, tok_d, pos_d,
                  active_d, remaining_d, t_cache, d_cache) = (
                     self._adapter_spec_multi_step_fn(
@@ -4654,9 +4828,7 @@ class ContinuousEngine:
             )
             fused_fam = "adapter_multi_step"
         elif self._speculative:
-            with self._led_device(
-                self._spec_multi_step_fn
-            ), annotate("engine.spec_multi_step"):
+            with self._led_device(self._spec_multi_step_fn):
                 (first_toks, buffers, counts, accs, props, tok_d, pos_d,
                  active_d, remaining_d, t_cache, d_cache) = (
                     self._spec_multi_step_fn(
@@ -4672,9 +4844,7 @@ class ContinuousEngine:
             )
             fused_fam = "multi_step"
         elif self._adapter_pool is not None:
-            with self._led_device(
-                self._adapter_multi_step_fn
-            ), annotate("engine.adapter_multi_step"):
+            with self._led_device(self._adapter_multi_step_fn):
                 first_toks, tok_d, active_d, remaining_d, self._cache = (
                     self._adapter_multi_step_fn(
                         params, pool_t, aidx_d, self._cache, chunks_d,
@@ -4690,9 +4860,7 @@ class ContinuousEngine:
             )
             fused_fam = "adapter_multi_step"
         else:
-            with self._led_device(
-                self._multi_step_fn
-            ), annotate("engine.multi_step"):
+            with self._led_device(self._multi_step_fn):
                 first_toks, tok_d, active_d, remaining_d, self._cache = (
                     self._multi_step_fn(
                         params, self._cache, chunks_d, lens_d, resets_d,
@@ -4721,6 +4889,7 @@ class ContinuousEngine:
         )
         self._c_multi_n.inc()
         self._c_multi_links.inc(n_live)
+        self._c_prefill_tok.inc(refill_scheduled)
         if self._adapter_pool is not None:
             self._c_adapter_n.inc(n_live)
             self._c_adapter_rows.inc(
@@ -4745,40 +4914,26 @@ class ContinuousEngine:
             self._c_spec_acc.inc(int(acc_np[:n_live].sum()))
             self._c_spec_prop.inc(int(props_np[:n_live].sum()))
         now = time.perf_counter()
-        for i in range(n_live):
-            first_np = toks_np[i]
-            for slot in links[i][3]:
-                # Prompt complete at link i: its first token came from
-                # that link's refill pick (same rule as the link loop).
-                t = int(first_np[slot])
-                self._out[slot].append(t)
-                self._emitted[slot] = 1
-                self._tok[slot] = t
-                self._slot_req[slot].first_token_t = now
-                self._ttimes[slot].append(now)
-                self.tracer.instant(
-                    "request.first_token", rid=self._req[slot]
-                )
-                if (self._eos is not None and t == self._eos) or (
-                    self._max_new == 1
-                ):
-                    self._retire(slot, now, retired)
-                else:
-                    self._active[slot] = True
-            for slot in range(b):
-                # Decode consumption: rows decoding at HORIZON START
-                # that are still live (a row that retired at an earlier
-                # link froze on device — its later lanes carry no real
-                # tokens). Same rule as the link loop's per-seg pass.
-                if was_active[slot] and self._req[slot] >= 0:
-                    if self._speculative:
-                        toks = (
-                            buffers_np[i, slot, : counts_np[i, slot]]
-                            .tolist()
-                        )
-                    else:
-                        toks = [int(first_np[slot])]
-                    self._consume(slot, toks, now, retired)
+        with self._led_consume():
+            for i in range(n_live):
+                first_np = toks_np[i]
+                # Prompts complete at link i (same rule as the link loop).
+                self._first_tokens(links[i][3], first_np, now, retired)
+                for slot in range(b):
+                    # Decode consumption: rows decoding at HORIZON START
+                    # that are still live (a row that retired at an
+                    # earlier link froze on device — its later lanes
+                    # carry no real tokens). Same rule as the link loop's
+                    # per-seg pass.
+                    if was_active[slot] and self._req[slot] >= 0:
+                        if self._speculative:
+                            toks = (
+                                buffers_np[i, slot, : counts_np[i, slot]]
+                                .tolist()
+                            )
+                        else:
+                            toks = [int(first_np[slot])]
+                        self._consume(slot, toks, now, retired)
         return "mixed"
 
     def _plan_next_horizon(self, n_links, per_link, chain_dec, links):
@@ -4798,7 +4953,7 @@ class ContinuousEngine:
         EOS — any faster drain or retirement misses the fingerprint."""
         self._staged_plan = None
         b = self._b
-        with self.ledger.measure("sched"):
+        with self.ledger.measure("sched", span="engine.plan", label="plan"):
             n_dec = min(len(links), max(0, chain_dec))
             rem = np.asarray(
                 [max(0, self._max_new - e) for e in self._emitted],
@@ -4876,7 +5031,9 @@ class ContinuousEngine:
             return
         # Observability tax, like _retire's booking: the probe is an
         # extra (cached) program dispatch, not serving work.
-        with self.ledger.measure("telemetry"):
+        with self.ledger.measure(
+            "telemetry", span="engine.telemetry", busy=True
+        ):
             cache = self._cache[0] if self._speculative else self._cache
             tok = jnp.asarray(self._tok, jnp.int32)
             act = jnp.asarray(self._active.astype(np.int32))
@@ -4885,6 +5042,8 @@ class ContinuousEngine:
                     params, cache, tok, act
                 )
             n_live, n_diff = int(n_live), int(n_diff)
+            if not self._in_flight:
+                self.ledger.device_empty()   # the probe has been read back
             self._c_comp_probes.inc()
             self._c_comp_disagree.inc(n_diff)
             frac = (n_diff / n_live) if n_live else 0.0
@@ -4991,7 +5150,10 @@ class ContinuousEngine:
         # claims its own exclusive slice via nested frames. Time between
         # step() calls is nobody's and derives as "idle". That is the
         # whole reconciliation argument: Σ buckets == wall, gated.
-        with self.ledger.measure("sched"):
+        self._step_n += 1
+        with self.ledger.measure(
+            "sched", span="engine.step", step=self._step_n
+        ) as frame:
             if self._staged_swap is not None:
                 self._try_commit_swap()
             if self._installed is not None:
@@ -5010,100 +5172,23 @@ class ContinuousEngine:
                 # its slot for this step's admission.
                 self._sweep_deadlines()
                 self._admit()
-                # Decode-stall accounting: a dispatch "stalls decode" when
-                # rows were actively decoding but the dispatch advanced
-                # none of them (the split engine's refill). The SLO feed
-                # sees a 0/1 stall indicator per dispatch-with-active-
-                # rows, so a ``decode_stall_share`` target reads as the
-                # fraction of such dispatches that parked decode behind
-                # refill.
-                had_active = bool(self._active.any())
+                rows = int(self._active.sum())
                 t0 = time.perf_counter()
                 try:
                     if self._mixed:
-                        # Wall time accrues to the program class that
-                        # actually ran: _mixed_dispatch's fallthroughs
-                        # (cache creation and speculative pure-refill →
-                        # "refill", pure-decode block → "decode") must
-                        # land in refill_s/decode_s, not mixed_s, or
-                        # refill_frac understates refill serialization. A
-                        # "refill" here CAN hold active decode rows in
-                        # exactly one regime — the degradation ladder's
-                        # split fallback on a speculative engine — and
-                        # then it stalls decode like the split engine's
-                        # refill does, so it books stall time and the SLO
-                        # stream sees it: the ladder is driven by that
-                        # monitor, and a degraded engine must not blind
-                        # the very telemetry that degraded it.
                         kind = self._mixed_dispatch(params, d_params, retired)
-                        if kind:
-                            dt = time.perf_counter() - t0
-                            with self.ledger.measure("telemetry"):
-                                if kind == "refill":
-                                    self._c_refill_s.inc(dt)
-                                    self._c_refill_n.inc()
-                                    if had_active:
-                                        self._c_stall_s.inc(dt)
-                                        if self.slo is not None:
-                                            self.slo.observe(
-                                                "decode_stall_share", 1.0
-                                            )
-                                    self.tracer.complete(
-                                        "engine.refill", t0, dt,
-                                        retired=len(retired),
-                                    )
-                                elif kind == "decode":
-                                    self._c_decode_s.inc(dt)
-                                    self._c_decode_n.inc()
-                                    self.tracer.complete(
-                                        "engine.decode", t0, dt,
-                                        retired=len(retired),
-                                    )
-                                    if had_active and self.slo is not None:
-                                        self.slo.observe(
-                                            "decode_stall_share", 0.0
-                                        )
-                                else:
-                                    self._c_mixed_s.inc(dt)
-                                    self._c_mixed_n.inc()
-                                    self.tracer.complete(
-                                        "engine.mixed", t0, dt,
-                                        retired=len(retired),
-                                    )
-                                    if had_active and self.slo is not None:
-                                        self.slo.observe(
-                                            "decode_stall_share", 0.0
-                                        )
                     elif self._refill_dispatch(params, d_params, retired):
-                        dt = time.perf_counter() - t0
-                        with self.ledger.measure("telemetry"):
-                            self._c_refill_s.inc(dt)
-                            self._c_refill_n.inc()
-                            if had_active:
-                                self._c_stall_s.inc(dt)
-                                if self.slo is not None:
-                                    self.slo.observe(
-                                        "decode_stall_share", 1.0
-                                    )
-                            self.tracer.complete(
-                                "engine.refill", t0, dt,
-                                retired=len(retired),
-                            )
+                        kind = "refill"
                     elif self._decode_dispatch(params, d_params, retired):
+                        kind = "decode"
+                    else:
                         # Only DISPATCHED time accrues: an idle poll
                         # (streaming drivers spin step() between
                         # arrivals) must not drown the refill/decode
                         # split.
-                        dt = time.perf_counter() - t0
-                        with self.ledger.measure("telemetry"):
-                            self._c_decode_s.inc(dt)
-                            self._c_decode_n.inc()
-                            if had_active and self.slo is not None:
-                                self.slo.observe("decode_stall_share", 0.0)
-                            self.tracer.complete(
-                                "engine.decode", t0, dt,
-                                retired=len(retired),
-                            )
+                        kind = None
+                    if kind:
+                        self._book_dispatch(kind, t0, rows)
                 except _RECOVERABLE_DISPATCH as e:
                     # Poison-request quarantine: strike every involved
                     # request, fail the repeat offenders, requeue the rest
@@ -5114,7 +5199,62 @@ class ContinuousEngine:
                 self._comp_maintain(params)
             self._g_active.set(int(self._active.sum()))
             self._g_queue.set(len(self._queue))
+        self._c_step_s.inc(frame.total_s)
         return retired
+
+    def _book_dispatch(self, kind, t0, rows):
+        """The books of ONE dispatch of ``kind`` ("refill" / "decode" /
+        "mixed") that started at ``t0`` with ``rows`` rows decoding.
+
+        Wall time accrues to the program class that actually ran:
+        ``_mixed_dispatch``'s fallthroughs (cache creation and
+        speculative pure-refill → "refill", pure-decode block →
+        "decode") land in refill_s/decode_s, not mixed_s, or refill_frac
+        would understate refill serialization.
+
+        Decode-stall accounting: a dispatch "stalls decode" when rows
+        were actively decoding but the dispatch advanced none of them —
+        the split engine's refill, and a mixed engine's "refill" in
+        exactly one regime, the degradation ladder's split fallback on a
+        speculative engine. It books stall time, and the SLO feed sees a
+        0/1 stall indicator per dispatch-with-active-rows, so a
+        ``decode_stall_share`` target reads as the fraction of such
+        dispatches that parked decode behind refill: the ladder is driven
+        by that monitor, and a degraded engine must not blind the very
+        telemetry that degraded it.
+
+        One ``engine.dispatch`` flight-recorder event per dispatch: its
+        seconds, leaves and tokens are the growth of the cumulative
+        counters since the previous event; ``starved_s`` is the
+        empty-device time that ended at this dispatch's enqueue."""
+        dt = time.perf_counter() - t0
+        with self.ledger.measure("telemetry", span="engine.telemetry"):
+            seconds, dispatches = {
+                "refill": (self._c_refill_s, self._c_refill_n),
+                "decode": (self._c_decode_s, self._c_decode_n),
+                "mixed": (self._c_mixed_s, self._c_mixed_n),
+            }[kind]
+            seconds.inc(dt)
+            dispatches.inc()
+            stalled = kind == "refill" and rows > 0
+            if stalled:
+                self._c_stall_s.inc(dt)
+            if rows and self.slo is not None:
+                self.slo.observe(
+                    "decode_stall_share", 1.0 if stalled else 0.0
+                )
+            now = {
+                field: getattr(self, attr).value
+                for field, attr in self._DISPATCH_DELTAS.items()
+            }
+            now["starved_s"] = self._starved_at_enqueue
+            self.recorder.record(
+                "engine.dispatch", family=self._last_family, phase=kind,
+                step=self._step_n, rows=rows, compiled=self._compiled,
+                **{f: v - self._booked[f] for f, v in now.items()},
+            )
+            self._booked = now
+            self._compiled = False
 
     # --- stats -------------------------------------------------------------
 

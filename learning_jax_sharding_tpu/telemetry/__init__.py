@@ -37,6 +37,20 @@ Stage 2 (PR 2) — diagnosis, four more:
   conservation invariant (Σ tenant device-seconds == fleet device
   bucket) and per-tenant SLO burn rates.
 
+Round 14's goodput ledger (:mod:`~.telemetry.ledger`) partitions a loop's
+wall into exclusive buckets; since PR 27 each frame the engine opens is
+also a span on its tracer, and so on the profiler's host timeline:
+``engine.step`` > ``engine.admission`` / ``engine.page_alloc`` /
+``engine.h2d`` / ``engine.enqueue.<family>`` / ``engine.wait.<family>`` /
+``engine.consume`` / ``engine.plan`` / ``engine.telemetry`` /
+``engine.recovery`` (and ``engine.kv_handoff``, ``engine.swap`` outside a
+step), with ``engine.refill`` / ``engine.decode`` / ``engine.mixed`` around
+a whole dispatch. A span is finer than its bucket, never a new bucket (the
+table is beside ``ContinuousEngine._led_device``). The same frames carry the
+empty-device clock (``engine_device_starved_seconds_total{span=...}``), an
+estimate of device idle on the host's clock; ``scripts/engine_breakdown.py``
+reads both back from a post-mortem bundle.
+
 Consumers: ``models.serving.ContinuousEngine`` (per-request span
 timeline, queue/page-pool gauges, SLO feed, flight-recorder lifecycle
 events), ``training.loop.fit`` + ``utils.metrics.MetricsLogger`` (same

@@ -48,6 +48,33 @@ are what the engine/loop wiring uses):
 ``idle``        derived starvation/idle time (never opened as a frame)
 ==============  ==========================================================
 
+A frame can also be a SPAN (``measure(bucket, span="engine.h2d")``):
+opening it opens the ledger's tracer span of that name, which enters a
+``jax.profiler.TraceAnnotation``. A span is FINER than its bucket, never
+a new bucket: the buckets, ``ledger_seconds_total`` and
+:meth:`~GoodputLedger.reconcile` read what they read without it. Because
+the frames partition a loop's step exhaustively, every instant of it lies
+in exactly one innermost span, on the tracer's ring and on the profiler's
+host timeline alike. The names are the caller's: the engine's
+(``engine.step`` > ``engine.admission`` / ``engine.page_alloc`` /
+``engine.h2d`` / ``engine.enqueue.<family>`` / ``engine.wait.<family>`` /
+``engine.consume`` / ``engine.plan`` / ``engine.telemetry`` /
+``engine.recovery``, and ``engine.kv_handoff`` / ``engine.swap``) are
+tabled beside ``ContinuousEngine._led_device`` in ``models/serving.py``
+and pinned by ``tests/test_engine_spans.py``.
+
+The same frames carry a second clock, the EMPTY-DEVICE clock: between
+:meth:`~GoodputLedger.device_empty` (a readback returned and nothing
+else is dispatched) and :meth:`~GoodputLedger.device_busy` (the next
+enqueue returned) every second goes to :data:`STARVED_METRIC` and, by
+the innermost frame it was spent in, to ``STARVED_METRIC{span=<label>}``:
+the frame's ``label`` (its bucket unless the caller names a finer one),
+or ``outside_step`` outside every frame. It is an ESTIMATE of device
+idle on the host's clock, with an error each way: the device starts
+inside the jitted call, so the tail of every enqueue is counted though
+the chip is already working; and while the caller keeps programs in
+flight the clock stands, though the chip may idle between them.
+
 Windowing mirrors the engine's ``reset_stats`` idiom: cumulative totals
 plus a :meth:`begin_window` base snapshot; :meth:`window_report` emits
 the per-window breakdown, ``host_share`` (1 − device/busy — the
@@ -75,6 +102,10 @@ BUCKETS = (
 )
 
 
+#: The empty-device clock's counter, plain and ``{span=<label>}``.
+STARVED_METRIC = "engine_device_starved_seconds_total"
+
+
 class Frame:
     """One open :meth:`GoodputLedger.measure` region. Exposed so callers
     can :meth:`rebucket` after the fact — the compile-steal idiom: open
@@ -82,13 +113,18 @@ class Frame:
     the frame to ``compile`` if the cache grew (the dispatch paid a
     trace+compile, not a device step)."""
 
-    __slots__ = ("bucket", "t0", "child_s", "family")
+    __slots__ = ("bucket", "t0", "child_s", "family", "label", "total_s")
 
-    def __init__(self, bucket: str, t0: float, family: Optional[str] = None):
+    def __init__(
+        self, bucket: str, t0: float, family: Optional[str] = None,
+        label: Optional[str] = None,
+    ):
         self.bucket = bucket
         self.t0 = t0
         self.child_s = 0.0
         self.family = family
+        self.label = label or bucket   # the empty-device clock's series
+        self.total_s = 0.0             # wall of the frame, set at its close
 
     def rebucket(self, bucket: str) -> None:
         self.bucket = bucket
@@ -106,10 +142,18 @@ class GoodputLedger:
         registry: Any | None = None,
         metric: str = "ledger_seconds_total",
         clock: Callable[[], float] = time.perf_counter,
+        tracer: Any | None = None,
     ):
         self._clock = clock
         self._registry = registry
         self._metric = metric
+        self._tracer = tracer
+        # The tracer's clock is perf_counter: where the ledger reads the
+        # same one, a frame hands its own edges to its span.
+        self._same_clock = clock is time.perf_counter
+        self._starved: dict[str, Any] = {}
+        self._empty_t: float | None = None   # device known empty since
+        self.starved_s = 0.0
         self._counters: dict[str, Any] = {}
         self._totals: dict[str, float] = {}
         self._covered = 0.0          # cumulative top-level frame seconds
@@ -151,7 +195,10 @@ class GoodputLedger:
 
     @contextlib.contextmanager
     def measure(
-        self, bucket: str, family: Optional[str] = None
+        self, bucket: str, family: Optional[str] = None, *,
+        span: Optional[str] = None, label: Optional[str] = None,
+        ring: bool = True, busy: bool = False, counter: Any | None = None,
+        **args,
     ) -> Iterator[Frame]:
         """Attribute the enclosed wall-clock to ``bucket``, exclusively:
         time claimed by nested ``measure`` frames is subtracted here and
@@ -159,15 +206,40 @@ class GoodputLedger:
         idle-derivation base). ``family`` tags device frames with the
         program family for :meth:`overlap_report` — frames that rebucket
         away from ``device`` (compile-steal) drop out of the family
-        totals together with their device seconds."""
-        f = Frame(bucket, self._clock(), family)
+        totals together with their device seconds.
+
+        ``span`` names the frame on the tracer and the profiler (module
+        docstring); ``args`` go to the span, and ``ring=False`` keeps it
+        off the tracer's ring. ``label`` is the frame's series of the
+        empty-device clock (default: its bucket). ``counter`` also
+        receives the frame's exclusive seconds. ``busy`` says the frame
+        dispatches device work outside the caller's enqueue/wait funnel:
+        the empty-device clock stops when it opens."""
+        t0 = self._clock()
+        self._tick(t0)
+        f = Frame(bucket, t0, family, label)
         self._stack.append(f)
+        if busy:
+            self._empty_t = None
+        sp = None
+        if span is not None and self._tracer is not None:
+            sp = self._tracer.begin(
+                span, keep=ring, at=t0 if self._same_clock else None,
+                **args,
+            )
         try:
             yield f
         finally:
-            total = self._clock() - f.t0
+            t1 = self._clock()
+            if sp is not None:
+                self._tracer.end(sp, at=t1 if self._same_clock else None)
+            self._tick(t1)
+            total = f.total_s = t1 - f.t0
+            own = max(0.0, total - f.child_s)
             self._stack.pop()
-            self._add(f.bucket, max(0.0, total - f.child_s), f.family)
+            self._add(f.bucket, own, f.family)
+            if counter is not None:
+                counter.inc(own)
             if f.bucket == "device":
                 fam = f.family or "unattributed"
                 self._dev_calls[fam] = self._dev_calls.get(fam, 0) + 1
@@ -176,6 +248,49 @@ class GoodputLedger:
             else:
                 self._covered += total
                 self._windows += 1
+
+    # --- the empty-device clock ---------------------------------------------
+
+    def _tick(self, t: float) -> None:
+        """Book the empty-device seconds up to ``t`` to the innermost
+        open frame (every frame edge and both marks call this)."""
+        if self._empty_t is None:
+            return
+        dt, self._empty_t = t - self._empty_t, t
+        if dt <= 0:
+            return
+        self.starved_s += dt
+        if self._registry is None:
+            return
+        label = self._stack[-1].label if self._stack else "outside_step"
+        for key in (None, label):
+            c = self._starved.get(key)
+            if c is None:
+                name = STARVED_METRIC
+                if key is not None:
+                    name += f'{{span="{key}"}}'
+                c = self._starved[key] = self._registry.counter(
+                    name,
+                    "seconds from a readback that left nothing in flight "
+                    "to the return of the next enqueue, by the innermost "
+                    "frame the host spent them in: an estimate of device "
+                    "idle on the host's clock (over by the tail of each "
+                    "enqueue, which the chip already works through; "
+                    "under while a chain is in flight, when it stands)",
+                )
+            c.inc(dt)
+
+    def device_empty(self) -> None:
+        """A blocking readback returned and nothing else is dispatched:
+        the device has no work until the next enqueue."""
+        if self._empty_t is None:
+            self._empty_t = self._clock()
+
+    def device_busy(self) -> None:
+        """An enqueue returned (or work was dispatched some other way):
+        stop the empty-device clock."""
+        self._tick(self._clock())
+        self._empty_t = None
 
     def account(
         self, bucket: str, seconds: float, family: Optional[str] = None

@@ -11,7 +11,21 @@ async intervals) and exports them as
 * **XProf/TensorBoard**, live: every :meth:`Tracer.span` also enters a
   ``jax.profiler.TraceAnnotation``, so when a ``utils.profiling.trace``
   capture is active the framework phases appear on the profiler's host
-  timeline next to the device ops they dispatched.
+  timeline next to the device ops they dispatched. The annotation
+  carries the span's ``args``; a ROOT span's (``engine.step``) also
+  carries ``ts_us``, the tracer's own timestamp of its start, so a
+  reader of the ``.xplane.pb`` can map tracer time onto profiler time,
+  and ``chrome_trace()["otherData"]["epoch_unix_ns"]`` is the wall time
+  of the tracer's epoch, which maps both onto the flight recorder's
+  ``t`` (``scripts/engine_breakdown.py --xplane`` places a bundle's
+  ``engine.dispatch`` events on a capture that way).
+
+The engine's goodput-ledger frames are spans (``GoodputLedger.measure(
+..., span=)`` opens one), so the ``engine.*`` names (the table is in
+``models/serving.py``) partition every ``step()`` on that timeline. They
+are about 22 ring events a dispatch: at the default ``max_events`` the
+ring then holds the last 9,000 dispatches or so, and the ``request.*``
+events written between them.
 
 Honesty under async dispatch is explicit: a span around a jitted call
 measures DISPATCH unless it contains a sync point (the reference's
@@ -31,6 +45,28 @@ import time
 from typing import Any, Iterator
 
 import jax
+
+
+class OpenSpan:
+    """An open :meth:`Tracer.span`: the event's ``args`` (add to them
+    before the span closes) and ``keep``, cleared for a span that turns
+    out to have wrapped nothing (a dispatch that did not run): its event
+    is then not written."""
+
+    __slots__ = ("name", "args", "keep", "start", "parent", "annotation")
+
+    def __init__(self, name: str, args: dict, keep: bool = True):
+        self.name = name
+        self.args = args
+        self.keep = keep
+        self.start = 0.0                       # tracer µs
+        self.parent: str | None = None
+        self.annotation = None
+
+
+#: What a disabled tracer hands out: nothing is timed, nothing allocated
+#: (callers may still write its fields; nobody reads them).
+_NO_SPAN = OpenSpan("", {}, keep=False)
 
 
 def device_sync(out: Any) -> None:
@@ -72,6 +108,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._t0 = time.perf_counter()
+        # Wall time of the epoch: ``ts`` µs after it is unix time
+        # ``epoch_unix_ns / 1e9 + ts / 1e6`` — the flight recorder's ``t``.
+        self._epoch_unix_ns = time.time_ns()
         # Deterministic ids: the OS pid and raw thread idents change per
         # run, which made merged fleet timelines interleave replicas
         # nondeterministically in Perfetto. Events carry pid 1 and small
@@ -135,7 +174,7 @@ class Tracer:
         ev.update(extra)
         return ev
 
-    def _stack(self) -> list[str]:
+    def _stack(self) -> list[OpenSpan]:
         stack = getattr(self._local, "stack", None)
         if stack is None:
             stack = self._local.stack = []
@@ -144,29 +183,66 @@ class Tracer:
     # --- recording API -----------------------------------------------------
 
     @contextlib.contextmanager
-    def span(self, name: str, **args) -> Iterator[None]:
+    def span(
+        self, name: str, *, keep: bool = True, **args
+    ) -> Iterator[OpenSpan]:
         """Nested complete event + XProf bridge. ``args`` become the
-        event's ``args`` dict (JSON-able values only)."""
-        if not self.enabled:
-            yield
-            return
-        stack = self._stack()
-        parent = stack[-1] if stack else None
-        stack.append(name)
-        start = self._now_us()
+        event's ``args`` dict (JSON-able values only) and the profiler
+        annotation's arguments.
+
+        ``keep=False`` opens a span that reaches the profiler and writes
+        no ring event unless the block sets ``sp.keep``: for a span that
+        can close many times inside one parent (a page claim per slot
+        per link), whose time then stays in that parent's event."""
+        sp = self.begin(name, keep=keep, **args)
         try:
-            with jax.profiler.TraceAnnotation(name):
-                yield
+            yield sp
         finally:
-            stack.pop()
-            end = self._now_us()
-            ev = self._base(name, "X", dur=end - start)
-            ev["ts"] = start
-            if parent is not None:
-                args = dict(args, parent=parent)
-            if args:
-                ev["args"] = args
-            self._emit(ev)
+            self.end(sp)
+
+    def begin(
+        self, name: str, *, keep: bool = True, at: float | None = None,
+        **args,
+    ) -> OpenSpan:
+        """Open a span; :meth:`end` closes it (:meth:`span` is the pair
+        as a context manager). ``at`` is the caller's own reading of
+        the ``perf_counter`` clock for the edge — the goodput ledger
+        passes its frame's, so the event IS the frame."""
+        if not self.enabled:
+            return _NO_SPAN
+        sp = OpenSpan(name, args, keep)
+        stack = self._stack()
+        sp.start = (
+            self._now_us() if at is None else (at - self._t0) * 1e6
+        )
+        if stack:
+            sp.parent = stack[-1].name
+            sp.annotation = jax.profiler.TraceAnnotation(name, **args)
+        else:
+            # A root span anchors the profiler's clock to the tracer's.
+            sp.annotation = jax.profiler.TraceAnnotation(
+                name, ts_us=sp.start, **args
+            )
+        stack.append(sp)
+        sp.annotation.__enter__()
+        return sp
+
+    def end(self, sp: OpenSpan, at: float | None = None) -> None:
+        if sp is _NO_SPAN:
+            return
+        sp.annotation.__exit__(None, None, None)
+        self._stack().pop()
+        if not sp.keep:
+            return
+        end = self._now_us() if at is None else (at - self._t0) * 1e6
+        ev = self._base(sp.name, "X", dur=end - sp.start)
+        ev["ts"] = sp.start
+        args = sp.args
+        if sp.parent is not None:
+            args = dict(args, parent=sp.parent)
+        if args:
+            ev["args"] = args
+        self._emit(ev)
 
     def complete(
         self, name: str, start_perf: float, duration_s: float, **args
@@ -257,7 +333,10 @@ class Tracer:
         return {
             "traceEvents": self.metadata_events() + self.events,
             "displayTimeUnit": "ms",
-            "otherData": {"dropped_events": self.dropped},
+            "otherData": {
+                "dropped_events": self.dropped,
+                "epoch_unix_ns": self._epoch_unix_ns,
+            },
         }
 
     def dump_chrome_trace(self, path) -> None:

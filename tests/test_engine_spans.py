@@ -1,0 +1,583 @@
+"""The engine's host phases, named where they happen (PR 27).
+
+Every goodput-ledger frame the engine opens is a span: it reaches the
+tracer's ring and, through ``jax.profiler.TraceAnnotation``, the
+profiler's host timeline. The same frames carry the empty-device clock
+(``engine_device_starved_seconds_total``) and feed the per-dispatch
+``engine.dispatch`` flight-recorder event. These tests pin the names, the
+partition, the identities between counters, events and request records,
+and that every benchmark metric file reads something a served engine has.
+"""
+
+import collections
+import dataclasses
+import glob
+import importlib.util
+import json
+import pathlib
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from learning_jax_sharding_tpu.models.serving import ContinuousEngine
+from learning_jax_sharding_tpu.models.transformer import (
+    CONFIG_TINY,
+    Transformer,
+)
+from learning_jax_sharding_tpu.parallel import build_mesh
+from learning_jax_sharding_tpu.parallel.logical import RULES_TP_SERVING
+from learning_jax_sharding_tpu.telemetry import GoodputLedger, Tracer
+from learning_jax_sharding_tpu.telemetry.flight_recorder import FlightRecorder
+from learning_jax_sharding_tpu.telemetry.registry import MetricsRegistry
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
+_SPEC = importlib.util.spec_from_file_location(
+    "engine_breakdown", REPO / "scripts" / "engine_breakdown.py"
+)
+engine_breakdown = importlib.util.module_from_spec(_SPEC)
+_SPEC.loader.exec_module(engine_breakdown)
+MAX_NEW = 6
+
+#: span -> ledger bucket. ``device`` stands for ``device`` + ``compile``
+#: (an enqueue whose executable cache grew is re-bucketed). The three
+#: dispatch spans are not frames: their own time is the step's.
+SPAN_BUCKET = {
+    "engine.step": "sched",
+    "engine.h2d": "sched",
+    "engine.consume": "sched",
+    "engine.plan": "sched",
+    "engine.refill": "sched",
+    "engine.decode": "sched",
+    "engine.mixed": "sched",
+    "engine.admission": "admission",
+    "engine.page_alloc": "page_alloc",
+    "engine.telemetry": "telemetry",
+    "engine.recovery": "recovery",
+    "engine.kv_handoff": "kv_handoff",
+    "engine.swap": "swap",
+    "engine.enqueue": "device",
+    "engine.wait": "device",
+}
+
+
+def _bucket_of(span: str) -> str:
+    return SPAN_BUCKET[".".join(span.split(".")[:2])]
+
+
+def _setup(**engine_kw):
+    cfg = dataclasses.replace(
+        CONFIG_TINY, dtype=jnp.float32, decode_attention="blocked"
+    )
+    mesh = build_mesh((1, 2), ("data", "model"), devices=jax.devices()[:2])
+    params = nn.meta.unbox(
+        jax.jit(lambda r, t: Transformer(cfg).init({"params": r}, t))(
+            jax.random.key(3), np.zeros((2, 8), np.int32)
+        )["params"]
+    )
+    eng = ContinuousEngine(
+        cfg, mesh, RULES_TP_SERVING, batch_size=2, max_new_tokens=MAX_NEW,
+        refill_chunk=8, paged_pages=12, page_size=8,
+        recorder=FlightRecorder(max_events=100_000), **engine_kw,
+    )
+    return cfg, params, eng
+
+
+def _prompts(cfg, seed, n):
+    rng = np.random.default_rng(seed)
+    return [
+        rng.integers(1, cfg.vocab_size, size=(k,)).astype(np.int32)
+        for k in rng.integers(5, 20, size=n)
+    ]
+
+
+def _drain(eng, params, prompts):
+    rids = [eng.add_request(p) for p in prompts]
+    while eng.has_work():
+        eng.step(params)
+    outs = eng.pop_finished()
+    return [outs[r] for r in rids]
+
+
+@pytest.fixture(scope="module")
+def served():
+    """A warm engine (everything compiled), then one measured drain."""
+    cfg, params, eng = _setup()
+    _drain(eng, params, _prompts(cfg, 1, 3))
+    eng.tracer.clear()
+    eng.recorder.clear()
+    eng.ledger.begin_window()
+    start = eng.registry.snapshot()
+    prompts = _prompts(cfg, 14, 6)
+    outs = _drain(eng, params, prompts)
+    return {
+        "eng": eng, "prompts": prompts, "outs": outs, "start": start,
+        "end": eng.registry.snapshot(),
+        "buckets": eng.ledger.window_buckets(),
+        "reconcile": eng.ledger.reconcile(),
+        "events": [e for e in eng.tracer.events if e["ph"] == "X"],
+        "dispatches": eng.recorder.events("engine.dispatch"),
+    }
+
+
+def _delta(served, name):
+    return served["end"].get(name, 0.0) - served["start"].get(name, 0.0)
+
+
+def _exclusive(events):
+    """Per span name its exclusive microseconds, by interval nesting, and
+    the events that nest in no other."""
+    own = collections.defaultdict(float)
+    stack, tops = [], []
+    for ev in sorted(events, key=lambda e: (e["ts"], -e["dur"])):
+        while stack and ev["ts"] >= stack[-1]["ts"] + stack[-1]["dur"]:
+            stack.pop()
+        if stack:
+            assert ev["ts"] + ev["dur"] <= (
+                stack[-1]["ts"] + stack[-1]["dur"] + 1e-3
+            ), f"{ev['name']} straddles the end of {stack[-1]['name']}"
+            own[id(stack[-1])] -= ev["dur"]
+        else:
+            tops.append(ev)
+        own[id(ev)] += ev["dur"]
+        stack.append(ev)
+    by_name = collections.defaultdict(float)
+    for ev in events:
+        assert own[id(ev)] >= -1e-3, (ev["name"], own[id(ev)])
+        by_name[ev["name"]] += own[id(ev)]
+    return by_name, tops
+
+
+# --- (a) the spans partition step() and add up to the ledger's buckets -------
+
+
+def test_every_instant_of_a_step_lies_in_one_innermost_engine_span(served):
+    by_name, tops = _exclusive(served["events"])
+    # Nothing but steps at the top: every other span nests inside one, so
+    # each instant of a step has exactly one innermost span.
+    assert {e["name"] for e in tops} == {"engine.step"}
+    assert all(name.startswith("engine.") for name in by_name)
+    steps = [e for e in served["events"] if e["name"] == "engine.step"]
+    ordinals = [e["args"]["step"] for e in steps]
+    assert ordinals == list(range(ordinals[0], ordinals[0] + len(steps)))
+    # ... and the exclusive times add up to the wall inside step().
+    assert sum(by_name.values()) / 1e6 == pytest.approx(
+        _delta(served, "engine_step_seconds_total"), rel=1e-6
+    )
+
+
+def test_span_exclusive_seconds_equal_the_ledger_buckets(served):
+    assert served["reconcile"]["ok"], served["reconcile"]
+    by_name, _ = _exclusive(served["events"])
+    by_bucket = collections.defaultdict(float)
+    for name, us in by_name.items():
+        by_bucket[_bucket_of(name)] += us / 1e6
+    ledger = dict(served["buckets"])
+    ledger["device"] += ledger.pop("compile")
+    ledger.pop("idle")
+    # A page claim writes no ring event: on the ring its time stays in
+    # the span that made it, a step's own code or an admission.
+    assert ledger["page_alloc"] > 0 and "page_alloc" not in by_bucket
+    for merged in (ledger, by_bucket):
+        merged["sched"] += merged.pop("admission") + merged.pop("page_alloc", 0)
+    for bucket, seconds in ledger.items():
+        assert by_bucket[bucket] == pytest.approx(seconds, rel=1e-6, abs=1e-7), (
+            bucket, dict(by_bucket), ledger,
+        )
+
+
+@pytest.mark.parametrize("span", [
+    "engine.step", "engine.admission", "engine.h2d",
+    "engine.enqueue.refill_step", "engine.wait.refill_step",
+    "engine.enqueue.decode_block", "engine.wait.decode_block",
+    "engine.consume", "engine.telemetry", "engine.recovery",
+    "engine.refill", "engine.decode",
+])
+def test_span_names_are_pinned(served, span):
+    assert any(e["name"] == span for e in served["events"])
+
+
+def test_an_undispatched_step_leaves_no_dispatch_span():
+    cfg, params, eng = _setup()
+    eng.step(params)                      # nothing queued: nothing to run
+    names = [e["name"] for e in eng.tracer.events]
+    assert "engine.step" in names
+    assert not {"engine.refill", "engine.decode", "engine.mixed"} & set(names)
+    assert not eng.recorder.events("engine.dispatch")
+
+
+def test_mixed_engine_books_each_dispatch_under_the_program_that_ran():
+    cfg, params, eng = _setup(mixed=True)
+    _drain(eng, params, _prompts(cfg, 5, 4))
+    names = collections.Counter(
+        e["name"] for e in eng.tracer.events if e["ph"] == "X"
+    )
+    kinds = collections.Counter(
+        e["phase"] for e in eng.recorder.events("engine.dispatch")
+    )
+    for kind in ("refill", "decode", "mixed"):
+        assert names[f"engine.{kind}"] == kinds[kind]
+    assert kinds["mixed"] and names["engine.enqueue.mixed_step"]
+    assert eng.ledger.reconcile()["ok"]
+
+
+def test_span_names_leave_the_buckets_alone():
+    """The same frames with and without a tracer, on a clock that ticks
+    once per reading: every bucket total is identical, so a span is finer
+    than its bucket and ``sched_host_share_pct`` reads what it read."""
+
+    def run(tracer):
+        ticks = iter(range(10**9))
+        led = GoodputLedger(
+            registry=MetricsRegistry(), clock=lambda: float(next(ticks)),
+            tracer=tracer,
+        )
+        with led.measure("sched", span="engine.step", step=1):
+            with led.measure("admission", span="engine.admission"):
+                pass
+            with led.measure("sched", span="engine.h2d", leaves=4):
+                pass
+            with led.measure(
+                "device", family="decode_block",
+                span="engine.enqueue.decode_block",
+            ) as f:
+                f.rebucket("compile")
+            with led.measure("sched", span="engine.consume"):
+                with led.measure("telemetry", span="engine.telemetry"):
+                    pass
+                with led.measure(
+                    "page_alloc", span="engine.page_alloc", ring=False
+                ):
+                    pass
+        assert led.reconcile(eps=1.0)["ok"]
+        return led.totals()
+
+    assert run(Tracer()) == run(None) == {
+        "admission": 1.0, "compile": 1.0, "telemetry": 1.0,
+        "page_alloc": 1.0, "sched": 9.0,
+    }
+
+
+def test_ensure_cache_opens_no_frame():
+    """The disaggregated bring-up hook pushes the block tables outside
+    ``step()``: the ledger, which covers steps, must not grow."""
+    cfg, params, eng = _setup()
+    eng.ensure_cache(params)
+    assert eng._cache is not None and not eng._tables_dirty
+    assert eng.ledger.totals() == {}
+    report = eng.ledger.window_report()
+    assert report["steps"] == 0 and report["busy_s"] == 0
+    assert not eng.tracer.events
+    assert eng.registry.snapshot()["engine_table_push_leaves_total"] == (
+        cfg.num_layers
+    )
+
+
+def test_a_disabled_tracer_costs_no_span_and_keeps_the_books():
+    cfg, params, eng = _setup(tracer=Tracer(enabled=False))
+    assert eng.tracer.begin("engine.step") is eng.tracer.begin("engine.h2d")
+    _drain(eng, params, _prompts(cfg, 1, 3))
+    assert not eng.tracer.events
+    assert eng.ledger.reconcile()["ok"]
+    snap = eng.registry.snapshot()
+    assert snap[STARVED] > 0 and snap["engine_h2d_seconds_total"] > 0
+    assert eng.recorder.events("engine.dispatch")
+
+
+# --- (b) the empty-device clock ------------------------------------------------
+
+STARVED = "engine_device_starved_seconds_total"
+
+
+def test_the_caller_names_the_starved_series():
+    """The ledger parses no span name: a frame's series is its ``label``,
+    its bucket by default, ``outside_step`` outside every frame."""
+    ticks = iter(range(10**9))
+    reg = MetricsRegistry()
+    led = GoodputLedger(registry=reg, clock=lambda: float(next(ticks)))
+    led.device_empty()                                       # t = 0
+    with led.measure("sched", span="nodot"):                 # 1: outside
+        with led.measure("sched", span="a.b.c", label="h2d"):   # 2: sched
+            pass                                             # 3: h2d
+        with led.measure("device", span="a.b.d", label="enqueue"):  # 4: sched
+            led.device_busy()                                # 5: enqueue
+        with led.measure("admission"):                       # 6 .. 7
+            pass
+    snap = reg.snapshot()
+    assert {
+        k[len(STARVED):]: v for k, v in snap.items() if k.startswith(STARVED)
+    } == {
+        "": 5.0, '{span="outside_step"}': 1.0, '{span="sched"}': 2.0,
+        '{span="h2d"}': 1.0, '{span="enqueue"}': 1.0,
+    }
+
+
+def test_labelled_starved_series_sum_to_the_plain_one(served):
+    labelled = {
+        k: _delta(served, k) for k in served["end"]
+        if k.startswith(STARVED + "{")
+    }
+    assert {'%s{span="%s"}' % (STARVED, s) for s in (
+        "h2d", "enqueue", "consume", "admission", "telemetry", "sched",
+        "outside_step",
+    )} <= set(labelled)
+    assert sum(labelled.values()) == pytest.approx(
+        _delta(served, STARVED), rel=1e-9
+    )
+    assert _delta(served, STARVED) > 0
+
+
+def test_starved_enqueue_and_wait_fit_inside_the_steps(served):
+    """Empty-chip seconds outside the enqueue, the enqueues and the waits
+    are disjoint pieces of the wall inside and between steps."""
+    starved = _delta(served, STARVED)
+    in_enqueue = _delta(served, STARVED + '{span="enqueue"}')
+    outside = _delta(served, STARVED + '{span="outside_step"}')
+    pieces = (
+        starved - in_enqueue
+        + _delta(served, "engine_enqueue_seconds_total")
+        + _delta(served, "engine_wait_seconds_total")
+    )
+    assert pieces <= _delta(served, "engine_step_seconds_total") + outside + 1e-9
+
+
+def test_the_clock_stops_while_a_chained_dispatch_is_in_flight():
+    """With ``decode_chain=2`` a long prompt's two refill chunks are
+    dispatched back to back: after the first readback one program is
+    still in flight, so the clock must not start until the second."""
+    cfg, params, eng = _setup(decode_chain=2)
+    prompt = _prompts(cfg, 3, 1)[0][:5]
+    long_prompt = np.concatenate([prompt] * 3)       # 15 tokens: 2 chunks
+    _drain(eng, params, [long_prompt])               # warm
+    marks = []
+    real_empty, led = eng.ledger.device_empty, eng.ledger
+
+    def device_empty():
+        marks.append(eng._in_flight)
+        real_empty()
+
+    led.device_empty = device_empty
+    waits = []
+    real_tick = led._tick
+
+    def tick(t):
+        # Every frame edge: is the clock running, and what is unread?
+        waits.append((led._empty_t is not None, eng._in_flight))
+        real_tick(t)
+
+    led._tick = tick
+    _drain(eng, params, [long_prompt])
+    assert marks and set(marks) == {0}
+    assert any(n == 2 for _, n in waits), "the chain never had two in flight"
+    assert not any(running for running, n in waits if n > 0)
+
+
+# --- (c) one engine.dispatch event per dispatch --------------------------------
+
+
+def test_one_dispatch_event_per_dispatch(served):
+    evs = served["dispatches"]
+    n = sum(
+        _delta(served, f"engine_{k}_dispatches_total")
+        for k in ("refill", "decode", "mixed")
+    )
+    assert len(evs) == n > 0
+    spans = [
+        e for e in served["events"]
+        if e["name"] in ("engine.refill", "engine.decode", "engine.mixed")
+    ]
+    assert [e["name"] for e in spans] == [f"engine.{e['phase']}" for e in evs]
+    steps = [e["step"] for e in evs]
+    assert steps == sorted(set(steps))
+    for e in evs:
+        assert set(e) >= {
+            "family", "phase", "step", "rows", "prefill_tokens",
+            "decode_steps", "context_tokens", "starved_s", "enqueue_s",
+            "wait_s", "h2d_s", "table_leaves", "compiled",
+        }
+        assert e["family"] == {
+            "refill": "refill_step", "decode": "decode_block"
+        }[e["phase"]]
+        assert not e["compiled"]
+        assert e["enqueue_s"] > 0 and e["wait_s"] > 0 and e["h2d_s"] > 0
+
+
+def test_dispatch_events_account_for_every_token(served):
+    evs, prompts, outs = served["dispatches"], served["prompts"], served["outs"]
+    generated = [len(o) - len(p) for o, p in zip(outs, prompts)]
+    assert sum(e["prefill_tokens"] for e in evs) == sum(map(len, prompts))
+    first_tokens = len(prompts)
+    assert sum(e["decode_steps"] for e in evs) + first_tokens == sum(generated)
+    # The j-th generated token (j >= 2) comes out of a step that reads a
+    # cache of prompt + j - 1 tokens.
+    context = sum(
+        len(p) + j - 1 for p, g in zip(prompts, generated)
+        for j in range(2, g + 1)
+    )
+    assert sum(e["context_tokens"] for e in evs) == context
+    assert _delta(served, "engine_decode_context_tokens_total") == context
+    # (the first event's share began before the window did)
+    assert 0 < sum(e["starved_s"] for e in evs[1:]) <= _delta(served, STARVED)
+    leaves = sum(e["table_leaves"] for e in evs)
+    assert leaves == _delta(served, "engine_table_push_leaves_total")
+    assert leaves % served["eng"]._cfg.num_layers == 0 and leaves > 0
+
+
+# --- (d) the spans reach the profiler's host timeline --------------------------
+
+
+def test_profiler_capture_holds_the_engine_spans(tmp_path):
+    from jax.profiler import ProfileData
+
+    cfg, params, eng = _setup()
+    _drain(eng, params, _prompts(cfg, 1, 2))          # warm
+    for p in _prompts(cfg, 2, 2):
+        eng.add_request(p)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for _ in range(3):
+            eng.step(params)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = collections.defaultdict(list)
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("engine."):
+                        host[ev.name].append(dict(ev.stats))
+    assert len(host["engine.step"]) == 3
+    for needed in ("engine.h2d", "engine.consume"):
+        assert host[needed], sorted(host)
+    for phase in ("enqueue", "wait"):
+        assert any(n.startswith(f"engine.{phase}.") for n in host), sorted(host)
+    ring = {
+        e["args"]["step"]: e["ts"] for e in eng.tracer.events
+        if e["name"] == "engine.step"
+    }
+    for stats in host["engine.step"]:
+        # One clock: the annotation carries the tracer's own timestamp.
+        assert float(stats["ts_us"]) == pytest.approx(ring[int(stats["step"])])
+    assert any("leaves" in stats for stats in host["engine.h2d"])
+    # ... and only a root span is an anchor; a page claim, which writes no
+    # ring event, is on the profiler's timeline all the same.
+    assert not any("ts_us" in stats for stats in host["engine.h2d"])
+    assert host["engine.page_alloc"]
+    assert not any(e["name"] == "engine.page_alloc" for e in eng.tracer.events)
+    other = eng.tracer.chrome_trace()["otherData"]
+    assert abs(other["epoch_unix_ns"] / 1e9 - eng.recorder.events()[0]["t"]) < 600
+
+
+def test_the_breakdown_tool_places_a_bundle_on_a_capture(tmp_path, capsys):
+    """``scripts/engine_breakdown.py`` is the reader of the dispatch
+    events, the labelled starved series and the clock anchors: it finds
+    in a bundle exactly the dispatches of the steps a capture caught."""
+    cfg, params, eng = _setup()
+    _drain(eng, params, _prompts(cfg, 1, 2))          # warm, before it
+    for p in _prompts(cfg, 2, 2):
+        eng.add_request(p)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "xplane"), profiler_options=options)
+    try:
+        for _ in range(3):
+            eng.step(params)
+    finally:
+        jax.profiler.stop_trace()
+    while eng.has_work():                             # ... and after it
+        eng.step(params)
+    bundle = eng.dump_diagnostics(tmp_path / "bundle")
+    out = engine_breakdown.main(
+        [str(bundle), "--xplane", str(tmp_path / "xplane"), "--json"]
+    )
+    assert json.loads(capsys.readouterr().out) == out
+    cap = out["capture"]
+    assert len(cap["captured_steps"]) == 3
+    assert cap["dispatch_steps"] == cap["captured_steps"]
+    assert 0 < cap["host_starved_s"] < cap["interval_s"]
+    events = eng.recorder.events("engine.dispatch")
+    assert len(events) > 3
+    rows, snap = out["by_family"], eng.registry.snapshot()
+    assert set(rows) == {"refill_step", "decode_block"}
+    assert sum(r["dispatches"] + r["compiled"] for r in rows.values()) == (
+        len(events)
+    )
+    steady = [e for e in events if not e["compiled"]]
+    assert 0 < len(steady) < len(events)      # the warm drain compiled
+    for field in ("wait_s", "h2d_s", "decode_steps", "context_tokens"):
+        assert sum(r[field] for r in rows.values()) == pytest.approx(
+            sum(e[field] for e in steady), rel=1e-9
+        )
+    assert sum(e["prefill_tokens"] for e in events) == (
+        snap["engine_prefill_tokens_total"]
+    )
+    reg = out["registry"]
+    assert set(reg["by_span_s"]) >= {"h2d", "enqueue", "consume", "sched"}
+    assert abs(reg["labelled_minus_plain_s"]) < 1e-9
+    assert reg["wait_share_pct"] + reg["starved_share_pct"] < 100.0
+    engine_breakdown.main([str(bundle)])              # the printed form
+    assert "starved by span: " in capsys.readouterr().out
+
+
+def test_the_breakdown_tool_names_an_idle_gap_by_its_engine_span():
+    """The device-plane half of the tool, on a capture written out by
+    hand (a CPU capture has no TPU plane): one 250 us hole in the ops,
+    its middle inside ``engine.h2d`` and the runtime's allocator."""
+    us = 1e3
+    capture = {
+        "anchors": [(1000 * us, 0.0, 7)],        # tracer 0 us = profiler 1 ms
+        "engine": [[
+            ("engine.step", 1000 * us, 1000 * us),
+            ("engine.h2d", 1100 * us, 200 * us),
+            ("engine.enqueue.decode_block", 1300 * us, 100 * us),
+        ]],
+        "runtime": [[("Allocate", 1150 * us, 100 * us)]],
+        "ops": [(1000 * us, 1100 * us), (1350 * us, 2000 * us)],
+    }
+    event = dict.fromkeys(engine_breakdown.SUMMED, 0.0)
+    event.update(
+        family="decode_block", compiled=False, step=7, starved_s=0.0003,
+        wait_s=0.0006,
+    )
+    bundle = {"epoch_unix_ns": 10**18, "dispatches": [
+        dict(event, t=1e9 + 0.0010),             # enqueued at tracer 0.4 ms
+        dict(event, t=1e9 + 0.0050, step=8),     # after the capture
+    ]}
+    out = engine_breakdown.place_on_capture(bundle, capture)
+    assert out["dispatch_steps"] == [7]
+    assert out["host_starved_s"] == pytest.approx(0.0003)
+    assert out["interval_s"] == pytest.approx(0.001)
+    assert out["device_idle_s"] == pytest.approx(250e-6)
+    assert out["idle_by_span_s"] == {"engine.h2d": pytest.approx(250e-6)}
+    assert list(out["idle_by_span_and_runtime_event_s"]) == [
+        "engine.h2d | Allocate"
+    ]
+
+
+# --- (e) the benchmark's metric files name things a served engine has ----------
+
+
+def _metric_files(readers):
+    out = []
+    for path in sorted((REPO / "benchmark" / "metrics").glob("*.json")):
+        spec = json.loads(path.read_text())
+        if spec["reader"] in readers:
+            out.append(pytest.param(spec, id=path.stem))
+    return out
+
+
+@pytest.mark.parametrize("spec", _metric_files({"registry", "ledger_share"}))
+def test_metric_file_reads_what_a_served_engine_has(served, spec):
+    params = spec["params"]
+    if spec["reader"] == "ledger_share":
+        report = served["eng"].ledger.window_report()
+        assert set(params["buckets"]) <= set(report["buckets"])
+    else:
+        for key in ("name", "over"):
+            if key in params:
+                assert params[key] in served["end"], params[key]

@@ -6,12 +6,21 @@ tests cannot see what Mosaic refuses — a misaligned slice, an unsupported
 vector cast, too much VMEM — so each kernel the trainer and the engine
 dispatch is compiled here with ``interpret=False`` at the widths the chip
 runs, and must come out as a ``tpu_custom_call``. Nothing executes: a
-compile that passes is not a chip run (``chip_smoke.py`` is).
+compile that passes is not a chip run (``chip_smoke.py`` is). The last
+section compiles the engine's step programs and the train step whole, at
+small widths, and looks up in them every module and instruction name that
+a ``benchmark/metrics/*.json`` file matches in a device trace.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and every xdist worker imports
 this file.
 """
+
+import dataclasses
+import json
+import pathlib
+import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -198,3 +207,170 @@ def test_int4_ff(one_chip):
         ((_NOUT // 2, _K), U8), ((_NOUT // _G, _K), F32),
     ]
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+# --- the names the benchmark's trace metrics match ----------------------------
+#
+# ``benchmark/metrics/*.json`` find their device time by name: a module
+# prefix (``jit_decode_block``) and an instruction prefix inside it
+# (``attn._blocked_cached_attention``). A rename would not fail anything
+# here on the CPU; it would null a metric on the chip. So the whole step
+# programs are compiled for the described chip, at widths small enough to
+# take seconds, and every name a metric file gives is looked up in them.
+
+_METRICS = pathlib.Path(__file__).resolve().parents[1] / "benchmark" / "metrics"
+
+
+def _named_by_trace_metrics():
+    """``(metric, module prefix, op prefix or None)`` for every metric file
+    whose reader matches names in a device trace."""
+    out = []
+    for path in sorted(_METRICS.glob("*.json")):
+        params = json.loads(path.read_text())["params"]
+        modules = params.get("modules") or (
+            [params["module"]] if "module" in params else []
+        )
+        for module in modules:
+            out.append(pytest.param(
+                module, params.get("op"), id=f"{path.stem}-{module}"
+            ))
+    return out
+
+
+@pytest.fixture(scope="module")
+def step_programs(topo):
+    """HLO module name -> optimized text of the engine's step programs and
+    the train step, compiled for one described chip. Nothing runs."""
+    import flax.linen as nn
+    import numpy as np
+    from flax.training.train_state import TrainState
+    from jax.sharding import Mesh
+
+    from learning_jax_sharding_tpu.models.serving import ContinuousEngine
+    from learning_jax_sharding_tpu.models.transformer import (
+        Transformer,
+        TransformerConfig,
+        fused_next_token_loss,
+    )
+    from learning_jax_sharding_tpu.ops.flash_attention import (
+        make_flash_attn_fn,
+    )
+    from learning_jax_sharding_tpu.parallel import mesh_sharding
+    from learning_jax_sharding_tpu.parallel.logical import (
+        RULES_DP_TP,
+        RULES_TP_SERVING,
+        activate,
+        tree_shardings,
+    )
+    from learning_jax_sharding_tpu.training.loop import (
+        TrainLoopConfig,
+        default_optimizer,
+    )
+    from learning_jax_sharding_tpu.training.pipeline import make_train_step
+
+    dev = topo.devices[0]
+    one = SingleDeviceSharding(dev)
+    mesh = Mesh(np.asarray([dev]).reshape(1, 1), ("data", "model"))
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=2, features=128, num_heads=2,
+        head_dim=64, hidden=256, max_seq_len=256, dtype=BF16,
+        param_dtype=BF16, decode_attention="blocked",
+    )
+    b, chunk = 4, 128
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+            tree,
+        )
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=one)
+
+    params = on_chip(jax.eval_shape(lambda: nn.meta.unbox(
+        Transformer(cfg).init(
+            {"params": jax.random.key(0)}, np.zeros((2, 8), np.int32)
+        )["params"]
+    )))
+    rng = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+    flags = jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=one)
+    compiled = []
+    # The kernels ask the backend whether to interpret: say "tpu" while
+    # lowering, as the chip would.
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        for mixed in (False, True):
+            eng = ContinuousEngine(
+                cfg, mesh, RULES_TP_SERVING, batch_size=b, max_new_tokens=8,
+                refill_chunk=chunk, paged_pages=9, page_size=64,
+                inference_dtype=BF16, mixed=mixed,
+            )
+            first = (params, None, ints(b, chunk), ints(b), ints(b), rng)
+            with activate(mesh, RULES_TP_SERVING):
+                cache = on_chip(jax.eval_shape(eng._first_refill_fn, *first)[1])
+                if mixed:
+                    compiled.append(eng._mixed_step_fn.lower(
+                        params, cache, ints(b, chunk), ints(b), flags,
+                        ints(b), ints(b), ints(b), ints(b), ints(b), rng,
+                    ))
+                    continue
+                compiled.append(eng._first_refill_fn.lower(*first))
+                compiled.append(eng._refill_step_fn.lower(
+                    params, None, cache, ints(b, chunk), ints(b), flags,
+                    ints(b), ints(b), rng,
+                ))
+                compiled.append(eng._decode_block_fn.lower(
+                    params, cache, ints(b), ints(b), ints(b), ints(b), rng,
+                ))
+
+        # The train step as benchmark/train.py builds it, its state abstract.
+        train_cfg = dataclasses.replace(
+            cfg, param_dtype=F32, decode_attention="dense", max_seq_len=1024,
+            attn_fn=make_flash_attn_fn(interpret=False),
+        )
+        module = Transformer(train_cfg)
+        optimizer = default_optimizer(
+            TrainLoopConfig(steps=10, global_batch_size=2)
+        )
+
+        def boxed_init(key, x):
+            return TrainState.create(
+                apply_fn=module.apply, tx=optimizer,
+                params=module.init({"params": key}, x)["params"],
+            )
+
+        tokens = jax.ShapeDtypeStruct((2, 1024), I32)
+        with activate(mesh, RULES_DP_TP):
+            abstract = jax.eval_shape(boxed_init, jax.random.key(0), tokens)
+            state_sh = tree_shardings(abstract, mesh, RULES_DP_TP)
+            state = jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                nn.meta.unbox(abstract), state_sh,
+            )
+            batch_sh = mesh_sharding(mesh, "data", None)
+            batch = {
+                k: jax.ShapeDtypeStruct(tokens.shape, I32, sharding=batch_sh)
+                for k in ("inputs", "targets")
+            }
+            step = make_train_step(
+                state_sh, {k: batch_sh for k in batch}, mesh, RULES_DP_TP,
+                loss_fn=fused_next_token_loss, loss_needs_params=True,
+                apply_kwargs={"return_hidden": True},
+            )
+            compiled.append(step.jitted.lower(state, batch))
+    texts = [low.compile().as_text() for low in compiled]
+    return {re.match(r"HloModule (\S+?),", t).group(1): t for t in texts}
+
+
+@pytest.mark.parametrize("module,op", _named_by_trace_metrics())
+def test_trace_metric_names_exist_in_the_compiled_programs(
+    step_programs, module, op
+):
+    hits = [t for name, t in step_programs.items() if name.startswith(module)]
+    assert hits, f"no program named {module}*: {sorted(step_programs)}"
+    if op is not None:
+        (text,) = hits
+        names = re.findall(r"%([A-Za-z_][\w.\-]*) = ", text)
+        assert any(n.startswith(op) for n in names), (
+            f"no instruction named {op}* in {module}"
+        )
+        assert "tpu_custom_call" in text
