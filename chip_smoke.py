@@ -45,7 +45,10 @@ from learning_jax_sharding_tpu.ops.attention import (
     causal_mask,
     dot_product_attention,
 )
-from learning_jax_sharding_tpu.ops.decode_attention import decode_attention
+from learning_jax_sharding_tpu.ops.decode_attention import (
+    decode_attention,
+    fuse_kv,
+)
 from learning_jax_sharding_tpu.ops.flash_attention import (
     flash_attention,
     make_flash_attn_fn,
@@ -374,15 +377,19 @@ def check_kernels(cfg, seed: int) -> dict:
     # (a) one token per row, its k/v merged into the page inside the kernel.
     q1, k_new, v_new = normal(b, 1, n, h), normal(b, n, 1, h), normal(b, n, 1, h)
     fold = jax.jit(
-        lambda q, kp, vp, i, kn, vn, t: decode_attention(
-            q, kp, vp, i, k_new=kn, v_new=vn, block_table=t
+        lambda q, kvp, i, kvn, t: decode_attention(
+            q, kvp, i, kv_new=kvn, block_table=t
         )
     )
-    args = (q1, to_pool(kc), to_pool(vc), index, k_new, v_new, table_j)
+    args = (
+        q1, fuse_kv(to_pool(kc), to_pool(vc)), index, fuse_kv(k_new, v_new),
+        table_j,
+    )
     require_kernel(
         "decode_attention paged+fold", fold.lower(*args).compile().as_text()
     )
-    out, k_pool, v_pool = fold(*args)
+    out, kv_pool = fold(*args)
+    k_pool, v_pool = kv_pool[..., :h], kv_pool[..., h:]
     rows = jnp.arange(b)
     kc_new = kc.at[rows, :, index].set(k_new[:, :, 0])
     vc_new = vc.at[rows, :, index].set(v_new[:, :, 0])
@@ -393,9 +400,9 @@ def check_kernels(cfg, seed: int) -> dict:
     # (b) a refill chunk whose k/v are already in the cache.
     qc = normal(b, REFILL_CHUNK, n, h)
     chunk = jax.jit(
-        lambda q, kp, vp, i, t: decode_attention(q, kp, vp, i, block_table=t)
+        lambda q, kvp, i, t: decode_attention(q, kvp, i, block_table=t)
     )
-    args = (qc, to_pool(kc), to_pool(vc), index, table_j)
+    args = (qc, fuse_kv(to_pool(kc), to_pool(vc)), index, table_j)
     require_kernel(
         "decode_attention paged chunk", chunk.lower(*args).compile().as_text()
     )

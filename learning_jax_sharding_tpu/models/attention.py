@@ -199,9 +199,10 @@ class MultiHeadAttention(nn.Module):
     # (ops/decode_attention.py) whose traffic scales with the VALID cache
     # length and which reads GQA caches at N_kv heads with no repeat_kv
     # expansion. "auto" (default) picks blocked on TPU, dense elsewhere.
-    # The backends differ in cache layout: dense stores (B, L, N_kv, H),
-    # blocked stores (B, N_kv, L, H) (sequence-major per head, so each cache
-    # block is one contiguous DMA).
+    # The backends differ in cache layout: dense stores k and v as
+    # (B, L, N_kv, H) each, blocked stores one (B, N_kv, L, 2H) buffer
+    # (sequence-major per head, k | v fused on the minor axis, so each cache
+    # block is one contiguous, lane-dense DMA).
     decode_block_k: Optional[int] = None   # blocked-backend cache block size
     quantization: Optional[str] = None
     # "int4": projections consume quantize_tree(bits=4) params VERBATIM via
@@ -543,14 +544,18 @@ class MultiHeadAttention(nn.Module):
 
         Same cache protocol as the dense path (append chunk at the index,
         attend against the valid prefix) but the cache lives sequence-major
-        per head — ``(B, N_kv, L, H)`` — and attention runs through
+        per head with k | v fused — ``(B, N_kv, L, 2H)`` — and attention
+        runs through
         :func:`ops.decode_attention.decode_attention`: HBM traffic per step
         scales with the valid cache length instead of ``max_decode_len``,
         GQA caches are read at N_kv heads (no ``repeat_kv`` expansion), and
         int8 caches are dequantized only for the blocks actually read —
         the three decode costs the dense path pays in full every token.
         """
-        from learning_jax_sharding_tpu.ops.decode_attention import decode_attention
+        from learning_jax_sharding_tpu.ops.decode_attention import (
+            decode_attention,
+            fuse_kv,
+        )
 
         b, s, n, h = q.shape
         n_kv = k.shape[2]
@@ -560,6 +565,9 @@ class MultiHeadAttention(nn.Module):
         store = self.kv_cache_dtype if self.kv_cache_dtype is not None else self.dtype
         quantized = store == jnp.int8
 
+        # Each position's k and v share one cache row, k | v on the minor
+        # axis: at head size 64 that is one full 128-lane row, where two
+        # (…, 64) buffers would each be padded to 128 lanes in HBM.
         if paged:
             if not ragged:
                 raise ValueError("decode_paged requires decode_ragged")
@@ -570,18 +578,15 @@ class MultiHeadAttention(nn.Module):
                     f"dividing max_decode_len ({length}); got {page}"
                 )
             pool = self.decode_page_count
-            kv_shape, sc_shape = (pool, n_kv, page, h), (pool, n_kv, page)
+            kv_shape, sc_shape = (pool, n_kv, page, 2 * h), (pool, n_kv, page)
             block_table = self.variable(
                 "cache", "block_table", jnp.zeros, (b, length // page),
                 jnp.int32,
             )
         else:
-            kv_shape, sc_shape = (b, n_kv, length, h), (b, n_kv, length)
-        cached_k = self.variable(
-            "cache", "cached_key", jnp.zeros, kv_shape, store
-        )
-        cached_v = self.variable(
-            "cache", "cached_value", jnp.zeros, kv_shape, store
+            kv_shape, sc_shape = (b, n_kv, length, 2 * h), (b, n_kv, length)
+        cached_kv = self.variable(
+            "cache", "cached_kv", jnp.zeros, kv_shape, store
         )
         cache_index = self.variable(
             "cache", "cache_index",
@@ -597,21 +602,20 @@ class MultiHeadAttention(nn.Module):
 
         idx = self._advance(cache_index, s, chunk_lengths)
         # Ragged single-token steps FOLD the write into the kernel: the new
-        # k/v merge in-VMEM at each row's slot and flush back through cache
-        # outputs aliased to the inputs — the per-row scatter (measured at
-        # ~18 µs of serial launch per layer, PERF.md "Ragged serving") never
-        # exists. Multi-token ragged chunks (prefill) still scatter — once
-        # per generation, amortized.
+        # k|v merges in-VMEM at each row's slot and flushes back through a
+        # cache output aliased to the input — the per-row scatter (measured
+        # at ~18 µs of serial launch per layer, PERF.md "Ragged serving")
+        # never exists. Multi-token ragged chunks (prefill) still scatter —
+        # once per generation, amortized.
         fold = ragged and s == 1
 
-        def to_seq_major(chunk):
-            if quantized:
-                scale, chunk = quantize_kv_chunk(chunk)
-                return (
-                    chunk.astype(store).transpose(0, 2, 1, 3),
-                    scale.transpose(0, 2, 1),
-                )
-            return chunk.astype(store).transpose(0, 2, 1, 3), None
+        # The chunk, sequence-major and fused: (B, N_kv, S, 2H), with its
+        # (B, N_kv, S) k and v scales when the cache is int8.
+        ks_sm = vs_sm = None
+        if quantized:
+            (ks_sm, k), (vs_sm, v) = quantize_kv_chunk(k), quantize_kv_chunk(v)
+            ks_sm, vs_sm = ks_sm.transpose(0, 2, 1), vs_sm.transpose(0, 2, 1)
+        kv_sm = fuse_kv(k, v).astype(store).transpose(0, 2, 1, 3)
 
         def ragged_write(buf, chunk):
             # Length-aware when per-row valid counts ride the call (see
@@ -646,30 +650,19 @@ class MultiHeadAttention(nn.Module):
             upd = jnp.moveaxis(chunk, 2, 1)
             return pool_buf.at[pages, :, slots].set(upd)
 
-        def write(var, chunk, scale_var=None):
-            chunk, scale = to_seq_major(chunk)
+        def write(var, chunk):
             if paged:
-                if quantized:
-                    scale_var.value = paged_write(scale_var.value, scale)
                 var.value = paged_write(var.value, chunk)
             elif ragged:
-                if quantized:
-                    scale_var.value = ragged_write(scale_var.value, scale)
                 var.value = ragged_write(var.value, chunk)
             else:
-                if quantized:
-                    scale_var.value = jax.lax.dynamic_update_slice(
-                        scale_var.value, scale, (0, 0, idx)
-                    )
                 var.value = jax.lax.dynamic_update_slice(
-                    var.value, chunk, (0, 0, idx, 0)
+                    var.value, chunk, (0, 0, idx) + (0,) * (chunk.ndim - 3)
                 )
 
         fold_args = {}
         if fold:
-            k_sm, ks_sm = to_seq_major(k)
-            v_sm, vs_sm = to_seq_major(v)
-            fold_args = dict(k_new=k_sm, v_new=v_sm)
+            fold_args = dict(kv_new=kv_sm)
             if quantized:
                 fold_args.update(ks_new=ks_sm, vs_new=vs_sm)
             if chunk_lengths is not None:
@@ -678,16 +671,17 @@ class MultiHeadAttention(nn.Module):
                 # slot out of range and flushes the block unchanged.
                 fold_args["write_enable"] = chunk_lengths
         else:
-            write(cached_k, k, k_scale if quantized else None)
-            write(cached_v, v, v_scale if quantized else None)
+            write(cached_kv, kv_sm)
+            if quantized:
+                write(k_scale, ks_sm)
+                write(v_scale, vs_sm)
 
         # Paged pools lead with the PAGE axis (shared across rows), so only
         # the heads dim carries a sharding hint; per-row buffers shard
         # batch × heads as before.
         kv_axes = (None, HEADS, None, KV) if paged else (BATCH, HEADS, None, KV)
         sc_axes = kv_axes[:-1]
-        kc = nn.with_logical_constraint(cached_k.value, kv_axes)
-        vc = nn.with_logical_constraint(cached_v.value, kv_axes)
+        kvc = nn.with_logical_constraint(cached_kv.value, kv_axes)
         scales = {}
         if quantized:
             scales = dict(
@@ -701,20 +695,14 @@ class MultiHeadAttention(nn.Module):
         # window/block_k pass at CALL time either way: the module is the
         # single source of truth, so a mesh-aware wrapper built without them
         # cannot silently drop the sliding window.
-        if fold:
-            result = fn(
-                q, kc, vc, idx,
-                window=self.window, block_k=self.decode_block_k,
-                **scales, **fold_args, **table_args,
-            )
-            out, new_k, new_v = result[:3]
-            cached_k.value = new_k
-            cached_v.value = new_v
-            if quantized:
-                k_scale.value, v_scale.value = result[3:]
-            return out
-        return fn(
-            q, kc, vc, idx,
+        result = fn(
+            q, kvc, idx,
             window=self.window, block_k=self.decode_block_k,
-            **scales, **table_args,
+            **scales, **fold_args, **table_args,
         )
+        if not fold:
+            return result
+        out, cached_kv.value = result[:2]
+        if quantized:
+            k_scale.value, v_scale.value = result[2:]
+        return out

@@ -134,7 +134,7 @@ _RECOVERABLE_DISPATCH = (InjectedFault, FloatingPointError)
 #: leaves ``kv_page_spill``/``kv_page_fill`` move one page of. Per-slot
 #: counters (cache_index, position, block_table) stay: a retained prefix
 #: page carries K/V only; the mapping is host state.
-_PAGE_LEAF_KEYS = ("cached_key", "cached_value", "key_scale", "value_scale")
+_PAGE_LEAF_KEYS = ("cached_kv", "key_scale", "value_scale")
 
 
 def _dispatch_span(kind):

@@ -8,20 +8,33 @@ tokens. Measured on the v5e 125M decode bench (1024-slot caches, ≤256 valid),
 that is ~4.6× off the HBM bandwidth roofline: decode is cache-bandwidth-bound,
 and most of the bandwidth went to zero padding.
 
-This kernel makes decode traffic proportional to the VALID cache length:
+This kernel makes decode traffic AND grid steps proportional to the VALID
+cache length:
 
-* the k/v grid dimension covers the full buffer (grids must be static), but
-  block index maps CLAMP out-of-range steps to the last needed block — Pallas
-  only issues a DMA when a block index changes between consecutive grid
-  steps, so clamped (repeated) steps move no HBM bytes, and ``pl.when`` skips
-  their compute. Cost scales with ``index + S``, not ``max_seq_len``.
+* the grid walks a WORK LIST, one step per (row, cache block) a row holds:
+  ``(nq, W)`` with ``W = Σ_rows blocks held`` a RUN-TIME scalar (Pallas
+  takes a traced grid bound), and two scalar-prefetched arrays naming each
+  step's row and logical block. Index maps read the list, so a step fetches
+  exactly one block that some row needs; no shape depends on the traffic.
+  A static ``(B, nq, L // block_k)`` grid, its out-of-range steps clamped
+  (no DMA) and skipped by ``pl.when``, still pays the pipeline's per-step
+  cost on every skipped step: at the paged serving shape (16 rows under a
+  table 16 pages wide, ~3.4 pages held a row) 256 steps for ~54 that read
+  anything, 0.29 µs each, a third of the call (PERF.md, PR 28).
+* each position's k and v share ONE cache row, ``k | v`` on the minor axis
+  (:func:`fuse_kv`): ``(B, N_kv, L, 2H)``. A TPU array's minor axis is
+  padded to 128 lanes in HBM, so at head size 64 two ``(…, 64)`` buffers
+  took twice their bytes in memory and in every block moved; the fused row
+  is exactly 128 lanes, and a block is one DMA instead of two. The kernel
+  splits the tile's lane halves in VMEM.
 * ALL kv heads ride one grid step (batched dot_generals over the head dim).
   At serving shapes the per-step work is tiny — a (B·N_kv, nk) grid was
-  measured grid-step-bound on the v5e, and folding heads cut the 125M decode
-  grid from 384 steps to 32.
-* the cache layout is ``(B, N_kv, L, H)`` — sequence-major per head — so each
-  ``(block_k, H)`` tile is one contiguous DMA (the model's ``(B, L, N, H)``
-  training layout would make every cache row a strided 128-byte read).
+  measured grid-step-bound on the v5e, which is why heads fold into one
+  step (and why no step reads nothing).
+* the cache layout is ``(B, N_kv, L, 2H)`` — sequence-major per head — so
+  each ``(block_k, 2H)`` tile is one contiguous DMA (the model's
+  ``(B, L, N, H)`` training layout would make every cache row a strided
+  read).
 * GQA-native: q arrives at full ``N = N_kv × group`` heads and is folded to
   ``(group·S, H)`` rows per kv head — the cache is never expanded by
   ``repeat_kv``, so K/V HBM traffic stays at ``N_kv`` heads (the whole point
@@ -31,12 +44,12 @@ This kernel makes decode traffic proportional to the VALID cache length:
   (``q·(k_int·s) = (q·k_int)·s``) and the probability columns for v, so the
   int8 bytes are what crosses HBM — the upcast never materializes.
 * a sliding window additionally advances the FIRST block read
-  (``kstart = (index - window + 1) // block_k``), so SWA decode touches only
-  the window band.
-* chunk queries (prefill / speculative verification) are tiled over a third
-  grid dimension in ``block_q``-row tiles, each stopping at its own causal
-  frontier — long prompts stay inside VMEM and skip strictly-future blocks'
-  traffic and compute both.
+  (``kstart = (index - window + 1) // block_k``; the row's work list starts
+  there), so SWA decode touches only the window band.
+* chunk queries (prefill / speculative verification) are tiled over the
+  grid's leading dimension in ``block_q``-row tiles, each stopping at its
+  own causal frontier — long prompts stay inside VMEM; a tile repeats its
+  last block for the row's strictly-future blocks (no DMA, no compute).
 
 The reference has no decode path at all (its attention forward is a timing
 harness, `/root/reference/case6_attention.py:229-238`); this is the serving
@@ -58,6 +71,11 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/max NaN-free
 _BLOCK_Q = 128    # q rows per grid tile; bounds VMEM for long prefill chunks
+# The name the kernel runs under in a device trace: XLA names the custom call
+# after the innermost scope, which was the attention module's cached-attention
+# method until the call was jitted here; the benchmark's trace metrics find the
+# kernel by it (benchmark/metrics/decode_attn_roofline.json).
+_TRACE_NAME = "attn._blocked_cached_attention"
 
 
 def auto_block_k(length: int, cap: int = 256) -> int:
@@ -73,42 +91,62 @@ def auto_block_k(length: int, cap: int = 256) -> int:
 def _last_block(bi, qi, sref, *, qb: int, s: int, block_k: int):
     """Last cache block q-tile ``qi`` of row ``bi`` may touch: its causal
     frontier (the tile's final query sits at ``index_b + min((qi+1)·qb, s)
-    - 1``), which never exceeds the row's valid prefix ``sref[bi, 1] - 1``.
+    - 1``), which never exceeds the row's valid prefix ``sref[1, bi] - 1``.
     Per-ROW: ragged batches (mixed prompt lengths) clamp each row to its own
     frontier, so short rows fetch fewer cache blocks."""
     last_q = jnp.minimum((qi + 1) * qb, s) - 1
-    return jnp.minimum(sref[bi, 1] - 1, (sref[bi, 2] + last_q) // block_k)
+    return jnp.minimum(sref[1, bi] - 1, (sref[2, bi] + last_q) // block_k)
+
+
+def _step_of(w, sref, *, b: int):
+    """Grid step ``w`` of the work list → ``(row, logical block)``. The list
+    is never materialized: rows follow each other in order, row ``r``
+    holding steps ``[ends[r-1], ends[r])`` with ``ends = sref[5]`` the
+    running total of blocks held, so the row is a binary search on the
+    scalar core and the block follows from the row's last one. ``sref[5]``
+    is padded to a power of two with a sentinel no step reaches, so the
+    search needs no bounds check. Bare ``lax`` primitives: every index map
+    of a call traces this, and ``jnp`` wrappers cost several times more."""
+    row = jnp.int32(0)
+    for shift in reversed(range((b - 1).bit_length())):
+        # Do at least ``row + 2^shift`` rows end at or before w?
+        ends = sref[5, jax.lax.add(row, jnp.int32((1 << shift) - 1))]
+        row = jax.lax.select(
+            jax.lax.le(ends, w), jax.lax.add(row, jnp.int32(1 << shift)), row
+        )
+    return row, jax.lax.sub(sref[1, row], jax.lax.sub(sref[5, row], w))
 
 
 def _kernel(
-    s_ref,                # SMEM (B, 5): [kstart_block, valid_blocks, index,
-    #                       write_block, write_offset] per row
+    s_ref,                # SMEM (6, B): kstart_block, valid_blocks, index,
+    #                       write_block, write_offset, blocks held by
+    #                       this row and the rows before it — per row
     *rest,                # [t_ref (paged block table, index maps only),]
     #                       q_ref (1, N_kv, GQ, H),
-    #                       k_ref/v_ref (1, N_kv, block_k, H), ...
+    #                       kv_ref (1, N_kv, block_k, 2H), ...
     scale: float, block_k: int, group: int, qb: int, s: int,
     window, quantized: bool, fold: bool, paged: bool = False,
 ):
     rest = list(rest)
     if paged:
         rest.pop(0)  # the block table feeds the index maps, not the body
-    q_ref, k_ref, v_ref = rest.pop(0), rest.pop(0), rest.pop(0)
+    q_ref, kv_ref = rest.pop(0), rest.pop(0)
     if quantized:
         ks_ref, vs_ref = rest.pop(0), rest.pop(0)
     if fold:
-        kn_ref, vn_ref = rest.pop(0), rest.pop(0)
+        kvn_ref = rest.pop(0)
         if quantized:
             ksn_ref, vsn_ref = rest.pop(0), rest.pop(0)
     o_ref = rest.pop(0)
     if fold:
-        ok_ref, ov_ref = rest.pop(0), rest.pop(0)
+        okv_ref = rest.pop(0)
         if quantized:
             oks_ref, ovs_ref = rest.pop(0), rest.pop(0)
     acc_ref, m_ref, l_ref = rest
-    bi, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    blk = s_ref[bi, 0] + j
+    qi = pl.program_id(0)
+    bi, blk = _step_of(pl.program_id(1), s_ref, b=s_ref.shape[1])
 
-    @pl.when(j == 0)
+    @pl.when(blk == s_ref[0, bi])
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
@@ -116,33 +154,27 @@ def _kernel(
 
     @pl.when(blk <= _last_block(bi, qi, s_ref, qb=qb, s=s, block_k=block_k))
     def _step():
-        k_blk = k_ref[0]                                   # (N_kv, bk, H)
-        v_blk = v_ref[0]
+        kv_blk = kv_ref[0]                                 # (N_kv, bk, 2H)
         if quantized:
             ks_blk, vs_blk = ks_ref[0], vs_ref[0]          # (N_kv, bk)
         if fold:
-            # The new token's k/v merge IN-VMEM at this row's write slot —
+            # The new token's k|v merges IN-VMEM at this row's write slot —
             # the separate per-row cache scatter (and its serial launch)
-            # never exists. Merged blocks flush back through the aliased
-            # cache outputs below.
+            # never exists. The merged block flushes back through the
+            # aliased cache output below.
             slot = jax.lax.broadcasted_iota(
                 jnp.int32, (1, block_k, 1), 1
-            ) == s_ref[bi, 4]
-            here = blk == s_ref[bi, 3]
-
-            def merge(blk_vals, new_ref):
-                return jnp.where(
-                    jnp.logical_and(here, slot), new_ref[0], blk_vals
-                )
-
-            k_blk = merge(k_blk, kn_ref)
-            v_blk = merge(v_blk, vn_ref)
+            ) == s_ref[4, bi]
+            here = blk == s_ref[3, bi]
+            kv_blk = jnp.where(
+                jnp.logical_and(here, slot), kvn_ref[0], kv_blk
+            )
             if quantized:
                 # A second iota, not ``slot[..., 0]``: Mosaic cannot
                 # squeeze a mask vector (i1 has no vreg bitcast).
                 slot2 = jax.lax.broadcasted_iota(
                     jnp.int32, (1, block_k), 1
-                ) == s_ref[bi, 4]
+                ) == s_ref[4, bi]
                 ks_blk = jnp.where(
                     jnp.logical_and(here, slot2), ksn_ref[0], ks_blk
                 )
@@ -152,12 +184,13 @@ def _kernel(
 
             @pl.when(here)
             def _write_back():
-                ok_ref[0] = k_blk
-                ov_ref[0] = v_blk
+                okv_ref[0] = kv_blk
                 if quantized:
                     oks_ref[0] = ks_blk
                     ovs_ref[0] = vs_blk
 
+        h = q_ref.shape[-1]
+        k_blk, v_blk = kv_blk[:, :, :h], kv_blk[:, :, h:]  # lane halves
         q = q_ref[0].astype(jnp.float32) * scale           # (N_kv, GQ, H)
         k = k_blk.astype(jnp.float32)
         sc = jax.lax.dot_general(
@@ -176,7 +209,7 @@ def _kernel(
         # chunk (non-dividing last tile) mask nothing extra — their stores
         # are dropped by the blocked write.
         rows = jax.lax.broadcasted_iota(jnp.int32, (1, gq, 1), 1)
-        qpos = s_ref[bi, 2] + qi * qb + (rows // group if group > 1 else rows)
+        qpos = s_ref[2, bi] + qi * qb + (rows // group if group > 1 else rows)
         cols = blk * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, 1, block_k), 2
         )
@@ -201,25 +234,30 @@ def _kernel(
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    # Output block index is constant over j, so it flushes once per q tile;
-    # write at the STATIC last step (skipped steps don't touch acc).
-    @pl.when(j == pl.num_programs(2) - 1)
+    # The output block index is constant over a row's steps, so it flushes
+    # once per row and q tile; write at the row's LAST listed block (a tile
+    # whose causal frontier came earlier skipped the steps between).
+    @pl.when(blk == s_ref[1, bi] - 1)
     def _finish():
         l = l_ref[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_ref[:] / safe_l).astype(o_ref.dtype)
 
 
+def fuse_kv(k: jax.Array, v: jax.Array) -> jax.Array:
+    """``(..., H)`` keys and values → the cache's fused ``(..., 2H)`` rows:
+    k in lanes ``[0, H)``, v in ``[H, 2H)``."""
+    return jnp.concatenate([k, v], axis=-1)
+
+
 def decode_attention(
     q: jax.Array,
-    k_cache: jax.Array,
-    v_cache: jax.Array,
+    kv_cache: jax.Array,
     index: jax.Array,
     *,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
-    k_new: jax.Array | None = None,
-    v_new: jax.Array | None = None,
+    kv_new: jax.Array | None = None,
     ks_new: jax.Array | None = None,
     vs_new: jax.Array | None = None,
     write_enable: jax.Array | None = None,
@@ -235,8 +273,12 @@ def decode_attention(
     Args:
         q: ``(B, S, N, H)`` chunk queries (S = 1 for token steps, the prompt
             length for prefill). N may exceed the cache's head count (GQA).
-        k_cache / v_cache: ``(B, N_kv, L, H)`` cache buffers — float, or int8
-            with ``k_scale``/``v_scale``.
+        kv_cache: ``(B, N_kv, L, 2H)`` cache buffer, each position's k and v
+            FUSED on the minor axis (:func:`fuse_kv`) — float, or int8 with
+            ``k_scale``/``v_scale``. One 128-lane row at head size 64: a
+            ``(..., 64)`` minor axis is padded to 128 lanes in HBM, so
+            separate k and v buffers cost twice their bytes in memory and
+            in every block the kernel moves.
         index: int32 scalar, or per-row ``(B,)`` for RAGGED batches (mixed
             prompt/generation lengths) — absolute position of each row's
             first chunk query; the chunk's own k/v must already be written
@@ -249,10 +291,10 @@ def decode_attention(
         window: causal sliding window — query at position p attends
             ``(p - window, p]``; blocks before every query's window are not
             even fetched.
-        k_new / v_new: FOLDED WRITE (ragged decode, S = 1 only):
-            ``(B, N_kv, 1, H)`` sequence-major new-token k/v, merged
+        kv_new: FOLDED WRITE (ragged decode, S = 1 only):
+            ``(B, N_kv, 1, 2H)`` sequence-major new-token k|v, merged
             IN-KERNEL at each row's ``index_b`` slot before attention and
-            flushed back through cache outputs ALIASED to the cache inputs
+            flushed back through a cache output ALIASED to the cache input
             — one modified block per row moves, and the per-row cache
             scatter (measured at ~18 µs of serial launch per layer,
             PERF.md "Ragged serving") never exists. The chunk must NOT
@@ -266,8 +308,8 @@ def decode_attention(
             every row.
         block_table: PAGED cache — ``(B, T)`` int32 mapping each row's
             logical block ``t`` (cache positions ``[t·page, (t+1)·page)``)
-            to a physical PAGE in a shared pool. The caches then arrive as
-            ``(P, N_kv, page, H)`` pools (scales ``(P, N_kv, page)``)
+            to a physical PAGE in a shared pool. The cache then arrives as
+            a ``(P, N_kv, page, 2H)`` pool (scales ``(P, N_kv, page)``)
             instead of per-row buffers: physical HBM scales with pages
             actually allocated, not ``B × max_len`` — the block table is
             a SECOND scalar-prefetch operand, and every BlockSpec index
@@ -282,13 +324,33 @@ def decode_attention(
 
     Returns:
         ``(B, S, N, H)`` attention output in ``q.dtype`` — plus, when
-        ``k_new`` is given, the updated cache buffers (and scale buffers
-        for int8): ``(out, k_cache, v_cache[, k_scale, v_scale])``.
+        ``kv_new`` is given, the updated cache buffer (and scale buffers
+        for int8): ``(out, kv_cache[, k_scale, v_scale])``.
     """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    return _decode_attention(
+        q, kv_cache, index, k_scale, v_scale, kv_new, ks_new, vs_new,
+        write_enable, block_table, window=window, scale=scale,
+        block_k=block_k, block_q=block_q, interpret=interpret,
+    )
+
+
+# One jitted body for every call of one configuration: a model's layers call
+# with the same shapes and statics, so the kernel, its index maps and the
+# scalars around it are traced and lowered ONCE per program, not per layer.
+@functools.partial(
+    jax.jit,
+    static_argnames=("window", "scale", "block_k", "block_q", "interpret"),
+)
+def _decode_attention(
+    q, kv_cache, index, k_scale, v_scale, kv_new, ks_new, vs_new,
+    write_enable, block_table, *, window, scale, block_k, block_q, interpret,
+):
     b, s, n, h = q.shape
     paged = block_table is not None
     if paged:
-        pool, n_kv, page, hk = k_cache.shape
+        pool, n_kv, page, hk = kv_cache.shape
         if block_table.shape[0] != b or block_table.ndim != 2:
             raise ValueError(
                 f"block_table {block_table.shape} must be (B, T) = ({b}, *)"
@@ -302,12 +364,12 @@ def decode_attention(
         length = block_table.shape[1] * page   # logical per-row capacity
         bk = b
     else:
-        bk, n_kv, length, hk = k_cache.shape
-    if (bk, hk) != (b, h) or v_cache.shape != k_cache.shape:
+        bk, n_kv, length, hk = kv_cache.shape
+    if (bk, hk) != (b, 2 * h):
         raise ValueError(
-            f"cache shapes {k_cache.shape}/{v_cache.shape} do not match "
-            f"queries {q.shape} (want "
-            f"{'(P, N_kv, page, H)' if paged else '(B, N_kv, L, H)'} "
+            f"cache shape {kv_cache.shape} does not match queries "
+            f"{q.shape} (want "
+            f"{'(P, N_kv, page, 2H)' if paged else '(B, N_kv, L, 2H)'} "
             f"with H = {h})"
         )
     if n % n_kv:
@@ -317,8 +379,6 @@ def decode_attention(
     quantized = k_scale is not None
     group = n // n_kv
     scale = h**-0.5 if scale is None else scale
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     block_k = auto_block_k(length) if block_k is None else block_k
     if length % block_k:
         raise ValueError(f"cache length {length} not divisible by block_k {block_k}")
@@ -329,21 +389,22 @@ def decode_attention(
     gq = qb * group
     nq = pl.cdiv(s, qb)
 
-    fold = k_new is not None
+    fold = kv_new is not None
     if fold:
-        if v_new is None:
-            raise ValueError("k_new and v_new must be given together")
         if s != 1:
             raise ValueError(f"folded cache write requires S = 1, got {s}")
         if quantized and (ks_new is None or vs_new is None):
             raise ValueError("int8 folded write needs ks_new and vs_new")
 
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
-    valid_blocks = (idx + s + block_k - 1) // block_k
+    # Block numbers stay inside the buffer (and the table): the index maps
+    # take addresses from them even for a row at or past its capacity.
+    valid_blocks = jnp.clip((idx + s + block_k - 1) // block_k, 1, nk)
     if window is not None:
         kstart = jnp.maximum(0, (idx - (window - 1)) // block_k)
     else:
         kstart = jnp.zeros((b,), jnp.int32)
+    kstart = jnp.minimum(kstart, valid_blocks - 1)
     # Disabled rows get a write offset of block_k — outside the kernel's
     # slot iota (0..block_k-1) — so the merge never matches and the block
     # flushes back bit-identical (the write-back itself still runs; it
@@ -351,13 +412,30 @@ def decode_attention(
     woff = idx % block_k
     if write_enable is not None:
         if not fold:
-            raise ValueError("write_enable requires the folded write (k_new)")
+            raise ValueError("write_enable requires the folded write (kv_new)")
         woff = jnp.where(
             jnp.broadcast_to(write_enable, (b,)) != 0, woff, block_k
         )
+    # The grid walks a work list: one step per (row, logical block) a row
+    # HOLDS, rows in order, each from its first block (``kstart``) to its
+    # last valid one. Its length ``ends[-1]`` is a run-time scalar — the
+    # grid's bound — so the step count follows the pages held, not
+    # ``B × nk``, and no shape depends on the traffic. Only the running
+    # totals are computed here (48 layers do it every decode step); each
+    # step finds its row and block from them (``_step_of``).
+    ends = jnp.sum(
+        jnp.where(jnp.tri(b, dtype=bool), (valid_blocks - kstart)[None], 0),
+        axis=1,
+    )                                  # a running total in one fusion
     sargs = jnp.stack(
-        [kstart, valid_blocks, idx, idx // block_k, woff], axis=1
+        [kstart, valid_blocks, idx, jnp.minimum(idx // block_k, nk - 1), woff,
+         ends]
     ).astype(jnp.int32)
+    # Columns up to a power of two, the padding beyond every step (_step_of).
+    sargs = jnp.pad(
+        sargs, ((0, 0), (0, (1 << (b - 1).bit_length()) - b)),
+        constant_values=jnp.iinfo(jnp.int32).max,
+    )
 
     # (B, S, N, H) → (B, N_kv, S·group, H): row r = query (r // group) for
     # in-group head (r % group); q head n belongs to kv head n // group
@@ -371,93 +449,82 @@ def decode_attention(
     last_block = functools.partial(_last_block, qb=qb, s=s, block_k=block_k)
 
     # All index maps take the scalar-prefetch refs as varargs: ``pf[0]`` is
-    # sargs, ``pf[1]`` (paged only) the block table. Paged maps indirect the
-    # LOGICAL block through the table into the page pool's leading axis —
-    # the only difference between the layouts; the kernel body is shared.
-    def qmap(bi, qi, j, *pf):
-        return (bi, 0, qi, 0)
+    # sargs, ``pf[1]`` (paged only) the block table. Paged maps indirect
+    # the LOGICAL block through the table into the page pool's leading axis
+    # — the only difference between the layouts; the kernel body is shared.
+    # ``tail`` is the block index of the dims after the sequence dim:
+    # ``(0,)`` for k|v, ``()`` for scales.
+    step_of = functools.partial(_step_of, b=b)
 
-    def clamped(bi, qi, j, *pf):
-        lb = jnp.minimum(pf[0][bi, 0] + j, last_block(bi, qi, pf[0]))
-        return (pf[1][bi, lb], 0, 0, 0) if paged else (bi, 0, lb, 0)
+    def row_map(tail):
+        return lambda qi, w, *pf: (step_of(w, pf[0])[0], 0, *tail)
 
-    def clamped_sc(bi, qi, j, *pf):
-        lb = jnp.minimum(pf[0][bi, 0] + j, last_block(bi, qi, pf[0]))
-        return (pf[1][bi, lb], 0, 0) if paged else (bi, 0, lb)
+    def block_at(bi, lb, pf, tail):
+        return (pf[1][bi, lb], 0, 0, *tail) if paged else (bi, 0, lb, *tail)
 
-    in_specs = [
-        pl.BlockSpec((1, n_kv, gq, h), qmap),
-        pl.BlockSpec((1, n_kv, block_k, h), clamped),
-        pl.BlockSpec((1, n_kv, block_k, h), clamped),
-    ]
-    operands = [qr, k_cache, v_cache]
+    def clamped(tail):
+        # A q tile whose causal frontier comes before the row's last block
+        # repeats its own last block for the steps between: no DMA moves.
+        def index_map(qi, w, *pf):
+            bi, blk = step_of(w, pf[0])
+            lb = jnp.minimum(blk, last_block(bi, qi, pf[0]))
+            return block_at(bi, lb, pf, tail)
+
+        return index_map
+
+    def written(tail):
+        def index_map(qi, w, *pf):
+            bi, _ = step_of(w, pf[0])
+            return block_at(bi, pf[0][3, bi], pf, tail)
+
+        return index_map
+
+    q_spec = pl.BlockSpec(
+        (1, n_kv, gq, h), lambda qi, w, *pf: (step_of(w, pf[0])[0], 0, qi, 0)
+    )
+    in_specs = [q_spec, pl.BlockSpec((1, n_kv, block_k, 2 * h), clamped((0,)))]
+    operands = [qr, kv_cache]
     if quantized:
-        in_specs += [pl.BlockSpec((1, n_kv, block_k), clamped_sc)] * 2
+        in_specs += [pl.BlockSpec((1, n_kv, block_k), clamped(()))] * 2
         operands += [k_scale, v_scale]
 
-    out_specs = [pl.BlockSpec((1, n_kv, gq, h), qmap)]
+    out_specs = [q_spec]
     out_shapes = [jax.ShapeDtypeStruct((b, n_kv, s * group, h), q.dtype)]
     aliases = {}
-    prefetch = 2 if paged else 1
+    prefetch_args = [sargs]
+    if paged:
+        prefetch_args.append(block_table.astype(jnp.int32))
     if fold:
-        # New-token chunks enter whole; the merged cache block flushes back
-        # through outputs ALIASED to the cache inputs (alias indices count
-        # the scalar-prefetch operands), so only each row's one modified
-        # block moves.
-        chunk_spec = pl.BlockSpec(
-            (1, n_kv, 1, h), lambda bi, qi, j, *pf: (bi, 0, 0, 0)
-        )
-        in_specs += [chunk_spec, chunk_spec]
-        operands += [k_new, v_new]
-
-        def wb(bi, qi, j, *pf):
-            blk = pf[0][bi, 3]
-            return (pf[1][bi, blk], 0, 0, 0) if paged else (bi, 0, blk, 0)
-
-        out_specs += [
-            pl.BlockSpec((1, n_kv, block_k, h), wb),
-            pl.BlockSpec((1, n_kv, block_k, h), wb),
-        ]
-        out_shapes += [
-            jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-            jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
-        ]
-        kidx = prefetch + 1              # operand index of k_cache
-        aliases[kidx] = 1                # k_cache → output 1
-        aliases[kidx + 1] = 2            # v_cache → output 2
+        # The new-token chunk enters whole; the merged cache block flushes
+        # back through an output ALIASED to the cache input (alias indices
+        # count the scalar-prefetch operands), so only each row's one
+        # modified block moves.
+        in_specs += [pl.BlockSpec((1, n_kv, 1, 2 * h), row_map((0, 0)))]
+        operands += [kv_new]
+        out_specs += [pl.BlockSpec((1, n_kv, block_k, 2 * h), written((0,)))]
+        out_shapes += [jax.ShapeDtypeStruct(kv_cache.shape, kv_cache.dtype)]
+        kidx = len(prefetch_args) + 1    # operand index of kv_cache
+        aliases[kidx] = 1                # kv_cache → output 1
         if quantized:
-            sc_chunk = pl.BlockSpec(
-                (1, n_kv, 1), lambda bi, qi, j, *pf: (bi, 0, 0)
-            )
+            sc_chunk = pl.BlockSpec((1, n_kv, 1), row_map((0,)))
             in_specs += [sc_chunk, sc_chunk]
             operands += [ks_new, vs_new]
-
-            def wbs(bi, qi, j, *pf):
-                blk = pf[0][bi, 3]
-                return (pf[1][bi, blk], 0, 0) if paged else (bi, 0, blk)
-
-            out_specs += [
-                pl.BlockSpec((1, n_kv, block_k), wbs),
-                pl.BlockSpec((1, n_kv, block_k), wbs),
-            ]
+            out_specs += [pl.BlockSpec((1, n_kv, block_k), written(()))] * 2
             out_shapes += [
                 jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
                 jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
             ]
-            aliases[kidx + 2] = 3        # k_scale → output 3
-            aliases[kidx + 3] = 4        # v_scale → output 4
+            aliases[kidx + 1] = 2        # k_scale → output 2
+            aliases[kidx + 2] = 3        # v_scale → output 3
 
-    prefetch_args = (
-        (sargs, block_table.astype(jnp.int32)) if paged else (sargs,)
-    )
-    result = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(
             _kernel, scale=scale, block_k=block_k, group=group, qb=qb, s=s,
             window=window, quantized=quantized, fold=fold, paged=paged,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=prefetch,
-            grid=(b, nq, nk),
+            num_scalar_prefetch=len(prefetch_args),
+            grid=(nq, ends[-1]),
             in_specs=in_specs,
             out_specs=out_specs if fold else out_specs[0],
             scratch_shapes=[
@@ -469,7 +536,9 @@ def decode_attention(
         out_shape=out_shapes if fold else out_shapes[0],
         input_output_aliases=aliases,
         interpret=interpret,
-    )(*prefetch_args, *operands)
+    )
+    with jax.named_scope(_TRACE_NAME):
+        result = call(*prefetch_args, *operands)
 
     out = result[0] if fold else result
     out = (
@@ -514,9 +583,9 @@ def make_decode_attn_fn(mesh, rules, **kwargs):
     paged_sc_spec = to_spec((None, HEADS, None))
 
     def attn_fn(
-        q, k_cache, v_cache, index, *,
+        q, kv_cache, index, *,
         k_scale=None, v_scale=None,
-        k_new=None, v_new=None, ks_new=None, vs_new=None,
+        kv_new=None, ks_new=None, vs_new=None,
         write_enable=None, block_table=None,
         **call_kwargs,
     ):
@@ -543,10 +612,10 @@ def make_decode_attn_fn(mesh, rules, **kwargs):
         # Scalar index replicates; a per-row (B,) index (ragged serving)
         # shards with the batch.
         idx_spec = row_idx_spec if jnp.ndim(index) == 1 else PartitionSpec()
-        in_specs = [q_spec, kv_spec, kv_spec, idx_spec]
-        args = [q, k_cache, v_cache, index]
+        in_specs = [q_spec, kv_spec, idx_spec]
+        args = [q, kv_cache, index]
         quantized = k_scale is not None
-        fold = k_new is not None
+        fold = kv_new is not None
         keys = []
         cache_sc_spec = paged_sc_spec if paged else sc_spec
         if quantized:
@@ -554,12 +623,11 @@ def make_decode_attn_fn(mesh, rules, **kwargs):
             args += [k_scale, v_scale]
             keys += ["k_scale", "v_scale"]
         if fold:
-            # New-token chunks (and their scales) are PER-ROW even in paged
+            # The new-token chunk (and its scales) is PER-ROW even in paged
             # mode — only the pools lose their batch axis.
-            chunk_spec = to_spec((BATCH, HEADS, None, None))
-            in_specs += [chunk_spec, chunk_spec]
-            args += [k_new, v_new]
-            keys += ["k_new", "v_new"]
+            in_specs += [to_spec((BATCH, HEADS, None, None))]
+            args += [kv_new]
+            keys += ["kv_new"]
             if quantized:
                 in_specs += [sc_spec, sc_spec]
                 args += [ks_new, vs_new]
@@ -571,7 +639,7 @@ def make_decode_attn_fn(mesh, rules, **kwargs):
         elif write_enable is not None:
             # Mirror decode_attention's own guard — the wrapper must not
             # silently drop a misused mask.
-            raise ValueError("write_enable requires the folded write (k_new)")
+            raise ValueError("write_enable requires the folded write (kv_new)")
         if paged:
             in_specs += [to_spec((BATCH, None))]
             args += [block_table]
@@ -580,12 +648,12 @@ def make_decode_attn_fn(mesh, rules, **kwargs):
         # the attention output; each keeps its input's sharding.
         out_specs = q_spec
         if fold:
-            out_specs = (q_spec, kv_spec, kv_spec)
+            out_specs = (q_spec, kv_spec)
             if quantized:
                 out_specs += (cache_sc_spec, cache_sc_spec)
 
-        def body(q_, k_, v_, i_, *rest):
-            return fn(q_, k_, v_, i_, **dict(zip(keys, rest)))
+        def body(q_, kv_, i_, *rest):
+            return fn(q_, kv_, i_, **dict(zip(keys, rest)))
 
         # check_vma=False: pallas_call's out_shape carries no varying-axes
         # metadata, which the static replication checker requires.

@@ -26,6 +26,7 @@ from learning_jax_sharding_tpu.ops.attention import dot_product_attention
 from learning_jax_sharding_tpu.ops.decode_attention import (
     auto_block_k,
     decode_attention,
+    fuse_kv,
     make_decode_attn_fn,
 )
 
@@ -70,7 +71,8 @@ class TestKernelParity:
         vc = self._rand(rng, self.B, self.NKV, self.L, self.H)
         with jax.default_matmul_precision("float32"):
             out = decode_attention(
-                q, kc, vc, idx, window=window, block_k=block_k, interpret=True
+                q, fuse_kv(kc, vc), idx, window=window, block_k=block_k,
+                interpret=True,
             )
             ref = _dense_oracle(q, kc, vc, idx, window=window)
         np.testing.assert_allclose(out, ref, atol=1e-5)
@@ -85,7 +87,8 @@ class TestKernelParity:
         vc = self._rand(rng, self.B, self.NKV, self.L, self.H)
         with jax.default_matmul_precision("float32"):
             out = decode_attention(
-                q, kc, vc, 20, block_k=16, block_q=block_q, interpret=True
+                q, fuse_kv(kc, vc), 20, block_k=16, block_q=block_q,
+                interpret=True,
             )
             ref = _dense_oracle(q, kc, vc, 20)
         np.testing.assert_allclose(out, ref, atol=1e-5)
@@ -102,7 +105,7 @@ class TestKernelParity:
         vi = np.round(vf / vs[..., None]).astype(np.int8)
         with jax.default_matmul_precision("float32"):
             out = decode_attention(
-                q, jnp.asarray(ki), jnp.asarray(vi), idx,
+                q, fuse_kv(jnp.asarray(ki), jnp.asarray(vi)), idx,
                 k_scale=jnp.asarray(ks, jnp.float32),
                 v_scale=jnp.asarray(vs, jnp.float32),
                 block_k=16, interpret=True,
@@ -125,8 +128,12 @@ class TestKernelParity:
         poison = jnp.full_like(kc, 1e9).at[:, :, : idx + 1].set(kc[:, :, : idx + 1])
         poison_v = jnp.full_like(vc, 1e9).at[:, :, : idx + 1].set(vc[:, :, : idx + 1])
         with jax.default_matmul_precision("float32"):
-            clean = decode_attention(q, kc, vc, idx, block_k=8, interpret=True)
-            dirty = decode_attention(q, poison, poison_v, idx, block_k=8, interpret=True)
+            clean = decode_attention(
+                q, fuse_kv(kc, vc), idx, block_k=8, interpret=True
+            )
+            dirty = decode_attention(
+                q, fuse_kv(poison, poison_v), idx, block_k=8, interpret=True
+            )
         np.testing.assert_allclose(clean, dirty, atol=1e-6)
 
     def test_shard_map_wrapper(self, rng, mesh22):
@@ -139,17 +146,20 @@ class TestKernelParity:
         vc = self._rand(rng, self.B, self.NKV, self.L, self.H)
         fn = make_decode_attn_fn(mesh22, RULES_DP_TP, block_k=16, interpret=True)
         with jax.default_matmul_precision("float32"):
-            out = jax.jit(fn)(q, kc, vc, jnp.asarray(25, jnp.int32))
+            out = jax.jit(fn)(q, fuse_kv(kc, vc), jnp.asarray(25, jnp.int32))
             ref = _dense_oracle(q, kc, vc, 25)
         np.testing.assert_allclose(out, ref, atol=1e-5)
 
     def test_validation(self, rng):
         q = self._rand(rng, self.B, 1, self.NKV, self.H)
         kc = self._rand(rng, self.B, self.NKV, self.L, self.H)
+        kv = fuse_kv(kc, kc)
         with pytest.raises(ValueError, match="k_scale and v_scale"):
-            decode_attention(q, kc, kc, 0, k_scale=jnp.ones((self.B, self.NKV, self.L)))
+            decode_attention(q, kv, 0, k_scale=jnp.ones((self.B, self.NKV, self.L)))
         with pytest.raises(ValueError, match="not divisible"):
-            decode_attention(q, kc, kc, 0, block_k=48, interpret=True)
+            decode_attention(q, kv, 0, block_k=48, interpret=True)
+        with pytest.raises(ValueError, match="does not match queries"):
+            decode_attention(q, kc, 0, interpret=True)  # k alone, not k|v
 
     def test_auto_block_k(self):
         assert auto_block_k(1024) == 256
@@ -231,11 +241,11 @@ class TestFoldedWriteEnable:
         idx = jnp.asarray([17, 9], jnp.int32)
         enable = jnp.asarray([1, 0], jnp.int32)
 
-        out, k_out, v_out = decode_attention(
-            q, kc, vc, idx, k_new=k_new, v_new=v_new,
+        out, kv_out = decode_attention(
+            q, fuse_kv(kc, vc), idx, kv_new=fuse_kv(k_new, v_new),
             write_enable=enable, block_k=16, interpret=True,
         )
-        k_out, v_out = np.asarray(k_out), np.asarray(v_out)
+        k_out, v_out = np.asarray(kv_out[..., :h]), np.asarray(kv_out[..., h:])
         # Row 0 (enabled): new token lands at its slot, rest unchanged.
         np.testing.assert_array_equal(k_out[0, :, 17], np.asarray(k_new)[0, :, 0])
         np.testing.assert_array_equal(v_out[0, :, 17], np.asarray(v_new)[0, :, 0])
@@ -257,15 +267,46 @@ class TestFoldedWriteEnable:
         q = jnp.asarray(rng.normal(size=(1, 1, 1, 8)), jnp.float32)
         with pytest.raises(ValueError, match="write_enable"):
             decode_attention(
-                q, kc, kc, 3, write_enable=jnp.ones((1,), jnp.int32),
+                q, fuse_kv(kc, kc), 3, write_enable=jnp.ones((1,), jnp.int32),
                 interpret=True,
             )
 
 
+def _quantize(x):
+    """Per-(token, head) symmetric int8: values and fp32 scales."""
+    scale = np.maximum(np.abs(x).max(-1), 1e-6) / 127.0
+    return np.round(x / scale[..., None]).astype(np.int8), scale.astype(np.float32)
+
+
+# Paged serving cases, each against the dense fp32 reference over the row's
+# own logical cache. ``idx``: tokens each row holds before the call; page 8,
+# table 4 wide (capacity 32). ``enable`` 0 freezes a row (folded write only).
+_PAGED_CASES = {
+    # S = 1, folded write: the decode step
+    "fold-frozen-empty-row": dict(idx=[0, 11], enable=[0, 1]),
+    "fold-one-token": dict(idx=[1, 1]),
+    "fold-exactly-a-page": dict(idx=[8, 16]),
+    "fold-a-page-plus-one": dict(idx=[9, 17]),
+    "fold-full-capacity": dict(idx=[31, 24]),
+    "fold-shared-prefix-pages": dict(idx=[19, 21], share=2),
+    "fold-tail-at-scratch-page": dict(idx=[3, 12], scratch_tail=True),
+    "fold-window": dict(idx=[29, 6], window=10),
+    "fold-int8": dict(idx=[13, 30], int8=True),
+    "fold-gqa": dict(idx=[15, 2, 27], group=3),
+    "fold-frozen-full-row": dict(idx=[31, 5], enable=[0, 1]),
+    # S > 1: a chunk already written to the cache (refill, verification)
+    "chunk": dict(idx=[0, 10], s=5),
+    "chunk-to-capacity": dict(idx=[24, 3], s=8),
+    "chunk-gqa-tiled-window": dict(idx=[9, 20], s=6, group=2, window=12, block_q=4),
+    "chunk-int8-scratch-tail": dict(idx=[4, 17], s=3, int8=True, scratch_tail=True),
+}
+
+
 class TestPagedCache:
-    """Paged layout: (P, N_kv, page, H) pools indirected through per-row
-    block tables. Oracle: bit-identical attention (and folded writes) to
-    the contiguous layout holding the same logical contents, for ANY page
+    """Paged layout: (P, N_kv, page, 2H) pools indirected through per-row
+    block tables. Oracles: the dense fp32 reference over each row's logical
+    cache, and bit-identical attention (and folded writes) to the
+    contiguous layout holding the same logical contents, for ANY page
     permutation — the table is pure indirection."""
 
     def _paged_from_contiguous(self, kc, vc, page, rng):
@@ -280,9 +321,106 @@ class TestPagedCache:
                 pool_k[table[bi, t]] = np.asarray(kc)[bi, :, t*page:(t+1)*page]
                 pool_v[table[bi, t]] = np.asarray(vc)[bi, :, t*page:(t+1)*page]
         return (
-            jnp.asarray(pool_k), jnp.asarray(pool_v),
+            fuse_kv(jnp.asarray(pool_k), jnp.asarray(pool_v)),
             jnp.asarray(table, jnp.int32),
         )
+
+    @pytest.mark.parametrize("case", _PAGED_CASES)
+    def test_contexts_match_dense(self, case):
+        """The grid walks the pages each row HOLDS: rows of every length,
+        shared and scratch-page table entries, frozen rows."""
+        c = dict(
+            s=1, group=1, window=None, int8=False, enable=None, share=0,
+            scratch_tail=False, block_q=128,
+        )
+        c.update(_PAGED_CASES[case])
+        rng = np.random.default_rng(7)
+        idx, s, page, T, n_kv, h = np.asarray(c["idx"]), c["s"], 8, 4, 2, 16
+        b, L, fold = len(idx), T * page, c["s"] == 1
+        P = b * T + 1
+        kf = rng.normal(size=(b, n_kv, L, h)).astype(np.float32)
+        vf = rng.normal(size=(b, n_kv, L, h)).astype(np.float32)
+        table = rng.permutation(np.arange(1, P)).reshape(b, T)
+        for t in range(c["share"]):          # rows share their prefix pages
+            table[1:, t] = table[0, t]
+            kf[1:, :, t*page:(t+1)*page] = kf[0, :, t*page:(t+1)*page]
+            vf[1:, :, t*page:(t+1)*page] = vf[0, :, t*page:(t+1)*page]
+        if c["scratch_tail"]:                # unallocated entries: page 0
+            for bi in range(b):
+                table[bi, -(-(idx[bi] + s) // page):] = 0
+        if c["int8"]:
+            (ki, ks), (vi, vs) = _quantize(kf), _quantize(vf)
+            kf, vf = ki * ks[..., None], vi * vs[..., None]
+        # Page 0 (scratch) and every slot no row maps hold poison.
+        pool = np.full((P, n_kv, page, 2 * h), 100 if c["int8"] else 1e9)
+        pool = pool.astype(np.int8 if c["int8"] else np.float32)
+        pool_ks = np.full((P, n_kv, page), 1e9, np.float32)
+        pool_vs = np.full((P, n_kv, page), 1e9, np.float32)
+        for bi in range(b):
+            for t in range(T):
+                at = np.s_[bi, :, t*page:(t+1)*page]
+                if table[bi, t] == 0:
+                    continue
+                pool[table[bi, t]] = np.concatenate(
+                    [ki[at], vi[at]] if c["int8"] else [kf[at], vf[at]], -1
+                )
+                if c["int8"]:
+                    pool_ks[table[bi, t]], pool_vs[table[bi, t]] = ks[at], vs[at]
+        n = n_kv * c["group"]
+        q = jnp.asarray(rng.normal(size=(b, s, n, h)), jnp.float32)
+        kw = dict(
+            block_table=jnp.asarray(table, jnp.int32), window=c["window"],
+            block_q=c["block_q"], interpret=True,
+        )
+        if c["int8"]:
+            kw.update(k_scale=jnp.asarray(pool_ks), v_scale=jnp.asarray(pool_vs))
+        enable = np.ones(b, np.int32) if c["enable"] is None else np.asarray(c["enable"])
+        if fold:
+            k_new = rng.normal(size=(b, n_kv, 1, h)).astype(np.float32)
+            v_new = rng.normal(size=(b, n_kv, 1, h)).astype(np.float32)
+            if c["int8"]:
+                (kn_i, kn_s), (vn_i, vn_s) = _quantize(k_new), _quantize(v_new)
+                kw.update(ks_new=jnp.asarray(kn_s), vs_new=jnp.asarray(vn_s))
+                kv_new = np.concatenate([kn_i, vn_i], -1)
+                k_new, v_new = kn_i * kn_s[..., None], vn_i * vn_s[..., None]
+            else:
+                kv_new = np.concatenate([k_new, v_new], -1)
+            kw.update(kv_new=jnp.asarray(kv_new))
+            if c["enable"] is not None:
+                kw.update(write_enable=jnp.asarray(enable))
+        with jax.default_matmul_precision("float32"):
+            result = decode_attention(
+                q, jnp.asarray(pool), jnp.asarray(idx, jnp.int32), **kw
+            )
+        out = np.asarray(result[0] if fold else result)
+        for bi in range(b):
+            if not enable[bi]:
+                continue                     # a frozen row's output is unused
+            kr, vr = kf[bi:bi+1].copy(), vf[bi:bi+1].copy()
+            if fold:
+                kr[0, :, idx[bi]], vr[0, :, idx[bi]] = k_new[bi, :, 0], v_new[bi, :, 0]
+            with jax.default_matmul_precision("float32"):
+                ref = _dense_oracle(
+                    q[bi:bi+1], jnp.asarray(kr), jnp.asarray(vr), int(idx[bi]),
+                    window=c["window"],
+                )
+            np.testing.assert_allclose(
+                out[bi], np.asarray(ref)[0], atol=1e-4 if c["int8"] else 1e-5
+            )
+        if fold:
+            # The pool afterwards: each writing row's slot holds its token;
+            # EVERYTHING else — frozen rows' pages, shared pages, the
+            # scratch page — is bit-identical.
+            want, want_ks, want_vs = pool.copy(), pool_ks.copy(), pool_vs.copy()
+            for bi in np.flatnonzero(enable):
+                at = (table[bi, idx[bi] // page], slice(None), idx[bi] % page)
+                want[at] = kv_new[bi, :, 0]
+                if c["int8"]:
+                    want_ks[at], want_vs[at] = kn_s[bi, :, 0], vn_s[bi, :, 0]
+            np.testing.assert_array_equal(np.asarray(result[1]), want)
+            if c["int8"]:
+                np.testing.assert_array_equal(np.asarray(result[2]), want_ks)
+                np.testing.assert_array_equal(np.asarray(result[3]), want_vs)
 
     @pytest.mark.parametrize("s,group", [(1, 1), (1, 2), (5, 1)])
     def test_read_parity(self, s, group):
@@ -295,10 +433,12 @@ class TestPagedCache:
         q = jnp.asarray(
             rng.normal(size=(b, s, n_kv * group, h)), jnp.float32
         )
-        ref = decode_attention(q, kc, vc, idx, block_k=page, interpret=True)
-        pk, pv, table = self._paged_from_contiguous(kc, vc, page, rng)
+        ref = decode_attention(
+            q, fuse_kv(kc, vc), idx, block_k=page, interpret=True
+        )
+        pkv, table = self._paged_from_contiguous(kc, vc, page, rng)
         out = decode_attention(
-            q, pk, pv, idx, block_table=table, interpret=True
+            q, pkv, idx, block_table=table, interpret=True
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6
@@ -312,38 +452,32 @@ class TestPagedCache:
         vc = jnp.asarray(rng.normal(size=(b, n_kv, L, h)), jnp.float32)
         idx = jnp.asarray([17, 9], jnp.int32)
         q = jnp.asarray(rng.normal(size=(b, 1, n_kv, h)), jnp.float32)
-        k_new = jnp.asarray(rng.normal(size=(b, n_kv, 1, h)), jnp.float32)
-        v_new = jnp.asarray(rng.normal(size=(b, n_kv, 1, h)), jnp.float32)
-        ref, rk, rv = decode_attention(
-            q, kc, vc, idx, k_new=k_new, v_new=v_new, block_k=page,
+        kv_new = jnp.asarray(rng.normal(size=(b, n_kv, 1, 2 * h)), jnp.float32)
+        ref, rkv = decode_attention(
+            q, fuse_kv(kc, vc), idx, kv_new=kv_new, block_k=page,
             interpret=True,
         )
-        pk, pv, table = self._paged_from_contiguous(kc, vc, page, rng)
-        out, ok, ov = decode_attention(
-            q, pk, pv, idx, k_new=k_new, v_new=v_new, block_table=table,
-            interpret=True,
+        pkv, table = self._paged_from_contiguous(kc, vc, page, rng)
+        out, okv = decode_attention(
+            q, pkv, idx, kv_new=kv_new, block_table=table, interpret=True,
         )
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), rtol=1e-6, atol=1e-6
         )
-        ok, ov = np.asarray(ok), np.asarray(ov)
-        tbl = np.asarray(table)
+        okv, tbl = np.asarray(okv), np.asarray(table)
         for bi in range(b):
             i = int(idx[bi])
             t, o = i // page, i % page
             np.testing.assert_array_equal(
-                ok[tbl[bi, t], :, o], np.asarray(rk)[bi, :, i]
-            )
-            np.testing.assert_array_equal(
-                ov[tbl[bi, t], :, o], np.asarray(rv)[bi, :, i]
+                okv[tbl[bi, t], :, o], np.asarray(rkv)[bi, :, i]
             )
 
     def test_block_k_mismatch_rejected(self):
         rng = np.random.default_rng(0)
-        pk = jnp.zeros((5, 1, 16, 8), jnp.float32)
+        pkv = jnp.zeros((5, 1, 16, 16), jnp.float32)
         q = jnp.asarray(rng.normal(size=(1, 1, 1, 8)), jnp.float32)
         table = jnp.zeros((1, 4), jnp.int32)
         with pytest.raises(ValueError, match="page"):
             decode_attention(
-                q, pk, pk, 3, block_table=table, block_k=8, interpret=True
+                q, pkv, 3, block_table=table, block_k=8, interpret=True
             )

@@ -32,7 +32,10 @@ from learning_jax_sharding_tpu.models.transformer import (
     CONFIG_TINY,
     Transformer,
 )
-from learning_jax_sharding_tpu.ops.decode_attention import decode_attention
+from learning_jax_sharding_tpu.ops.decode_attention import (
+    decode_attention,
+    fuse_kv,
+)
 from learning_jax_sharding_tpu.parallel import mesh_sharding, put
 from learning_jax_sharding_tpu.parallel.logical import RULES_DP_TP
 from learning_jax_sharding_tpu.training.pipeline import sharded_train_state
@@ -66,10 +69,13 @@ class TestKernelPerRowIndex:
         vc = jnp.asarray(rng.normal(size=(b, n_kv, length, h)), jnp.float32)
         idx = jnp.asarray([5, 40, 17, 0], jnp.int32)
         with jax.default_matmul_precision("float32"):
-            batched = decode_attention(q, kc, vc, idx, block_k=16, interpret=True)
+            batched = decode_attention(
+                q, fuse_kv(kc, vc), idx, block_k=16, interpret=True
+            )
             for row in range(b):
                 single = decode_attention(
-                    q[row : row + 1], kc[row : row + 1], vc[row : row + 1],
+                    q[row : row + 1],
+                    fuse_kv(kc[row : row + 1], vc[row : row + 1]),
                     int(idx[row]), block_k=16, interpret=True,
                 )
                 np.testing.assert_allclose(
@@ -86,11 +92,12 @@ class TestKernelPerRowIndex:
         idx = jnp.asarray([50, 9, 23], jnp.int32)
         with jax.default_matmul_precision("float32"):
             batched = decode_attention(
-                q, kc, vc, idx, window=16, block_k=8, interpret=True
+                q, fuse_kv(kc, vc), idx, window=16, block_k=8, interpret=True
             )
             for row in range(b):
                 single = decode_attention(
-                    q[row : row + 1], kc[row : row + 1], vc[row : row + 1],
+                    q[row : row + 1],
+                    fuse_kv(kc[row : row + 1], vc[row : row + 1]),
                     int(idx[row]), window=16, block_k=8, interpret=True,
                 )
                 np.testing.assert_allclose(

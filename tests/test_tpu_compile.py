@@ -110,41 +110,50 @@ def test_flash_attention(one_chip, b, s, n, n_kv, h, window, grads):
 _B, _N, _H, _L = 8, 12, 64, 1024
 
 
-def _decode_case(*, s=1, page=None, fold=False, int8=False, window=None):
-    """(fn, shapes) for one decode_attention variant at the 125M widths."""
+def _decode_case(
+    *, s=1, page=None, fold=False, int8=False, window=None,
+    b=_B, n=_N, pool=None,
+):
+    """(fn, shapes) for one decode_attention variant: at the 125M widths,
+    or with ``b``/``n``/``pool`` at a cell's own."""
     cache_dt = I8 if int8 else BF16
     if page is None:
-        cache = ((_B, _N, _L, _H), cache_dt)
-        scales = ((_B, _N, _L), F32)
+        cache = ((b, n, _L, 2 * _H), cache_dt)
+        scales = ((b, n, _L), F32)
     else:
-        pool = _B * (_L // page) + 1
-        cache = ((pool, _N, page, _H), cache_dt)
-        scales = ((pool, _N, page), F32)
-    names = ["q", "k_cache", "v_cache", "index"]
-    shapes = [((_B, s, _N, _H), BF16), cache, cache, ((_B,), I32)]
+        pool = b * (_L // page) + 1 if pool is None else pool
+        cache = ((pool, n, page, 2 * _H), cache_dt)
+        scales = ((pool, n, page), F32)
+    names = ["q", "kv_cache", "index"]
+    shapes = [((b, s, n, _H), BF16), cache, ((b,), I32)]
     if int8:
         names += ["k_scale", "v_scale"]
         shapes += [scales, scales]
     if fold:
-        names += ["k_new", "v_new"]
-        shapes += [((_B, _N, 1, _H), cache_dt)] * 2
+        names += ["kv_new"]
+        shapes += [((b, n, 1, 2 * _H), cache_dt)]
         if int8:
             names += ["ks_new", "vs_new"]
-            shapes += [((_B, _N, 1), F32)] * 2
+            shapes += [((b, n, 1), F32)] * 2
         names += ["write_enable"]
-        shapes += [((_B,), I32)]
+        shapes += [((b,), I32)]
     if page is not None:
         names += ["block_table"]
-        shapes += [((_B, _L // page), I32)]
+        shapes += [((b, _L // page), I32)]
 
     def fn(*args):
         kw = dict(zip(names, args))
         return decode_attention(
-            kw.pop("q"), kw.pop("k_cache"), kw.pop("v_cache"),
-            kw.pop("index"), window=window, interpret=False, **kw,
+            kw.pop("q"), kw.pop("kv_cache"), kw.pop("index"),
+            window=window, interpret=False, **kw,
         )
 
     return fn, shapes
+
+
+# gpt2-xl.chat_backlog's engine: 16 slots, 25 heads x 64, pages of 64, a
+# table 16 wide over a 96-page pool.
+_XL = dict(b=16, n=25, page=64, pool=96)
 
 
 @pytest.mark.parametrize(
@@ -159,10 +168,13 @@ def _decode_case(*, s=1, page=None, fold=False, int8=False, window=None):
         dict(page=64, int8=True, fold=True),
         dict(fold=True, int8=True),
         dict(window=256),
+        dict(fold=True, **_XL),
+        dict(s=128, **_XL),
     ],
     ids=[
         "per-row", "paged16", "paged64", "paged64-fold", "paged64-chunk128",
         "int8", "paged64-int8-fold", "int8-fold", "window256",
+        "xl-cell-fold", "xl-cell-chunk128",
     ],
 )
 def test_decode_attention(one_chip, case):
