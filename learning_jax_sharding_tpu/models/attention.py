@@ -14,6 +14,12 @@ Rebuilds the reference's ``FlaxAttention``
 * fp32 softmax upcast (`case6_attention.py:121-130`) via ``ops.attention``;
 * selectable attention backend: dense einsum attention (reference semantics),
   or the Pallas flash kernel for long sequences.
+
+Two attention modules live here. :class:`MultiHeadAttention` (MHA / GQA /
+MQA, learned positions or RoPE, one global window; K and V cached per head:
+every GPT-2-shaped and LLaMA-shaped config) and :class:`LatentAttention`
+(low-rank q and a shared kv latent cached as one row a token:
+JoyAI-LLM-Flash, ``TransformerConfig.latent_kv_rank``).
 """
 
 from __future__ import annotations
@@ -122,6 +128,24 @@ def row_update_masked(
         return jax.lax.dynamic_update_slice(b_buf, merged, tuple(starts))
 
     return jax.vmap(one)(buf, chunk, idx, lengths)
+
+
+def paged_slots(table, idx, s: int, page: int, chunk_lengths, length: int):
+    """Where a chunk's ``s`` positions land in a page pool: cache position
+    ``idx_b + t`` lives at ``(table[b, pos // page], pos % page)``. Invalid
+    positions (padding past a row's ``chunk_lengths``, or past ``length``
+    without them) are redirected to the reserved scratch page 0, so masked
+    writes can never touch live pages. Returns ``(pages, slots)``, ``(B, S)``
+    each."""
+    pos = idx[:, None] + jnp.arange(s)[None, :]
+    pages = jnp.take_along_axis(
+        table, jnp.minimum(pos // page, table.shape[1] - 1), axis=1
+    )
+    if chunk_lengths is not None:
+        valid = jnp.arange(s)[None, :] < chunk_lengths[:, None]
+    else:
+        valid = pos < length
+    return jnp.where(valid, pages, 0), pos % page
 
 
 def repeat_kv(kv: jax.Array, num_heads: int) -> jax.Array:
@@ -628,23 +652,11 @@ class MultiHeadAttention(nn.Module):
             return row_update(buf, chunk, idx, seq_dim=2)
 
         def paged_write(pool_buf, chunk):
-            # Scatter a sequence-major chunk through the block table: cache
-            # position idx_b + t lives at (table[b, pos // page], pos %
-            # page) in the pool. Invalid positions (padding past a row's
-            # chunk_lengths) are redirected to the reserved scratch page 0,
-            # so masked writes can never touch live pages.
-            tbl = block_table.value
-            pos = idx[:, None] + jnp.arange(s)[None, :]          # (B, S)
-            t_cap = tbl.shape[1]
-            pages = jnp.take_along_axis(
-                tbl, jnp.minimum(pos // page, t_cap - 1), axis=1
+            # Scatter a sequence-major chunk through the block table
+            # (paged_slots: masked positions go to scratch page 0).
+            pages, slots = paged_slots(
+                block_table.value, idx, s, page, chunk_lengths, length
             )
-            slots = pos % page
-            if chunk_lengths is not None:
-                valid = jnp.arange(s)[None, :] < chunk_lengths[:, None]
-            else:
-                valid = pos < length
-            pages = jnp.where(valid, pages, 0)
             # chunk (B, N_kv, S, ...) → (B, S, N_kv, ...): advanced indices
             # on pool axes 0 and 2 put the (B, S) index shape in front.
             upd = jnp.moveaxis(chunk, 2, 1)
@@ -705,4 +717,245 @@ class MultiHeadAttention(nn.Module):
         out, cached_kv.value = result[:2]
         if quantized:
             k_scale.value, v_scale.value = result[2:]
+        return out
+
+
+class _Kernel(nn.Module):
+    """A projection's ``kernel`` parameter alone, for a caller that
+    contracts it itself (same tree as an ``nn.Dense`` of that name)."""
+
+    shape: tuple
+    axes: tuple
+    param_dtype: jnp.dtype = jnp.float32
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        return self.param(
+            "kernel", nn.with_logical_partitioning(self.kernel_init, self.axes),
+            self.shape, self.param_dtype,
+        )
+
+
+class LatentAttention(nn.Module):
+    """Multi-head LATENT attention (DeepSeek-V2's MLA; JoyAI-LLM-Flash).
+
+    Queries pass a rank-``q_rank`` bottleneck with its own RMSNorm; keys and
+    values are expanded from ONE rank-``kv_rank`` latent a token (RMSNorm'd)
+    plus ONE ``rope_dim``-wide rotary key shared by every head::
+
+        c_q = RMSNorm(x W_qa);  [q_nope | q_rope]_h = c_q W_qb
+        [c_kv | k_r] = x W_kva; c_kv = RMSNorm(c_kv); k_rope = RoPE(k_r)
+        [k_nope | v]_h = c_kv W_kvb
+        s_h(t, u) = (q_nope_h(t)·k_nope_h(u) + RoPE(q_rope_h)(t)·k_rope(u))
+                    / sqrt(nope_dim + rope_dim)
+
+    RoPE rotates neighbouring pairs (``rope_interleave``). No biases.
+
+    Two forms of the same mathematics. EXPANDED (``absorbed=False``, the
+    training form): ``k_nope, v`` are materialised per head and ordinary
+    attention runs over ``nope + rope`` / ``v_dim`` wide heads. ABSORBED:
+    ``W_kvb``'s key half moves onto the query (``q~_h = q_nope_h W_k,h^T``,
+    ``kv_rank`` wide) and its value half behind the softmax, so attention
+    itself is 32 heads against one shared row ``[c_kv | k_rope]``.
+
+    ``decode=True`` always runs the absorbed form against a cache of those
+    rows — ``kv_rank + rope_dim`` values a token a layer where per-head K
+    and V would be ``heads x (nope + rope + v)`` — through the latent form
+    of the paged kernel (``ops.decode_attention``, ``latent_v``): S = 1
+    folds the write, a refill chunk scatters its rows first and attends
+    earlier chunks through the cache. The cache variable keeps the name
+    ``cached_kv``; its shape ``(P | B, 1, page | L, kv_rank + rope_dim)`` is
+    what tells the layouts apart. There is one cached path: the kernel (the
+    interpreter off the TPU), whatever ``decode_attention`` says.
+    """
+
+    features: int
+    num_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10_000.0
+    norm_eps: float = 1e-6
+    causal: bool = True
+    absorbed: bool = False
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    kernel_init: Callable = nn.initializers.lecun_normal()
+    decode: bool = False
+    max_decode_len: int = 0
+    decode_block_k: Optional[int] = None
+    decode_ragged: bool = False
+    decode_paged: bool = False
+    decode_page_count: int = 0
+
+    def _dense(self, features: int, kernel_axes, name: str) -> nn.Module:
+        return nn.Dense(
+            features, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            kernel_init=nn.with_logical_partitioning(
+                self.kernel_init, kernel_axes
+            ),
+            name=name,
+        )
+
+    def _norm(self, name: str) -> nn.Module:
+        return nn.RMSNorm(
+            epsilon=self.norm_eps, dtype=self.dtype,
+            param_dtype=self.param_dtype, name=name,
+        )
+
+    @nn.compact
+    def __call__(
+        self, x: jax.Array, *, deterministic: bool = True,
+        chunk_lengths: Optional[jax.Array] = None,
+    ) -> jax.Array:
+        b, s, _ = x.shape
+        n, dn, dr, dv, r = (
+            self.num_heads, self.nope_dim, self.rope_dim, self.v_dim,
+            self.kv_rank,
+        )
+        if chunk_lengths is not None and not self.decode_ragged:
+            raise ValueError("chunk_lengths requires decode_ragged=True")
+        x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
+        scale = (dn + dr) ** -0.5
+
+        c_q = self._norm("q_norm")(self._dense(self.q_rank, (EMBED, None), "q_a")(x))
+        q = self._dense(n * (dn + dr), (None, HEADS), "q_b")(c_q)
+        q = q.reshape(b, s, n, dn + dr)
+        q_nope, q_rope = q[..., :dn], q[..., dn:]
+        kv = self._dense(r + dr, (EMBED, None), "kv_a")(x)
+        c_kv = self._norm("kv_norm")(kv[..., :r])
+        # W_kvb (r, N, nope | v): contracted with c_kv (expanded) or split
+        # onto the query and the attention output (absorbed).
+        w_kvb = _Kernel(
+            (r, n * (dn + dv)), (None, HEADS), self.param_dtype,
+            self.kernel_init, name="kv_b",
+        )().astype(self.dtype).reshape(r, n, dn + dv)
+
+        if self.decode:
+            idx0 = self.get_variable(
+                "cache", "cache_index",
+                jnp.zeros((b,) if self.decode_ragged else (), jnp.int32),
+            )
+            positions = (
+                idx0[:, None] + jnp.arange(s) if self.decode_ragged
+                else idx0 + jnp.arange(s)
+            )
+        else:
+            positions = jnp.arange(s)
+        q_rope = apply_rope(q_rope, positions, self.rope_theta, interleave=True)
+        k_rope = apply_rope(
+            kv[..., None, r:], positions, self.rope_theta, interleave=True
+        )                                                   # (B, S, 1, dr)
+        rows = jnp.concatenate([c_kv[..., None, :], k_rope], axis=-1)
+
+        if self.decode or self.absorbed:
+            q_lat = jnp.einsum("bsnd,rnd->bsnr", q_nope, w_kvb[..., :dn])
+            q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)    # (B,S,N,r+dr)
+            if self.decode:
+                o_lat = self._cached_attention(q_cat, rows, chunk_lengths, scale)
+            else:
+                sc = jnp.einsum(
+                    "bqnr,bkr->bnqk", q_cat.astype(jnp.float32),
+                    rows[:, :, 0].astype(jnp.float32),
+                ) * scale
+                if self.causal:
+                    sc = jnp.where(causal_mask(s), sc, -jnp.inf)
+                p = jax.nn.softmax(sc, axis=-1)
+                o_lat = jnp.einsum(
+                    "bnqk,bkr->bqnr", p, c_kv.astype(jnp.float32)
+                ).astype(self.dtype)
+            out = jnp.einsum("bsnr,rnd->bsnd", o_lat, w_kvb[..., dn:])
+        else:
+            k_v = jnp.einsum("bsr,rnd->bsnd", c_kv, w_kvb)
+            k = jnp.concatenate(
+                [k_v[..., :dn], jnp.broadcast_to(k_rope, (b, s, n, dr))], axis=-1
+            )
+            out = dot_product_attention(
+                jnp.concatenate([q_nope, q_rope], axis=-1), k, k_v[..., dn:],
+                mask=causal_mask(s) if self.causal else None, scale=scale,
+            )
+        out = nn.with_logical_constraint(out, (BATCH, SEQ, HEADS, KV))
+        out = self._dense(self.features, (HEADS, EMBED), "out")(
+            out.reshape(b, s, n * dv)
+        )
+        return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))
+
+    def _cached_attention(self, q_cat, rows, chunk_lengths, scale):
+        """Absorbed attention of chunk queries ``(B, S, N, R)`` against the
+        latent cache, after appending the chunk's ``rows`` ``(B, S, 1, R)``.
+        The protocol of ``MultiHeadAttention._blocked_cached_attention``
+        (same variable names, same index advance, page 0 the scratch target
+        of masked writes) over one shared row a token."""
+        from learning_jax_sharding_tpu.ops.decode_attention import (
+            decode_attention,
+        )
+
+        b, s, n, width = q_cat.shape
+        if self.max_decode_len <= 0:
+            raise ValueError("decode=True requires max_decode_len > 0")
+        ragged, paged = self.decode_ragged, self.decode_paged
+        length = self.max_decode_len
+        if paged:
+            if not ragged:
+                raise ValueError("decode_paged requires decode_ragged")
+            page = self.decode_block_k
+            if not page or length % page:
+                raise ValueError(
+                    f"decode_paged needs decode_block_k (page size) "
+                    f"dividing max_decode_len ({length}); got {page}"
+                )
+            shape = (self.decode_page_count, 1, page, width)
+            block_table = self.variable(
+                "cache", "block_table", jnp.zeros, (b, length // page),
+                jnp.int32,
+            )
+        else:
+            shape = (b, 1, length, width)
+        cached = self.variable("cache", "cached_kv", jnp.zeros, shape, self.dtype)
+        cache_index = self.variable(
+            "cache", "cache_index",
+            lambda: jnp.zeros((b,) if ragged else (), jnp.int32),
+        )
+        idx = cache_index.value
+        cache_index.value = idx + (s if chunk_lengths is None else chunk_lengths)
+        rows_sm = rows.astype(self.dtype).transpose(0, 2, 1, 3)   # (B,1,S,R)
+        fold = ragged and s == 1
+        kw = {}
+        if paged:
+            kw["block_table"] = block_table.value
+        if chunk_lengths is not None:
+            # A row with nothing valid in this chunk (a decoding slot riding
+            # a refill, a frozen slot riding a decode step) is not attended
+            # at all: at 32 heads over 576 lanes its blocks are real work.
+            kw["row_enable"] = chunk_lengths
+        if fold:
+            kw["kv_new"] = rows_sm
+            if chunk_lengths is not None:
+                kw["write_enable"] = chunk_lengths
+        elif paged:
+            pages, slots = paged_slots(
+                block_table.value, idx, s, page, chunk_lengths, length
+            )
+            cached.value = cached.value.at[pages, 0, slots].set(rows_sm[:, 0])
+        elif ragged:
+            write = (
+                functools.partial(row_update_masked, lengths=chunk_lengths)
+                if chunk_lengths is not None else row_update
+            )
+            cached.value = write(cached.value, rows_sm, idx, seq_dim=2)
+        else:
+            cached.value = jax.lax.dynamic_update_slice(
+                cached.value, rows_sm, (0, 0, idx, 0)
+            )
+        result = decode_attention(
+            q_cat, cached.value, idx, scale=scale, latent_v=self.kv_rank,
+            block_k=self.decode_block_k, **kw,
+        )
+        if not fold:
+            return result
+        out, cached.value = result
         return out

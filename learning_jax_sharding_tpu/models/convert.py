@@ -86,6 +86,76 @@ def config_from_hf_gpt2(hf_config: Any, **overrides) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+def config_from_hf_joyai_llm_flash(hf_config: Any, **overrides) -> TransformerConfig:
+    """TransformerConfig for a ``joyai_llm_flash`` ``config.json`` (the
+    DeepSeek-V3 key set: latent attention, ``first_k_dense_replace`` dense
+    layers, then sigmoid-routed dropless experts with shared experts).
+
+    Refuses what the program does not compute rather than approximate it:
+    grouped top-k (``n_group`` / ``topk_group`` > 1), rope scaling, biases,
+    a non-interleaved rotary layout, unnormalised pick weights, expert
+    layers at another frequency than every layer. The multi-token-
+    prediction modules (``num_nextn_predict_layers``) are a training loss
+    and an optional drafter: they are not built, whatever the key says.
+    """
+    c = hf_config
+    unsupported = {
+        "hidden_act": c.hidden_act != "silu",
+        "scoring_func": c.scoring_func != "sigmoid",
+        "topk_method": c.topk_method != "noaux_tc",
+        "n_group/topk_group": (c.n_group, c.topk_group) != (1, 1),
+        "norm_topk_prob": not c.norm_topk_prob,
+        "rope_scaling": c.rope_scaling is not None,
+        "rope_interleave": not c.rope_interleave,
+        "attention_bias": bool(c.attention_bias),
+        "moe_layer_freq": c.moe_layer_freq != 1,
+        "tie_word_embeddings": bool(c.tie_word_embeddings),
+        "num_key_value_heads": c.num_key_value_heads != c.num_attention_heads,
+        "qk_head_dim": c.qk_head_dim != c.qk_nope_head_dim + c.qk_rope_head_dim,
+    }
+    bad = sorted(k for k, v in unsupported.items() if v)
+    if bad:
+        raise ValueError(
+            f"unsupported joyai_llm_flash settings: {bad} (the program "
+            "computes sigmoid noaux_tc routing in one group, interleaved "
+            "un-scaled RoPE, no biases, an untied head)"
+        )
+    import jax.numpy as jnp
+
+    defaults = dict(
+        vocab_size=c.vocab_size,
+        num_layers=c.num_hidden_layers,
+        features=c.hidden_size,
+        num_heads=c.num_attention_heads,
+        head_dim=c.v_head_dim,
+        hidden=c.intermediate_size,
+        max_seq_len=c.max_position_embeddings,
+        use_bias=False,
+        norm="rmsnorm",
+        norm_eps=c.rms_norm_eps,
+        rope=True,                      # no position table
+        rope_theta=float(c.rope_theta),
+        causal=True,
+        latent_kv_rank=c.kv_lora_rank,
+        latent_q_rank=c.q_lora_rank,
+        qk_nope_dim=c.qk_nope_head_dim,
+        qk_rope_dim=c.qk_rope_head_dim,
+        v_head_dim=c.v_head_dim,
+        ff_gated=True,
+        first_k_dense=c.first_k_dense_replace,
+        num_experts=c.n_routed_experts,
+        moe_top_k=c.num_experts_per_tok,
+        moe_routing="sigmoid_dropless",
+        moe_hidden=c.moe_intermediate_size,
+        moe_shared_experts=c.n_shared_experts,
+        moe_routed_scaling=float(c.routed_scaling_factor),
+        dtype=jnp.float32,
+        param_dtype=jnp.float32,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
 def params_from_hf_gpt2(hf_model: Any) -> dict:
     """Map a ``transformers.GPT2LMHeadModel`` state dict onto this
     framework's ``Transformer`` param tree (plain numpy leaves — shard with

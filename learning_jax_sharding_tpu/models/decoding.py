@@ -174,7 +174,10 @@ def make_cached_apply(
     the upcast is its call — ``bench.py`` measures it.
     """
 
-    def apply(params: Any, cache: Any, tokens: jax.Array, chunk_lengths=None):
+    def apply(
+        params: Any, cache: Any, tokens: jax.Array, chunk_lengths=None,
+        logit_positions=None,
+    ):
         if dequantize:
             from learning_jax_sharding_tpu.models.quantize import dequantize_tree
 
@@ -185,6 +188,8 @@ def make_cached_apply(
         kwargs = {}
         if chunk_lengths is not None:  # ragged decode only (decode_ragged)
             kwargs["chunk_lengths"] = chunk_lengths
+        if logit_positions is not None:  # head on one position a row: (B, 1, V)
+            kwargs["logit_positions"] = logit_positions
         logits, mut = model.apply(
             variables, tokens, mutable=("cache",), **kwargs
         )

@@ -21,6 +21,20 @@ parallelism (EP/MoE): ❌"); this module adds it the TPU way:
 The load-balancing auxiliary loss (Switch Transformer eq. 4) is sown into the
 ``"losses"`` collection; ``training.pipeline.make_train_step(...,
 aux_loss_collection="losses")`` adds it to the task loss.
+
+Two routing rules live here (``TransformerConfig.moe_routing``):
+
+* ``"softmax_capacity"`` — :class:`MoEFeedForward` and :func:`assign_slots`,
+  everything above: softmax gates, a capacity per expert, overflow dropped,
+  two-matrix GELU experts, three dispatches (einsum / scatter / all-to-all).
+  Used by ``CONFIG_TINY_MOE``, the expert-parallel training tests and
+  ``ops/moe_dispatch.py``; no benchmark cell runs it.
+* ``"sigmoid_dropless"`` — :class:`DroplessMoE`: sigmoid scores, selection
+  on score + a learned per-expert bias, weights renormalised over the picks
+  and scaled, gated SiLU experts, always-on shared experts, NO capacity and
+  no drop; expert compute through ``ops/moe_experts.py``. Used by
+  JoyAI-LLM-Flash (``benchmark/configs/joyai-llm-flash.json``) on one chip;
+  not sharded over experts yet.
 """
 
 from __future__ import annotations
@@ -287,4 +301,113 @@ class MoEFeedForward(nn.Module):
                 "tec,ecm->tm", combine.astype(self.dtype), expert_out
             )
         out = out.reshape(b, s, m)
+        return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))
+
+
+class DroplessMoE(nn.Module):
+    """Sigmoid-routed, dropless expert FFN with shared experts (the
+    DeepSeek-V3 rule; ``topk_method`` ``noaux_tc`` with one group)::
+
+        s = sigmoid(x W_r)                      float32, (T, E)
+        picks = top_k(s + bias)                 the bias only SELECTS
+        w_i = routed_scaling * s_i / (sum_picks s + 1e-20)
+        y = sum_i w_i E_i(x) + E_shared(x)      E = (silu(x W_g) * x W_u) W_d
+
+    Every pick is computed: there is no capacity, so however uneven the
+    routing no token's output lacks an expert. ``valid`` ``(B, S)`` marks
+    the tokens that exist (a refill chunk's padding and a frozen decode row
+    do not): the others are routed nowhere, read no expert and get the
+    shared expert's output only (which their row discards).
+
+    Parameters: ``router/kernel`` ``(M, E)``, ``bias`` ``(E,)`` (the
+    selection bias, a leaf of this module so that its path ends in
+    ``['bias']``), ``gate`` / ``up`` ``(E, M, H)``, ``down`` ``(E, H, M)``,
+    and ``shared/{gate,up,down}/kernel`` of width ``shared_experts * H``.
+    All in ``param_dtype``; router scores, bias add, top-k and weights run
+    in float32 whatever the compute dtype.
+
+    ``count``: keep cumulative ``moe_stats`` ``(3,)`` int32 in the
+    ``"cache"`` collection (assignments routed, experts read, layer-steps):
+    the serving engine returns their growth with each dispatch's readback.
+    """
+
+    features: int
+    hidden: int
+    num_experts: int
+    top_k: int
+    shared_experts: int = 0
+    routed_scaling: float = 1.0
+    experts: str = "auto"
+    count: bool = False
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    router_dtype: jnp.dtype = jnp.float32   # the model's rule; a test lowers
+                                            # it to show the tolerance bites
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    @nn.compact
+    def __call__(self, x: jax.Array, *, valid: jax.Array | None = None) -> jax.Array:
+        from learning_jax_sharding_tpu.models.transformer import FeedForward
+        from learning_jax_sharding_tpu.ops.moe_experts import routed_experts
+
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k={self.top_k} not in [1, {self.num_experts}]")
+        b, s, m = x.shape
+        e, t = self.num_experts, b * s
+        x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
+
+        with jax.named_scope("moe.route"):
+            router = nn.Dense(
+                e, use_bias=False, dtype=self.router_dtype,
+                param_dtype=self.param_dtype,
+                # A TPU's default float32 matmul is one bf16 pass.
+                precision=jax.lax.Precision.HIGHEST,
+                kernel_init=nn.with_logical_partitioning(
+                    self.kernel_init, (EMBED, EXPERT)
+                ),
+                name="router",
+            )
+            bias = self.param(
+                "bias",
+                nn.with_logical_partitioning(nn.initializers.zeros_init(), (EXPERT,)),
+                (e,), self.param_dtype,
+            )
+            scores = jax.nn.sigmoid(
+                router(x.astype(self.router_dtype)).reshape(t, e)
+            )
+            _, idx = jax.lax.top_k(
+                scores + bias.astype(self.router_dtype), self.top_k
+            )
+            picked = jnp.take_along_axis(scores, idx, axis=-1)
+            weights = self.routed_scaling * picked / (
+                jnp.sum(picked, axis=-1, keepdims=True) + 1e-20
+            )
+
+        def experts(name, shape, axes):
+            return self.param(
+                name, nn.with_logical_partitioning(self.kernel_init, axes),
+                shape, self.param_dtype,
+            )
+
+        w_gate = experts("gate", (e, m, self.hidden), (EXPERT, EMBED, MLP))
+        w_up = experts("up", (e, m, self.hidden), (EXPERT, EMBED, MLP))
+        w_down = experts("down", (e, self.hidden, m), (EXPERT, MLP, EMBED))
+        out, stats = routed_experts(
+            x.reshape(t, m).astype(self.dtype), idx, weights, w_gate, w_up,
+            w_down, valid=None if valid is None else valid.reshape(t),
+            backend=self.experts,
+        )
+        out = out.reshape(b, s, m)
+        if self.count:
+            seen = self.variable(
+                "cache", "moe_stats", jnp.zeros, (3,), jnp.int32
+            )
+            seen.value = seen.value + stats
+        if self.shared_experts:
+            with jax.named_scope("moe.shared"):
+                out = out + FeedForward(
+                    features=m, hidden=self.shared_experts * self.hidden,
+                    gated=True, dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="shared",
+                )(x)
         return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))

@@ -122,6 +122,7 @@ from learning_jax_sharding_tpu.telemetry import (
     Tracer,
 )
 from learning_jax_sharding_tpu.telemetry.compile_watch import cache_size
+from learning_jax_sharding_tpu.telemetry.registry import labeled_name
 
 #: Dispatch failures the engine RECOVERS from (quarantine/requeue)
 #: instead of propagating: the chaos harness's injected faults and the
@@ -635,10 +636,55 @@ class ContinuousEngine:
                     "probes at fused-dispatch granularity"
                 )
 
+        # A latent-attention config caches ONE row [c_kv | k_rope] a token
+        # and a dropless expert layer reports its counts with the split
+        # programs' readbacks: what has not been taught either yet is
+        # refused here, by name, never served wrong.
+        latent = bool(config.latent_kv_rank)
+        moe_counted = (
+            config.num_experts > 0 and config.moe_routing == "sigmoid_dropless"
+        )
+        if latent or moe_counted:
+            what = "latent attention" if latent else "dropless experts"
+            refused = {
+                "draft_config (speculative decoding)": (
+                    speculative, "rollback and the draft's lockstep cache "
+                    "assume K and V per head",
+                ),
+                "prefix_cache": (
+                    prefix_cache, "page copies and the registry were written "
+                    "for (P, N_kv, page, 2H) pools and are untested over "
+                    "latent rows",
+                ),
+                "mixed / horizon": (
+                    mixed, "the fused step families do not return the expert "
+                    "counters or skip idle rows' latent attention",
+                ),
+                "adapter_pool": (adapter_pool is not None, "it needs mixed=True"),
+                "comm_compression": (comp is not None, "it needs mixed=True"),
+                "dequantize": (
+                    bool(dequantize), "the latent projections and the expert "
+                    "matrices have no quantized form",
+                ),
+                "a mesh of more than one device": (
+                    mesh.size > 1, "the latent row is one shared head (nothing "
+                    "to split over KV heads) and the expert kernel is not "
+                    "sharded over experts",
+                ),
+            }
+            for name, (asked, why) in refused.items():
+                if asked:
+                    raise ValueError(
+                        f"{name} is not supported with {what}: {why}"
+                    )
+
         def check_paged(name, c):
             # ONE copy of the paged preconditions, applied to the target and
             # (when speculative) the draft — their caches page side by side.
-            if resolve_decode_backend(c.decode_attention) != "blocked":
+            if (
+                not c.latent_kv_rank   # its one cached path IS the kernel
+                and resolve_decode_backend(c.decode_attention) != "blocked"
+            ):
                 raise ValueError(
                     f"paged_pages requires the blocked decode backend for the "
                     f"{name} config (decode_attention='blocked', or 'auto' on "
@@ -791,20 +837,63 @@ class ContinuousEngine:
             # must mirror the target's valid prefix for verification); the
             # pick is each row's first generated token — position 0 of its
             # stream.
+            last = jnp.maximum(lengths - 1, 0)
             if speculative:
                 t_cache, d_cache = cache
                 logits, t_cache = apply(params, t_cache, chunk, lengths)
                 _, d_cache = d_apply(d_params, d_cache, chunk, lengths)
                 cache = (t_cache, d_cache)
+            elif latent:
+                # The head on each row's last valid position only: at this
+                # family's vocabulary (129,280) the chunk's full (B, S, V)
+                # logits are 2.1 GB in float32 and 2.2 TFLOP a dispatch.
+                # GPT-2-shaped configs keep the program their goldens pin.
+                logits, cache = apply(
+                    params, cache, chunk, lengths, logit_positions=last
+                )
+                last = jnp.zeros_like(last)      # logits are (B, 1, V)
             else:
                 logits, cache = apply(params, cache, chunk, lengths)
-            pick = jnp.take_along_axis(
-                logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
-            )[:, 0]
+            pick = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
             tok = sample_rows(pick, rng, rid, jnp.zeros_like(rid))
             return tok, cache
 
+        def moe_seen(cache):
+            """Sum of the expert layers' cumulative ``moe_stats``: ``(3,)``
+            int32 (assignments, expert reads, layer-steps)."""
+            seen = jnp.zeros((3,), jnp.int32)
+            if cache is not None:
+                for path, x in jax.tree_util.tree_flatten_with_path(cache)[0]:
+                    if getattr(path[-1], "key", None) == "moe_stats":
+                        seen = seen + x
+            return seen
+
+        def with_moe(cache_arg):
+            """A dropless-expert config's split programs return, after
+            their usual outputs (the cache last), the growth of
+            ``moe_seen`` over the call: it comes back with the readback
+            the dispatch makes anyway. ``cache_arg``: which positional
+            argument is the cache going in (None: the call creates it).
+            Other configs' programs are untouched."""
+
+            def deco(program):
+                if not moe_counted:
+                    return program
+
+                @functools.wraps(program)
+                def counted(*args):
+                    before = moe_seen(
+                        None if cache_arg is None else args[cache_arg]
+                    )
+                    out = program(*args)
+                    return (*out, moe_seen(out[-1]) - before)
+
+                return counted
+
+            return deco
+
         @jax.jit
+        @with_moe(2)
         def refill_step(
             params, d_params, cache, chunk, lengths, reset_mask, reset_to,
             rid, rng,
@@ -826,11 +915,13 @@ class ContinuousEngine:
         # refill_step minus the reset (Flax creates the zeroed caches —
         # make_cached_apply treats a None cache as the creating call).
         @jax.jit
+        @with_moe(None)
         def first_refill(params, d_params, chunk, lengths, rid, rng):
             cache = (None, None) if speculative else None
             return _refill(params, d_params, cache, chunk, lengths, rid, rng)
 
         @jax.jit
+        @with_moe(1)
         def decode_block(params, cache, tok, active, remaining, rid, rng):
             """``decode_block_steps`` tokens per call, scanned ON DEVICE — the
             host loop costs one dispatch/readback per BLOCK, not per token
@@ -1565,6 +1656,8 @@ class ContinuousEngine:
         self._spec_disabled = False
         self._shed_all = False
         self._base_budget: int | None = None
+        self._latent = latent
+        self._moe_counted = moe_counted
         self._paged = paged
         self._paged_pages = paged_pages
         self._page_size = page_size
@@ -1922,7 +2015,27 @@ class ContinuousEngine:
             "engine_decode_context_tokens_total",
             "sum over decode row-steps of the row's cache length at that "
             "step: x 2 x layers x kv_heads x head_dim x bytes is the K,V "
-            "a decode kernel had to read")
+            "a decode kernel had to read (a latent cache: x layers x "
+            "(kv_rank + rope_dim) x bytes)")
+        # Dropless expert layers (models.moe.DroplessMoE), counted on the
+        # device and read back with a split dispatch's own readback, by
+        # phase of the dispatch.
+        self._c_moe = {
+            phase: tuple(
+                r.counter(labeled_name(name, phase=phase), help_)
+                for name, help_ in (
+                    ("engine_moe_assignments_total",
+                     "token x expert pairs routed"),
+                    ("engine_moe_expert_reads_total",
+                     "summed over expert layers and model steps: experts "
+                     "with at least one token, whose three matrices the "
+                     "expert kernel read"),
+                    ("engine_moe_layer_steps_total",
+                     "expert-layer applications that routed anything"),
+                )
+            )
+            for phase in ("decode", "refill")
+        } if self._moe_counted else {}
         self._span_names: dict[tuple[str, str], str] = {}  # (phase, family)
         # Goodput ledger (round 14): exhaustive wall-clock attribution
         # for the engine loop. step() is the top-level frame (its
@@ -1946,6 +2059,8 @@ class ContinuousEngine:
         self._compiled = False
         self._booked = dict.fromkeys(self._DISPATCH_DELTAS, 0.0)
         self._booked["starved_s"] = 0.0
+        if self._moe_counted:
+            self._booked.update(moe_assignments=0.0, expert_reads=0.0)
         # Request-scoped trace sink (telemetry.tracecontext.TraceStore).
         # The fleet router attaches its store (and the replica name) to
         # every replica; a solo driver may attach its own — legs are
@@ -2051,6 +2166,14 @@ class ContinuousEngine:
         "decode_steps": "_c_decode_steps",
         "context_tokens": "_c_decode_ctx",
     }
+
+    def _book_moe(self, phase, stats):
+        """Add one readback's expert counts ``(3,)`` (a dropless-expert
+        config's programs return them; already on the host) to
+        ``phase``'s counters."""
+        with self.ledger.measure("telemetry", span="engine.telemetry"):
+            for counter, n in zip(self._c_moe[phase], stats.tolist()):
+                counter.inc(n)
 
     @contextlib.contextmanager
     def _led_device(self, fn=None, family=None, in_flight=0):
@@ -2853,6 +2976,12 @@ class ContinuousEngine:
     # --- disaggregated prefill/decode handoff (round 11) -------------------
 
     def _check_handoff_supported(self, what: str):
+        if self._latent:
+            raise ValueError(
+                f"{what}: latent-attention engines are not supported — the "
+                "transfer plans move (B, L, N_kv, H) K and V rows, and a "
+                "latent cache holds one [c_kv | k_rope] row a token"
+            )
         if self._speculative:
             raise ValueError(
                 f"{what}: speculative engines are not supported — the "
@@ -2890,7 +3019,7 @@ class ContinuousEngine:
                 jnp.zeros((self._b,), jnp.int32), self._rid_arr(),
                 self.rng,
             )
-            _, self._cache = self._first_refill_fn(*first_args)
+            self._cache = self._first_refill_fn(*first_args)[1]
             if self._paged:
                 # Outside step(), which is what the ledger covers.
                 self._cache = self._set_tables(self._cache, frame=False)
@@ -3914,7 +4043,7 @@ class ContinuousEngine:
                         self.rng,
                     )
                     with self._led_device(self._first_refill_fn):
-                        _, self._cache = self._first_refill_fn(*first_args)
+                        self._cache = self._first_refill_fn(*first_args)[1]
                     self.cache_creations += 1
                     self._c_creations.inc()
                     self.recorder.record(
@@ -3929,7 +4058,9 @@ class ContinuousEngine:
                         jnp.asarray(lengths), self._rid_arr(), self.rng,
                     )
                 with self._led_device(self._first_refill_fn):
-                    tok_new, self._cache = self._first_refill_fn(*first_args)
+                    tok_new, self._cache, *moe = self._first_refill_fn(
+                        *first_args
+                    )
                 seg_fam = "first_refill"
                 self.cache_creations += 1
                 self._c_creations.inc()
@@ -3952,7 +4083,7 @@ class ContinuousEngine:
                     reset_to_d = jnp.asarray(self._reset_to.copy())
                     rid_d = self._rid_arr()
                 with self._led_device(self._refill_step_fn):
-                    tok_new, self._cache = self._refill_step_fn(
+                    tok_new, self._cache, *moe = self._refill_step_fn(
                         params, d_params, self._cache, chunk_d, lengths_d,
                         reset_d, reset_to_d, rid_d, self.rng,
                     )
@@ -3980,16 +4111,20 @@ class ContinuousEngine:
                         and self._req[slot] >= 0
                     ):
                         seg_completes.append(slot)
-            segs.append((tok_new, seg_completes, seg_fam))
+            segs.append((tok_new, seg_completes, seg_fam, moe))
             self._c_prefill_tok.inc(int(lengths.sum()))
         if not segs:
             return False
-        for i, (tok_new, seg_completes, seg_fam) in enumerate(segs):
+        for i, (tok_new, seg_completes, seg_fam, moe) in enumerate(segs):
             with self._led_device(
                 family=seg_fam, in_flight=len(segs) - 1 - i
             ):
-                tok_new = np.asarray(tok_new)   # each segment's own sync
+                # Each segment's own sync: its tokens and, from the same
+                # program, a dropless-expert config's counts.
+                tok_new, *moe = (np.asarray(x) for x in (tok_new, *moe))
             now = time.perf_counter()       # its host-visibility time
+            if moe:
+                self._book_moe("refill", moe[0])
             with self._led_consume():
                 self._first_tokens(seg_completes, tok_new, now, retired)
         return True
@@ -4164,10 +4299,10 @@ class ContinuousEngine:
                 cache, d_cache = self._cache
             else:
                 cache, d_cache = self._cache, None
-            segs = []
+            segs, moe_segs = [], []
             for _ in range(chain):
                 with self._led_device(self._decode_block_fn):
-                    toks, active_d, remaining_d, cache = (
+                    toks, active_d, remaining_d, cache, *moe = (
                         self._decode_block_fn(
                             params, cache, tok_d, active_d,
                             remaining_d, rid, self.rng,
@@ -4177,6 +4312,7 @@ class ContinuousEngine:
                 # (frozen rows repeat their token — correct carry).
                 tok_d = toks[:, -1]
                 segs.append(toks)
+                moe_segs += moe
             if self._speculative:
                 self._cache = (cache, d_cache)
                 self._last_decode_plain_args = lambda: (
@@ -4191,7 +4327,10 @@ class ContinuousEngine:
                 )
             with self._led_device(family="decode_block"):
                 segs = [np.asarray(t) for t in segs]   # ONE sync
+                moe_segs = jax.device_get(moe_segs)    # the same programs' counts
             now = time.perf_counter()
+            for moe in moe_segs:
+                self._book_moe("decode", moe)
             was_active = self._active.copy()
             with self._led_consume():
                 for toks in segs:
@@ -4283,7 +4422,7 @@ class ContinuousEngine:
                 self.rng,
             )
             with self._led_device(self._first_refill_fn):
-                _, self._cache = self._first_refill_fn(*first_args)
+                self._cache = self._first_refill_fn(*first_args)[1]
             self.cache_creations += 1
             self._c_creations.inc()
             self.recorder.record(
@@ -5248,6 +5387,12 @@ class ContinuousEngine:
                 for field, attr in self._DISPATCH_DELTAS.items()
             }
             now["starved_s"] = self._starved_at_enqueue
+            if self._moe_counted:
+                # Both phases' series: a dispatch grows one of them.
+                for field, i in (("moe_assignments", 0), ("expert_reads", 1)):
+                    now[field] = sum(
+                        series[i].value for series in self._c_moe.values()
+                    )
             self.recorder.record(
                 "engine.dispatch", family=self._last_family, phase=kind,
                 step=self._step_n, rows=rows, compiled=self._compiled,

@@ -16,6 +16,17 @@ that composition:
   final norm, logits head: the 125M-parameter flagship configuration of
   `BASELINE.json` ("case4+case6 composed 125M transformer").
 
+Block kinds (a config picks one attention and, per layer, one feed-forward):
+
+* attention: :class:`~.attention.MultiHeadAttention` (MHA / GQA / MQA,
+  learned positions or RoPE, one window: the GPT-2 cells and every test
+  preset) or :class:`~.attention.LatentAttention` when
+  ``latent_kv_rank`` is set (JoyAI-LLM-Flash);
+* feed-forward: two matrices with GELU (GPT-2), or three with a SiLU gate
+  (``ff_gated``); or an expert layer from ``models/moe.py`` when
+  ``num_experts > 0`` — in every block, or from block ``first_k_dense`` on
+  (JoyAI-LLM-Flash: one dense layer, then expert layers).
+
 Everything is dtype-parameterized: bf16 compute / fp32 params is the TPU MXU
 sweet spot and the default for benchmarks.
 """
@@ -30,7 +41,10 @@ import jax
 import jax.numpy as jnp
 import optax
 
-from learning_jax_sharding_tpu.models.attention import MultiHeadAttention
+from learning_jax_sharding_tpu.models.attention import (
+    LatentAttention,
+    MultiHeadAttention,
+)
 from learning_jax_sharding_tpu.parallel.logical import (
     BATCH,
     EMBED,
@@ -116,7 +130,8 @@ class _CompressedDense(nn.Module):
 
 
 class FeedForward(nn.Module):
-    """Position-wise FF: up-project → GELU → down-project.
+    """Position-wise FF: up-project → GELU → down-project; with ``gated``
+    the three-matrix SiLU form ``(silu(x W_gate) * x W_up) W_down``.
 
     The case-4 feed-forward (`/root/reference/case4_gspmd_ff.py:36-58`) grown
     into a real module: with MLP→model rules the up-projection is
@@ -128,6 +143,7 @@ class FeedForward(nn.Module):
     features: int
     hidden: int
     use_bias: bool = False
+    gated: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
     kernel_init: Callable = nn.initializers.lecun_normal()
@@ -183,7 +199,11 @@ class FeedForward(nn.Module):
             return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))
         h = self._dense(self.hidden, (EMBED, MLP), "up")(x)
         h = nn.with_logical_constraint(h, (BATCH, SEQ, HIDDEN))
-        h = nn.gelu(h)
+        if self.gated:
+            g = self._dense(self.hidden, (EMBED, MLP), "gate")(x)
+            h = nn.silu(nn.with_logical_constraint(g, (BATCH, SEQ, HIDDEN))) * h
+        else:
+            h = nn.gelu(h)
         if self.comm_compress_fn is not None and self.quantization is None:
             # The down projection is the block's one all-reduce site (the
             # up projection is column-parallel, collective-free): swap in
@@ -210,6 +230,7 @@ class FeedForward(nn.Module):
             self.quantization == "int4"
             and self.quantized_matmul_fn is None
             and not self.use_bias
+            and not self.gated
             and self.features == k
             and int4_ff_eligible(k, self.hidden, self.quantization_group)
         )
@@ -337,6 +358,20 @@ class TransformerBlock(nn.Module):
                                   # fused residual+norm kernel (param-tree
                                   # identical; see FusedNorm)
     scan: bool = False            # under nn.scan: return (x, None) pairs
+    # Latent attention and the gated / dropless feed-forwards: the config's
+    # fields of the same names (TransformerConfig).
+    latent_kv_rank: Optional[int] = None
+    latent_q_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    latent_absorbed: bool = False
+    ff_gated: bool = False
+    moe_routing: str = "softmax_capacity"
+    moe_hidden: Optional[int] = None
+    moe_shared_experts: int = 0
+    moe_routed_scaling: float = 1.0
+    moe_experts: str = "auto"
 
     def _norm(self, name: str):
         if self.fused_norm:
@@ -357,6 +392,33 @@ class TransformerBlock(nn.Module):
     ):
         x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
         h, _ = self._norm("ln_attn")(x)
+        if self.latent_kv_rank:
+            return self._finish(
+                x,
+                LatentAttention(
+                    features=self.features,
+                    num_heads=self.num_heads,
+                    q_rank=self.latent_q_rank,
+                    kv_rank=self.latent_kv_rank,
+                    nope_dim=self.qk_nope_dim,
+                    rope_dim=self.qk_rope_dim,
+                    v_dim=self.v_head_dim,
+                    rope_theta=self.rope_theta,
+                    norm_eps=self.norm_eps,
+                    causal=self.causal,
+                    absorbed=self.latent_absorbed,
+                    dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                    decode=self.decode,
+                    max_decode_len=self.max_decode_len,
+                    decode_block_k=self.decode_block_k,
+                    decode_ragged=self.decode_ragged,
+                    decode_paged=self.decode_paged,
+                    decode_page_count=self.decode_page_count,
+                    name="attn",
+                )(h, deterministic=deterministic, chunk_lengths=chunk_lengths),
+                deterministic, chunk_lengths,
+            )
         attn_out = MultiHeadAttention(
             features=self.features,
             num_heads=self.num_heads,
@@ -386,10 +448,35 @@ class TransformerBlock(nn.Module):
             quantized_matmul_fn=self.quantized_matmul_fn,
             name="attn",
         )(h, deterministic=deterministic, chunk_lengths=chunk_lengths)
+        return self._finish(x, attn_out, deterministic, chunk_lengths)
+
+    def _finish(self, x, attn_out, deterministic, chunk_lengths):
+        """The second half of a block: residual add, norm, feed-forward."""
         # The block boundary: residual add + norm — ONE fused HBM pass
         # under fused_norm, the plain pair otherwise (identical math).
         h, x = self._norm("ln_ff")(attn_out, x)
-        if self.num_experts > 0:
+        if self.num_experts > 0 and self.moe_routing == "sigmoid_dropless":
+            from learning_jax_sharding_tpu.models.moe import DroplessMoE
+
+            valid = None
+            if chunk_lengths is not None:
+                valid = (
+                    jnp.arange(h.shape[1])[None, :] < chunk_lengths[:, None]
+                )
+            x = x + DroplessMoE(
+                features=self.features,
+                hidden=self.moe_hidden or self.hidden,
+                num_experts=self.num_experts,
+                top_k=self.moe_top_k,
+                shared_experts=self.moe_shared_experts,
+                routed_scaling=self.moe_routed_scaling,
+                experts=self.moe_experts,
+                count=self.decode,
+                dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                name="moe",
+            )(h, valid=valid)
+        elif self.num_experts > 0:
             from learning_jax_sharding_tpu.models.moe import MoEFeedForward
 
             x = x + MoEFeedForward(
@@ -409,6 +496,7 @@ class TransformerBlock(nn.Module):
                 features=self.features,
                 hidden=self.hidden,
                 use_bias=self.use_bias,
+                gated=self.ff_gated,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
                 quantization=self.quantization,
@@ -518,6 +606,39 @@ class TransformerConfig:
                                      # make_compressed_matmul_fn); injected by
                                      # ContinuousEngine(comm_compression=...);
                                      # param-tree identical to the plain path
+    # --- latent attention (models.attention.LatentAttention) --------------
+    latent_kv_rank: Optional[int] = None  # set: q through a rank-
+                                     # latent_q_rank bottleneck, k/v from ONE
+                                     # latent of this rank a token plus one
+                                     # shared rotary key; num_heads heads of
+                                     # qk_nope_dim + qk_rope_dim (scores) and
+                                     # v_head_dim (values); head_dim,
+                                     # num_kv_heads, window and rope are then
+                                     # unused (positions are the rotary key's)
+    latent_q_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    latent_absorbed: bool = False    # the NORMAL path's form: expanded
+                                     # (per-head k and v) or absorbed (heads
+                                     # against the shared latent row); decode
+                                     # is always absorbed
+    # --- feed-forward kinds ------------------------------------------------
+    ff_gated: bool = False           # (silu(x W_gate) * x W_up) W_down
+    first_k_dense: int = 0           # with num_experts > 0: blocks before
+                                     # this one keep the dense FF (hidden)
+    moe_routing: str = "softmax_capacity"  # models/moe.py: that rule
+                                     # (softmax, capacity, drops:
+                                     # MoEFeedForward) or "sigmoid_dropless"
+                                     # (DroplessMoE: sigmoid scores, selection
+                                     # bias, renormalised x moe_routed_scaling,
+                                     # gated experts, shared experts)
+    moe_hidden: Optional[int] = None  # expert width (None: hidden)
+    moe_shared_experts: int = 0      # always-on experts beside the routed
+    moe_routed_scaling: float = 1.0
+    moe_experts: str = "auto"        # dropless expert compute: "pallas"
+                                     # (ops/moe_experts.py), "ragged" (sorted
+                                     # XLA ragged_dot) or "auto" (pallas on TPU)
 
     def __post_init__(self):
         # Fail fast on typos; 'nothing' IS the default, so only a policy that
@@ -526,6 +647,33 @@ class TransformerConfig:
             raise ValueError(
                 "remat_policy is set but remat=False — the policy would "
                 "be silently ignored; set remat=True (or drop the policy)"
+            )
+        if self.moe_routing not in ("softmax_capacity", "sigmoid_dropless"):
+            raise ValueError(
+                f"unknown moe_routing {self.moe_routing!r}: "
+                f"'softmax_capacity' or 'sigmoid_dropless'"
+            )
+        if self.first_k_dense and self.scan_layers:
+            raise ValueError(
+                "first_k_dense makes the blocks differ by layer: scan_layers "
+                "stacks ONE block"
+            )
+        if self.latent_kv_rank and not (
+            self.latent_q_rank and self.qk_nope_dim and self.qk_rope_dim
+            and self.v_head_dim
+        ):
+            raise ValueError(
+                "latent_kv_rank needs latent_q_rank, qk_nope_dim, "
+                "qk_rope_dim and v_head_dim"
+            )
+        if self.latent_kv_rank and (
+            self.kv_cache_dtype is not None or self.quantization
+            or self.attn_fn is not None or self.window is not None
+        ):
+            raise ValueError(
+                "latent attention has one cache row a token in the compute "
+                "dtype and its own attention paths: kv_cache_dtype, "
+                "quantization, attn_fn and window do not apply to it"
             )
         if self.decode_paged:
             if not self.decode_ragged:
@@ -559,50 +707,72 @@ class TransformerConfig:
         runs. MFU accounting therefore uses this standard analytic count
         (PaLM-style): ``6 × matmul_params`` per token plus the attention
         einsums, with causal attention counted at half the S² (what a
-        block-skipping kernel actually computes).
+        block-skipping kernel actually computes). Expert layers count a
+        token's ACTIVATED parameters (its picks, the shared experts, the
+        router); latent attention its expanded form.
         """
-        ff_params = 2 * self.features * self.hidden
-        if self.num_experts > 0:
-            # Per-token ACTIVATED params: top_k routed expert FFs + router.
-            ff_params = ff_params * self.moe_top_k + self.features * self.num_experts
-        matmul_params_per_layer = (
-            self._attn_proj_params + ff_params
-        )
         matmul_params = (
-            self.num_layers * matmul_params_per_layer
+            sum(
+                self._attn_proj_params + self._ff_params(i, activated=True)
+                for i in range(self.num_layers)
+            )
             + self.features * self.vocab_size        # lm_head
         )
+        qk_v = (
+            self.qk_nope_dim + self.qk_rope_dim + self.v_head_dim
+            if self.latent_kv_rank else 2 * self.head_dim
+        )
         attn_per_token = (
-            4 * seq * self.num_heads * self.head_dim * self.num_layers
+            2 * seq * self.num_heads * qk_v * self.num_layers
         ) * (0.5 if self.causal else 1.0)
         per_token = 6 * matmul_params + 3 * attn_per_token
         return float(per_token) * batch * seq
 
     @property
     def _attn_proj_params(self) -> int:
-        """q + k + v + out projection params (k/v shrink under GQA)."""
+        """q + k + v + out projection params (k/v shrink under GQA; the
+        five low-rank matrices of latent attention)."""
+        if self.latent_kv_rank:
+            n, qk = self.num_heads, self.qk_nope_dim + self.qk_rope_dim
+            return (
+                self.features * self.latent_q_rank
+                + self.latent_q_rank * n * qk
+                + self.features * (self.latent_kv_rank + self.qk_rope_dim)
+                + self.latent_kv_rank * n * (self.qk_nope_dim + self.v_head_dim)
+                + n * self.v_head_dim * self.features
+            )
         kv_heads = self.num_kv_heads if self.num_kv_heads is not None else self.num_heads
         return (
             2 * self.features * self.num_heads * self.head_dim   # q + out
             + 2 * self.features * kv_heads * self.head_dim       # k + v
         )
 
+    def _ff_params(self, layer: int, *, activated: bool = False) -> int:
+        """Feed-forward matmul parameters of block ``layer``: all of them,
+        or with ``activated`` those one token multiplies by."""
+        mats = 3 if self.ff_gated else 2
+        if not self.num_experts or layer < self.first_k_dense:
+            return mats * self.features * self.hidden
+        router = self.features * self.num_experts
+        if self.moe_routing == "softmax_capacity":
+            expert = 2 * self.features * self.hidden
+            return router + expert * (self.moe_top_k if activated else self.num_experts)
+        expert = 3 * self.features * (self.moe_hidden or self.hidden)
+        held = self.moe_top_k if activated else self.num_experts
+        return router + expert * (held + self.moe_shared_experts)
+
     @property
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks + head)."""
-        ff_params = 2 * self.features * self.hidden             # ff up + down
-        if self.num_experts > 0:
-            ff_params *= self.num_experts                        # E expert FFs
-            ff_params += self.features * self.num_experts        # router
-        per_block = (
-            self._attn_proj_params                               # qkv + out
-            + ff_params
-            + 4 * self.features                                  # 2 LN scale+bias
+        """Approximate parameter count (embeddings + blocks + head; a norm
+        counted as 2 vectors whichever kind it is)."""
+        blocks = sum(
+            self._attn_proj_params + self._ff_params(i) + 4 * self.features
+            for i in range(self.num_layers)
         )
         pos = 0 if self.rope else self.max_seq_len * self.features
         embed = self.vocab_size * self.features + pos
         head = self.features * self.vocab_size
-        return embed + self.num_layers * per_block + 2 * self.features + head
+        return embed + blocks + 2 * self.features + head
 
 
 #: The BASELINE.json flagship: "case4+case6 composed 125M transformer".
@@ -644,10 +814,15 @@ class Transformer(nn.Module):
         deterministic: bool = True,
         return_hidden: bool = False,
         chunk_lengths: Optional[jax.Array] = None,
+        logit_positions: Optional[jax.Array] = None,
     ) -> jax.Array:
         """``chunk_lengths``: ragged decode only (``config.decode_ragged``)
         — per-row valid-token count of this chunk; see
-        ``models.attention.MultiHeadAttention.__call__``."""
+        ``models.attention.MultiHeadAttention.__call__``.
+        ``logit_positions`` ``(B,)``: the final norm and the head run on
+        that ONE position a row and the logits come back ``(B, 1, V)`` (a
+        refill chunk needs its last valid position's logits only; at a
+        vocabulary of 129,280 the full ``(B, S, V)`` is gigabytes)."""
         cfg = self.config
         b, s = tokens.shape
         if s > cfg.max_seq_len:
@@ -743,7 +918,26 @@ class Transformer(nn.Module):
             comm_compress_fn=cfg.comm_compress_fn,
             norm=cfg.norm,
             fused_norm=cfg.fused_norm,
+            latent_kv_rank=cfg.latent_kv_rank,
+            latent_q_rank=cfg.latent_q_rank,
+            qk_nope_dim=cfg.qk_nope_dim,
+            qk_rope_dim=cfg.qk_rope_dim,
+            v_head_dim=cfg.v_head_dim,
+            latent_absorbed=cfg.latent_absorbed,
+            ff_gated=cfg.ff_gated,
+            moe_routing=cfg.moe_routing,
+            moe_hidden=cfg.moe_hidden,
+            moe_shared_experts=cfg.moe_shared_experts,
+            moe_routed_scaling=cfg.moe_routed_scaling,
+            moe_experts=cfg.moe_experts,
         )
+
+        def fields_of(i):
+            # Blocks differ by layer only in whether the FF is routed.
+            if cfg.num_experts and i < cfg.first_k_dense:
+                return {**block_fields, "num_experts": 0}
+            return block_fields
+
         if cfg.scan_layers:
             if cfg.decode:
                 raise ValueError(
@@ -799,14 +993,16 @@ class Transformer(nn.Module):
                 if cfg.decode:
                     # chunk_lengths rides only the decode path (remat wraps
                     # the training call and pins its positional signature).
-                    x = block_cls(**block_fields, name=f"block_{i}")(
+                    x = block_cls(**fields_of(i), name=f"block_{i}")(
                         x, deterministic, chunk_lengths
                     )
                 else:
-                    x = block_cls(**block_fields, name=f"block_{i}")(
+                    x = block_cls(**fields_of(i), name=f"block_{i}")(
                         x, deterministic
                     )
 
+        if logit_positions is not None:
+            x = jnp.take_along_axis(x, logit_positions[:, None, None], axis=1)
         if cfg.fused_norm:
             x, _ = FusedNorm(
                 kind=cfg.norm, eps=cfg.norm_eps, dtype=cfg.dtype,

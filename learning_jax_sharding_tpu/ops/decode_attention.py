@@ -76,6 +76,10 @@ _BLOCK_Q = 128    # q rows per grid tile; bounds VMEM for long prefill chunks
 # method until the call was jitted here; the benchmark's trace metrics find the
 # kernel by it (benchmark/metrics/decode_attn_roofline.json).
 _TRACE_NAME = "attn._blocked_cached_attention"
+# The latent (MLA) form of the same call: one shared "head" whose cache row
+# ``[c_kv | k_rope]`` is both the key (all of it) and the value (its first
+# ``latent_v`` lanes) — benchmark/metrics/mla_decode_attn_roofline.json.
+_LATENT_TRACE_NAME = "attn.mla_cached_attention"
 
 
 def auto_block_k(length: int, cap: int = 256) -> int:
@@ -126,6 +130,7 @@ def _kernel(
     #                       kv_ref (1, N_kv, block_k, 2H), ...
     scale: float, block_k: int, group: int, qb: int, s: int,
     window, quantized: bool, fold: bool, paged: bool = False,
+    latent_v: int | None = None,
 ):
     rest = list(rest)
     if paged:
@@ -190,13 +195,26 @@ def _kernel(
                     ovs_ref[0] = vs_blk
 
         h = q_ref.shape[-1]
-        k_blk, v_blk = kv_blk[:, :, :h], kv_blk[:, :, h:]  # lane halves
-        q = q_ref[0].astype(jnp.float32) * scale           # (N_kv, GQ, H)
-        k = k_blk.astype(jnp.float32)
+        if latent_v is None:
+            k_blk, v_blk = kv_blk[:, :, :h], kv_blk[:, :, h:]  # lane halves
+            q = q_ref[0].astype(jnp.float32) * scale       # (N_kv, GQ, H)
+            k = k_blk.astype(jnp.float32)
+            mm = jnp.float32
+        else:
+            # Latent rows: the whole row is the key, its first ``latent_v``
+            # lanes (whole 128-lane tiles) the value — no second operand.
+            # The dots take the cache's own type (bf16 products are exact
+            # in the f32 accumulator; a chunk's 128-row dots over 576 lanes
+            # are real MXU work), the scale moves onto the f32 scores.
+            k_blk, v_blk = kv_blk, kv_blk[:, :, :latent_v]
+            mm = kv_blk.dtype
+            q, k = q_ref[0].astype(mm), k_blk
         sc = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )                                                  # (N_kv, GQ, bk)
+        if latent_v is not None:
+            sc = sc * scale
         if quantized:
             # Per-(token, head) k scales are constant over H, so they commute
             # with the contraction: scale the score COLUMNS instead of
@@ -226,9 +244,9 @@ def _kernel(
         if quantized:
             # v scales are per cache row = per probability column.
             p = p * vs_blk[:, None, :]
-        v = v_blk.astype(jnp.float32)
+        v = v_blk.astype(mm)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((2,), (1,)), ((0,), (0,))),
+            p.astype(mm), v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
@@ -262,10 +280,12 @@ def decode_attention(
     vs_new: jax.Array | None = None,
     write_enable: jax.Array | None = None,
     block_table: jax.Array | None = None,
+    row_enable: jax.Array | None = None,
     window: int | None = None,
     scale: float | None = None,
     block_k: int | None = None,
     block_q: int = _BLOCK_Q,
+    latent_v: int | None = None,
     interpret: bool | None = None,
 ):
     """Attend chunk queries against the valid prefix of a KV cache.
@@ -318,6 +338,19 @@ def decode_attention(
             write flushes through the row's mapped page. Unallocated
             entries are never read (per-row frontier clamping) but should
             point at a reserved scratch page for masked writes.
+        row_enable: per-row ``(B,)`` mask (nonzero = attend). A row with 0
+            gets NO step of the work list: none of its blocks is read, its
+            folded write does not happen, and its output is zeros. For
+            refill chunks, where every slot of the engine rides the call
+            and only the refilling ones have queries worth answering.
+        latent_v: LATENT cache (multi-head latent attention, absorbed
+            form): ``kv_cache`` is ``(B | P, 1, L | page, R)`` with one
+            row ``[c_kv | k_rope]`` a token shared by every head, ``q`` is
+            ``(B, S, N, R)`` (``[q_nope · W_k | q_rope]``), scores run over
+            all ``R`` lanes and the values are the row's first
+            ``latent_v`` lanes (a multiple of 128): the result is
+            ``(B, S, N, latent_v)``. ``scale`` has to be given (the model's
+            ``(nope + rope) ** -0.5``, not ``R ** -0.5``). No int8 form.
         block_k: cache block size; None auto-selects (≤256 dividing L).
         block_q: q rows per grid tile (VMEM bound for long chunks).
         interpret: run the Pallas interpreter; None = auto (True off-TPU).
@@ -331,8 +364,9 @@ def decode_attention(
         interpret = jax.default_backend() != "tpu"
     return _decode_attention(
         q, kv_cache, index, k_scale, v_scale, kv_new, ks_new, vs_new,
-        write_enable, block_table, window=window, scale=scale,
-        block_k=block_k, block_q=block_q, interpret=interpret,
+        write_enable, block_table, row_enable, window=window, scale=scale,
+        block_k=block_k, block_q=block_q, latent_v=latent_v,
+        interpret=interpret,
     )
 
 
@@ -341,13 +375,18 @@ def decode_attention(
 # scalars around it are traced and lowered ONCE per program, not per layer.
 @functools.partial(
     jax.jit,
-    static_argnames=("window", "scale", "block_k", "block_q", "interpret"),
+    static_argnames=(
+        "window", "scale", "block_k", "block_q", "latent_v", "interpret"
+    ),
 )
 def _decode_attention(
     q, kv_cache, index, k_scale, v_scale, kv_new, ks_new, vs_new,
-    write_enable, block_table, *, window, scale, block_k, block_q, interpret,
+    write_enable, block_table, row_enable, *, window, scale, block_k,
+    block_q, latent_v, interpret,
 ):
     b, s, n, h = q.shape
+    latent = latent_v is not None
+    h_out = latent_v if latent else h
     paged = block_table is not None
     if paged:
         pool, n_kv, page, hk = kv_cache.shape
@@ -365,7 +404,21 @@ def _decode_attention(
         bk = b
     else:
         bk, n_kv, length, hk = kv_cache.shape
-    if (bk, hk) != (b, 2 * h):
+    if latent:
+        if (
+            n_kv != 1 or hk != h or not 0 < latent_v <= h
+            or (latent_v % LANES and not interpret)   # Mosaic: whole tiles
+        ):
+            raise ValueError(
+                f"latent cache {kv_cache.shape} with queries {q.shape}: want "
+                f"one shared row of the queries' width R = {h} a token and "
+                f"latent_v ({latent_v}) a multiple of {LANES} within it"
+            )
+        if k_scale is not None or scale is None:
+            raise ValueError(
+                "latent cache: no int8 form, and scale must be given"
+            )
+    elif (bk, hk) != (b, 2 * h):
         raise ValueError(
             f"cache shape {kv_cache.shape} does not match queries "
             f"{q.shape} (want "
@@ -423,9 +476,15 @@ def _decode_attention(
     # ``B × nk``, and no shape depends on the traffic. Only the running
     # totals are computed here (48 layers do it every decode step); each
     # step finds its row and block from them (``_step_of``).
+    held = valid_blocks - kstart
+    if row_enable is not None:
+        enable = jnp.broadcast_to(row_enable, (b,)) != 0
+        # A list with no step at all would be a grid of size 0: row 0 then
+        # stays on it (its output is zeroed below like any disabled row's).
+        listed = enable | (~jnp.any(enable) & (jnp.arange(b) == 0))
+        held = jnp.where(listed, held, 0)
     ends = jnp.sum(
-        jnp.where(jnp.tri(b, dtype=bool), (valid_blocks - kstart)[None], 0),
-        axis=1,
+        jnp.where(jnp.tri(b, dtype=bool), held[None], 0), axis=1
     )                                  # a running total in one fusion
     sargs = jnp.stack(
         [kstart, valid_blocks, idx, jnp.minimum(idx // block_k, nk - 1), woff,
@@ -482,14 +541,19 @@ def _decode_attention(
     q_spec = pl.BlockSpec(
         (1, n_kv, gq, h), lambda qi, w, *pf: (step_of(w, pf[0])[0], 0, qi, 0)
     )
-    in_specs = [q_spec, pl.BlockSpec((1, n_kv, block_k, 2 * h), clamped((0,)))]
+    in_specs = [q_spec, pl.BlockSpec((1, n_kv, block_k, hk), clamped((0,)))]
     operands = [qr, kv_cache]
     if quantized:
         in_specs += [pl.BlockSpec((1, n_kv, block_k), clamped(()))] * 2
         operands += [k_scale, v_scale]
 
-    out_specs = [q_spec]
-    out_shapes = [jax.ShapeDtypeStruct((b, n_kv, s * group, h), q.dtype)]
+    out_specs = [
+        pl.BlockSpec(
+            (1, n_kv, gq, h_out),
+            lambda qi, w, *pf: (step_of(w, pf[0])[0], 0, qi, 0),
+        )
+    ]
+    out_shapes = [jax.ShapeDtypeStruct((b, n_kv, s * group, h_out), q.dtype)]
     aliases = {}
     prefetch_args = [sargs]
     if paged:
@@ -499,9 +563,9 @@ def _decode_attention(
         # back through an output ALIASED to the cache input (alias indices
         # count the scalar-prefetch operands), so only each row's one
         # modified block moves.
-        in_specs += [pl.BlockSpec((1, n_kv, 1, 2 * h), row_map((0, 0)))]
+        in_specs += [pl.BlockSpec((1, n_kv, 1, hk), row_map((0, 0)))]
         operands += [kv_new]
-        out_specs += [pl.BlockSpec((1, n_kv, block_k, 2 * h), written((0,)))]
+        out_specs += [pl.BlockSpec((1, n_kv, block_k, hk), written((0,)))]
         out_shapes += [jax.ShapeDtypeStruct(kv_cache.shape, kv_cache.dtype)]
         kidx = len(prefetch_args) + 1    # operand index of kv_cache
         aliases[kidx] = 1                # kv_cache → output 1
@@ -521,6 +585,7 @@ def _decode_attention(
         functools.partial(
             _kernel, scale=scale, block_k=block_k, group=group, qb=qb, s=s,
             window=window, quantized=quantized, fold=fold, paged=paged,
+            latent_v=latent_v,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch_args),
@@ -528,7 +593,7 @@ def _decode_attention(
             in_specs=in_specs,
             out_specs=out_specs if fold else out_specs[0],
             scratch_shapes=[
-                pltpu.VMEM((n_kv, gq, h), jnp.float32),
+                pltpu.VMEM((n_kv, gq, h_out), jnp.float32),
                 pltpu.VMEM((n_kv, gq, LANES), jnp.float32),
                 pltpu.VMEM((n_kv, gq, LANES), jnp.float32),
             ],
@@ -537,15 +602,18 @@ def _decode_attention(
         input_output_aliases=aliases,
         interpret=interpret,
     )
-    with jax.named_scope(_TRACE_NAME):
+    with jax.named_scope(_LATENT_TRACE_NAME if latent else _TRACE_NAME):
         result = call(*prefetch_args, *operands)
 
     out = result[0] if fold else result
     out = (
-        out.reshape(b, n_kv, s, group, h)
+        out.reshape(b, n_kv, s, group, h_out)
         .transpose(0, 2, 1, 3, 4)
-        .reshape(b, s, n, h)
+        .reshape(b, s, n, h_out)
     )
+    if row_enable is not None:
+        # A row off the list was never written: zeros, not what was there.
+        out = jnp.where(enable[:, None, None, None], out, 0)
     if fold:
         return (out, *result[1:])
     return out

@@ -38,13 +38,16 @@ def rope_angles(
 
 
 def apply_rope(
-    x: jax.Array, positions: jax.Array, theta: float = 10_000.0
+    x: jax.Array, positions: jax.Array, theta: float = 10_000.0,
+    *, interleave: bool = False,
 ) -> jax.Array:
     """Rotate ``x`` of shape ``(B, S, N, H)`` by its absolute positions.
 
     ``positions`` is ``(S,)`` or ``(B, S)``. Pairing follows the split-half
     convention (x[..., :H/2] with x[..., H/2:]), matching the common
-    NeoX/LLaMA layout.
+    NeoX/LLaMA layout; ``interleave`` rotates the neighbours
+    ``(x[..., 2i], x[..., 2i+1])`` in place instead (the GPT-J / DeepSeek
+    layout the latent-attention models publish).
     """
     h = x.shape[-1]
     cos, sin = rope_angles(positions, h, theta)  # (..., S, H/2)
@@ -53,6 +56,12 @@ def apply_rope(
         cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     else:  # (B, S, H/2) → (B, S, 1, H/2)
         cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    if interleave:
+        x1, x2 = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
+        rotated = jnp.stack(
+            [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
+        ).reshape(x.shape)
+        return rotated.astype(x.dtype)
     x1, x2 = x[..., : h // 2].astype(jnp.float32), x[..., h // 2 :].astype(jnp.float32)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return rotated.astype(x.dtype)

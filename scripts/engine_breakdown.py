@@ -49,6 +49,9 @@ SUMMED = (
     "starved_s", "enqueue_s", "wait_s", "h2d_s", "table_leaves",
     "prefill_tokens", "decode_steps", "context_tokens",
 )
+#: Fields only a dropless-expert engine's events carry (PR 29).
+MOE_SUMMED = ("moe_assignments", "expert_reads")
+MOE = "engine_moe_"
 #: Gaps shorter than this are launch latency between ops, not the host.
 SMALL_GAP_NS = 20_000.0
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
@@ -79,6 +82,9 @@ def by_family(dispatches: list[dict]) -> dict[str, dict]:
         row["pushes"] += bool(e["table_leaves"])
         for key in SUMMED:
             row[key] += e[key]
+        for key in MOE_SUMMED:
+            if key in e:
+                row[key] += e[key]
     return {family: dict(row) for family, row in out.items()}
 
 
@@ -262,7 +268,26 @@ def _print_families(rows: dict[str, dict]) -> None:
             + f"; {r['prefill_tokens']:.0f} prompt tokens, "
             f"{r['decode_steps']:.0f} row-steps over "
             f"{r['context_tokens']:.0f} cached tokens"
+            + (f"; {r['moe_assignments']:.0f} expert assignments over "
+               f"{r['expert_reads']:.0f} expert reads"
+               if r.get("expert_reads") else "")
         )
+
+
+def moe_by_phase(registry: dict) -> dict[str, dict]:
+    """The dropless-expert counters of a registry snapshot, by phase:
+    assignments, expert reads, layer-steps, and tokens per expert read."""
+    out: dict[str, dict] = {}
+    for key, value in registry.items():
+        m = re.fullmatch(MOE + r'(\w+)_total\{phase="(\w+)"\}', key)
+        if m:
+            out.setdefault(m.group(2), {})[m.group(1)] = value
+    for row in out.values():
+        if row.get("expert_reads"):
+            row["tokens_per_expert_read"] = (
+                row["assignments"] / row["expert_reads"]
+            )
+    return out
 
 
 def main(argv=None) -> dict:
@@ -275,6 +300,7 @@ def main(argv=None) -> dict:
     out = {
         "by_family": by_family(bundle["dispatches"]),
         "registry": starved_by_span(bundle["registry"]),
+        "moe": moe_by_phase(bundle["registry"]),
     }
     if args.xplane:
         out["capture"] = place_on_capture(bundle, load_capture(args.xplane))
@@ -294,6 +320,14 @@ def main(argv=None) -> dict:
         print("  starved by span: " + ", ".join(
             f"{k} {v:.4f}" for k, v in reg["by_span_s"].items()
         ))
+    for phase, row in out["moe"].items():
+        print(
+            f"experts, {phase}: {row.get('assignments', 0):.0f} assignments, "
+            f"{row.get('expert_reads', 0):.0f} expert reads in "
+            f"{row.get('layer_steps', 0):.0f} layer-steps"
+            + (f": {row['tokens_per_expert_read']:.2f} tokens a read"
+               if "tokens_per_expert_read" in row else "")
+        )
     cap = out.get("capture")
     if cap:
         print(

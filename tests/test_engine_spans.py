@@ -26,6 +26,7 @@ from learning_jax_sharding_tpu.models.serving import ContinuousEngine
 from learning_jax_sharding_tpu.models.transformer import (
     CONFIG_TINY,
     Transformer,
+    TransformerConfig,
 )
 from learning_jax_sharding_tpu.parallel import build_mesh
 from learning_jax_sharding_tpu.parallel.logical import RULES_TP_SERVING
@@ -571,13 +572,107 @@ def _metric_files(readers):
     return out
 
 
-@pytest.mark.parametrize("spec", _metric_files({"registry", "ledger_share"}))
-def test_metric_file_reads_what_a_served_engine_has(served, spec):
+@pytest.mark.parametrize(
+    "spec",
+    _metric_files({"registry", "ledger_share", "trace_roofline_counted"}),
+)
+def test_metric_file_reads_what_a_served_engine_has(served, served_moe, spec):
     params = spec["params"]
     if spec["reader"] == "ledger_share":
         report = served["eng"].ledger.window_report()
         assert set(params["buckets"]) <= set(report["buckets"])
     else:
-        for key in ("name", "over"):
+        for key in ("name", "over", "count", "per"):
             if key in params:
-                assert params[key] in served["end"], params[key]
+                # The expert counters exist on an engine that has experts.
+                end = (served_moe if "_moe_" in params[key] else served)["end"]
+                assert params[key] in end, params[key]
+
+
+# --- (f) dropless expert layers: counted on the device, read with the tokens ---
+
+
+def _setup_moe():
+    cfg = TransformerConfig(
+        vocab_size=257, num_layers=3, features=64, num_heads=4, hidden=128,
+        max_seq_len=128, dtype=jnp.float32, norm="rmsnorm", rope=True,
+        latent_kv_rank=16, latent_q_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+        v_head_dim=16, ff_gated=True, first_k_dense=1, num_experts=8,
+        moe_top_k=2, moe_hidden=32, moe_routing="sigmoid_dropless",
+        moe_shared_experts=1, moe_routed_scaling=2.5, moe_experts="pallas",
+    )
+    mesh = build_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    params = nn.meta.unbox(
+        jax.jit(lambda r, t: Transformer(cfg).init({"params": r}, t))(
+            jax.random.key(3), np.zeros((2, 8), np.int32)
+        )["params"]
+    )
+    eng = ContinuousEngine(
+        cfg, mesh, RULES_TP_SERVING, batch_size=2, max_new_tokens=MAX_NEW,
+        refill_chunk=8, paged_pages=12, page_size=8,
+        recorder=FlightRecorder(max_events=100_000),
+    )
+    return cfg, params, eng
+
+
+@pytest.fixture(scope="module")
+def served_moe():
+    cfg, params, eng = _setup_moe()
+    _drain(eng, params, _prompts(cfg, 1, 3))
+    eng.recorder.clear()
+    start = eng.registry.snapshot()
+    prompts = _prompts(cfg, 14, 5)
+    outs = _drain(eng, params, prompts)
+    return {
+        "eng": eng, "cfg": cfg, "prompts": prompts, "outs": outs,
+        "start": start, "end": eng.registry.snapshot(),
+        "dispatches": eng.recorder.events("engine.dispatch"),
+    }
+
+
+def _moe(served_moe, what, phase):
+    return _delta(served_moe, f'engine_moe_{what}_total{{phase="{phase}"}}')
+
+
+def test_expert_counters_count_every_routed_token(served_moe):
+    cfg, prompts, outs = (served_moe[k] for k in ("cfg", "prompts", "outs"))
+    layers = cfg.num_layers - cfg.first_k_dense
+    per_token = cfg.moe_top_k * layers
+    assert _moe(served_moe, "assignments", "refill") == per_token * sum(map(len, prompts))
+    decoded = sum(len(o) - len(p) - 1 for o, p in zip(outs, prompts))
+    assert decoded == _delta(served_moe, "engine_decode_steps_total")
+    assert _moe(served_moe, "assignments", "decode") == per_token * decoded
+    for phase in ("refill", "decode"):
+        reads = _moe(served_moe, "expert_reads", phase)
+        steps = _moe(served_moe, "layer_steps", phase)
+        # A layer-step reads between 1 expert (every token agrees) and
+        # min(experts, its assignments): never none, never an untouched one.
+        assert steps > 0 and steps <= reads <= min(
+            cfg.num_experts * steps, _moe(served_moe, "assignments", phase)
+        )
+
+
+def test_dispatch_events_carry_the_expert_counts(served_moe):
+    evs = served_moe["dispatches"]
+    assert all({"moe_assignments", "expert_reads"} <= set(e) for e in evs)
+    for phase in ("refill", "decode"):
+        mine = [e for e in evs if e["phase"] == phase]
+        assert sum(e["moe_assignments"] for e in mine) == _moe(served_moe, "assignments", phase)
+        assert sum(e["expert_reads"] for e in mine) == _moe(served_moe, "expert_reads", phase)
+        assert all(e["expert_reads"] > 0 for e in mine)
+
+
+def test_an_engine_without_experts_has_no_expert_series(served):
+    assert not [k for k in served["end"] if k.startswith("engine_moe_")]
+    assert not any("expert_reads" in e for e in served["dispatches"])
+
+
+def test_the_breakdown_tool_prints_the_expert_counts(served_moe, tmp_path, capsys):
+    served_moe["eng"].dump_diagnostics(tmp_path)
+    out = engine_breakdown.main([str(tmp_path)])
+    assert set(out["moe"]) == {"decode", "refill"}
+    reg = served_moe["end"]
+    assert out["moe"]["decode"]["expert_reads"] == reg['engine_moe_expert_reads_total{phase="decode"}']
+    assert out["moe"]["decode"]["tokens_per_expert_read"] >= 1.0
+    printed = capsys.readouterr().out
+    assert "experts, decode:" in printed and "expert assignments over" in printed

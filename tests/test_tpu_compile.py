@@ -182,6 +182,57 @@ def test_decode_attention(one_chip, case):
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
 
 
+# joyai-llm-flash.chat_backlog_2k's engine: 32 slots, 32 heads against one
+# latent row of 512 + 64 values a token (4.5 lane tiles), pages of 128 in a
+# 512-page pool, a table 1,024 wide (131,072 positions).
+@pytest.mark.parametrize("s", [1, 128], ids=["fold", "chunk128"])
+def test_latent_decode_attention(one_chip, s):
+    b, n, r, v, page, pool, t_cap = 32, 32, 576, 512, 128, 512, 1024
+
+    def fn(q, cache, index, table, new, enable):
+        fold = dict(kv_new=new, write_enable=enable) if s == 1 else {}
+        return decode_attention(
+            q, cache, index, block_table=table, row_enable=enable,
+            block_k=page, latent_v=v, scale=192**-0.5, interpret=False,
+            **fold,
+        )
+
+    shapes = [
+        ((b, s, n, r), BF16), ((pool, 1, page, r), BF16), ((b,), I32),
+        ((b, t_cap), I32), ((b, 1, 1, r), BF16), ((b,), I32),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+# The same cell's expert layer: 256 experts of 2048 x 768 (x 3 matrices);
+# a decode step's 256 assignments in tiles of 16 rows, a refill chunk's
+# 32,768 in tiles of 128.
+@pytest.mark.parametrize(
+    "tokens,tm", [(32, 16), (4096, 128)], ids=["decode32", "refill4096"]
+)
+def test_moe_experts(one_chip, tokens, tm):
+    from learning_jax_sharding_tpu.ops.moe_experts import (
+        routed_experts,
+        tile_rows,
+    )
+
+    e, d, f, k = 256, 2048, 768, 8
+    assert tile_rows(tokens * k, e) == tm
+
+    def fn(x, idx, w, valid, w_gate, w_up, w_down):
+        return routed_experts(
+            x, idx, w, w_gate, w_up, w_down, valid=valid, backend="pallas",
+            interpret=False,
+        )
+
+    shapes = [
+        ((tokens, d), BF16), ((tokens, k), I32), ((tokens, k), F32),
+        ((tokens,), jnp.bool_), ((e, d, f), BF16), ((e, d, f), BF16),
+        ((e, f, d), BF16),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
 def test_fused_residual_norm_fwd_bwd(one_chip):
     def loss(x, resid, gamma, beta):
         y, r = fused_residual_norm(x, resid, gamma, beta, interpret=False)
@@ -334,6 +385,39 @@ def step_programs(topo):
                     params, cache, ints(b), ints(b), ints(b), ints(b), rng,
                 ))
 
+        # A latent-attention, dropless-expert engine (the joyai-llm-flash
+        # family at small widths; the rank a whole lane tile, as Mosaic asks).
+        latent_cfg = TransformerConfig(
+            vocab_size=512, num_layers=2, features=128, num_heads=2,
+            hidden=256, max_seq_len=256, dtype=BF16, param_dtype=BF16,
+            norm="rmsnorm", rope=True, latent_kv_rank=128, latent_q_rank=64,
+            qk_nope_dim=32, qk_rope_dim=16, v_head_dim=32, ff_gated=True,
+            first_k_dense=1, num_experts=8, moe_top_k=2, moe_hidden=128,
+            moe_routing="sigmoid_dropless", moe_shared_experts=1,
+        )
+        latent_params = on_chip(jax.eval_shape(lambda: nn.meta.unbox(
+            Transformer(latent_cfg).init(
+                {"params": jax.random.key(0)}, np.zeros((2, 8), np.int32)
+            )["params"]
+        )))
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            eng = ContinuousEngine(
+                latent_cfg, mesh, RULES_TP_SERVING, batch_size=b,
+                max_new_tokens=8, refill_chunk=chunk, paged_pages=9,
+                page_size=64, inference_dtype=BF16,
+            )
+            first = (latent_params, None, ints(b, chunk), ints(b), ints(b), rng)
+            with activate(mesh, RULES_TP_SERVING):
+                cache = on_chip(jax.eval_shape(eng._first_refill_fn, *first)[1])
+                compiled.append(eng._refill_step_fn.lower(
+                    latent_params, None, cache, ints(b, chunk), ints(b),
+                    flags, ints(b), ints(b), rng,
+                ))
+                compiled.append(eng._decode_block_fn.lower(
+                    latent_params, cache, ints(b), ints(b), ints(b), ints(b),
+                    rng,
+                ))
+
         # The train step as benchmark/train.py builds it, its state abstract.
         train_cfg = dataclasses.replace(
             cfg, param_dtype=F32, decode_attention="dense", max_seq_len=1024,
@@ -370,19 +454,27 @@ def step_programs(topo):
             )
             compiled.append(step.jitted.lower(state, batch))
     texts = [low.compile().as_text() for low in compiled]
-    return {re.match(r"HloModule (\S+?),", t).group(1): t for t in texts}
+    # Two engines give two programs of one name: pairs, not a dict.
+    return [(re.match(r"HloModule (\S+?),", t).group(1), t) for t in texts]
 
 
 @pytest.mark.parametrize("module,op", _named_by_trace_metrics())
 def test_trace_metric_names_exist_in_the_compiled_programs(
     step_programs, module, op
 ):
-    hits = [t for name, t in step_programs.items() if name.startswith(module)]
-    assert hits, f"no program named {module}*: {sorted(step_programs)}"
+    hits = [t for name, t in step_programs if name.startswith(module)]
+    assert hits, (
+        f"no program named {module}*: {sorted(n for n, _ in step_programs)}"
+    )
     if op is not None:
-        (text,) = hits
-        names = re.findall(r"%([A-Za-z_][\w.\-]*) = ", text)
-        assert any(n.startswith(op) for n in names), (
-            f"no instruction named {op}* in {module}"
-        )
-        assert "tpu_custom_call" in text
+        # The program of whichever engine runs that op (GPT-2-shaped or
+        # latent): one of that name has to hold it, as a Mosaic kernel.
+        holding = [
+            t for t in hits
+            if any(
+                n.startswith(op)
+                for n in re.findall(r"%([A-Za-z_][\w.\-]*) = ", t)
+            )
+        ]
+        assert holding, f"no instruction named {op}* in {module}"
+        assert all("tpu_custom_call" in t for t in holding)
