@@ -20,23 +20,32 @@ per-token latency (TPOT), and inter-token gaps (ITL), with p50/p99.
 TPU-shaped design — the host drives, the device stays static:
 
 * two steady-state compiled programs serve any workload — ``refill_step``
-  (a fixed ``(B, refill_chunk)`` chunk; each row's valid length rides the
-  ragged ``chunk_lengths``, so any mix of fresh prompts, continuing long
-  prompts, and idle/decoding rows shares one executable) and
-  ``decode_block`` (K tokens per active row, scanned on device) — plus
-  the one-shot cache-creating first refill (once per ENGINE, not per
-  call);
-* admission is a pure cache-index RESET (per-row counters zero; stale K/V
-  beyond a row's new index is invisible to the causal-at-index masks and
+  (a fixed ``(B, refill_chunk)`` array of CHUNK ROWS; a row is one chunk
+  of one slot's prompt: the slot it belongs to (``rows``), how far past
+  the slot's consumed tokens it starts (``offsets``) and its valid length
+  (the ragged ``chunk_lengths``), so any mix of fresh prompts, several
+  consecutive chunks of one long prompt, and unused rows shares one
+  executable) and ``decode_block`` (K tokens per active row, scanned on
+  device) — plus the one-shot cache-creating first refill (once per
+  ENGINE, not per call);
+* admission is a pure cache-index RESET (per-slot counters zero; stale K/V
+  beyond a slot's new index is invisible to the causal-at-index masks and
   overwritten as the new request advances) — no cache clearing, no
   reallocation;
-* prompts longer than ``refill_chunk`` stream through several refill
-  calls (the row stays inactive between them; its slot advances by each
-  chunk's valid count while every other row advances by 0);
-* decoding rows keep their state while other slots refill (they ride the
-  refill chunk with length 0 and resume on the next decode block) — the
-  batch never DRAINS to admit work, though rows pause for the refill
-  dispatches themselves;
+* a refill dispatch PACKS CHUNKS, NOT SLOTS: every slot with prompt left
+  takes its next chunk in its own row; on a paged engine the rows nobody
+  refills then carry FURTHER consecutive chunks of those prompts, fewest
+  chunks left first. Rows of one slot share its block table: every layer
+  scatters the chunks' K,V into the page pool and then attends through
+  the table, each row causal at its own index, so the second row reads in
+  each layer what the first wrote there — the mathematics of two
+  dispatches in one. A contiguous ``(B, L, ...)`` cache owns its rows and
+  keeps one row a slot (``rows = arange(B)``, ``offsets = 0``), so there a
+  prompt longer than ``refill_chunk`` streams through several dispatches;
+* decoding slots keep their state while others refill (their counters
+  are not touched; their rows ride with length 0 or carry another slot's
+  chunk) and resume on the next decode block — the batch never DRAINS to
+  admit work, though decoding pauses for the refill dispatches themselves;
 * rows freeze IN-SCAN at their generation budget (a per-row ``remaining``
   counter carried through the decode block), so a retired row's
   ``cache_index`` can never advance past ``prompt + max_new_tokens`` —
@@ -184,6 +193,13 @@ class RequestFailure:
     tokens: np.ndarray | None = None
 
 
+#: The per-slot decode counters among the cache leaves: admission sets
+#: them (``_reset_rows``), and with a paged engine's ``block_table`` they
+#: are what a refill dispatch's chunk rows take from their slots
+#: (``_take_rows``).
+_SLOT_COUNTER_KEYS = ("cache_index", "position")
+
+
 def _reset_rows(
     cache: Any, mask: jax.Array, values: jax.Array | None = None
 ) -> Any:
@@ -196,7 +212,7 @@ def _reset_rows(
     speculative rollback relies on, ``models/speculative.py::_rollback``)."""
 
     def leaf(path, x):
-        if getattr(path[-1], "key", None) in ("cache_index", "position"):
+        if getattr(path[-1], "key", None) in _SLOT_COUNTER_KEYS:
             v = (
                 jnp.zeros_like(x)
                 if values is None
@@ -206,6 +222,43 @@ def _reset_rows(
         return x
 
     return jax.tree_util.tree_map_with_path(leaf, cache)
+
+
+def _take_rows(cache: Any, rows: jax.Array, offsets: jax.Array) -> Any:
+    """The cache as a refill dispatch's CHUNK ROWS see it: row ``r`` carries
+    a chunk of slot ``rows[r]`` that starts ``offsets[r]`` tokens past what
+    the slot has consumed, so it takes that slot's ``block_table`` and its
+    counters moved on by the offset. Page pools (and ``moe_stats``) are
+    shared by all rows and pass through: every layer writes its chunk into
+    the pool before it attends through the table, so a row reads in each
+    layer what an earlier row of the same slot wrote in that layer."""
+
+    def leaf(path, x):
+        key = getattr(path[-1], "key", None)
+        if key == "block_table":
+            return x[rows]
+        if key in _SLOT_COUNTER_KEYS:
+            return x[rows] + offsets.astype(x.dtype)
+        return x
+
+    return jax.tree_util.tree_map_with_path(leaf, cache)
+
+
+def _put_rows(cache: Any, row_cache: Any, rows: jax.Array) -> Any:
+    """Fold the chunk rows' cache back into per-SLOT state: a slot's
+    counters become the furthest any of its rows reached (a row with no
+    tokens reaches where its slot already was), the tables go back as they
+    came, everything else (pools, ``moe_stats``) is the call's."""
+
+    def leaf(path, old, new):
+        key = getattr(path[-1], "key", None)
+        if key == "block_table":
+            return old
+        if key in _SLOT_COUNTER_KEYS:
+            return old.at[rows].max(new)
+        return new
+
+    return jax.tree_util.tree_map_with_path(leaf, cache, row_cache)
 
 
 @dataclasses.dataclass
@@ -269,8 +322,9 @@ class ContinuousEngine:
     or after ``max_new_tokens``.
 
     ``batch_size`` fixes the device batch (cache slots); ``refill_chunk``
-    fixes the admission chunk length (longer prompts stream through
-    several refill calls); ``decode_block_steps`` fixes how many decode
+    fixes the admission chunk length (a longer prompt takes several chunk
+    rows: of one refill call where a paged engine has rows to spare, of
+    several calls otherwise); ``decode_block_steps`` fixes how many decode
     rounds each dispatch scans on device (the host loop pays one
     round-trip per block; rows freeze in-scan at EOS or at their budget,
     so a retired row's cache index never advances past
@@ -896,20 +950,25 @@ class ContinuousEngine:
         @with_moe(2)
         def refill_step(
             params, d_params, cache, chunk, lengths, reset_mask, reset_to,
-            rid, rng,
+            rid, rng, rows, offsets,
         ):
-            # Admission: set the admitted rows' counters (0, or the shared-
-            # prefix length under prefix caching), then run the chunk — every
-            # row's cache advance is its own valid length (0 for rows that
-            # are decoding or idle this call). The cache-None first call
-            # routes to first_refill instead.
-            if speculative:
-                cache = tuple(
-                    _reset_rows(c, reset_mask, reset_to) for c in cache
-                )
-            else:
-                cache = _reset_rows(cache, reset_mask, reset_to)
-            return _refill(params, d_params, cache, chunk, lengths, rid, rng)
+            # Admission: set the admitted SLOTS' counters (0, or the shared-
+            # prefix length under prefix caching), then run the chunk ROWS:
+            # row r is a chunk of slot rows[r], offsets[r] tokens past what
+            # that slot has consumed (_take_rows), so a long prompt may take
+            # several rows of one call; a row's cache advance is its own
+            # valid length (0 for a row nobody uses). A slot's counters come
+            # back as the furthest its rows reached. A contiguous cache owns
+            # its rows: its engine passes rows = arange(B), offsets = 0. The
+            # cache-None first call routes to first_refill instead.
+            # (The speculative pair's (target, draft) caches are one tree
+            # to the three helpers: both take the same rows.)
+            cache = _reset_rows(cache, reset_mask, reset_to)
+            tok, out = _refill(
+                params, d_params, _take_rows(cache, rows, offsets), chunk,
+                lengths, rid[rows], rng,
+            )
+            return tok, _put_rows(cache, out, rows)
 
         # Cache creation needs an apply without a cache; same program shape as
         # refill_step minus the reset (Flax creates the zeroed caches —
@@ -2008,6 +2067,14 @@ class ContinuousEngine:
         self._c_prefill_tok = r.counter(
             "engine_prefill_tokens_total",
             "prompt tokens consumed by refill dispatches")
+        self._c_refill_slots = r.counter(
+            "engine_refill_token_slots_total",
+            "token slots refill dispatches ran (batch x refill_chunk a "
+            "dispatch, whoever refills): engine_prefill_tokens_total over "
+            "this is how full the split engine's refill dispatches were")
+        self._c_chunk_rows = r.counter(
+            "engine_refill_chunk_rows_total",
+            "rows of refill dispatches that carried prompt tokens")
         self._c_decode_steps = r.counter(
             "engine_decode_steps_total",
             "decode row-steps advanced (tokens emitted after the first)")
@@ -2163,6 +2230,7 @@ class ContinuousEngine:
         "enqueue_s": "_c_enqueue_s", "wait_s": "_c_wait_s",
         "h2d_s": "_c_h2d_s", "table_leaves": "_c_table_leaves",
         "prefill_tokens": "_c_prefill_tok",
+        "token_slots": "_c_refill_slots", "chunk_rows": "_c_chunk_rows",
         "decode_steps": "_c_decode_steps",
         "context_tokens": "_c_decode_ctx",
     }
@@ -3976,26 +4044,64 @@ class ContinuousEngine:
                                     )
             self._g_queue.set(len(self._queue))
 
+    def _spare_chunk_rows(self, firsts):
+        """The chunk rows a refill dispatch has LEFT once every refilling
+        slot holds its next chunk (``firsts``: slot -> tokens of that
+        chunk): further consecutive chunks ``(slot, offset, n)`` of slots
+        with prompt left, fewest chunks left first, so the dispatch
+        completes as many prompts as it can. Only a paged cache can lend
+        a slot several rows (rows of one table share its pages; a
+        contiguous cache owns its rows), and only a chunk wider than one
+        token writes its K,V before it attends (a one-token step folds
+        the write into the kernel). Pages for every position the rows
+        write are claimed here; under page backpressure a slot keeps the
+        whole chunks its pages cover."""
+        b, c = self._b, self._refill_chunk
+        spare = b - len(firsts)
+        extra = []
+        if not (self._paged and c > 1 and spare):
+            return extra
+        left = {
+            slot: -(-(self._pending[slot].size - n) // c)
+            for slot, n in firsts.items() if self._pending[slot].size > n
+        }
+        for slot in sorted(left, key=lambda s: (left[s], s)):
+            if not spare:
+                break
+            k = min(left[slot], spare)
+            size = self._pending[slot].size
+            consumed = self._plen[slot] - size
+            try:
+                self._ensure(slot, consumed + min(size, (k + 1) * c))
+            except RuntimeError:
+                covered = len(self._held[slot]) * self._page_size - consumed
+                k = min(k, covered // c - 1)
+            extra += [
+                (slot, j * c, min(c, size - j * c)) for j in range(1, k + 1)
+            ]
+            spare -= k
+        return extra
+
     @_dispatch_span("refill")
     def _refill_dispatch(self, params, d_params, retired):
-        # One refill chunk for every slot with pending prompt tokens
-        # (fresh or continuing); decoding rows ride along with length 0.
-        # With ``decode_chain > 1`` up to that many CHUNKS are dispatched
-        # back-to-back with a single host sync at the end — chunk
-        # contents are host-known (the pending prompt), so nothing in a
-        # later chunk depends on an earlier chunk's readback; a long
-        # prompt pays one round trip per CHAIN instead of per chunk.
+        # One dispatch carries up to B CHUNK ROWS (slot, offset, n): the
+        # next chunk of every slot with pending prompt tokens (fresh or
+        # continuing) in the slot's own row, then, on a paged engine,
+        # further chunks of the same prompts in the rows nobody refills
+        # (_spare_chunk_rows); rows left over ride with length 0, as
+        # decoding slots do. With ``decode_chain > 1`` up to that many
+        # dispatches go back-to-back with a single host sync at the end —
+        # chunk contents are host-known (the pending prompt), so nothing
+        # in a later dispatch depends on an earlier one's readback; a long
+        # prompt pays one round trip per CHAIN instead of per dispatch.
         b = self._b
-        segs = []            # (lengths, tok_new_device) per chained chunk
+        segs = []            # (tok_new_device, completes, ...) per dispatch
         for _ in range(self.decode_chain):
-            lengths = np.zeros((b,), np.int32)
-            chunk = np.zeros((b, self._refill_chunk), np.int32)
-            for slot in range(b):
-                n = min(self._pending[slot].size, self._refill_chunk)
-                if n:
-                    chunk[slot, :n] = self._pending[slot][:n]
-                    lengths[slot] = n
-            if not lengths.any():
+            firsts = {
+                slot: min(self._pending[slot].size, self._refill_chunk)
+                for slot in range(b) if self._pending[slot].size
+            }
+            if not firsts:
                 break
             with self.ledger.measure("recovery", span="engine.recovery"):
                 # An armed chaos seam spends its injected delay (hang,
@@ -4004,33 +4110,29 @@ class ContinuousEngine:
                     "engine.dispatch", phase="refill",
                     rids=[r for r in self._req if r >= 0],
                 )
+            extra = []
             if self._paged:
-                for slot in range(b):
-                    if lengths[slot]:
-                        consumed = (
-                            self._plen[slot] - self._pending[slot].size
-                        )
-                        try:
-                            self._ensure(
-                                slot, consumed + int(lengths[slot])
-                            )
-                        except RuntimeError:
-                            # Backpressure instead of a wedge: if any
-                            # OTHER slot is mid-flight, its retirement
-                            # will free pages — requeue this request and
-                            # serve the rest. Raise only when this
-                            # request is alone (it can never fit).
-                            if not any(
-                                self._req[s] >= 0
-                                for s in range(b) if s != slot
-                            ):
-                                raise
-                            self._unadmit(slot)
-                            self._c_preempt.inc()
-                            lengths[slot] = 0
-                            chunk[slot, :] = 0
-                if not lengths.any():
+                for slot, n in list(firsts.items()):
+                    consumed = self._plen[slot] - self._pending[slot].size
+                    try:
+                        self._ensure(slot, consumed + n)
+                    except RuntimeError:
+                        # Backpressure instead of a wedge: if any
+                        # OTHER slot is mid-flight, its retirement
+                        # will free pages — requeue this request and
+                        # serve the rest. Raise only when this
+                        # request is alone (it can never fit).
+                        if not any(
+                            self._req[s] >= 0
+                            for s in range(b) if s != slot
+                        ):
+                            raise
+                        self._unadmit(slot)
+                        self._c_preempt.inc()
+                        del firsts[slot]
+                if not firsts:
                     break
+                extra = self._spare_chunk_rows(firsts)
                 if self._cache is None:
                     # Create faithful zero caches with a NO-OP refill
                     # (every length 0 — no writes, no advances), so the
@@ -4038,7 +4140,7 @@ class ContinuousEngine:
                     # with the block tables already installed.
                     first_args = (
                         params, d_params,
-                        jnp.zeros_like(jnp.asarray(chunk)),
+                        jnp.zeros((b, self._refill_chunk), jnp.int32),
                         jnp.zeros((b,), jnp.int32), self._rid_arr(),
                         self.rng,
                     )
@@ -4051,6 +4153,24 @@ class ContinuousEngine:
                     )
                     self._last_first_refill_args = lambda: first_args
                 self._cache = self._set_tables(self._cache)
+            # Row r of the dispatch: slot rows[r]'s chunk at offsets[r]
+            # past what the slot has consumed. A slot's next chunk sits in
+            # its own row; the further ones fill the rows without one.
+            rows = np.arange(b, dtype=np.int32)
+            offsets = np.zeros((b,), np.int32)
+            lengths = np.zeros((b,), np.int32)
+            chunk = np.zeros((b, self._refill_chunk), np.int32)
+            last_row = rows.copy()      # slot -> the row of its last chunk
+            took = dict.fromkeys(firsts, 0)     # slot -> tokens it sends
+            free = (r for r in range(b) if r not in firsts)
+            for slot, off, n in (
+                *((slot, 0, n) for slot, n in firsts.items()), *extra
+            ):
+                r = next(free) if off else slot
+                rows[r], offsets[r], lengths[r] = slot, off, n
+                chunk[r, :n] = self._pending[slot][off:off + n]
+                last_row[slot] = r
+                took[slot] += n
             if self._cache is None:
                 with self._led_h2d():
                     first_args = (
@@ -4082,40 +4202,41 @@ class ContinuousEngine:
                     reset_d = jnp.asarray(self._needs_reset.copy())
                     reset_to_d = jnp.asarray(self._reset_to.copy())
                     rid_d = self._rid_arr()
+                    rows_d = jnp.asarray(rows)
+                    offsets_d = jnp.asarray(offsets)
                 with self._led_device(self._refill_step_fn):
                     tok_new, self._cache, *moe = self._refill_step_fn(
                         params, d_params, self._cache, chunk_d, lengths_d,
-                        reset_d, reset_to_d, rid_d, self.rng,
+                        reset_d, reset_to_d, rid_d, self.rng, rows_d,
+                        offsets_d,
                     )
                 seg_fam = "refill_step"
                 self._last_refill_args = lambda: (
                     params, d_params, self._cache, chunk_d, lengths_d,
-                    reset_d, reset_to_d, rid_d, self.rng,
+                    reset_d, reset_to_d, rid_d, self.rng, rows_d, offsets_d,
                 )
             # The dispatch has its own copy of the admission resets, so
-            # consume the flags (every flagged row had pending tokens and
-            # therefore rode this chunk).
+            # consume the flags (every flagged slot had pending tokens and
+            # therefore rode this dispatch).
             self._needs_reset[:] = False
             self._reset_to[:] = 0
-            # Advance the host-side pending views NOW (later chunks in
+            # Advance the host-side pending views NOW (later dispatches in
             # the chain read them); completions are processed after the
             # single sync, per segment, in order.
             seg_completes = []
-            for slot in range(b):
-                if lengths[slot]:
-                    self._pending[slot] = (
-                        self._pending[slot][lengths[slot]:]
-                    )
-                    if (
-                        self._pending[slot].size == 0
-                        and self._req[slot] >= 0
-                    ):
-                        seg_completes.append(slot)
-            segs.append((tok_new, seg_completes, seg_fam, moe))
-            self._c_prefill_tok.inc(int(lengths.sum()))
+            for slot, n in took.items():
+                self._pending[slot] = self._pending[slot][n:]
+                if self._pending[slot].size == 0 and self._req[slot] >= 0:
+                    seg_completes.append(slot)
+            segs.append((tok_new, seg_completes, last_row, seg_fam, moe))
+            self._c_prefill_tok.inc(sum(took.values()))
+            self._c_refill_slots.inc(chunk.size)
+            self._c_chunk_rows.inc(len(firsts) + len(extra))
         if not segs:
             return False
-        for i, (tok_new, seg_completes, seg_fam, moe) in enumerate(segs):
+        for i, (tok_new, seg_completes, last_row, seg_fam, moe) in enumerate(
+            segs
+        ):
             with self._led_device(
                 family=seg_fam, in_flight=len(segs) - 1 - i
             ):
@@ -4126,7 +4247,10 @@ class ContinuousEngine:
             if moe:
                 self._book_moe("refill", moe[0])
             with self._led_consume():
-                self._first_tokens(seg_completes, tok_new, now, retired)
+                # A slot's first token: the pick of its LAST chunk's row.
+                self._first_tokens(
+                    seg_completes, tok_new[last_row], now, retired
+                )
         return True
 
     def _first_tokens(self, slots, tok_new, now, retired):

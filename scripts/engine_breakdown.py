@@ -51,6 +51,9 @@ SUMMED = (
 )
 #: Fields only a dropless-expert engine's events carry (PR 29).
 MOE_SUMMED = ("moe_assignments", "expert_reads")
+#: Fields of a refill dispatch since PR 30: the token slots the program ran
+#: (batch x refill_chunk a dispatch) and the rows that carried a chunk.
+REFILL_SUMMED = ("token_slots", "chunk_rows")
 MOE = "engine_moe_"
 #: Gaps shorter than this are launch latency between ops, not the host.
 SMALL_GAP_NS = 20_000.0
@@ -82,7 +85,7 @@ def by_family(dispatches: list[dict]) -> dict[str, dict]:
         row["pushes"] += bool(e["table_leaves"])
         for key in SUMMED:
             row[key] += e[key]
-        for key in MOE_SUMMED:
+        for key in MOE_SUMMED + REFILL_SUMMED:
             if key in e:
                 row[key] += e[key]
     return {family: dict(row) for family, row in out.items()}
@@ -265,8 +268,12 @@ def _print_families(rows: dict[str, dict]) -> None:
             f"{r['pushes']:.0f} table pushes"
             + (f" of {r['table_leaves'] / r['pushes']:.0f} leaves"
                if r["pushes"] else "")
-            + f"; {r['prefill_tokens']:.0f} prompt tokens, "
-            f"{r['decode_steps']:.0f} row-steps over "
+            + f"; {r['prefill_tokens']:.0f} prompt tokens"
+            + (f" in {r['chunk_rows'] / n:.1f} chunk rows a dispatch, fill "
+               f"{100 * r['prefill_tokens'] / r['token_slots']:.1f} % of "
+               f"{r['token_slots'] / n:.0f} token slots"
+               if r.get("token_slots") else "")
+            + f", {r['decode_steps']:.0f} row-steps over "
             f"{r['context_tokens']:.0f} cached tokens"
             + (f"; {r['moe_assignments']:.0f} expert assignments over "
                f"{r['expert_reads']:.0f} expert reads"

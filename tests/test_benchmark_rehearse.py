@@ -41,8 +41,12 @@ def _rehearse(cell: str, trace: int) -> dict:
     "cell,trace,reports",
     [
         ("joyai-llm-flash.chat_backlog_2k", 0, {"serve_tok_s", "tpot_p95_ms", "setup_s"}),
-        ("joyai-llm-flash.chat_backlog_2k", 1, {"moe_tokens_per_expert_read", "refill_host_share_pct"}),
+        (
+            "joyai-llm-flash.chat_backlog_2k", 1,
+            {"moe_tokens_per_expert_read", "refill_host_share_pct", "refill_fill_pct"},
+        ),
         ("gpt2-xl.chat_backlog", 0, {"serve_tok_s", "tpot_p95_ms", "setup_s"}),
+        ("gpt2-xl.chat_backlog", 1, {"refill_host_share_pct", "refill_fill_pct"}),
     ],
 )
 def test_a_serving_cell_rehearses_to_a_correct_line(cell, trace, reports):
@@ -52,3 +56,6 @@ def test_a_serving_cell_rehearses_to_a_correct_line(cell, trace, reports):
     assert reports <= set(line["metrics"])
     # A rehearsal never reports a device-trace metric: it has no device.
     assert not {"mla_decode_attn_roofline", "moe_expert_roofline", "decode_attn_roofline"} & set(line["metrics"])
+    if "refill_fill_pct" in reports:
+        # Prompt tokens over token slots: a share, and the dispatches carry prompts.
+        assert 0.0 < line["metrics"]["refill_fill_pct"]["value"] <= 100.0

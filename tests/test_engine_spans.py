@@ -345,12 +345,13 @@ def test_starved_enqueue_and_wait_fit_inside_the_steps(served):
 
 
 def test_the_clock_stops_while_a_chained_dispatch_is_in_flight():
-    """With ``decode_chain=2`` a long prompt's two refill chunks are
-    dispatched back to back: after the first readback one program is
-    still in flight, so the clock must not start until the second."""
+    """With ``decode_chain=2`` a long prompt's two refill dispatches (its
+    four chunks, two rows each) go back to back: after the first readback
+    one program is still in flight, so the clock must not start until the
+    second."""
     cfg, params, eng = _setup(decode_chain=2)
     prompt = _prompts(cfg, 3, 1)[0][:5]
-    long_prompt = np.concatenate([prompt] * 3)       # 15 tokens: 2 chunks
+    long_prompt = np.concatenate([prompt] * 5)       # 25 tokens: 4 chunks
     _drain(eng, params, [long_prompt])               # warm
     marks = []
     real_empty, led = eng.ledger.device_empty, eng.ledger
@@ -396,7 +397,8 @@ def test_one_dispatch_event_per_dispatch(served):
         assert set(e) >= {
             "family", "phase", "step", "rows", "prefill_tokens",
             "decode_steps", "context_tokens", "starved_s", "enqueue_s",
-            "wait_s", "h2d_s", "table_leaves", "compiled",
+            "wait_s", "h2d_s", "table_leaves", "compiled", "token_slots",
+            "chunk_rows",
         }
         assert e["family"] == {
             "refill": "refill_step", "decode": "decode_block"
@@ -409,6 +411,20 @@ def test_dispatch_events_account_for_every_token(served):
     evs, prompts, outs = served["dispatches"], served["prompts"], served["outs"]
     generated = [len(o) - len(p) for o, p in zip(outs, prompts)]
     assert sum(e["prefill_tokens"] for e in evs) == sum(map(len, prompts))
+    # A refill dispatch runs batch x refill_chunk token slots; a row that
+    # carries a chunk holds 1 to refill_chunk of the prompt tokens.
+    refills = [e for e in evs if e["phase"] == "refill"]
+    assert {e["token_slots"] for e in refills} == {2 * 8}
+    assert all(
+        e["chunk_rows"] <= e["prefill_tokens"] <= 8 * e["chunk_rows"]
+        and 1 <= e["chunk_rows"] <= 2 for e in refills
+    )
+    assert not any(e["token_slots"] or e["chunk_rows"] for e in evs if e not in refills)
+    for field, counter in (
+        ("token_slots", "engine_refill_token_slots_total"),
+        ("chunk_rows", "engine_refill_chunk_rows_total"),
+    ):
+        assert sum(e[field] for e in evs) == _delta(served, counter)
     first_tokens = len(prompts)
     assert sum(e["decode_steps"] for e in evs) + first_tokens == sum(generated)
     # The j-th generated token (j >= 2) comes out of a step that reads a
@@ -514,15 +530,23 @@ def test_the_breakdown_tool_places_a_bundle_on_a_capture(tmp_path, capsys):
         assert sum(r[field] for r in rows.values()) == pytest.approx(
             sum(e[field] for e in steady), rel=1e-9
         )
-    assert sum(e["prefill_tokens"] for e in events) == (
-        snap["engine_prefill_tokens_total"]
-    )
+    for field, counter in (
+        ("prefill_tokens", "engine_prefill_tokens_total"),
+        ("token_slots", "engine_refill_token_slots_total"),
+        ("chunk_rows", "engine_refill_chunk_rows_total"),
+    ):
+        assert sum(e[field] for e in events) == snap[counter]
+    refill = rows["refill_step"]
+    assert refill["token_slots"] == 2 * 8 * refill["dispatches"]
+    assert refill["dispatches"] <= refill["chunk_rows"] <= 2 * refill["dispatches"]
     reg = out["registry"]
     assert set(reg["by_span_s"]) >= {"h2d", "enqueue", "consume", "sched"}
     assert abs(reg["labelled_minus_plain_s"]) < 1e-9
     assert reg["wait_share_pct"] + reg["starved_share_pct"] < 100.0
     engine_breakdown.main([str(bundle)])              # the printed form
-    assert "starved by span: " in capsys.readouterr().out
+    printed = capsys.readouterr().out
+    assert "starved by span: " in printed
+    assert "chunk rows a dispatch, fill " in printed
 
 
 def test_the_breakdown_tool_names_an_idle_gap_by_its_engine_span():

@@ -379,7 +379,7 @@ def step_programs(topo):
                 compiled.append(eng._first_refill_fn.lower(*first))
                 compiled.append(eng._refill_step_fn.lower(
                     params, None, cache, ints(b, chunk), ints(b), flags,
-                    ints(b), ints(b), rng,
+                    ints(b), ints(b), rng, ints(b), ints(b),
                 ))
                 compiled.append(eng._decode_block_fn.lower(
                     params, cache, ints(b), ints(b), ints(b), ints(b), rng,
@@ -411,7 +411,7 @@ def step_programs(topo):
                 cache = on_chip(jax.eval_shape(eng._first_refill_fn, *first)[1])
                 compiled.append(eng._refill_step_fn.lower(
                     latent_params, None, cache, ints(b, chunk), ints(b),
-                    flags, ints(b), ints(b), rng,
+                    flags, ints(b), ints(b), rng, ints(b), ints(b),
                 ))
                 compiled.append(eng._decode_block_fn.lower(
                     latent_params, cache, ints(b), ints(b), ints(b), ints(b),
