@@ -832,8 +832,8 @@ def build_search_inputs(name: str, mesh: Any = None) -> dict:
         for n in (20, 5)
     ]
     eng.serve(params, prompts)
-    progs = {n: (f, a) for n, f, a in eng._dispatched_programs()}
-    fn, args = progs[name]
+    prog = eng.program(name)
+    fn, args = prog.fn, prog.last_args()
     hint = (
         int(eng.horizon) if name == "multi_step" else int(eng._block_steps)
     )

@@ -102,17 +102,12 @@ from learning_jax_sharding_tpu.models.decoding import (
     make_cached_apply,
     make_param_caster,
 )
-from learning_jax_sharding_tpu.models.attention import (
-    resolve_decode_backend,
-    row_update_masked,
-)
-from learning_jax_sharding_tpu.models.generate import filtered_logits
-from learning_jax_sharding_tpu.models.speculative import (
-    _greedy as greedy_pick,
-    _pos_key,
-    _rollback,
-    emit_vector,
-    greedy_accept_emit,
+from learning_jax_sharding_tpu.models.attention import resolve_decode_backend
+from learning_jax_sharding_tpu.models.engine_programs import (
+    _PAGE_LEAF_KEYS,
+    Program,
+    build_drift_probe,
+    build_programs,
 )
 from learning_jax_sharding_tpu.models.transformer import (
     Transformer,
@@ -139,13 +134,6 @@ from learning_jax_sharding_tpu.telemetry.registry import labeled_name
 #: infrastructure errors (OOM, XLA internal) still propagate — recovery
 #: must never guess.
 _RECOVERABLE_DISPATCH = (InjectedFault, FloatingPointError)
-
-#: Cache leaves with a leading PHYSICAL-PAGE dim on paged engines — the
-#: leaves ``kv_page_spill``/``kv_page_fill`` move one page of. Per-slot
-#: counters (cache_index, position, block_table) stay: a retained prefix
-#: page carries K/V only; the mapping is host state.
-_PAGE_LEAF_KEYS = ("cached_kv", "key_scale", "value_scale")
-
 
 def _dispatch_span(kind):
     """Wrap a dispatch method in the tracer span ``engine.<kind>``. The
@@ -191,74 +179,6 @@ class RequestFailure:
     status: str              # deadline|poisoned|malformed|shutdown|rerouted
     error: str | None = None
     tokens: np.ndarray | None = None
-
-
-#: The per-slot decode counters among the cache leaves: admission sets
-#: them (``_reset_rows``), and with a paged engine's ``block_table`` they
-#: are what a refill dispatch's chunk rows take from their slots
-#: (``_take_rows``).
-_SLOT_COUNTER_KEYS = ("cache_index", "position")
-
-
-def _reset_rows(
-    cache: Any, mask: jax.Array, values: jax.Array | None = None
-) -> Any:
-    """Set the per-row decode counters (``cache_index`` and ``position``)
-    where ``mask`` is True — request admission. ``values`` (``(B,)``,
-    default zeros) is the admission index: 0 for a fresh prompt, or the
-    shared-prefix length when prefix caching hands the row pre-filled
-    pages. Stale K/V past a reset row's index is masked by causal-at-index
-    attention and overwritten as the new request writes (same invariant
-    speculative rollback relies on, ``models/speculative.py::_rollback``)."""
-
-    def leaf(path, x):
-        if getattr(path[-1], "key", None) in _SLOT_COUNTER_KEYS:
-            v = (
-                jnp.zeros_like(x)
-                if values is None
-                else jnp.broadcast_to(values.astype(x.dtype), x.shape)
-            )
-            return jnp.where(mask, v, x)
-        return x
-
-    return jax.tree_util.tree_map_with_path(leaf, cache)
-
-
-def _take_rows(cache: Any, rows: jax.Array, offsets: jax.Array) -> Any:
-    """The cache as a refill dispatch's CHUNK ROWS see it: row ``r`` carries
-    a chunk of slot ``rows[r]`` that starts ``offsets[r]`` tokens past what
-    the slot has consumed, so it takes that slot's ``block_table`` and its
-    counters moved on by the offset. Page pools (and ``moe_stats``) are
-    shared by all rows and pass through: every layer writes its chunk into
-    the pool before it attends through the table, so a row reads in each
-    layer what an earlier row of the same slot wrote in that layer."""
-
-    def leaf(path, x):
-        key = getattr(path[-1], "key", None)
-        if key == "block_table":
-            return x[rows]
-        if key in _SLOT_COUNTER_KEYS:
-            return x[rows] + offsets.astype(x.dtype)
-        return x
-
-    return jax.tree_util.tree_map_with_path(leaf, cache)
-
-
-def _put_rows(cache: Any, row_cache: Any, rows: jax.Array) -> Any:
-    """Fold the chunk rows' cache back into per-SLOT state: a slot's
-    counters become the furthest any of its rows reached (a row with no
-    tokens reaches where its slot already was), the tables go back as they
-    came, everything else (pools, ``moe_stats``) is the call's."""
-
-    def leaf(path, old, new):
-        key = getattr(path[-1], "key", None)
-        if key == "block_table":
-            return old
-        if key in _SLOT_COUNTER_KEYS:
-            return old.at[rows].max(new)
-        return new
-
-    return jax.tree_util.tree_map_with_path(leaf, cache, row_cache)
 
 
 @dataclasses.dataclass
@@ -820,858 +740,14 @@ class ContinuousEngine:
             # Drift oracle: the SAME weights and cache served through a
             # plain-collective apply (``comm_compress_fn=None`` — same
             # param tree, since _CompressedDense declares the identical
-            # down/kernel). The probe runs one greedy decode step under
-            # both applies and counts active rows whose argmax diverged;
-            # the caches it produces are discarded, so probing never
-            # perturbs the served stream.
-            oracle_apply = make_cached_apply(
+            # down/kernel).
+            comp_probe = build_drift_probe(apply, make_cached_apply(
                 Transformer(
                     dataclasses.replace(cfg, comm_compress_fn=None)
                 ),
                 dequantize=bool(dequantize) and not fused,
                 dequant_dtype=cfg.param_dtype,
-            )
-
-            @jax.jit
-            def comp_probe(params, cache, tok, active):
-                lc, _ = apply(params, cache, tok[:, None], active)
-                lo, _ = oracle_apply(params, cache, tok[:, None], active)
-                agree = (
-                    jnp.argmax(lc[:, -1], axis=-1)
-                    == jnp.argmax(lo[:, -1], axis=-1)
-                )
-                live = active == 1
-                return jnp.sum(live), jnp.sum(live & ~agree)
-
-        def _greedy(logits):
-            return greedy_pick(logits, vocab_limit)
-
-        def row_keys(rng, rid, pos):
-            """(B,) keys from (request id, generated position): the stream a
-            request samples from depends only on its own identity and how far
-            it has generated — never on scheduling."""
-
-            def one(r, p):
-                return jax.random.fold_in(jax.random.fold_in(rng, r), p)
-
-            return jax.vmap(one)(rid, pos)
-
-        def spec_keys(rng, rid, pos, tag):
-            """Per-REQUEST rejection streams: ``speculative._pos_key``'s
-            position+tag derivation (THE definition of the three stream roles)
-            under a request-id fold — position-keyed, so a rolled-back
-            position re-derives its draws and a round/block boundary lands
-            nowhere in the stream (schedule independence, test-pinned)."""
-
-            def one(r, p):
-                return _pos_key(jax.random.fold_in(rng, r), p, tag)
-
-            return jax.vmap(one)(rid, pos)
-
-        def to_flogits(logits):
-            """The filtered sampling distribution in logit space — shared with
-            ``sample_rows`` via ``generate.filtered_logits`` (THE definition
-            of the filter order) so the speculative acceptance distribution
-            cannot drift from what plain sampling draws."""
-            return filtered_logits(
-                logits, temperature, top_k, top_p, min_p, vocab_limit
-            )
-
-        def sample_rows(logits, rng, rid, pos):
-            """Per-row sampling with (request, position) keys; greedy ignores
-            the keys entirely (deterministic)."""
-            if temperature == 0.0:
-                return _greedy(logits)
-            return jax.vmap(jax.random.categorical)(
-                row_keys(rng, rid, pos), to_flogits(logits)
-            ).astype(jnp.int32)
-
-        def _refill(params, d_params, cache, chunk, lengths, rid, rng):
-            # Run the chunk through the target (and the draft, whose cache
-            # must mirror the target's valid prefix for verification); the
-            # pick is each row's first generated token — position 0 of its
-            # stream.
-            last = jnp.maximum(lengths - 1, 0)
-            if speculative:
-                t_cache, d_cache = cache
-                logits, t_cache = apply(params, t_cache, chunk, lengths)
-                _, d_cache = d_apply(d_params, d_cache, chunk, lengths)
-                cache = (t_cache, d_cache)
-            elif latent:
-                # The head on each row's last valid position only: at this
-                # family's vocabulary (129,280) the chunk's full (B, S, V)
-                # logits are 2.1 GB in float32 and 2.2 TFLOP a dispatch.
-                # GPT-2-shaped configs keep the program their goldens pin.
-                logits, cache = apply(
-                    params, cache, chunk, lengths, logit_positions=last
-                )
-                last = jnp.zeros_like(last)      # logits are (B, 1, V)
-            else:
-                logits, cache = apply(params, cache, chunk, lengths)
-            pick = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
-            tok = sample_rows(pick, rng, rid, jnp.zeros_like(rid))
-            return tok, cache
-
-        def moe_seen(cache):
-            """Sum of the expert layers' cumulative ``moe_stats``: ``(3,)``
-            int32 (assignments, expert reads, layer-steps)."""
-            seen = jnp.zeros((3,), jnp.int32)
-            if cache is not None:
-                for path, x in jax.tree_util.tree_flatten_with_path(cache)[0]:
-                    if getattr(path[-1], "key", None) == "moe_stats":
-                        seen = seen + x
-            return seen
-
-        def with_moe(cache_arg):
-            """A dropless-expert config's split programs return, after
-            their usual outputs (the cache last), the growth of
-            ``moe_seen`` over the call: it comes back with the readback
-            the dispatch makes anyway. ``cache_arg``: which positional
-            argument is the cache going in (None: the call creates it).
-            Other configs' programs are untouched."""
-
-            def deco(program):
-                if not moe_counted:
-                    return program
-
-                @functools.wraps(program)
-                def counted(*args):
-                    before = moe_seen(
-                        None if cache_arg is None else args[cache_arg]
-                    )
-                    out = program(*args)
-                    return (*out, moe_seen(out[-1]) - before)
-
-                return counted
-
-            return deco
-
-        @jax.jit
-        @with_moe(2)
-        def refill_step(
-            params, d_params, cache, chunk, lengths, reset_mask, reset_to,
-            rid, rng, rows, offsets,
-        ):
-            # Admission: set the admitted SLOTS' counters (0, or the shared-
-            # prefix length under prefix caching), then run the chunk ROWS:
-            # row r is a chunk of slot rows[r], offsets[r] tokens past what
-            # that slot has consumed (_take_rows), so a long prompt may take
-            # several rows of one call; a row's cache advance is its own
-            # valid length (0 for a row nobody uses). A slot's counters come
-            # back as the furthest its rows reached. A contiguous cache owns
-            # its rows: its engine passes rows = arange(B), offsets = 0. The
-            # cache-None first call routes to first_refill instead.
-            # (The speculative pair's (target, draft) caches are one tree
-            # to the three helpers: both take the same rows.)
-            cache = _reset_rows(cache, reset_mask, reset_to)
-            tok, out = _refill(
-                params, d_params, _take_rows(cache, rows, offsets), chunk,
-                lengths, rid[rows], rng,
-            )
-            return tok, _put_rows(cache, out, rows)
-
-        # Cache creation needs an apply without a cache; same program shape as
-        # refill_step minus the reset (Flax creates the zeroed caches —
-        # make_cached_apply treats a None cache as the creating call).
-        @jax.jit
-        @with_moe(None)
-        def first_refill(params, d_params, chunk, lengths, rid, rng):
-            cache = (None, None) if speculative else None
-            return _refill(params, d_params, cache, chunk, lengths, rid, rng)
-
-        @jax.jit
-        @with_moe(1)
-        def decode_block(params, cache, tok, active, remaining, rid, rng):
-            """``decode_block_steps`` tokens per call, scanned ON DEVICE — the
-            host loop costs one dispatch/readback per BLOCK, not per token
-            (rounds 1-5, on a remotely attached chip: per-token host
-            stepping ran 30× slower than the same work scanned; not
-            measured on today's machine). Rows that emit ``eos`` OR
-            exhaust their per-row ``remaining`` budget flip inactive IN-scan —
-            chunk_lengths 0 from then on, so a retired row stops consuming
-            cache mid-block and its index can never pass its admission
-            budget."""
-
-            def body(carry, _):
-                tok, active, remaining, cache = carry
-                logits, cache = apply(params, cache, tok[:, None], active)
-                # This draw's generated position: the row has already emitted
-                # max_new_tokens - remaining tokens.
-                pos = max_new_tokens - remaining
-                nxt = sample_rows(logits[:, -1], rng, rid, pos)
-                nxt = jnp.where(active == 1, nxt, tok)
-                remaining = remaining - active
-                if eos_id is not None:
-                    active = active * (nxt != eos_id).astype(jnp.int32)
-                active = active * (remaining > 0).astype(jnp.int32)
-                return (nxt, active, remaining, cache), nxt
-
-            (tok, active, remaining, cache), toks = jax.lax.scan(
-                body, (tok, active, remaining, cache), None,
-                length=decode_block_steps,
-            )
-            return toks.T, active, remaining, cache   # (B, K) tokens
-
-        def spec_round(carry, params, d_params, rid, rng, apply_fn=apply):
-            """ONE draft-verify ROUND with PER-ROW acceptance and rollback —
-            THE shared speculative core of the engine: ``decode_block_spec``
-            scans it ``decode_block_steps`` times, ``spec_mixed_step`` runs
-            it once after its fused refill sub-step, so the acceptance /
-            emission / rollback rules cannot drift between the two program
-            families. Frozen rows (``active == 0`` — idle, refilling, or
-            retired) ride every sub-call with length 0 and ``n_emit`` 0, so
-            the round's rollback broadcast re-asserts their current ``pos``
-            without moving it.
-
-            ``apply_fn`` is the VERIFIER's apply (default: the target
-            model's). The multi-LoRA engine passes its per-row
-            adapter-gathered apply here — the draft always proposes with
-            the BASE weights (a proposal distribution never defines the
-            output; the verifier does), so one shared draft serves every
-            tenant in the batch."""
-            idx = jnp.arange(num_draft + 1)
-            (tok, active, pos, remaining, count, buffer, acc, prop,
-             t_cache, d_cache) = carry
-            # Each row's next GENERATED position (the refill's pick was
-            # position 0 of its stream).
-            gen = max_new_tokens - remaining
-
-            # 1. Draft proposes per row (frozen rows ride with length 0).
-            if temperature == 0.0:
-
-                def draft_step(c, j):
-                    prev, dc = c
-                    lg, dc = d_apply(d_params, dc, prev[:, None], active)
-                    nxt = jnp.where(active == 1, _greedy(lg[:, -1]), prev)
-                    return (nxt, dc), nxt
-
-                (last_d, d_cache), drafts = jax.lax.scan(
-                    draft_step, (tok, d_cache), jnp.arange(num_draft)
-                )
-                q_all = None
-            else:
-
-                def draft_step(c, j):
-                    prev, dc = c
-                    lg, dc = d_apply(d_params, dc, prev[:, None], active)
-                    fl = to_flogits(lg[:, -1])
-                    nxt = jax.vmap(jax.random.categorical)(
-                        spec_keys(rng, rid, gen + j, 0), fl
-                    ).astype(jnp.int32)
-                    nxt = jnp.where(active == 1, nxt, prev)
-                    return (nxt, dc), (nxt, jax.nn.softmax(fl, axis=-1))
-
-                (last_d, d_cache), (drafts, q_all) = jax.lax.scan(
-                    draft_step, (tok, d_cache), jnp.arange(num_draft)
-                )
-            drafts = drafts.T
-            _, d_cache = d_apply(
-                d_params, d_cache, last_d[:, None], active
-            )
-
-            # 2. One chunked target verify.
-            chunk = jnp.concatenate([tok[:, None], drafts], axis=1)
-            t_logits, t_cache = apply_fn(
-                params, t_cache, chunk, active * (num_draft + 1)
-            )
-
-            # 3. Per-row acceptance; emitted = accepted drafts + the
-            #    bonus/correction (greedy) or residual sample (sampling) —
-            #    the shared cores, models/speculative.py.
-            if temperature == 0.0:
-                m, emitted, _ = greedy_accept_emit(
-                    drafts, _greedy(t_logits)
-                )
-            else:
-                q_all = jnp.moveaxis(q_all, 0, 1)    # (B, num_draft, V)
-                p_all = jax.nn.softmax(to_flogits(t_logits), axis=-1)
-                p_at = jnp.take_along_axis(
-                    p_all[:, :num_draft], drafts[..., None], axis=-1
-                )[..., 0]
-                q_at = jnp.take_along_axis(
-                    q_all, drafts[..., None], axis=-1
-                )[..., 0]
-                u = jax.vmap(
-                    lambda j: jax.vmap(jax.random.uniform)(
-                        spec_keys(rng, rid, gen + j, 1)
-                    ),
-                    out_axes=1,
-                )(jnp.arange(num_draft))             # (B, num_draft)
-                accept = u * q_at < p_at
-                m = jnp.sum(
-                    jnp.cumprod(accept.astype(jnp.int32), axis=1), axis=1
-                )
-                q_pad = jnp.concatenate(
-                    [q_all, jnp.zeros_like(q_all[:, :1])], axis=1
-                )
-
-                def take_m(x):
-                    return jnp.take_along_axis(
-                        x, m[:, None, None], axis=1
-                    )[:, 0]
-
-                p_m = take_m(p_all)
-                residual = jnp.maximum(p_m - take_m(q_pad), 0.0)
-                mass = jnp.sum(residual, axis=-1, keepdims=True)
-                residual = jnp.where(mass > 0, residual / mass, p_m)
-                token_m = jax.vmap(jax.random.categorical)(
-                    spec_keys(rng, rid, gen + m, 2), jnp.log(residual)
-                ).astype(jnp.int32)
-                emitted = emit_vector(drafts, m, token_m)
-
-            # 4. Truncate each row's emission at EOS and at its budget.
-            raw = 1 + m
-            if eos_id is not None:
-                hit = (emitted == eos_id) & (idx[None, :] < raw[:, None])
-                any_hit = jnp.any(hit, axis=1)
-                first = jnp.argmax(hit, axis=1)
-                n_stop = jnp.where(any_hit, first + 1, raw)
-            else:
-                any_hit = jnp.zeros_like(active, dtype=bool)
-                n_stop = raw
-            n_emit = jnp.minimum(n_stop, remaining) * active
-
-            # 5. Append at each row's own offset; advance the pending
-            #    token to the last emitted one.
-            buffer = row_update_masked(
-                buffer, emitted, count, n_emit, seq_dim=1
-            )
-            new_tok = jnp.take_along_axis(
-                emitted, jnp.maximum(n_emit - 1, 0)[:, None], axis=1
-            )[:, 0]
-            tok = jnp.where(active == 1, new_tok, tok)
-
-            # 6. Per-row rollback: the row's new index is pos + n_emit
-            #    (frozen rows: +0, i.e. their current index — one
-            #    broadcast serves all rows).
-            pos = pos + n_emit
-            t_cache = _rollback(t_cache, pos)
-            d_cache = _rollback(d_cache, pos)
-
-            remaining = remaining - n_emit
-            count = count + n_emit
-            # Acceptance telemetry: verifier acceptance per live round
-            # (before EOS/budget truncation — the DRAFT's quality, which
-            # is what the operator tunes num_draft against).
-            acc = acc + m * active
-            prop = prop + active * num_draft
-            stopped_eos = any_hit & (n_stop <= n_emit) & (active == 1)
-            active = (
-                active
-                * (remaining > 0).astype(jnp.int32)
-                * (1 - stopped_eos.astype(jnp.int32))
-            )
-            return (
-                tok, active, pos, remaining, count, buffer, acc, prop,
-                t_cache, d_cache
-            )
-
-        def _spec_carry_init(tok, active, pos, remaining, width):
-            b = tok.shape[0]
-            return (
-                tok, active, pos, remaining,
-                jnp.zeros((b,), jnp.int32),          # count
-                jnp.zeros((b, width), jnp.int32),    # buffer
-                jnp.zeros((b,), jnp.int32),          # acc
-                jnp.zeros((b,), jnp.int32),          # prop
-            )
-
-        @jax.jit
-        def decode_block_spec(
-            params, d_params, t_cache, d_cache, tok, active, pos, remaining,
-            rid, rng,
-        ):
-            """Speculative decode block: ``decode_block_steps`` draft-verify
-            ROUNDS (``spec_round`` — the shared core), each emitting
-            1..num_draft+1 tokens per row with PER-ROW acceptance and
-            rollback (the ragged-cache machinery of
-            ``models/speculative.py::generate_ragged``, driven inside the
-            engine's scan). ``pos`` is each row's current cache index
-            (prompt_len + emitted - 1); EOS and budget truncate a round's
-            per-row emission exactly, so the buffer/counts the block returns
-            are final — the host appends them verbatim.
-
-            ``temperature > 0``: speculative SAMPLING (Leviathan rejection) —
-            the draft proposes from the filtered distribution, acceptance is
-            ``u·q < p`` per position, the slot-m token samples the residual
-            ``norm(max(p − q, 0))`` — with every draw keyed by (request id,
-            generated position, stream tag) via ``spec_keys``, so a request's
-            sampled output is independent of batch composition, round
-            boundaries, and block boundaries (rollback re-derives draws)."""
-            width = decode_block_steps * (num_draft + 1)
-
-            def body(carry, _):
-                return spec_round(carry, params, d_params, rid, rng), None
-
-            (tok, active, pos, remaining, count, buffer, acc, prop,
-             t_cache, d_cache), _ = (
-                jax.lax.scan(
-                    body,
-                    _spec_carry_init(tok, active, pos, remaining, width)
-                    + (t_cache, d_cache),
-                    None,
-                    length=decode_block_steps,
-                )
-            )
-            # tok and pos ride the return so CHAINED dispatches can carry
-            # them device-to-device (decode_chain — no host sync between
-            # chained blocks).
-            return (
-                buffer, count, acc, prop, tok, pos, active, remaining,
-                t_cache, d_cache,
-            )
-
-        def _mixed_core(
-            apply_fn, params, cache, chunk, lengths, reset_mask, reset_to,
-            tok, active, remaining, rid, rng,
-        ):
-            # THE fused-iteration body, shared by ``mixed_step`` (plain
-            # apply) and ``adapter_mixed_step`` (per-row adapter-gathered
-            # apply) so the scheduling/sampling rules cannot drift between
-            # the single-tenant and multi-tenant program families.
-            cache = _reset_rows(cache, reset_mask, reset_to)
-            dec = active == 1   # decoding rows never hold pending tokens
-            eff_len = jnp.where(dec, 1, lengths)
-            chunk = chunk.at[:, 0].set(jnp.where(dec, tok, chunk[:, 0]))
-            logits, cache = apply_fn(params, cache, chunk, eff_len)
-            pick = jnp.take_along_axis(
-                logits, jnp.maximum(eff_len - 1, 0)[:, None, None], axis=1
-            )[:, 0]
-            # Refill rows sample their stream's position 0 (the refill
-            # pick); decode rows their current generated position — the
-            # same keys the split programs use.
-            pos = jnp.where(dec, max_new_tokens - remaining, 0)
-            nxt = sample_rows(pick, rng, rid, pos)
-            tok = jnp.where(dec, nxt, tok)
-            remaining = remaining - dec.astype(jnp.int32)
-            if eos_id is not None:
-                active = active * jnp.where(
-                    dec, (nxt != eos_id).astype(jnp.int32), 1
-                )
-            active = active * jnp.where(
-                dec, (remaining > 0).astype(jnp.int32), 1
-            )
-            return nxt, tok, active, remaining, cache
-
-        @jax.jit
-        def mixed_step(
-            params, cache, chunk, lengths, reset_mask, reset_to, tok,
-            active, remaining, rid, rng,
-        ):
-            """ONE FUSED engine iteration (``mixed=True``): every DECODING
-            row advances one token AND every scheduled REFILL row pushes its
-            budgeted prompt chunk, in a single compiled dispatch — decode
-            never waits for another slot's prefill to stream through.
-
-            Decode rows ride the ragged chunk with length 1 (their pending
-            token spliced into column 0); refill rows ride with their
-            host-scheduled ``chunk_lengths`` (admission resets applied
-            first, exactly as in ``refill_step``); idle rows ride with
-            length 0. The per-row computation is identical to what
-            ``refill_step`` / ``decode_block``'s scan body would have done
-            for that row — ragged rows are independent — so greedy token
-            streams stay bit-identical to the split-program engine
-            (test-pinned). Carries (tok/active/remaining) ride the return so
-            ``decode_chain`` links can flow device-to-device with one host
-            sync per chain."""
-            return _mixed_core(
-                apply, params, cache, chunk, lengths, reset_mask, reset_to,
-                tok, active, remaining, rid, rng,
-            )
-
-        def _merge_row(p, a):
-            # One ROW's adapter folded into the base tree — the EXACT op
-            # order of ``training.lora.merge_lora`` (scale · A@B, then
-            # astype into the kernel dtype), with the python-float
-            # ``alpha/rank`` scale replaced by the pool's per-slot scale
-            # array cast to the A@B dtype (same promotion a weak-typed
-            # scalar takes), so a pooled tenant's merged weights are
-            # BIT-IDENTICAL to ``merge_lora``'s — the multi-tenant
-            # bit-identity oracle rests on this mirror.
-            if not isinstance(p, dict):
-                return p
-            out = {}
-            for k, v in p.items():
-                sub = a.get(k) if isinstance(a, dict) else None
-                if (
-                    sub is not None and isinstance(sub, dict)
-                    and set(sub) == {"lora_a", "lora_b", "scale"}
-                ):
-                    ab = sub["lora_a"] @ sub["lora_b"]
-                    out[k] = v + (sub["scale"].astype(ab.dtype) * ab).astype(
-                        v.dtype
-                    )
-                else:
-                    out[k] = _merge_row(v, sub if sub is not None else {})
-            return out
-
-        def _adapter_apply(sel):
-            # Per-row adapter-gathered apply: ``sel`` is the pool tree
-            # already GATHERED at each row's adapter slot (leaves
-            # (B, ...) — the gather runs once, outside the vmap). Each
-            # row folds its own adapter into the base and runs the model
-            # at batch 1; vmap stacks the rows back into one fused
-            # program, so heterogeneous tenants share a single dispatch.
-            def apply_rows(params, cache, chunk, lens):
-                cache_b = jax.tree.map(lambda x: x[:, None], cache)
-
-                def one(sel_row, cache_row, ch, ln):
-                    merged = _merge_row(params, sel_row)
-                    lg, c2 = apply(merged, cache_row, ch[None], ln[None])
-                    return lg[0], jax.tree.map(lambda x: x[0], c2)
-
-                return jax.vmap(one)(sel, cache_b, chunk, lens)
-
-            return apply_rows
-
-        @jax.jit
-        def adapter_mixed_step(
-            params, pool, aidx, cache, chunk, lengths, reset_mask,
-            reset_to, tok, active, remaining, rid, rng,
-        ):
-            """``mixed_step`` with a PER-ROW adapter gather (multi-LoRA
-            serving): ``pool`` is the stacked adapter tree
-            (``tenancy.AdapterPool.tree`` — leading slot dim), ``aidx``
-            each row's adapter slot (0 = the base/zero adapter). One
-            fused program serves requests for DIFFERENT tenants'
-            adapters in the same batch, bit-identical to each tenant
-            solo against ``merge_lora``-folded weights (test-pinned)."""
-            sel = jax.tree.map(lambda s: s[aidx], pool)
-            return _mixed_core(
-                _adapter_apply(sel), params, cache, chunk, lengths,
-                reset_mask, reset_to, tok, active, remaining, rid, rng,
-            )
-
-        def _spec_mixed_core(
-            apply_fn, params, d_params, t_cache, d_cache, chunk, lengths,
-            reset_mask, reset_to, tok, active, pos, remaining, rid, rng,
-        ):
-            # The speculative fused-iteration body (shared with the
-            # adapter-gathered variant, like ``_mixed_core``): the
-            # verifier AND the refill stream run through ``apply_fn``;
-            # the draft always proposes with the base weights.
-            t_cache = _reset_rows(t_cache, reset_mask, reset_to)
-            d_cache = _reset_rows(d_cache, reset_mask, reset_to)
-            r_logits, t_cache = apply_fn(params, t_cache, chunk, lengths)
-            _, d_cache = d_apply(d_params, d_cache, chunk, lengths)
-            r_pick = jnp.take_along_axis(
-                r_logits, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
-            )[:, 0]
-            first_tok = sample_rows(r_pick, rng, rid, jnp.zeros_like(rid))
-            pos = pos + lengths
-            (tok, active, pos, remaining, count, buffer, acc, prop,
-             t_cache, d_cache) = spec_round(
-                _spec_carry_init(tok, active, pos, remaining, num_draft + 1)
-                + (t_cache, d_cache),
-                params, d_params, rid, rng, apply_fn=apply_fn,
-            )
-            return (
-                first_tok, buffer, count, acc, prop, tok, pos, active,
-                remaining, t_cache, d_cache,
-            )
-
-        @jax.jit
-        def spec_mixed_step(
-            params, d_params, t_cache, d_cache, chunk, lengths, reset_mask,
-            reset_to, tok, active, pos, remaining, rid, rng,
-        ):
-            """The speculative fused iteration: the budgeted refill chunk
-            streams through TARGET AND DRAFT (decoding rows ride with
-            length 0), then ONE draft-verify round (``spec_round`` — the
-            same per-row acceptance/rollback core as ``decode_block_spec``)
-            advances every decoding row by 1..num_draft+1 tokens. ``pos``
-            tracks every row's cache index: refill rows advance by their
-            chunk length BEFORE the round, so the round's rollback
-            broadcast re-asserts (never clobbers) their refill advance."""
-            return _spec_mixed_core(
-                apply, params, d_params, t_cache, d_cache, chunk, lengths,
-                reset_mask, reset_to, tok, active, pos, remaining, rid, rng,
-            )
-
-        @jax.jit
-        def adapter_spec_mixed_step(
-            params, pool, aidx, d_params, t_cache, d_cache, chunk, lengths,
-            reset_mask, reset_to, tok, active, pos, remaining, rid, rng,
-        ):
-            """``spec_mixed_step`` with the per-row adapter gather: refill
-            and VERIFICATION run each row against its own merged weights
-            (so accepted tokens are exactly what the tenant's solo merged
-            model would emit — greedy exactness through the verifier);
-            the shared draft proposes with the base weights, which only
-            moves the acceptance rate, never the output distribution."""
-            sel = jax.tree.map(lambda s: s[aidx], pool)
-            return _spec_mixed_core(
-                _adapter_apply(sel), params, d_params, t_cache, d_cache,
-                chunk, lengths, reset_mask, reset_to, tok, active, pos,
-                remaining, rid, rng,
-            )
-
-        def _multi_scan(apply_fn):
-            # THE device-resident multi-step loop (ROADMAP item 1): a
-            # ``lax.scan`` over the EXACT ``_mixed_core`` body, with the
-            # slot bookkeeping the host used to re-derive every iteration
-            # (tok/active/remaining) carried in the scan state instead.
-            # The host plans the whole horizon's refill schedule up front
-            # (stacked (N, B, ...) plan arrays ride as scan xs) and
-            # touches Python ONCE per horizon — one dispatch, one sync.
-            # Per-step ``lax.cond`` early-exit: a step the host did not
-            # plan (``live`` 0 — the fixed-shape horizon's trailing
-            # padding) or whose plan row has no refill while the carry
-            # holds no active row skips the model apply entirely, so
-            # padded steps cost control flow, not FLOPs. The ``live``
-            # gate is load-bearing, not an optimization: the host only
-            # consumes tokens from PLANNED links, so an unplanned step
-            # must not advance any row (a speculative row can still be
-            # active past the optimistic chain cap).
-            def run(params, cache, chunks, lengths, reset_mask, reset_to,
-                    live, tok, active, remaining, rid, rng):
-                def body(carry, x):
-                    tok, active, remaining, cache = carry
-                    chunk, lens, rmask, rto, lv = x
-
-                    def step(_):
-                        nxt, tok2, active2, remaining2, cache2 = (
-                            _mixed_core(
-                                apply_fn, params, cache, chunk, lens,
-                                rmask, rto, tok, active, remaining, rid,
-                                rng,
-                            )
-                        )
-                        return (tok2, active2, remaining2, cache2), nxt
-
-                    def frozen(_):
-                        return (tok, active, remaining, cache), tok
-
-                    has_work = jnp.logical_and(
-                        lv > 0,
-                        jnp.logical_or(
-                            jnp.any(lens > 0), jnp.any(active == 1)
-                        ),
-                    )
-                    return jax.lax.cond(has_work, step, frozen, None)
-
-                (tok, active, remaining, cache), toks = jax.lax.scan(
-                    body, (tok, active, remaining, cache),
-                    (chunks, lengths, reset_mask, reset_to, live),
-                )
-                return toks, tok, active, remaining, cache
-
-            return run
-
-        @jax.jit
-        def multi_step(
-            params, cache, chunks, lengths, reset_mask, reset_to, live,
-            tok, active, remaining, rid, rng,
-        ):
-            """``horizon`` fused engine iterations in ONE dispatch: a
-            ``lax.scan`` whose body is exactly ``mixed_step``'s
-            (``_mixed_core`` — shared, so the two program families cannot
-            drift), consuming one host-planned (chunk, lengths, resets)
-            plan row per step and carrying tok/active/remaining/cache
-            device-side. Per-row retirement happens IN-scan (remaining
-            hits 0 / EOS flips ``active``), and a ``cond`` skips steps
-            with no work, so the program is one executable per horizon
-            and the host syncs once per N tokens instead of once per
-            token. Token streams are bit-identical to N sequential
-            ``mixed_step`` iterations (test-pinned): the per-row
-            computation is the same, and sampling draws are keyed by
-            (request id, generated position), never by schedule."""
-            return _multi_scan(apply)(
-                params, cache, chunks, lengths, reset_mask, reset_to,
-                live, tok, active, remaining, rid, rng,
-            )
-
-        @jax.jit
-        def adapter_multi_step(
-            params, pool, aidx, cache, chunks, lengths, reset_mask,
-            reset_to, live, tok, active, remaining, rid, rng,
-        ):
-            """``multi_step`` with the per-row adapter gather: ``sel`` is
-            gathered ONCE outside the scan (``aidx`` is fixed for the
-            whole horizon — admission only lands at horizon boundaries),
-            then every scanned step applies each row's merged weights,
-            bit-identical to N ``adapter_mixed_step`` iterations."""
-            sel = jax.tree.map(lambda s: s[aidx], pool)
-            return _multi_scan(_adapter_apply(sel))(
-                params, cache, chunks, lengths, reset_mask, reset_to,
-                live, tok, active, remaining, rid, rng,
-            )
-
-        def _spec_multi_scan(apply_fn):
-            # The speculative multi-step loop: scans ``_spec_mixed_core``
-            # with the per-row rollback state (pos) and BOTH caches in
-            # the carry; each step's emission buffer/count/acceptance
-            # telemetry ride the scan ys (stacked (N, B, ...) — the host
-            # consumes them per planned link after the one sync).
-            def run(params, d_params, t_cache, d_cache, chunks, lengths,
-                    reset_mask, reset_to, live, tok, active, pos,
-                    remaining, rid, rng):
-                width = num_draft + 1
-
-                def body(carry, x):
-                    tok, active, pos, remaining, t_cache, d_cache = carry
-                    chunk, lens, rmask, rto, lv = x
-
-                    def step(_):
-                        (first_tok, buffer, count, acc, prop, tok2, pos2,
-                         active2, remaining2, t2, d2) = _spec_mixed_core(
-                            apply_fn, params, d_params, t_cache, d_cache,
-                            chunk, lens, rmask, rto, tok, active, pos,
-                            remaining, rid, rng,
-                        )
-                        return (
-                            (tok2, active2, pos2, remaining2, t2, d2),
-                            (first_tok, buffer, count, acc, prop),
-                        )
-
-                    def frozen(_):
-                        zi = jnp.zeros_like(tok)
-                        zb = jnp.zeros((tok.shape[0], width), jnp.int32)
-                        return (
-                            (tok, active, pos, remaining, t_cache,
-                             d_cache),
-                            (tok, zb, zi, zi, zi),
-                        )
-
-                    has_work = jnp.logical_and(
-                        lv > 0,
-                        jnp.logical_or(
-                            jnp.any(lens > 0), jnp.any(active == 1)
-                        ),
-                    )
-                    return jax.lax.cond(has_work, step, frozen, None)
-
-                carry0 = (tok, active, pos, remaining, t_cache, d_cache)
-                (tok, active, pos, remaining, t_cache, d_cache), ys = (
-                    jax.lax.scan(
-                        body, carry0,
-                        (chunks, lengths, reset_mask, reset_to, live),
-                    )
-                )
-                first_toks, buffers, counts, accs, props = ys
-                return (
-                    first_toks, buffers, counts, accs, props, tok, pos,
-                    active, remaining, t_cache, d_cache,
-                )
-
-            return run
-
-        @jax.jit
-        def spec_multi_step(
-            params, d_params, t_cache, d_cache, chunks, lengths,
-            reset_mask, reset_to, live, tok, active, pos, remaining, rid,
-            rng,
-        ):
-            """The speculative ``multi_step``: ``horizon`` scanned
-            ``spec_mixed_step`` bodies, each a budgeted refill sub-step
-            plus one draft-verify round, with the per-row rollback index
-            (``pos``) and both caches carried device-side. A step's
-            1..num_draft+1 accepted tokens land in its ys buffer row; the
-            host appends them per planned link after the single sync —
-            bit-identical to N sequential ``spec_mixed_step``
-            iterations."""
-            return _spec_multi_scan(apply)(
-                params, d_params, t_cache, d_cache, chunks, lengths,
-                reset_mask, reset_to, live, tok, active, pos, remaining,
-                rid, rng,
-            )
-
-        @jax.jit
-        def adapter_spec_multi_step(
-            params, pool, aidx, d_params, t_cache, d_cache, chunks,
-            lengths, reset_mask, reset_to, live, tok, active, pos,
-            remaining, rid, rng,
-        ):
-            """``spec_multi_step`` with the per-row adapter gather (once,
-            outside the scan — see ``adapter_multi_step``): verification
-            runs each row against its own merged weights, the shared
-            draft proposes with the base weights, exactly as in
-            ``adapter_spec_mixed_step``."""
-            sel = jax.tree.map(lambda s: s[aidx], pool)
-            return _spec_multi_scan(_adapter_apply(sel))(
-                params, d_params, t_cache, d_cache, chunks, lengths,
-                reset_mask, reset_to, live, tok, active, pos, remaining,
-                rid, rng,
-            )
-
-        @jax.jit
-        def kv_export(cache, slot):
-            """One slot's cache ROW — every cache leaf indexed at ``slot``
-            on its batch dim, per-row counters included (fixed shapes, so
-            the export is one executable for the engine's lifetime). The
-            prefill half of the DISAGGREGATED handoff (round 11): a pure
-            per-device gather whose golden contract
-            (``analysis/golden/kv_export.json``) pins that extracting a
-            row adds no collectives — the cross-replica byte movement
-            rides the explicit host transfer plan
-            (``fleet.kv_transfer``), where it is counted, never hidden
-            in XLA resharding."""
-            return jax.tree.map(
-                lambda x: jax.lax.dynamic_index_in_dim(
-                    x, slot, 0, keepdims=False
-                ),
-                cache,
-            )
-
-        @jax.jit
-        def kv_ingest(cache, rows, slot, index):
-            """Write an externally produced cache row into ``slot`` and
-            set its per-row counters to ``index`` (the row's valid
-            length) — the decode half of the disaggregated handoff.
-            Bytes past ``index`` are invisible to the causal-at-index
-            masks (the ``_reset_rows`` invariant), so the transfer plan
-            only has to deliver the valid prefix; its own golden
-            (``analysis/golden/kv_ingest.json``) pins that the update
-            adds no collectives when the rows arrive in this cache's own
-            row layout (``kv_row_shardings``)."""
-
-            def leaf(path, x, row):
-                if getattr(path[-1], "key", None) in (
-                    "cache_index", "position"
-                ):
-                    row = jnp.asarray(index)
-                return jax.lax.dynamic_update_index_in_dim(
-                    x, row.astype(x.dtype), slot, 0
-                )
-
-            return jax.tree_util.tree_map_with_path(leaf, cache, rows)
-
-        @jax.jit
-        def kv_page_spill(cache, pid):
-            """One physical PAGE's K/V — every page-pool leaf
-            (``_PAGE_LEAF_KEYS``) indexed at ``pid`` on its pool dim,
-            returned as a flatten-ordered LIST (the page has no per-slot
-            counters; a list avoids inventing a partial tree structure).
-            The demotion half of the KV tier ladder (round 15): a pure
-            per-device gather whose golden
-            (``analysis/golden/kv_page_spill.json``) pins that demoting
-            a page adds no collectives — the HBM→host bytes ride the
-            counted ``parallel.resharding`` host plan."""
-            return [
-                jax.lax.dynamic_index_in_dim(x, pid, 0, keepdims=False)
-                for path, x in jax.tree_util.tree_flatten_with_path(cache)[0]
-                if getattr(path[-1], "key", None) in _PAGE_LEAF_KEYS
-            ]
-
-        @jax.jit
-        def kv_page_fill(cache, page_rows, pid):
-            """Write a spilled page's K/V rows back into physical page
-            ``pid`` — the promotion half of the tier ladder, inverse of
-            ``kv_page_spill`` (same flatten-ordered leaf list). Its own
-            golden (``analysis/golden/kv_page_fill.json``) pins zero
-            collectives when the rows arrive in this cache's page-row
-            layout (pool dim dropped from each leaf's spec)."""
-            flat, treedef = jax.tree_util.tree_flatten_with_path(cache)
-            it = iter(page_rows)
-            out = []
-            for path, x in flat:
-                if getattr(path[-1], "key", None) in _PAGE_LEAF_KEYS:
-                    row = next(it)
-                    x = jax.lax.dynamic_update_index_in_dim(
-                        x, row.astype(x.dtype), pid, 0
-                    )
-                out.append(x)
-            return jax.tree_util.tree_unflatten(treedef, out)
+            ))
 
         # --- engine configuration and compiled programs -------------------
         self._mesh, self._rules = mesh, rules
@@ -1723,22 +799,17 @@ class ContinuousEngine:
         self._prefix = prefix_cache
         self._maybe_cast = maybe_cast
         self._d_cast = d_cast
-        self._first_refill_fn = first_refill
-        self._refill_step_fn = refill_step
-        self._decode_block_fn = decode_block
-        self._decode_block_spec_fn = decode_block_spec
-        self._mixed_step_fn = mixed_step
-        self._spec_mixed_step_fn = spec_mixed_step
-        self._adapter_mixed_step_fn = adapter_mixed_step
-        self._adapter_spec_mixed_step_fn = adapter_spec_mixed_step
-        self._multi_step_fn = multi_step
-        self._spec_multi_step_fn = spec_multi_step
-        self._adapter_multi_step_fn = adapter_multi_step
-        self._adapter_spec_multi_step_fn = adapter_spec_multi_step
-        self._kv_export_fn = kv_export
-        self._kv_ingest_fn = kv_ingest
-        self._kv_page_spill_fn = kv_page_spill
-        self._kv_page_fill_fn = kv_page_fill
+        # THE table of step programs this engine's mode can dispatch
+        # (family -> Program): every report of "which programs exist"
+        # maps over it.
+        self._programs = build_programs(
+            apply, d_apply, adapter=adapter_pool is not None, latent=latent,
+            moe_counted=moe_counted, mixed=bool(mixed), paged=paged,
+            prefix_cache=prefix_cache, temperature=temperature, top_k=top_k,
+            top_p=top_p, min_p=min_p, vocab_limit=vocab_limit,
+            max_new_tokens=max_new_tokens, eos_id=eos_id,
+            decode_block_steps=decode_block_steps, num_draft=num_draft,
+        )
         # Comm compression: the validated config, the drift probe, and
         # the host-side KV codec every counted transfer threads through.
         # The drift ladder is a dedicated one-level DegradationLadder —
@@ -1772,29 +843,11 @@ class ContinuousEngine:
         self._next_rid = 0
         self._cast_src: tuple | None = None
         self._cast_out: tuple | None = None
-        # Most recent dispatch arguments (closures over the engine's
-        # live state — cleared when the served params change, see
-        # _cast_params) — collective_inventory() re-lowers the compiled
-        # programs with them to read per-step collective counts off the
-        # HLO. NOTE abstract ShapeDtypeStruct capture does not work
-        # here: AOT lowering treats a struct's sharding as a hard
-        # constraint, and host-committed inputs that live dispatch
-        # happily transfers then refuse to lower against the mesh.
-        self._last_first_refill_args = None
-        self._last_refill_args = None
-        self._last_decode_args = None
-        self._last_decode_plain_args = None   # degraded-spec decode_block
-        self._last_mixed_args = None
-        self._last_multi_args = None          # multi-step scan (horizon>1)
         # The async planner's staged next-horizon plan: (fingerprint,
         # plan) — consumed by the next _multi_dispatch only when the
         # boundary state still matches the prediction (see
         # _plan_next_horizon), so staging can never change results.
         self._staged_plan = None
-        self._last_kv_export_args = None      # disaggregated handoff
-        self._last_kv_ingest_args = None
-        self._last_kv_page_spill_args = None  # KV tier ladder (round 15)
-        self._last_kv_page_fill_args = None
         # Tenancy (round 12): zero-downtime weight hot-swap + multi-LoRA.
         # ``weights_version`` is pinned onto every request AT ADMISSION —
         # in-flight requests finish (or recompute bit-identically) on the
@@ -2135,47 +1188,6 @@ class ContinuousEngine:
         # carries, so the sink costs nothing when absent.
         self.trace_sink = None
         self.trace_replica = "engine"
-        # fn-identity → program-family memo for _program_family (device
-        # frames tag their ledger seconds with the dispatching program).
-        self._fam_cache: dict[int, str] = {}
-
-    #: jitted-fn attribute → program-family name, mirroring the names
-    #: :meth:`_dispatched_programs` publishes — the ledger's per-family
-    #: device attribution must key identically or overlap_report rows
-    #: would never match a costmodel prediction.
-    _FN_FAMILY_ATTRS = (
-        ("_first_refill_fn", "first_refill"),
-        ("_refill_step_fn", "refill_step"),
-        ("_decode_block_spec_fn", "decode_block_spec"),
-        ("_decode_block_fn", "decode_block"),
-        ("_adapter_spec_mixed_step_fn", "adapter_mixed_step"),
-        ("_adapter_mixed_step_fn", "adapter_mixed_step"),
-        ("_spec_mixed_step_fn", "mixed_step"),
-        ("_mixed_step_fn", "mixed_step"),
-        ("_adapter_spec_multi_step_fn", "adapter_multi_step"),
-        ("_adapter_multi_step_fn", "adapter_multi_step"),
-        ("_spec_multi_step_fn", "multi_step"),
-        ("_multi_step_fn", "multi_step"),
-        ("_kv_export_fn", "kv_export"),
-        ("_kv_ingest_fn", "kv_ingest"),
-        ("_kv_page_spill_fn", "kv_page_spill"),
-        ("_kv_page_fill_fn", "kv_page_fill"),
-    )
-
-    def _program_family(self, fn):
-        """Program-family name for a jitted engine fn (None for frames
-        with no fn — blocking readbacks book as "unattributed")."""
-        if fn is None:
-            return None
-        fam = self._fam_cache.get(id(fn))
-        if fam is None:
-            fam = "unattributed"
-            for attr, name in self._FN_FAMILY_ATTRS:
-                if getattr(self, attr, None) is fn:
-                    fam = name
-                    break
-            self._fam_cache[id(fn)] = fam
-        return fam
 
     # Every goodput-ledger frame the engine opens is a span: a tracer
     # event and a ``jax.profiler.TraceAnnotation`` of the same name, so
@@ -2204,7 +1216,7 @@ class ContinuousEngine:
     # engine.telemetry          telemetry   telemetry   counters, recorder, SLO
     # engine.recovery / engine.kv_handoff / engine.swap: their bucket
     #
-    # ``<family>`` is ``_program_family``'s name. ``engine.refill`` /
+    # ``<family>`` is the ``Program``'s. ``engine.refill`` /
     # ``engine.decode`` / ``engine.mixed`` (``_dispatch_span``) are tracer
     # spans around a whole dispatch, not frames: their own time is the
     # step's.
@@ -2244,10 +1256,11 @@ class ContinuousEngine:
                 counter.inc(n)
 
     @contextlib.contextmanager
-    def _led_device(self, fn=None, family=None, in_flight=0):
+    def _led_device(self, prog: Program | None = None, family=None,
+                    in_flight=0):
         """Ledger frame for a dispatch or blocking readback: books to
-        the ``device`` bucket (tagged with ``fn``'s program family for
-        :meth:`overlap_report`), unless ``fn``'s executable cache GREW
+        the ``device`` bucket (tagged with ``prog``'s family for
+        :meth:`overlap_report`), unless ``prog``'s executable cache GREW
         inside the region — then the call paid a trace+compile, not a
         device step, and the whole frame re-buckets to ``compile`` (the
         compile-steal idiom; ``cache_size`` probes the jit cache).
@@ -2264,23 +1277,22 @@ class ContinuousEngine:
         is how many dispatched programs are still unread once this
         readback returns (the device runs them in order); at 0 the chip
         is empty until the next enqueue returns."""
-        before = cache_size(fn) if fn is not None else None
-        fam = family if family is not None else self._program_family(fn)
-        phase = "enqueue" if fn is not None else "wait"
+        if prog is not None:
+            before, fam, phase = cache_size(prog.fn), prog.family, "enqueue"
+        else:
+            fam, phase = family, "wait"
         span = self._span_names.get((phase, fam))
         if span is None:
             span = self._span_names[phase, fam] = f"engine.{phase}.{fam}"
         with self.ledger.measure(
             "device", family=fam, span=span, label=phase,
-            counter=self._c_enqueue_s if fn is not None else self._c_wait_s,
+            counter=self._c_enqueue_s if prog is not None else self._c_wait_s,
         ) as f:
             yield f
-            if before is not None:
-                after = cache_size(fn)
-                if after is not None and (before is None or after > before):
+            if prog is not None:
+                if before is not None and (cache_size(prog.fn) or 0) > before:
                     f.rebucket("compile")
                     self._compiled = True
-            if fn is not None:
                 self.ledger.device_busy()
                 self._starved_at_enqueue = self.ledger.starved_s
                 self._in_flight += 1
@@ -2695,16 +1707,9 @@ class ContinuousEngine:
         return out
 
     def _clear_dispatch_args(self):
-        self._last_first_refill_args = None
-        self._last_refill_args = self._last_decode_args = None
-        self._last_decode_plain_args = None
-        self._last_mixed_args = None
-        self._last_multi_args = None
+        for prog in self._programs.values():
+            prog.last_args = None
         self._staged_plan = None
-        self._last_kv_export_args = None
-        self._last_kv_ingest_args = None
-        self._last_kv_page_spill_args = None
-        self._last_kv_page_fill_args = None
 
     # --- zero-downtime weight hot-swap (round 12) --------------------------
 
@@ -3081,20 +2086,32 @@ class ContinuousEngine:
         if self._cache is not None:
             return
         with activate(self._mesh, self._rules):
-            first_args = (
-                params, d_params,
-                jnp.zeros((self._b, self._refill_chunk), jnp.int32),
-                jnp.zeros((self._b,), jnp.int32), self._rid_arr(),
-                self.rng,
-            )
-            self._cache = self._first_refill_fn(*first_args)[1]
+            # Outside step(), which is what the ledger covers.
+            self._create_cache(params, d_params, frame=False)
             if self._paged:
-                # Outside step(), which is what the ledger covers.
                 self._cache = self._set_tables(self._cache, frame=False)
+
+    def _create_cache(self, params, d_params, *, frame=True):
+        """Create faithful zero caches with a NO-OP first refill (every
+        length 0 — no writes, no advances): a paged engine's first real
+        chunk then runs through the steady-state path with the block
+        tables already installed, and a multi-LoRA engine never streams
+        prompt CONTENT through the base weights."""
+        prog = self._programs["first_refill"]
+        first_args = (
+            params, d_params,
+            jnp.zeros((self._b, self._refill_chunk), jnp.int32),
+            jnp.zeros((self._b,), jnp.int32), self._rid_arr(), self.rng,
+        )
+        with self._led_device(prog) if frame else contextlib.nullcontext():
+            self._cache = prog.fn(*first_args)[1]
+        self._book_cache_creation(first_args)
+
+    def _book_cache_creation(self, first_args):
         self.cache_creations += 1
         self._c_creations.inc()
         self.recorder.record("engine.cache_create", n=self.cache_creations)
-        self._last_first_refill_args = lambda: first_args
+        self._programs["first_refill"].last_args = lambda: first_args
 
     def kv_row_shardings(self):
         """Per-leaf :class:`~jax.sharding.NamedSharding` of ONE cache row
@@ -3168,12 +2185,13 @@ class ContinuousEngine:
             "kv_handoff", span="engine.kv_handoff", busy=True
         ):
             slot_j = jnp.int32(slot)
+            prog = self._programs["kv_export"]
             with activate(self._mesh, self._rules):
-                rows = self._kv_export_fn(self._cache, slot_j)
-            # Read the LIVE cache at relower time (like _last_decode_args
-            # et al.) — capturing the tuple would pin this moment's cache
-            # tree in HBM after later dispatches replace it.
-            self._last_kv_export_args = lambda: (self._cache, slot_j)
+                rows = prog.fn(self._cache, slot_j)
+            # Read the LIVE cache at relower time (like the decode
+            # programs' slots) — capturing the tuple would pin this
+            # moment's cache tree in HBM after later dispatches replace it.
+            prog.last_args = lambda: (self._cache, slot_j)
             length = max(0, self._plen[slot] + self._emitted[slot] - 1)
             self._c_kv_exports.inc()
             self.recorder.record(
@@ -3227,16 +2245,13 @@ class ContinuousEngine:
                 )
             self.ensure_cache(params)
             slot_j, idx_j = jnp.int32(slot), jnp.int32(int(p.size))
+            prog = self._programs["kv_ingest"]
             with activate(self._mesh, self._rules):
-                self._cache = self._kv_ingest_fn(
-                    self._cache, rows, slot_j, idx_j
-                )
+                self._cache = prog.fn(self._cache, rows, slot_j, idx_j)
             # Live-cache closure (see export_kv): only the one transferred
             # row tree stays retained for relowering, never a stale copy of
             # the whole pre-ingest cache.
-            self._last_kv_ingest_args = lambda: (
-                self._cache, rows, slot_j, idx_j,
-            )
+            prog.last_args = lambda: (self._cache, rows, slot_j, idx_j)
             now = time.perf_counter()
             r = _Request(
                 rid=rid, prompt=p,
@@ -3425,11 +2440,12 @@ class ContinuousEngine:
             "kv_handoff", span="engine.kv_handoff", busy=True
         ):
             pid_j = jnp.int32(pid)
+            prog = self._programs["kv_page_spill"]
             with activate(self._mesh, self._rules):
-                dev_rows = self._kv_page_spill_fn(self._cache, pid_j)
+                dev_rows = prog.fn(self._cache, pid_j)
             # Live-cache closure (see export_kv): relowering reads the
             # engine's CURRENT cache, never a pinned stale copy.
-            self._last_kv_page_spill_args = lambda: (self._cache, pid_j)
+            prog.last_args = lambda: (self._cache, pid_j)
             codec = self._kv_codec
             ckey = (
                 (codec.name, getattr(codec, "block", 0))
@@ -3532,15 +2548,12 @@ class ContinuousEngine:
                 raw_bytes += stats.get("raw_bytes", stats["bytes"])
                 nsegs += stats["segments"]
             pid_j = jnp.int32(pid)
+            prog = self._programs["kv_page_fill"]
             with activate(self._mesh, self._rules):
-                self._cache = self._kv_page_fill_fn(
-                    self._cache, dev_rows, pid_j
-                )
+                self._cache = prog.fn(self._cache, dev_rows, pid_j)
             # Only the one promoted row list stays retained for
             # relowering, never a stale copy of the whole cache.
-            self._last_kv_page_fill_args = lambda: (
-                self._cache, dev_rows, pid_j,
-            )
+            prog.last_args = lambda: (self._cache, dev_rows, pid_j)
             self._prefix_registry[key] = pid
             self._key_of_page[pid] = key
             self._refcnt[pid] = 0
@@ -4134,24 +3147,7 @@ class ContinuousEngine:
                     break
                 extra = self._spare_chunk_rows(firsts)
                 if self._cache is None:
-                    # Create faithful zero caches with a NO-OP refill
-                    # (every length 0 — no writes, no advances), so the
-                    # real first chunk runs through the steady-state path
-                    # with the block tables already installed.
-                    first_args = (
-                        params, d_params,
-                        jnp.zeros((b, self._refill_chunk), jnp.int32),
-                        jnp.zeros((b,), jnp.int32), self._rid_arr(),
-                        self.rng,
-                    )
-                    with self._led_device(self._first_refill_fn):
-                        self._cache = self._first_refill_fn(*first_args)[1]
-                    self.cache_creations += 1
-                    self._c_creations.inc()
-                    self.recorder.record(
-                        "engine.cache_create", n=self.cache_creations
-                    )
-                    self._last_first_refill_args = lambda: first_args
+                    self._create_cache(params, d_params)
                 self._cache = self._set_tables(self._cache)
             # Row r of the dispatch: slot rows[r]'s chunk at offsets[r]
             # past what the slot has consumed. A slot's next chunk sits in
@@ -4172,22 +3168,16 @@ class ContinuousEngine:
                 last_row[slot] = r
                 took[slot] += n
             if self._cache is None:
+                # A contiguous cache's first real chunk creates it.
+                prog = self._programs["first_refill"]
                 with self._led_h2d():
                     first_args = (
                         params, d_params, jnp.asarray(chunk),
                         jnp.asarray(lengths), self._rid_arr(), self.rng,
                     )
-                with self._led_device(self._first_refill_fn):
-                    tok_new, self._cache, *moe = self._first_refill_fn(
-                        *first_args
-                    )
-                seg_fam = "first_refill"
-                self.cache_creations += 1
-                self._c_creations.inc()
-                self.recorder.record(
-                    "engine.cache_create", n=self.cache_creations
-                )
-                self._last_first_refill_args = lambda: first_args
+                with self._led_device(prog):
+                    tok_new, self._cache, *moe = prog.fn(*first_args)
+                self._book_cache_creation(first_args)
             else:
                 # COPIES, not the live arrays: jnp.asarray of a numpy
                 # array can be zero-copy (the jax.Array aliases the host
@@ -4204,14 +3194,14 @@ class ContinuousEngine:
                     rid_d = self._rid_arr()
                     rows_d = jnp.asarray(rows)
                     offsets_d = jnp.asarray(offsets)
-                with self._led_device(self._refill_step_fn):
-                    tok_new, self._cache, *moe = self._refill_step_fn(
+                prog = self._programs["refill_step"]
+                with self._led_device(prog):
+                    tok_new, self._cache, *moe = prog.fn(
                         params, d_params, self._cache, chunk_d, lengths_d,
                         reset_d, reset_to_d, rid_d, self.rng, rows_d,
                         offsets_d,
                     )
-                seg_fam = "refill_step"
-                self._last_refill_args = lambda: (
+                prog.last_args = lambda: (
                     params, d_params, self._cache, chunk_d, lengths_d,
                     reset_d, reset_to_d, rid_d, self.rng, rows_d, offsets_d,
                 )
@@ -4228,7 +3218,7 @@ class ContinuousEngine:
                 self._pending[slot] = self._pending[slot][n:]
                 if self._pending[slot].size == 0 and self._req[slot] >= 0:
                     seg_completes.append(slot)
-            segs.append((tok_new, seg_completes, last_row, seg_fam, moe))
+            segs.append((tok_new, seg_completes, last_row, prog.family, moe))
             self._c_prefill_tok.inc(sum(took.values()))
             self._c_refill_slots.inc(chunk.size)
             self._c_chunk_rows.inc(len(firsts) + len(extra))
@@ -4379,20 +3369,19 @@ class ContinuousEngine:
                     )
                 )
         if spec:
+            prog = self._programs["decode_block_spec"]
             t_cache, d_cache = self._cache
             segs = []
             for _ in range(chain):
-                with self._led_device(self._decode_block_spec_fn):
+                with self._led_device(prog):
                     (buffer, counts, acc, prop, tok_d, pos_d, active_d,
-                     remaining_d, t_cache, d_cache) = (
-                        self._decode_block_spec_fn(
-                            params, d_params, t_cache, d_cache, tok_d,
-                            active_d, pos_d, remaining_d, rid, self.rng,
-                        )
+                     remaining_d, t_cache, d_cache) = prog.fn(
+                        params, d_params, t_cache, d_cache, tok_d,
+                        active_d, pos_d, remaining_d, rid, self.rng,
                     )
                 segs.append((buffer, counts, acc, prop))
             self._cache = (t_cache, d_cache)
-            self._last_decode_args = lambda: (
+            prog.last_args = lambda: (
                 params, d_params, self._cache[0], self._cache[1], tok_d,
                 active_d, pos_d, remaining_d, rid, self.rng,
             )
@@ -4423,29 +3412,29 @@ class ContinuousEngine:
                 cache, d_cache = self._cache
             else:
                 cache, d_cache = self._cache, None
+            prog = self._programs["decode_block"]
             segs, moe_segs = [], []
             for _ in range(chain):
-                with self._led_device(self._decode_block_fn):
-                    toks, active_d, remaining_d, cache, *moe = (
-                        self._decode_block_fn(
-                            params, cache, tok_d, active_d,
-                            remaining_d, rid, self.rng,
-                        )
+                with self._led_device(prog):
+                    toks, active_d, remaining_d, cache, *moe = prog.fn(
+                        params, cache, tok_d, active_d, remaining_d, rid,
+                        self.rng,
                     )
                 # Next block's pending token: each row's last emitted
                 # (frozen rows repeat their token — correct carry).
                 tok_d = toks[:, -1]
                 segs.append(toks)
                 moe_segs += moe
+            # The slot reads the LIVE target cache at relower time.
             if self._speculative:
                 self._cache = (cache, d_cache)
-                self._last_decode_plain_args = lambda: (
+                prog.last_args = lambda: (
                     params, self._cache[0], tok_d, active_d, remaining_d,
                     rid, self.rng,
                 )
             else:
                 self._cache = cache
-                self._last_decode_args = lambda: (
+                prog.last_args = lambda: (
                     params, self._cache, tok_d, active_d, remaining_d,
                     rid, self.rng,
                 )
@@ -4535,24 +3524,10 @@ class ContinuousEngine:
                     else False
                 )
             # Adapter engines must never stream prompt CONTENT through
-            # the base-weights refill programs: create the cache with a
-            # ZERO-LENGTH first refill (no writes, no advances) and fall
-            # through to the fused adapter step below, which prefills
-            # every row through its own tenant's merged weights.
-            first_args = (
-                params, d_params,
-                jnp.zeros((self._b, self._refill_chunk), jnp.int32),
-                jnp.zeros((self._b,), jnp.int32), self._rid_arr(),
-                self.rng,
-            )
-            with self._led_device(self._first_refill_fn):
-                self._cache = self._first_refill_fn(*first_args)[1]
-            self.cache_creations += 1
-            self._c_creations.inc()
-            self.recorder.record(
-                "engine.cache_create", n=self.cache_creations
-            )
-            self._last_first_refill_args = lambda: first_args
+            # the base-weights refill programs: create the cache empty
+            # and fall through to the fused adapter step below, which
+            # prefills every row through its own tenant's merged weights.
+            self._create_cache(params, d_params)
         b = self._b
         if self._speculative and self._spec_disabled:
             # Degradation level >= 1 on a speculative MIXED engine: run
@@ -4653,6 +3628,7 @@ class ContinuousEngine:
             chain_dec = chain_cap(remaining, self._active)
         was_active = self._active.copy()
         n_active = int(was_active.sum())
+        pos_d = None
         with self._led_h2d():
             tok_d = jnp.asarray(self._tok)
             active_d = jnp.asarray(was_active.astype(np.int32))
@@ -4680,22 +3656,24 @@ class ContinuousEngine:
                 )
             if self._adapter_pool is not None:
                 aidx_d = jnp.asarray(self._aidx)
-        if self._speculative:
-            t_cache, d_cache = self._cache
+        t_cache, d_cache = self._cache if self._speculative else (None, None)
         with self.ledger.measure("recovery", span="engine.recovery"):
             # Armed chaos delay books as recovery, never device.
             chaos_hook(
                 "engine.dispatch", phase="mixed",
                 rids=[r for r in self._req if r >= 0],
             )
-        if self._adapter_pool is not None:
-            # One fused program serves every tenant in the batch: the
-            # stacked pool rides in as an argument (stable treedef →
-            # stable compile) and the per-row adapter index gathers each
-            # row's slice on device. _aidx is fixed for the whole chain:
-            # admission ran before this dispatch and nothing re-admits
-            # mid-chain.
-            pool_t = self._adapter_pool.tree
+        # A multi-LoRA engine's fused programs take (pool, aidx) after
+        # params; the prefix is empty without a pool. One program serves
+        # every tenant in the batch: the stacked pool rides in as an
+        # argument (stable treedef → stable compile) and the per-row
+        # adapter index gathers each row's slice on device. _aidx is fixed
+        # for the whole chain: admission ran before this dispatch and
+        # nothing re-admits mid-chain.
+        adapter_ops = (
+            (self._adapter_pool.tree, aidx_d)
+            if self._adapter_pool is not None else ()
+        )
         if horizon > 1:
             # Device-resident multi-step path: the horizon's plan is
             # staged host-side and ONE scanned program advances all of
@@ -4707,16 +3685,29 @@ class ContinuousEngine:
                 per_link=per_link, chain_dec=chain_dec,
                 was_active=was_active, n_active=n_active, tok_d=tok_d,
                 active_d=active_d, remaining_d=remaining_d, rid=rid,
-                pos_d=pos_d if self._speculative else None,
-                t_cache=t_cache if self._speculative else None,
-                d_cache=d_cache if self._speculative else None,
-                pool_t=(
-                    pool_t if self._adapter_pool is not None else None
-                ),
-                aidx_d=(
-                    aidx_d if self._adapter_pool is not None else None
-                ),
+                pos_d=pos_d, t_cache=t_cache, d_cache=d_cache,
+                adapter_ops=adapter_ops,
             )
+        prog = self._programs[
+            "adapter_mixed_step" if adapter_ops else "mixed_step"
+        ]
+
+        def operands():
+            # As the locals stand: the call's operands before it, its
+            # results (the LIVE caches and carries, never the trees they
+            # replaced) after it.
+            if self._speculative:
+                return (
+                    params, *adapter_ops, d_params, t_cache, d_cache,
+                    chunk_d, lengths_d, reset_d, reset_to_d, tok_d,
+                    active_d, pos_d, remaining_d, rid, self.rng,
+                )
+            return (
+                params, *adapter_ops, self._cache, chunk_d, lengths_d,
+                reset_d, reset_to_d, tok_d, active_d, remaining_d, rid,
+                self.rng,
+            )
+
         segs = []
         starved_total = 0
         refill_scheduled = 0
@@ -4753,72 +3744,18 @@ class ContinuousEngine:
                 lengths_d = jnp.asarray(lengths)
                 reset_d = jnp.asarray(self._needs_reset.copy())
                 reset_to_d = jnp.asarray(self._reset_to.copy())
-            if self._speculative and self._adapter_pool is not None:
-                with self._led_device(self._adapter_spec_mixed_step_fn):
+            with self._led_device(prog):
+                if self._speculative:
                     (first_tok, buffer, counts, acc, prop, tok_d, pos_d,
-                     active_d, remaining_d, t_cache, d_cache) = (
-                        self._adapter_spec_mixed_step_fn(
-                            params, pool_t, aidx_d, d_params, t_cache,
-                            d_cache, chunk_d, lengths_d, reset_d,
-                            reset_to_d, tok_d, active_d, pos_d,
-                            remaining_d, rid, self.rng,
-                        )
+                     active_d, remaining_d, t_cache, d_cache) = prog.fn(
+                        *operands()
                     )
-                args = (
-                    params, pool_t, aidx_d, d_params, t_cache, d_cache,
-                    chunk_d, lengths_d, reset_d, reset_to_d, tok_d,
-                    active_d, pos_d, remaining_d, rid, self.rng,
-                )
-                link_fam = "adapter_mixed_step"
-            elif self._speculative:
-                with self._led_device(self._spec_mixed_step_fn):
-                    (first_tok, buffer, counts, acc, prop, tok_d, pos_d,
-                     active_d, remaining_d, t_cache, d_cache) = (
-                        self._spec_mixed_step_fn(
-                            params, d_params, t_cache, d_cache, chunk_d,
-                            lengths_d, reset_d, reset_to_d, tok_d,
-                            active_d, pos_d, remaining_d, rid, self.rng,
-                        )
-                    )
-                args = (
-                    params, d_params, t_cache, d_cache, chunk_d,
-                    lengths_d, reset_d, reset_to_d, tok_d, active_d,
-                    pos_d, remaining_d, rid, self.rng,
-                )
-                link_fam = "mixed_step"
-            elif self._adapter_pool is not None:
-                with self._led_device(self._adapter_mixed_step_fn):
+                else:
                     first_tok, tok_d, active_d, remaining_d, self._cache = (
-                        self._adapter_mixed_step_fn(
-                            params, pool_t, aidx_d, self._cache, chunk_d,
-                            lengths_d, reset_d, reset_to_d, tok_d,
-                            active_d, remaining_d, rid, self.rng,
-                        )
+                        prog.fn(*operands())
                     )
-                buffer = counts = acc = prop = None
-                args = (
-                    params, pool_t, aidx_d, self._cache, chunk_d,
-                    lengths_d, reset_d, reset_to_d, tok_d, active_d,
-                    remaining_d, rid, self.rng,
-                )
-                link_fam = "adapter_mixed_step"
-            else:
-                with self._led_device(self._mixed_step_fn):
-                    first_tok, tok_d, active_d, remaining_d, self._cache = (
-                        self._mixed_step_fn(
-                            params, self._cache, chunk_d, lengths_d,
-                            reset_d, reset_to_d, tok_d, active_d,
-                            remaining_d, rid, self.rng,
-                        )
-                    )
-                buffer = counts = acc = prop = None
-                args = (
-                    params, self._cache, chunk_d, lengths_d, reset_d,
-                    reset_to_d, tok_d, active_d, remaining_d, rid,
-                    self.rng,
-                )
-                link_fam = "mixed_step"
-            self._last_mixed_args = lambda a=args: a
+                    buffer = counts = acc = prop = None
+            prog.last_args = lambda a=operands(): a
             self._needs_reset[:] = False
             self._reset_to[:] = 0
             # Advance the host-side pending views NOW (later links read
@@ -4858,11 +3795,11 @@ class ContinuousEngine:
             enumerate(segs)
         ):
             left = len(segs) - 1 - i
-            with self._led_device(family=link_fam, in_flight=left):
+            with self._led_device(family=prog.family, in_flight=left):
                 first_np = np.asarray(first_tok)   # each link's own sync
             now = time.perf_counter()
             if self._speculative:
-                with self._led_device(family=link_fam, in_flight=left):
+                with self._led_device(family=prog.family, in_flight=left):
                     counts_np = np.asarray(counts)
                     buffer_np = np.asarray(buffer)
                     acc_np = np.asarray(acc)
@@ -5011,7 +3948,7 @@ class ContinuousEngine:
     def _multi_dispatch(
         self, params, d_params, retired, *, n_links, per_link, chain_dec,
         was_active, n_active, tok_d, active_d, remaining_d, rid,
-        pos_d=None, t_cache=None, d_cache=None, pool_t=None, aidx_d=None,
+        pos_d=None, t_cache=None, d_cache=None, adapter_ops=(),
     ):
         # The DEVICE-RESIDENT steady-state loop (horizon > 1): plan the
         # whole horizon's refill schedule host-side, dispatch ONE scanned
@@ -5073,72 +4010,37 @@ class ContinuousEngine:
             resets_d = jnp.asarray(resets)
             reset_tos_d = jnp.asarray(reset_tos)
             live_d = jnp.asarray(live)
-        if self._speculative and self._adapter_pool is not None:
-            with self._led_device(self._adapter_spec_multi_step_fn):
+        prog = self._programs[
+            "adapter_multi_step" if adapter_ops else "multi_step"
+        ]
+
+        def operands():
+            # As in _mixed_dispatch: operands before the call, the live
+            # results after it.
+            if self._speculative:
+                return (
+                    params, *adapter_ops, d_params, t_cache, d_cache,
+                    chunks_d, lens_d, resets_d, reset_tos_d, live_d, tok_d,
+                    active_d, pos_d, remaining_d, rid, self.rng,
+                )
+            return (
+                params, *adapter_ops, self._cache, chunks_d, lens_d,
+                resets_d, reset_tos_d, live_d, tok_d, active_d, remaining_d,
+                rid, self.rng,
+            )
+
+        with self._led_device(prog):
+            if self._speculative:
                 (first_toks, buffers, counts, accs, props, tok_d, pos_d,
-                 active_d, remaining_d, t_cache, d_cache) = (
-                    self._adapter_spec_multi_step_fn(
-                        params, pool_t, aidx_d, d_params, t_cache,
-                        d_cache, chunks_d, lens_d, resets_d, reset_tos_d,
-                        live_d, tok_d, active_d, pos_d, remaining_d, rid,
-                        self.rng,
-                    )
+                 active_d, remaining_d, t_cache, d_cache) = prog.fn(
+                    *operands()
                 )
-            args = (
-                params, pool_t, aidx_d, d_params, t_cache, d_cache,
-                chunks_d, lens_d, resets_d, reset_tos_d, live_d, tok_d,
-                active_d, pos_d, remaining_d, rid, self.rng,
-            )
-            fused_fam = "adapter_multi_step"
-        elif self._speculative:
-            with self._led_device(self._spec_multi_step_fn):
-                (first_toks, buffers, counts, accs, props, tok_d, pos_d,
-                 active_d, remaining_d, t_cache, d_cache) = (
-                    self._spec_multi_step_fn(
-                        params, d_params, t_cache, d_cache, chunks_d,
-                        lens_d, resets_d, reset_tos_d, live_d, tok_d,
-                        active_d, pos_d, remaining_d, rid, self.rng,
-                    )
-                )
-            args = (
-                params, d_params, t_cache, d_cache, chunks_d, lens_d,
-                resets_d, reset_tos_d, live_d, tok_d, active_d, pos_d,
-                remaining_d, rid, self.rng,
-            )
-            fused_fam = "multi_step"
-        elif self._adapter_pool is not None:
-            with self._led_device(self._adapter_multi_step_fn):
+            else:
                 first_toks, tok_d, active_d, remaining_d, self._cache = (
-                    self._adapter_multi_step_fn(
-                        params, pool_t, aidx_d, self._cache, chunks_d,
-                        lens_d, resets_d, reset_tos_d, live_d, tok_d,
-                        active_d, remaining_d, rid, self.rng,
-                    )
+                    prog.fn(*operands())
                 )
-            buffers = counts = accs = props = None
-            args = (
-                params, pool_t, aidx_d, self._cache, chunks_d, lens_d,
-                resets_d, reset_tos_d, live_d, tok_d, active_d,
-                remaining_d, rid, self.rng,
-            )
-            fused_fam = "adapter_multi_step"
-        else:
-            with self._led_device(self._multi_step_fn):
-                first_toks, tok_d, active_d, remaining_d, self._cache = (
-                    self._multi_step_fn(
-                        params, self._cache, chunks_d, lens_d, resets_d,
-                        reset_tos_d, live_d, tok_d, active_d,
-                        remaining_d, rid, self.rng,
-                    )
-                )
-            buffers = counts = accs = props = None
-            args = (
-                params, self._cache, chunks_d, lens_d, resets_d,
-                reset_tos_d, live_d, tok_d, active_d, remaining_d, rid,
-                self.rng,
-            )
-            fused_fam = "multi_step"
-        self._last_multi_args = lambda a=args: a
+                buffers = counts = accs = props = None
+        prog.last_args = lambda a=operands(): a
         if self._speculative:
             self._cache = (t_cache, d_cache)
         self._needs_reset[:] = False
@@ -5166,7 +4068,7 @@ class ContinuousEngine:
         self._plan_next_horizon(n_links, per_link, chain_dec, links)
         # ONE blocking readback for the whole horizon (the host's single
         # touch per N iterations — books as in-flight device time).
-        with self._led_device(family=fused_fam):
+        with self._led_device(family=prog.family):
             toks_np = np.asarray(first_toks)
             if self._speculative:
                 counts_np = np.asarray(counts)
@@ -5328,18 +4230,12 @@ class ContinuousEngine:
         if comp is None or not comp.enabled:
             return
         comp.enabled = False
-        cleared = 0
-        for attr, _ in self._FN_FAMILY_ATTRS:
-            if attr.startswith("_kv_"):
-                continue  # handoff/page programs never embed the apply
-            fn = getattr(self, attr, None)
-            if fn is not None and hasattr(fn, "clear_cache"):
-                fn.clear_cache()
-                cleared += 1
-        if self._comp_probe_fn is not None and hasattr(
-            self._comp_probe_fn, "clear_cache"
-        ):
-            self._comp_probe_fn.clear_cache()
+        # Every program that traces the apply retraces; the cache-moving
+        # ones never embed it.
+        stale = [p.fn for p in self._programs.values() if p.applies]
+        for fn in (*stale, self._comp_probe_fn):
+            fn.clear_cache()
+        cleared = len(stale)
         self._c_comp_trips.inc()
         self._g_comp_on.set(0)
         self.recorder.record(
@@ -5648,57 +4544,25 @@ class ContinuousEngine:
         self.last_stats = stats or None
         self.last_latency = self.latency_stats()
 
+    def program(self, family: str) -> Program:
+        """The table's entry for ``family`` (KeyError where this engine's
+        mode cannot dispatch it): its jitted ``fn`` and, once it has
+        dispatched, ``last_args()``."""
+        return self._programs[family]
+
     def compile_counts(self) -> dict[str, int | None]:
         """Executable-cache size per compiled engine program — each is
         that program's lifetime compile count (one executable per
         distinct shape/static combination), the "did serving recompile
-        mid-flight?" probe. The steady-state engine holds these at 1."""
-        fns = {
-            "first_refill": self._first_refill_fn,
-            "refill_step": self._refill_step_fn,
+        mid-flight?" probe. The steady-state engine holds these at 1
+        (the fused horizon program too: one executable per horizon, by
+        the same fixed-shape plan arrays that hold mixed_step at 1).
+        Programs off the steady path are listed once they have
+        dispatched (``Program.steady``)."""
+        return {
+            p.family: cache_size(p.fn) for p in self._programs.values()
+            if p.steady or p.last_args is not None
         }
-        if self._speculative:
-            fns["decode_block_spec"] = self._decode_block_spec_fn
-            if self._last_decode_plain_args is not None:
-                # The degradation ladder's plain decode path has
-                # dispatched: its executable cache is a live program too.
-                fns["decode_block"] = self._decode_block_fn
-        else:
-            fns["decode_block"] = self._decode_block_fn
-        if self._mixed and self._adapter_pool is not None:
-            fns["adapter_mixed_step"] = (
-                self._adapter_spec_mixed_step_fn if self._speculative
-                else self._adapter_mixed_step_fn
-            )
-        elif self._mixed:
-            fns["mixed_step"] = (
-                self._spec_mixed_step_fn if self._speculative
-                else self._mixed_step_fn
-            )
-        if self._mixed and self._last_multi_args is not None:
-            # The fused horizon program (horizon > 1): ONE additional
-            # steady-state executable per engaged program family — held
-            # at 1 per (horizon, family) by the same fixed-shape plan
-            # arrays that hold mixed_step at 1.
-            if self._adapter_pool is not None:
-                fns["adapter_multi_step"] = (
-                    self._adapter_spec_multi_step_fn if self._speculative
-                    else self._adapter_multi_step_fn
-                )
-            else:
-                fns["multi_step"] = (
-                    self._spec_multi_step_fn if self._speculative
-                    else self._multi_step_fn
-                )
-        if self._last_kv_export_args is not None:
-            fns["kv_export"] = self._kv_export_fn
-        if self._last_kv_ingest_args is not None:
-            fns["kv_ingest"] = self._kv_ingest_fn
-        if self._last_kv_page_spill_args is not None:
-            fns["kv_page_spill"] = self._kv_page_spill_fn
-        if self._last_kv_page_fill_args is not None:
-            fns["kv_page_fill"] = self._kv_page_fill_fn
-        return {k: cache_size(f) for k, f in fns.items()}
 
     def _dispatched_programs(self):
         """``(program_name, jitted_fn, args)`` for every engine program
@@ -5707,80 +4571,10 @@ class ContinuousEngine:
         pass so a new program cannot be visible to one and invisible to
         the other. ``first_refill`` is included so single-chunk prefills
         are not silently missing."""
-        out = []
-        if self._last_first_refill_args is not None:
-            out.append((
-                "first_refill", self._first_refill_fn,
-                self._last_first_refill_args(),
-            ))
-        if self._last_refill_args is not None:
-            out.append((
-                "refill_step", self._refill_step_fn,
-                self._last_refill_args(),
-            ))
-        if self._last_decode_args is not None:
-            if self._speculative:
-                fn, name = self._decode_block_spec_fn, "decode_block_spec"
-            else:
-                fn, name = self._decode_block_fn, "decode_block"
-            out.append((name, fn, self._last_decode_args()))
-        if self._last_decode_plain_args is not None:
-            # The degradation ladder's target-only decode on a SPEC
-            # engine — the same program a plain engine runs, visible to
-            # the contract pass under the plain ``decode_step`` golden.
-            out.append((
-                "decode_block", self._decode_block_fn,
-                self._last_decode_plain_args(),
-            ))
-        if self._last_mixed_args is not None:
-            if self._adapter_pool is not None:
-                fn = (
-                    self._adapter_spec_mixed_step_fn if self._speculative
-                    else self._adapter_mixed_step_fn
-                )
-                name = "adapter_mixed_step"
-            else:
-                fn = (
-                    self._spec_mixed_step_fn if self._speculative
-                    else self._mixed_step_fn
-                )
-                name = "mixed_step"
-            out.append((name, fn, self._last_mixed_args()))
-        if self._last_multi_args is not None:
-            if self._adapter_pool is not None:
-                fn = (
-                    self._adapter_spec_multi_step_fn if self._speculative
-                    else self._adapter_multi_step_fn
-                )
-                name = "adapter_multi_step"
-            else:
-                fn = (
-                    self._spec_multi_step_fn if self._speculative
-                    else self._multi_step_fn
-                )
-                name = "multi_step"
-            out.append((name, fn, self._last_multi_args()))
-        if self._last_kv_export_args is not None:
-            out.append((
-                "kv_export", self._kv_export_fn,
-                self._last_kv_export_args(),
-            ))
-        if self._last_kv_ingest_args is not None:
-            out.append((
-                "kv_ingest", self._kv_ingest_fn,
-                self._last_kv_ingest_args(),
-            ))
-        if self._last_kv_page_spill_args is not None:
-            out.append((
-                "kv_page_spill", self._kv_page_spill_fn,
-                self._last_kv_page_spill_args(),
-            ))
-        if self._last_kv_page_fill_args is not None:
-            out.append((
-                "kv_page_fill", self._kv_page_fill_fn,
-                self._last_kv_page_fill_args(),
-            ))
-        return out
+        return [
+            (p.family, p.fn, p.last_args())
+            for p in self._programs.values() if p.last_args is not None
+        ]
 
     def _program_reports(self) -> dict[str, dict]:
         """Full ``executable_report`` per dispatched engine program,
@@ -5819,32 +4613,18 @@ class ContinuousEngine:
                 for name, fn, args in self._dispatched_programs()
             }
 
-    #: Engine program → golden contract name (``analysis/golden/<name>.json``)
-    #: — the names ``analysis.entrypoints`` generates under. A SPECULATIVE
-    #: engine's programs get a ``spec_`` prefix on top (its refill also
-    #: prefills the draft cache — a different program family with its own
-    #: goldens): spec_first_prefill / spec_prefill / spec_decode_step.
-    CONTRACT_NAMES = {
-        "first_refill": "first_prefill",
-        "refill_step": "prefill",
-        "decode_block": "decode_step",
-        "decode_block_spec": "decode_step",
-        "mixed_step": "mixed_step",
-        "adapter_mixed_step": "adapter_mixed_step",
-        "multi_step": "multi_step",
-        "adapter_multi_step": "adapter_multi_step",
-        "kv_export": "kv_export",
-        "kv_ingest": "kv_ingest",
-        "kv_page_spill": "kv_page_spill",
-        "kv_page_fill": "kv_page_fill",
-    }
-
     def contract_name(self, program: str) -> str:
-        base = self.CONTRACT_NAMES.get(program, program)
+        """Engine program → golden contract name
+        (``analysis/golden/<name>.json``) — the names
+        ``analysis.entrypoints`` generates under: the table's base name,
+        ``_q8`` under comm compression, and a ``spec_`` prefix on a
+        SPECULATIVE engine's programs (its refill also prefills the draft
+        cache — a different program family with its own goldens:
+        spec_first_prefill / spec_prefill / spec_decode_step)."""
+        prog = self._programs.get(program)
+        base = prog.contract if prog is not None else program
         comp = self._comp
-        if program in (
-            "kv_export", "kv_ingest", "kv_page_spill", "kv_page_fill"
-        ):
+        if prog is not None and not prog.applies:
             # The handoff programs are only dispatchable on non-spec
             # engines (export/ingest raise otherwise) — one golden each.
             # A KV codec does not change the DEVICE program (the codec

@@ -1,0 +1,253 @@
+"""The engine's program table (models/engine_programs.py).
+
+Every consumer of "which programs exist" maps over one table built for
+the engine's mode. These cases pin, per mode and WITHOUT dispatching
+anything, what no other test holds together: the table has exactly the
+families that mode can dispatch; every jitted function keeps the name
+XLA's module names (and so the benchmark's trace metrics) are matched
+by; ``contract_name`` maps every entry to the golden it always had; and
+``compile_counts()`` lists, before any dispatch, the keys it always did.
+The expectations are literals read off the engine as it was before the
+table existed.
+"""
+
+import dataclasses
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from learning_jax_sharding_tpu.analysis import GOLDEN_DIR
+from learning_jax_sharding_tpu.models.engine_programs import build_programs
+from learning_jax_sharding_tpu.models.serving import ContinuousEngine
+from learning_jax_sharding_tpu.models.transformer import (
+    CONFIG_TINY,
+    Transformer,
+    TransformerConfig,
+)
+from learning_jax_sharding_tpu.parallel import build_mesh
+from learning_jax_sharding_tpu.parallel.compression import CommCompression
+from learning_jax_sharding_tpu.parallel.logical import RULES_TP_SERVING
+from learning_jax_sharding_tpu.telemetry.compile_watch import cache_size
+from learning_jax_sharding_tpu.tenancy import AdapterPool
+
+TINY = dataclasses.replace(
+    CONFIG_TINY, dtype=jnp.float32, decode_attention="blocked"
+)
+DRAFT = dataclasses.replace(TINY, num_layers=1, hidden=64)
+LATENT = TransformerConfig(
+    vocab_size=128, num_layers=2, features=32, num_heads=2, hidden=64,
+    max_seq_len=64, dtype=jnp.float32, norm="rmsnorm", rope=True,
+    latent_kv_rank=16, latent_q_rank=8, qk_nope_dim=8, qk_rope_dim=8,
+    v_head_dim=8, ff_gated=True, first_k_dense=1, num_experts=4,
+    moe_top_k=2, moe_hidden=32, moe_routing="sigmoid_dropless",
+    moe_shared_experts=1,
+)
+
+SPLIT = ("first_refill", "refill_step")
+HANDOFF = ("kv_export", "kv_ingest")
+TIER = ("kv_page_spill", "kv_page_fill")
+BASE = {
+    "first_refill": "first_prefill", "refill_step": "prefill",
+    "decode_block": "decode_step", "decode_block_spec": "decode_step",
+}
+
+
+def _expect(spec, pool, mixed, *, paged=False, prefix=False, latent=False):
+    """``(families in table order, {family: fn.__name__}, families that
+    compile_counts lists before any dispatch)`` for one mode."""
+    decode = ("decode_block_spec", "decode_block") if spec else ("decode_block",)
+    fams = [*SPLIT, *decode]
+    names = {f: f for f in fams}
+    steady = [*SPLIT, decode[0]]
+    if mixed:
+        tenant, s = "adapter_" if pool else "", "spec_" if spec else ""
+        for kind in ("mixed_step", "multi_step"):
+            fams.append(f"{tenant}{kind}")
+            names[f"{tenant}{kind}"] = f"{tenant}{s}{kind}"
+        steady.append(f"{tenant}mixed_step")
+    if not (spec or pool or paged or latent):
+        fams += HANDOFF
+    if paged and prefix and not spec:
+        fams += TIER
+    names.update({f: f for f in (*HANDOFF, *TIER) if f in fams})
+    return fams, names, steady
+
+
+def _modes():
+    """id -> (constructor keywords, the mode as ``_expect`` reads it)."""
+    modes = {}
+    for spec in (False, True):
+        for pool in (False, True):
+            for sched, kw in (
+                ("split", {}), ("mixed", {"mixed": True}),
+                ("mixed-h4", {"mixed": True, "horizon": 4}),
+            ):
+                if pool and not kw:
+                    continue        # adapter_pool requires mixed=True
+                name = "-".join([
+                    "spec" if spec else "plain", *(["pool"] if pool else []),
+                    sched,
+                ])
+                modes[name] = (
+                    dict(kw, spec=spec, pool=pool),
+                    (spec, pool, bool(kw), {}),
+                )
+    paged = dict(paged_pages=9, page_size=16)
+    modes["plain-paged"] = (paged, (False, False, False, dict(paged=True)))
+    modes["plain-paged-prefix"] = (
+        dict(paged, prefix_cache=True),
+        (False, False, False, dict(paged=True, prefix=True)),
+    )
+    modes["spec-paged-prefix-mixed"] = (
+        dict(paged, prefix_cache=True, mixed=True, spec=True),
+        (True, False, True, dict(paged=True, prefix=True)),
+    )
+    modes["latent-dropless"] = (
+        dict(paged, cfg=LATENT),
+        (False, False, False, dict(paged=True, latent=True)),
+    )
+    return modes
+
+
+MODES = _modes()
+
+
+@pytest.fixture(scope="module")
+def mesh():
+    return build_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+
+
+@pytest.fixture(scope="module")
+def tiny_params():
+    return nn.meta.unbox(
+        Transformer(TINY).init(
+            {"params": jax.random.key(0)}, np.zeros((2, 8), np.int32)
+        )["params"]
+    )
+
+
+@pytest.fixture
+def make_engine(mesh, tiny_params):
+    def build(kw, **more):
+        kw = dict(kw, **more)
+        cfg = kw.pop("cfg", TINY)
+        if kw.pop("spec", False):
+            kw["draft_config"] = DRAFT
+        if kw.pop("pool", False):
+            kw["adapter_pool"] = AdapterPool(tiny_params, slots=2, rank=2)
+        return ContinuousEngine(
+            cfg, mesh, RULES_TP_SERVING, batch_size=2, max_new_tokens=4,
+            refill_chunk=8, decode_block_steps=2, **kw,
+        )
+
+    return build
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_table_holds_the_modes_families_under_their_names(make_engine, mode):
+    kw, (spec, pool, mixed, more) = MODES[mode]
+    fams, names, steady = _expect(spec, pool, mixed, **more)
+    eng = make_engine(kw)
+    assert list(eng._programs) == fams
+    assert {f: p.fn.__name__ for f, p in eng._programs.items()} == names
+    assert all(p.family == f for f, p in eng._programs.items())
+    # One fused step and one scanned step at most.
+    assert sum(f.endswith("mixed_step") for f in eng._programs) <= 1
+    assert sum(f.endswith("multi_step") for f in eng._programs) <= 1
+    # Nothing has dispatched: only the steady programs are listed, no
+    # slot is filled, nothing is relowerable.
+    assert list(eng.compile_counts()) == steady
+    assert set(eng.compile_counts().values()) == {0}
+    assert all(p.last_args is None for p in eng._programs.values())
+    assert eng._dispatched_programs() == []
+    with pytest.raises(KeyError):
+        eng.program("no_such_program")
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_contract_names_are_the_goldens(make_engine, mode):
+    kw, (spec, *_rest) = MODES[mode]
+    eng = make_engine(kw)
+    for fam in eng._programs:
+        base = BASE.get(fam, fam)
+        want = (
+            f"spec_{base}" if spec and fam != "decode_block" else base
+        )
+        assert eng.contract_name(fam) == want
+        assert (GOLDEN_DIR / f"{want}.json").is_file(), want
+
+
+@pytest.mark.parametrize(
+    "mode", [m for m in MODES if "latent" not in m]
+)
+@pytest.mark.parametrize("collectives", [False, True])
+def test_contract_names_under_comm_compression(make_engine, mode, collectives):
+    kw, (spec, _pool, mixed, _more) = MODES[mode]
+    if collectives and not mixed:
+        with pytest.raises(ValueError, match="requires mixed=True"):
+            make_engine(kw, comm_compression=True)
+        return
+    eng = make_engine(
+        kw, comm_compression=CommCompression(collectives=collectives)
+    )
+    for fam, prog in eng._programs.items():
+        base = BASE.get(fam, fam)
+        if not prog.applies:
+            want = f"{base}_q8"         # the KV codec's regime, not spec's
+        else:
+            q8 = f"{base}_q8" if collectives else base
+            want = f"spec_{q8}" if spec and fam != "decode_block" else q8
+        assert eng.contract_name(fam) == want
+    # A drift trip turns the collective codec off: the apply programs
+    # contract under their plain names again, the KV programs stay _q8.
+    eng._comp.enabled = False
+    for fam, prog in eng._programs.items():
+        if prog.applies:
+            assert "_q8" not in eng.contract_name(fam)
+
+
+def test_no_kv_codec_leaves_the_kv_programs_plain(make_engine):
+    eng = make_engine(
+        dict(paged_pages=9, page_size=16, prefix_cache=True),
+        comm_compression=CommCompression(collectives=False, kv_codec=None),
+    )
+    assert eng.contract_name("kv_page_spill") == "kv_page_spill"
+    assert eng.contract_name("kv_page_fill") == "kv_page_fill"
+
+
+def test_every_jitted_name_is_one_the_trace_metrics_can_match():
+    """No built function may be called ``step`` (the trainer's module is
+    ``jit_step``) or carry a factory's inner name (``program``, ``run``,
+    ``counted``): XLA's module name is ``jit_<__name__>``."""
+    seen = set()
+    for spec in (None, object()):
+        for pool in (False, True):
+            for moe in (False, True):
+                table = build_programs(
+                    object(), spec, adapter=pool, mixed=True, paged=moe,
+                    prefix_cache=moe and spec is None, moe_counted=moe,
+                    max_new_tokens=4, decode_block_steps=2,
+                )
+                seen |= {p.fn.__name__ for p in table.values()}
+    table = build_programs(object(), max_new_tokens=4, decode_block_steps=2)
+    seen |= {p.fn.__name__ for p in table.values()}
+    assert seen == {
+        "first_refill", "refill_step", "decode_block", "decode_block_spec",
+        "mixed_step", "spec_mixed_step", "adapter_mixed_step",
+        "adapter_spec_mixed_step", "multi_step", "spec_multi_step",
+        "adapter_multi_step", "adapter_spec_multi_step", "kv_export",
+        "kv_ingest", "kv_page_spill", "kv_page_fill",
+    }
+
+
+def test_two_engines_share_no_executable_cache(make_engine):
+    """A jit cache is keyed by its function: every table is built from
+    fresh functions, so one engine's compiles (and a compression trip's
+    ``clear_cache``) never show in another's ``compile_counts``."""
+    a, b = make_engine({}), make_engine({})
+    a.program("kv_export").fn({"x": jnp.zeros((2, 3))}, jnp.int32(0))
+    assert cache_size(a.program("kv_export").fn) == 1
+    assert cache_size(b.program("kv_export").fn) == 0

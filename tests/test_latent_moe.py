@@ -33,10 +33,8 @@ from learning_jax_sharding_tpu.models.decoding import (  # noqa: E402
     make_cached_apply,
 )
 from learning_jax_sharding_tpu.models.moe import DroplessMoE  # noqa: E402
-from learning_jax_sharding_tpu.models.serving import (  # noqa: E402
-    ContinuousEngine,
-    _reset_rows,
-)
+from learning_jax_sharding_tpu.models.engine_programs import _reset_rows  # noqa: E402
+from learning_jax_sharding_tpu.models.serving import ContinuousEngine  # noqa: E402
 from learning_jax_sharding_tpu.models.transformer import Transformer  # noqa: E402
 from learning_jax_sharding_tpu.ops.decode_attention import decode_attention  # noqa: E402
 from learning_jax_sharding_tpu.ops.moe_experts import (  # noqa: E402

@@ -24,11 +24,11 @@ sys.path.insert(0, str(REPO))
 
 from benchmark.families import joyai_llm_flash as family  # noqa: E402
 from learning_jax_sharding_tpu.models.generate import make_generate_fn  # noqa: E402
-from learning_jax_sharding_tpu.models.serving import (  # noqa: E402
-    ContinuousEngine,
+from learning_jax_sharding_tpu.models.engine_programs import (  # noqa: E402
     _put_rows,
     _take_rows,
 )
+from learning_jax_sharding_tpu.models.serving import ContinuousEngine  # noqa: E402
 from learning_jax_sharding_tpu.models.transformer import (  # noqa: E402
     CONFIG_TINY,
     Transformer,
@@ -202,7 +202,7 @@ def test_spare_rows_go_to_the_prompt_with_fewest_chunks_left(mesh11):
     assert [r.size for r in eng._pending] == [0, 12, 4, 8]
     eng.step(params)                        # slot 0 decodes: its row is spare
     assert [r.size for r in eng._pending] == [0, 8, 0, 0]
-    args = eng._last_refill_args()
+    args = eng.program("refill_step").last_args()
     np.testing.assert_array_equal(np.asarray(args[-2]), [3, 1, 2, 3])      # rows
     np.testing.assert_array_equal(np.asarray(args[-1]), [4, 0, 0, 0])      # offsets
     assert _dispatches(eng)[-1]["chunk_rows"] == 4
@@ -275,7 +275,7 @@ def test_a_contiguous_engine_keeps_one_row_a_slot(mesh22):
     got = eng.serve(params, prompts)
     assert _chunk_rows(eng) == [4, 2, 1, 1]
     assert eng.registry.counter("engine_refill_token_slots_total").value == 4 * B * CHUNK
-    rows, offsets = eng._last_refill_args()[-2:]
+    rows, offsets = eng.program("refill_step").last_args()[-2:]
     np.testing.assert_array_equal(np.asarray(rows), np.arange(B))
     np.testing.assert_array_equal(np.asarray(offsets), np.zeros(B))
     gen = make_generate_fn(cfg, mesh22, RULES_DP_TP, max_new_tokens=NEW)

@@ -369,19 +369,19 @@ def step_programs(topo):
             )
             first = (params, None, ints(b, chunk), ints(b), ints(b), rng)
             with activate(mesh, RULES_TP_SERVING):
-                cache = on_chip(jax.eval_shape(eng._first_refill_fn, *first)[1])
+                cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
                 if mixed:
-                    compiled.append(eng._mixed_step_fn.lower(
+                    compiled.append(eng.program("mixed_step").fn.lower(
                         params, cache, ints(b, chunk), ints(b), flags,
                         ints(b), ints(b), ints(b), ints(b), ints(b), rng,
                     ))
                     continue
-                compiled.append(eng._first_refill_fn.lower(*first))
-                compiled.append(eng._refill_step_fn.lower(
+                compiled.append(eng.program("first_refill").fn.lower(*first))
+                compiled.append(eng.program("refill_step").fn.lower(
                     params, None, cache, ints(b, chunk), ints(b), flags,
                     ints(b), ints(b), rng, ints(b), ints(b),
                 ))
-                compiled.append(eng._decode_block_fn.lower(
+                compiled.append(eng.program("decode_block").fn.lower(
                     params, cache, ints(b), ints(b), ints(b), ints(b), rng,
                 ))
 
@@ -408,12 +408,12 @@ def step_programs(topo):
             )
             first = (latent_params, None, ints(b, chunk), ints(b), ints(b), rng)
             with activate(mesh, RULES_TP_SERVING):
-                cache = on_chip(jax.eval_shape(eng._first_refill_fn, *first)[1])
-                compiled.append(eng._refill_step_fn.lower(
+                cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
+                compiled.append(eng.program("refill_step").fn.lower(
                     latent_params, None, cache, ints(b, chunk), ints(b),
                     flags, ints(b), ints(b), rng, ints(b), ints(b),
                 ))
-                compiled.append(eng._decode_block_fn.lower(
+                compiled.append(eng.program("decode_block").fn.lower(
                     latent_params, cache, ints(b), ints(b), ints(b), ints(b),
                     rng,
                 ))

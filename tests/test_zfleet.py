@@ -424,7 +424,7 @@ class TestDisaggregatedHandoff:
             r.engine for r in dec
             if r.engine.registry.counter("engine_kv_ingests_total").value
         )
-        args = eng._last_kv_ingest_args()
+        args = eng.program("kv_ingest").last_args()
         rows, shardings = args[1], eng.kv_row_shardings()
         jax.tree.map(
             lambda x, s: None if x.sharding == s else pytest.fail(
