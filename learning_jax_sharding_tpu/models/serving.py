@@ -87,7 +87,7 @@ import dataclasses
 import functools
 import hashlib
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Optional
 
 import jax
@@ -1115,8 +1115,12 @@ class ContinuousEngine:
             "device ahead of an enqueue")
         self._c_table_leaves = r.counter(
             "engine_table_push_leaves_total",
-            "block_table arrays pushed from the host (one per layer per "
-            "push)")
+            "block_table leaves of the cache tree that pushes installed "
+            "a fresh table in (every layer's, each push)")
+        self._c_table_arrays = r.counter(
+            "engine_table_push_arrays_total",
+            "host-to-device arrays the table pushes made (one per distinct "
+            "leaf width a push; the leaves of a width share it)")
         self._c_prefill_tok = r.counter(
             "engine_prefill_tokens_total",
             "prompt tokens consumed by refill dispatches")
@@ -1205,7 +1209,8 @@ class ContinuousEngine:
     # engine.page_alloc         page_alloc  page_alloc  _ensure when it claims
     #                                                   pages (no ring event)
     # engine.h2d                sched       h2d         the block-table push
-    #                                                   (argument leaves), the
+    #                                                   (arguments leaves,
+    #                                                   arrays), the
     #                                                   jnp.asarray of inputs
     # engine.enqueue.<family>   device      enqueue     the jitted call alone
     #                           / compile
@@ -1241,6 +1246,7 @@ class ContinuousEngine:
     _DISPATCH_DELTAS = {
         "enqueue_s": "_c_enqueue_s", "wait_s": "_c_wait_s",
         "h2d_s": "_c_h2d_s", "table_leaves": "_c_table_leaves",
+        "table_arrays": "_c_table_arrays",
         "prefill_tokens": "_c_prefill_tok",
         "token_slots": "_c_refill_slots", "chunk_rows": "_c_chunk_rows",
         "decode_steps": "_c_decode_steps",
@@ -1344,7 +1350,7 @@ class ContinuousEngine:
         t_cap = self._cfg.max_seq_len // self._page_size
         self._table_np = np.zeros((b, t_cap), np.int32)
         self._tables_dirty = True
-        self._table_leaves = None      # block_table arrays in the cache tree
+        self._table_widths = None      # width -> block_table leaves of it
         # Prefix-cache state (the metrics registry is the separate,
         # public ``self.registry``): page-aligned token-prefix bytes →
         # the page holding that prefix's LAST page of K/V; refcounts for pages
@@ -1619,41 +1625,52 @@ class ContinuousEngine:
         self._update_high_water()
 
     def _set_tables(self, cache, frame=True):
-        # Push the host tables into every layer's block_table leaf
-        # (target AND draft trees; the draft's table may be narrower —
-        # same prefix, same page ids). Skipped entirely when no
-        # allocation changed since the last push — the steady-state
+        # Push the host table to the device ONCE per distinct leaf width
+        # and install that one array in every block_table leaf of the
+        # width (target AND draft trees; the draft's table may be
+        # narrower — same prefix, same page ids — so a speculative
+        # engine pushes one or two arrays, every other engine one). The
+        # cost of a push is per array (allocate, linearize, transfer),
+        # not per byte. Sharing one buffer under many arguments is legal
+        # because no step program donates its cache: the PR that brings
+        # donation (ROADMAP S3b) must keep the tables out of the donated
+        # tree — a buffer cannot be donated twice. Skipped entirely when
+        # no allocation changed since the last push — the steady-state
         # decode loop mostly doesn't allocate. The push is the frame
         # ``engine.h2d``; ``frame=False`` is for a caller outside step().
         if not self._tables_dirty:
             return cache
         self._tables_dirty = False
-        table_np = self._table_np
 
         def is_table(path):
             return getattr(path[-1], "key", None) == "block_table"
 
-        def leaf(path, x):
-            if is_table(path):
-                # .copy(): the full-width slice is a contiguous view and
-                # jnp.asarray may alias it zero-copy — the host table is
-                # mutated in place by later allocations/releases.
-                return jnp.asarray(table_np[:, : x.shape[1]].copy())
-            return x
-
-        if self._table_leaves is None:
-            # One array per layer (target and draft): counted once, the
-            # cache's tree never changes shape.
-            self._table_leaves = sum(
-                is_table(path)
-                for path, _ in jax.tree_util.tree_flatten_with_path(cache)[0]
+        if self._table_widths is None:
+            # Counted once, the cache's tree never changes shape: the
+            # width of every table leaf (one leaf a layer).
+            self._table_widths = Counter(
+                x.shape[1]
+                for path, x in jax.tree_util.tree_flatten_with_path(cache)[0]
+                if is_table(path)
             )
-        self._c_table_leaves.inc(self._table_leaves)
+        leaves, arrays = self._table_widths.total(), len(self._table_widths)
+        self._c_table_leaves.inc(leaves)
+        self._c_table_arrays.inc(arrays)
         with (
-            self._led_h2d(leaves=self._table_leaves) if frame
+            self._led_h2d(leaves=leaves, arrays=arrays) if frame
             else contextlib.nullcontext()
         ):
-            return jax.tree_util.tree_map_with_path(leaf, cache)
+            # .copy(): the full-width slice is a contiguous view and
+            # jnp.asarray may alias it zero-copy — the host table is
+            # mutated in place by later allocations/releases.
+            pushed = {
+                width: jnp.asarray(self._table_np[:, :width].copy())
+                for width in self._table_widths
+            }
+            return jax.tree_util.tree_map_with_path(
+                lambda path, x: pushed[x.shape[1]] if is_table(path) else x,
+                cache,
+            )
 
     # --- request lifecycle -------------------------------------------------
 
@@ -4383,7 +4400,7 @@ class ContinuousEngine:
         telemetry that degraded it.
 
         One ``engine.dispatch`` flight-recorder event per dispatch: its
-        seconds, leaves and tokens are the growth of the cumulative
+        seconds, leaves, arrays and tokens are the growth of the cumulative
         counters since the previous event; ``starved_s`` is the
         empty-device time that ended at this dispatch's enqueue."""
         dt = time.perf_counter() - t0

@@ -10,7 +10,8 @@ Its input is a post-mortem bundle, ``engine.dump_diagnostics(outdir)``:
 * per program family, from the ``engine.dispatch`` events: dispatches
   (those that compiled apart), seconds waiting on the chip, empty-chip
   seconds that ended at the enqueue, ``engine.h2d`` and
-  ``engine.enqueue`` seconds, block-table pushes and leaves a push,
+  ``engine.enqueue`` seconds, block-table pushes with the leaves and the
+  host-to-device arrays of a push,
   prompt tokens, decode row-steps and the cached tokens they read;
 * from the registry, cumulative since the engine was built (set-up's
   compiles included): ``engine_device_starved_seconds_total`` by span,
@@ -54,6 +55,9 @@ MOE_SUMMED = ("moe_assignments", "expert_reads")
 #: Fields of a refill dispatch since PR 30: the token slots the program ran
 #: (batch x refill_chunk a dispatch) and the rows that carried a chunk.
 REFILL_SUMMED = ("token_slots", "chunk_rows")
+#: Field of a table push since PR 32: the host-to-device arrays it made (one
+#: per distinct leaf width), which every ``table_leaves`` leaf shares.
+PUSH_SUMMED = ("table_arrays",)
 MOE = "engine_moe_"
 #: Gaps shorter than this are launch latency between ops, not the host.
 SMALL_GAP_NS = 20_000.0
@@ -85,7 +89,7 @@ def by_family(dispatches: list[dict]) -> dict[str, dict]:
         row["pushes"] += bool(e["table_leaves"])
         for key in SUMMED:
             row[key] += e[key]
-        for key in MOE_SUMMED + REFILL_SUMMED:
+        for key in MOE_SUMMED + REFILL_SUMMED + PUSH_SUMMED:
             if key in e:
                 row[key] += e[key]
     return {family: dict(row) for family, row in out.items()}
@@ -268,6 +272,8 @@ def _print_families(rows: dict[str, dict]) -> None:
             f"{r['pushes']:.0f} table pushes"
             + (f" of {r['table_leaves'] / r['pushes']:.0f} leaves"
                if r["pushes"] else "")
+            + (f" in {r['table_arrays'] / r['pushes']:.0f} arrays"
+               if r.get("table_arrays") else "")
             + f"; {r['prefill_tokens']:.0f} prompt tokens"
             + (f" in {r['chunk_rows'] / n:.1f} chunk rows a dispatch, fill "
                f"{100 * r['prefill_tokens'] / r['token_slots']:.1f} % of "

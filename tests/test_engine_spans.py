@@ -271,9 +271,9 @@ def test_ensure_cache_opens_no_frame():
     report = eng.ledger.window_report()
     assert report["steps"] == 0 and report["busy_s"] == 0
     assert not eng.tracer.events
-    assert eng.registry.snapshot()["engine_table_push_leaves_total"] == (
-        cfg.num_layers
-    )
+    snap = eng.registry.snapshot()
+    assert snap["engine_table_push_leaves_total"] == cfg.num_layers
+    assert snap["engine_table_push_arrays_total"] == 1
 
 
 def test_a_disabled_tracer_costs_no_span_and_keeps_the_books():
@@ -397,8 +397,8 @@ def test_one_dispatch_event_per_dispatch(served):
         assert set(e) >= {
             "family", "phase", "step", "rows", "prefill_tokens",
             "decode_steps", "context_tokens", "starved_s", "enqueue_s",
-            "wait_s", "h2d_s", "table_leaves", "compiled", "token_slots",
-            "chunk_rows",
+            "wait_s", "h2d_s", "table_leaves", "table_arrays", "compiled",
+            "token_slots", "chunk_rows",
         }
         assert e["family"] == {
             "refill": "refill_step", "decode": "decode_block"
@@ -437,9 +437,28 @@ def test_dispatch_events_account_for_every_token(served):
     assert _delta(served, "engine_decode_context_tokens_total") == context
     # (the first event's share began before the window did)
     assert 0 < sum(e["starved_s"] for e in evs[1:]) <= _delta(served, STARVED)
+
+
+@pytest.mark.parametrize("engine", ["served", "served_moe"])
+def test_table_pushes_add_up(engine, request):
+    """A push installs its table in every layer's leaf and sends it to
+    the device once (one leaf width): the ``engine.dispatch`` events, the
+    ``engine.h2d`` spans of the pushes and the two counters agree."""
+    got = request.getfixturevalue(engine)
+    evs, layers = got["dispatches"], got["eng"]._cfg.num_layers
     leaves = sum(e["table_leaves"] for e in evs)
-    assert leaves == _delta(served, "engine_table_push_leaves_total")
-    assert leaves % served["eng"]._cfg.num_layers == 0 and leaves > 0
+    arrays = sum(e["table_arrays"] for e in evs)
+    assert leaves == _delta(got, "engine_table_push_leaves_total")
+    assert arrays == _delta(got, "engine_table_push_arrays_total")
+    assert leaves == layers * arrays > 0
+    assert all(e["table_leaves"] == layers * e["table_arrays"] for e in evs)
+    if "events" in got:             # the fixture that kept its window's ring
+        pushes = [
+            e["args"] for e in got["events"]
+            if e["name"] == "engine.h2d" and "leaves" in e["args"]
+        ]
+        assert sum(a["arrays"] for a in pushes) == arrays
+        assert sum(a["leaves"] for a in pushes) == leaves
 
 
 # --- (d) the spans reach the profiler's host timeline --------------------------
@@ -480,7 +499,7 @@ def test_profiler_capture_holds_the_engine_spans(tmp_path):
     for stats in host["engine.step"]:
         # One clock: the annotation carries the tracer's own timestamp.
         assert float(stats["ts_us"]) == pytest.approx(ring[int(stats["step"])])
-    assert any("leaves" in stats for stats in host["engine.h2d"])
+    assert any({"leaves", "arrays"} <= set(stats) for stats in host["engine.h2d"])
     # ... and only a root span is an anchor; a page claim, which writes no
     # ring event, is on the profiler's timeline all the same.
     assert not any("ts_us" in stats for stats in host["engine.h2d"])
@@ -547,6 +566,27 @@ def test_the_breakdown_tool_places_a_bundle_on_a_capture(tmp_path, capsys):
     printed = capsys.readouterr().out
     assert "starved by span: " in printed
     assert "chunk rows a dispatch, fill " in printed
+    assert f"table pushes of {cfg.num_layers} leaves in 1 arrays" in printed
+
+
+@pytest.mark.parametrize("arrays", [None, 2.0], ids=["before_pr32", "with_arrays"])
+def test_the_breakdown_tool_reads_bundles_with_and_without_table_arrays(
+    arrays, capsys
+):
+    """``table_arrays`` came with PR 32: an older bundle's events lack it,
+    and the tool prints the leaves of a push alone."""
+    event = dict.fromkeys(engine_breakdown.SUMMED, 0.0)
+    event.update(family="decode_block", compiled=False)
+    push, none = dict(event, table_leaves=48.0), dict(event)
+    if arrays:
+        push["table_arrays"], none["table_arrays"] = arrays, 0.0
+    rows = engine_breakdown.by_family([push, none])
+    assert rows["decode_block"]["pushes"] == 1
+    assert rows["decode_block"].get("table_arrays") == arrays
+    engine_breakdown._print_families(rows)
+    printed = capsys.readouterr().out
+    assert "1 table pushes of 48 leaves" in printed
+    assert (" leaves in 2 arrays" in printed) == bool(arrays)
 
 
 def test_the_breakdown_tool_names_an_idle_gap_by_its_engine_span():

@@ -4,8 +4,10 @@ does not carry all of the engine's tests. The oracle is test_serving's:
 scheduling and paging never change results."""
 
 
+import collections
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -81,19 +83,104 @@ class TestPagedKVCache:
         assert hw_long >= 2 * 2       # 2 slots × >=2 pages mid-flight
         assert hw_long > hw_short
 
-    def test_paged_speculative_matches(self, setup, mesh22):
+    @staticmethod
+    def _draft(max_seq_len):
+        """The draft cut to ``max_seq_len`` positions: a shorter draft
+        holds a narrower block table."""
+        dcfg = dataclasses.replace(
+            DRAFT_CFG, decode_attention="blocked", max_seq_len=max_seq_len
+        )
+        d_params = dict(_draft_params())
+        d_params["pos_embed"] = d_params["pos_embed"][:max_seq_len]
+        return dcfg, d_params
+
+    @pytest.mark.parametrize("draft_len", [64, 32], ids=["draft_table_as_wide", "narrower_draft_table"])
+    def test_paged_speculative_matches(self, setup, mesh22, draft_len):
+        """The draft's block table holds the same prefix of the same page
+        ids, narrower where its ``max_seq_len`` is shorter: a push sends
+        one array a width, and the emitted tokens do not change."""
         cfg, params, prompts = setup
         cfg = dataclasses.replace(cfg, decode_attention="blocked")
-        dcfg = dataclasses.replace(DRAFT_CFG, decode_attention="blocked")
+        dcfg, d_params = self._draft(draft_len)
         plain = self._engine(cfg, mesh22)
         paged_spec = self._engine(
             cfg, mesh22, paged_pages=9, page_size=self.PAGE,
             draft_config=dcfg, num_draft=2,
         )
         ref = plain(params, prompts)
-        got = paged_spec(params, prompts, draft_params=_draft_params())
+        got = paged_spec(params, prompts, draft_params=d_params)
         for r, g in zip(ref, got):
             np.testing.assert_array_equal(g, r)
+        snap = paged_spec.engine.registry.snapshot()
+        pushes = snap["engine_table_push_arrays_total"] / (1 + (draft_len < 64))
+        assert pushes >= 1 and snap["engine_table_push_leaves_total"] == (
+            pushes * (cfg.num_layers + dcfg.num_layers)
+        )
+
+    #: engine kind -> (configuration changes, engine arguments, the
+    #: draft's ``max_seq_len`` or None without one).
+    PUSH_KINDS = {
+        "split": ({}, {}, None),
+        "mixed": ({}, dict(mixed=True), None),
+        "int8_kv": (dict(kv_cache_dtype=jnp.int8), {}, None),
+        "speculative": ({}, dict(num_draft=2), 64),
+        "speculative_narrower_draft": ({}, dict(num_draft=2), 32),
+    }
+
+    @pytest.mark.parametrize("kind", PUSH_KINDS)
+    def test_a_push_sends_one_array_a_width(self, setup, mesh22, kind):
+        """The dirty host table reaches the device once per distinct leaf
+        width, whatever the layer count; every ``block_table`` leaf of a
+        width IS that one array; and it is a copy: the host table, which
+        later allocations and releases mutate in place, cannot reach it."""
+        cfg, params, _ = setup
+        cfg_kw, kw, draft_len = self.PUSH_KINDS[kind]
+        cfg = dataclasses.replace(cfg, decode_attention="blocked", **cfg_kw)
+        d_params = None
+        if draft_len:
+            dcfg, d_params = self._draft(draft_len)
+            kw = dict(kw, draft_config=dcfg)
+        eng = self._engine(
+            cfg, mesh22, paged_pages=9, page_size=self.PAGE, **kw
+        ).engine
+        eng.ensure_cache(params, d_params)          # the first push
+
+        def tables():
+            return [
+                x for path, x in
+                jax.tree_util.tree_flatten_with_path(eng._cache)[0]
+                if getattr(path[-1], "key", None) == "block_table"
+            ]
+
+        widths = collections.Counter(
+            [cfg.max_seq_len // self.PAGE] * cfg.num_layers
+            + ([draft_len // self.PAGE] * DRAFT_CFG.num_layers if draft_len else [])
+        )
+        before = eng.registry.snapshot()
+        eng._ensure(0, 2 * self.PAGE + 1)           # three pages: dirty
+        eng._ensure(1, 1)
+        assert eng._tables_dirty
+        eng._cache = eng._set_tables(eng._cache, frame=False)
+        after = eng.registry.snapshot()
+        assert {
+            k: after[f"engine_table_push_{k}_total"]
+            - before[f"engine_table_push_{k}_total"]
+            for k in ("arrays", "leaves")
+        } == {"arrays": len(widths), "leaves": widths.total()}
+        pushed = tables()
+        assert collections.Counter(t.shape[1] for t in pushed) == widths
+        assert len({id(t) for t in pushed}) == len(widths)
+        held = eng._table_np.copy()
+        assert np.count_nonzero(held) == 4          # page 0 is scratch
+        for t in pushed:
+            assert t.dtype == jnp.int32
+            np.testing.assert_array_equal(t, held[:, : t.shape[1]])
+        # The host table moves on; what was pushed stays what it was.
+        eng._release(0)
+        eng._ensure(1, 3 * self.PAGE)
+        assert not np.array_equal(eng._table_np, held)
+        for t in pushed:
+            np.testing.assert_array_equal(t, held[:, : t.shape[1]])
 
     def test_paged_int8_kv_matches_unpaged(self, setup, mesh22):
         """Paged pools carry the int8 KV scales in page-shaped pools of
