@@ -156,6 +156,105 @@ def config_from_hf_joyai_llm_flash(hf_config: Any, **overrides) -> TransformerCo
     return TransformerConfig(**defaults)
 
 
+def config_from_hf_nemotron_h(hf_config: Any, **overrides) -> TransformerConfig:
+    """TransformerConfig for a ``nemotron_h`` ``config.json`` (NVIDIA
+    Nemotron-H / Nemotron-3: ONE mixer a layer by
+    ``hybrid_override_pattern`` — ``M`` Mamba-2, ``E`` latent relu^2
+    experts under sigmoid routing with a shared expert, ``*`` grouped-query
+    attention without positional encoding).
+
+    Refuses what the program does not compute rather than approximate it:
+    a pattern character it does not build (``-``, the family's dense MLP
+    layer), grouped top-k, unnormalised pick weights, biases other than
+    the convolution's, a sliding window, tied embeddings, an expansion
+    that is not ``mamba_num_heads x mamba_head_dim``, another activation
+    than ``relu2`` / ``silu``. ``rope_theta`` and ``partial_rotary_factor``
+    are in the file and the family's attention reads neither. The
+    multi-token-prediction module (``num_nextn_predict_layers``) is not
+    built, whatever the key says. ``chunk_size`` is the chunked scan's
+    tile; ``time_step_*`` and ``rescale_prenorm_residual`` act at
+    initialisation only.
+    """
+    from learning_jax_sharding_tpu.models.transformer import MIXER_KINDS
+
+    c = hf_config
+    pattern = c.hybrid_override_pattern
+    if "-" in pattern:
+        raise ValueError(
+            "hybrid_override_pattern holds '-', the nemotron_h dense MLP "
+            "layer: the program builds 'M' (Mamba-2), 'E' (experts) and '*' "
+            "(attention) layers only"
+        )
+    unknown = sorted(set(pattern) - set(MIXER_KINDS))
+    if unknown:
+        raise ValueError(
+            f"hybrid_override_pattern holds unknown layer kinds {unknown} "
+            f"(known: {MIXER_KINDS})"
+        )
+    unsupported = {
+        "hybrid_override_pattern/num_hidden_layers":
+            len(pattern) != c.num_hidden_layers,
+        "mlp_hidden_act": c.mlp_hidden_act != "relu2",
+        "mamba_hidden_act": c.mamba_hidden_act != "silu",
+        "n_group/topk_group": (c.n_group, c.topk_group) != (1, 1),
+        "norm_topk_prob": not c.norm_topk_prob,
+        "attention_bias/mlp_bias/use_bias/mamba_proj_bias": bool(
+            c.attention_bias or c.mlp_bias or c.use_bias or c.mamba_proj_bias
+        ),
+        "use_conv_bias": not c.use_conv_bias,
+        "sliding_window": c.sliding_window is not None,
+        "tie_word_embeddings": bool(c.tie_word_embeddings),
+        "expand": c.expand * c.hidden_size != c.mamba_num_heads * c.mamba_head_dim,
+        "moe_shared_expert_overlap": bool(c.moe_shared_expert_overlap),
+        "n_shared_experts": c.n_shared_experts != 1,
+    }
+    bad = sorted(k for k, v in unsupported.items() if v)
+    if bad:
+        raise ValueError(
+            f"unsupported nemotron_h settings: {bad} (the program computes "
+            "relu2 experts under sigmoid routing in one group with one "
+            "shared expert, silu Mamba-2 with a convolution bias and no "
+            "other, full causal attention, an untied head)"
+        )
+    import jax.numpy as jnp
+
+    defaults = dict(
+        vocab_size=c.vocab_size,
+        num_layers=c.num_hidden_layers,
+        layer_pattern=pattern,
+        features=c.hidden_size,
+        num_heads=c.num_attention_heads,
+        num_kv_heads=c.num_key_value_heads,
+        head_dim=c.head_dim,
+        hidden=c.intermediate_size,
+        max_seq_len=c.max_position_embeddings,
+        use_bias=False,
+        norm="rmsnorm",
+        norm_eps=c.layer_norm_epsilon,
+        no_positions=True,
+        causal=True,
+        ssm_heads=c.mamba_num_heads,
+        ssm_head_dim=c.mamba_head_dim,
+        ssm_groups=c.n_groups,
+        ssm_state_size=c.ssm_state_size,
+        ssm_conv_kernel=c.conv_kernel,
+        ssm_chunk=c.chunk_size,
+        num_experts=c.n_routed_experts,
+        moe_top_k=c.num_experts_per_tok,
+        moe_routing="sigmoid_dropless",
+        moe_hidden=c.moe_intermediate_size,
+        moe_expert_act="relu2",
+        moe_latent=c.moe_latent_size,
+        moe_shared_experts=c.n_shared_experts,
+        moe_shared_hidden=c.moe_shared_expert_intermediate_size,
+        moe_routed_scaling=float(c.routed_scaling_factor),
+        dtype=jnp.float32,
+        param_dtype=jnp.float32,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
 def params_from_hf_gpt2(hf_model: Any) -> dict:
     """Map a ``transformers.GPT2LMHeadModel`` state dict onto this
     framework's ``Transformer`` param tree (plain numpy leaves — shard with

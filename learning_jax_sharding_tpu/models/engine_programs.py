@@ -52,6 +52,19 @@ _PAGE_LEAF_KEYS = ("cached_kv", "key_scale", "value_scale")
 #: (``_take_rows``).
 _SLOT_COUNTER_KEYS = ("cache_index", "position")
 
+#: The second kind of state in the cache tree: a Mamba-2 layer's recurrent
+#: state and its convolution's last inputs (``models/ssm.py``), fixed-size
+#: and BY SLOT (leading dim = slots, never a page). Admission zeroes them
+#: (``_reset_rows``); a refill dispatch's chunk row takes its slot's
+#: (``_take_rows``) and the slot keeps what its LAST row leaves
+#: (``_put_rows``); an idle or decoding slot's stay as they are.
+_SLOT_STATE_KEYS = ("ssm_state", "conv_state")
+
+#: Per chunk row, the row of the SAME dispatch whose final state it starts
+#: from (-1: its slot's own): what a recurrent layer needs of ``rows`` and
+#: ``offsets``. At rest (decode, a contiguous cache) every entry is -1.
+_ROW_CARRY_KEY = "carry_from"
+
 
 @dataclasses.dataclass
 class Program:
@@ -95,16 +108,35 @@ def _reset_rows(
     speculative rollback relies on, ``models/speculative.py::_rollback``)."""
 
     def leaf(path, x):
-        if getattr(path[-1], "key", None) in _SLOT_COUNTER_KEYS:
+        key = getattr(path[-1], "key", None)
+        if key in _SLOT_COUNTER_KEYS:
             v = (
                 jnp.zeros_like(x)
                 if values is None
                 else jnp.broadcast_to(values.astype(x.dtype), x.shape)
             )
             return jnp.where(mask, v, x)
+        if key in _SLOT_STATE_KEYS:
+            # A new request starts from a zero recurrent state (an engine
+            # with such layers admits at index 0 only: no prefix reuse).
+            return jnp.where(mask.reshape((-1,) + (1,) * (x.ndim - 1)), 0, x)
         return x
 
     return jax.tree_util.tree_map_with_path(leaf, cache)
+
+
+def _row_chain(rows: jax.Array, offsets: jax.Array):
+    """How a dispatch's chunk rows follow one another: ``(carry_from,
+    is_last)``. Row ``r``'s predecessor is the row of the same slot with
+    the largest smaller offset (-1: none, it starts from the slot's own
+    state); ``is_last`` marks the row of each slot no other row follows. A
+    row nobody uses is its own slot's only row (``rows[r] = r``, offset 0)."""
+    same = rows[:, None] == rows[None, :]
+    earlier = same & (offsets[None, :] < offsets[:, None])
+    at = jnp.argmax(jnp.where(earlier, offsets[None, :], -1), axis=1)
+    carry_from = jnp.where(jnp.any(earlier, axis=1), at, -1).astype(jnp.int32)
+    is_last = ~jnp.any(same & (offsets[None, :] > offsets[:, None]), axis=1)
+    return carry_from, is_last
 
 
 def _take_rows(cache: Any, rows: jax.Array, offsets: jax.Array) -> Any:
@@ -114,31 +146,48 @@ def _take_rows(cache: Any, rows: jax.Array, offsets: jax.Array) -> Any:
     counters moved on by the offset. Page pools (and ``moe_stats``) are
     shared by all rows and pass through: every layer writes its chunk into
     the pool before it attends through the table, so a row reads in each
-    layer what an earlier row of the same slot wrote in that layer."""
+    layer what an earlier row of the same slot wrote in that layer. A
+    recurrent layer has no pool: each row takes its slot's state
+    (``_SLOT_STATE_KEYS``) and the layer is told which row of this dispatch
+    it continues (``carry_from``), so that it starts from that row's final
+    state in the same layer instead."""
+    chain = functools.cache(lambda: _row_chain(rows, offsets))
 
     def leaf(path, x):
         key = getattr(path[-1], "key", None)
-        if key == "block_table":
+        if key == "block_table" or key in _SLOT_STATE_KEYS:
             return x[rows]
         if key in _SLOT_COUNTER_KEYS:
             return x[rows] + offsets.astype(x.dtype)
+        if key == _ROW_CARRY_KEY:
+            return chain()[0]
         return x
 
     return jax.tree_util.tree_map_with_path(leaf, cache)
 
 
-def _put_rows(cache: Any, row_cache: Any, rows: jax.Array) -> Any:
+def _put_rows(
+    cache: Any, row_cache: Any, rows: jax.Array, offsets: jax.Array
+) -> Any:
     """Fold the chunk rows' cache back into per-SLOT state: a slot's
     counters become the furthest any of its rows reached (a row with no
-    tokens reaches where its slot already was), the tables go back as they
-    came, everything else (pools, ``moe_stats``) is the call's."""
+    tokens reaches where its slot already was), its recurrent state what
+    its LAST row left (a slot without a row keeps its own), the tables and
+    the resting ``carry_from`` go back as they came, everything else
+    (pools, ``moe_stats``) is the call's."""
+    # One writer a slot; the other rows aim past the end and are dropped.
+    last_rows = functools.cache(
+        lambda: jnp.where(_row_chain(rows, offsets)[1], rows, rows.shape[0])
+    )
 
     def leaf(path, old, new):
         key = getattr(path[-1], "key", None)
-        if key == "block_table":
+        if key in ("block_table", _ROW_CARRY_KEY):
             return old
         if key in _SLOT_COUNTER_KEYS:
             return old.at[rows].max(new)
+        if key in _SLOT_STATE_KEYS:
+            return old.at[last_rows()].set(new, mode="drop")
         return new
 
     return jax.tree_util.tree_map_with_path(leaf, cache, row_cache)
@@ -237,7 +286,7 @@ class _Bodies:
 
     apply: Callable
     d_apply: Callable | None     # the draft's; None = not speculative
-    latent: bool
+    head_on_last: bool   # refill runs the head on a row's last position only
     temperature: float
     top_k: int | None
     top_p: float | None
@@ -310,7 +359,7 @@ class _Bodies:
             logits, t_cache = self.apply(params, t_cache, chunk, lengths)
             _, d_cache = self.d_apply(d_params, d_cache, chunk, lengths)
             cache = (t_cache, d_cache)
-        elif self.latent:
+        elif self.head_on_last:
             # The head on each row's last valid position only: at this
             # family's vocabulary (129,280) the chunk's full (B, S, V)
             # logits are 2.1 GB in float32 and 2.2 TFLOP a dispatch.
@@ -345,7 +394,7 @@ class _Bodies:
             params, d_params, _take_rows(cache, rows, offsets), chunk,
             lengths, rid[rows], rng,
         )
-        return tok, _put_rows(cache, out, rows)
+        return tok, _put_rows(cache, out, rows, offsets)
 
     def first_refill(self, params, d_params, chunk, lengths, rid, rng):
         # Cache creation needs an apply without a cache; same program shape
@@ -882,7 +931,8 @@ def _kv_programs():
 
 
 def build_programs(
-    apply, d_apply=None, *, adapter=False, latent=False, moe_counted=False,
+    apply, d_apply=None, *, adapter=False, head_on_last=False, kv_rows=True,
+    moe_counted=False,
     mixed=False, paged=False, prefix_cache=False, temperature=0.0,
     top_k=None, top_p=None, min_p=None, vocab_limit=None, max_new_tokens,
     eos_id=None, decode_block_steps, num_draft=4,
@@ -890,11 +940,14 @@ def build_programs(
     """The program table of one engine mode, in the order every report
     lists it. ``apply`` / ``d_apply`` are the target's and the draft's
     ``make_cached_apply`` (a draft makes the engine speculative);
-    ``adapter``: a multi-LoRA pool rides the fused programs; ``latent`` /
-    ``moe_counted``: what the config's attention caches and whether its
-    expert layers count; ``mixed`` / ``paged`` / ``prefix_cache`` decide
-    which families the scheduler, the handoff and the tier ladder can
-    reach; the rest is what the bodies close over.
+    ``adapter``: a multi-LoRA pool rides the fused programs;
+    ``head_on_last``: refill runs the head on each row's last valid
+    position only (latent-attention and one-mixer-a-layer configs);
+    ``kv_rows``: every cache leaf is contiguous ``(B, L, N_kv, H)`` K,V
+    rows, which the disaggregated hand-off can move; ``moe_counted``:
+    whether the expert layers count; ``mixed`` / ``paged`` /
+    ``prefix_cache`` decide which families the scheduler, the handoff and
+    the tier ladder can reach; the rest is what the bodies close over.
 
     The split programs are in every table (``compile_counts`` has always
     listed them, though a multi-LoRA engine's scheduler reaches only
@@ -902,7 +955,8 @@ def build_programs(
     horizon scan: speculation picks the body, the pool picks the apply."""
     speculative = d_apply is not None
     b = _Bodies(
-        apply=apply, d_apply=d_apply, latent=latent, temperature=temperature,
+        apply=apply, d_apply=d_apply, head_on_last=head_on_last,
+        temperature=temperature,
         top_k=top_k, top_p=top_p, min_p=min_p, vocab_limit=vocab_limit,
         max_new_tokens=max_new_tokens, eos_id=eos_id,
         decode_block_steps=decode_block_steps, num_draft=num_draft,
@@ -940,7 +994,7 @@ def build_programs(
             apply, adapter,
         ), steady=False)
     kv_export, kv_ingest, kv_page_spill, kv_page_fill = _kv_programs()
-    if not (latent or speculative or paged or adapter):
+    if kv_rows and not (speculative or paged or adapter):
         # The disaggregated handoff moves contiguous (B, L, N_kv, H) rows.
         add("kv_export", jax.jit(kv_export), steady=False, applies=False)
         add("kv_ingest", jax.jit(kv_ingest), steady=False, applies=False)

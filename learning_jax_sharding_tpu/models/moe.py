@@ -319,16 +319,45 @@ class DroplessMoE(nn.Module):
     do not): the others are routed nowhere, read no expert and get the
     shared expert's output only (which their row discards).
 
+    Variants (the ``nemotron_h`` family uses all three):
+
+    * ``held=(first, count)``: this chip HOLDS experts ``[first, first +
+      count)`` of ``num_experts`` — its share of an expert-parallel group.
+      The router keeps ``num_experts`` outputs and the weights their
+      renormalisation over all picks; the expert matrices have ``count``
+      rows; a pick outside the range is computed nowhere here (another
+      chip adds it: what comes back is this chip's PART of the routed sum,
+      and on one chip, without the exchange, that part is what goes on).
+      ``None``: every expert is held;
+    * ``gated=False``: ``E(x) = relu(x W_u)^2 W_d`` (no ``gate``), for the
+      routed experts and the shared expert alike;
+    * ``latent > 0``: the routed experts live in a latent of that width::
+
+          y = (sum_i w_i E_i(x W_down_latent)) W_up_latent + E_shared(x)
+
+    Initialisers: the gated form's expert tensors take ``kernel_init`` as
+    it is (``lecun_normal`` counts the expert axis of ``(E, in, out)`` into
+    the fan-in: a routed sum far below the shared expert's output, as
+    ``joyai-llm-flash``'s cell was measured); the ungated form's take each
+    expert's OWN fan-in, ``down`` times ``expert_init_scale`` (a benchmark
+    lowers it where its float32 reference cannot follow the program's
+    picks: a bf16 flip of one pick then moves the output by that much
+    less). ``centred_down``: the down projections (routed and shared) have
+    no gain for the mean of what they read (``transformer.zero_mean``).
+
     Parameters: ``router/kernel`` ``(M, E)``, ``bias`` ``(E,)`` (the
     selection bias, a leaf of this module so that its path ends in
-    ``['bias']``), ``gate`` / ``up`` ``(E, M, H)``, ``down`` ``(E, H, M)``,
-    and ``shared/{gate,up,down}/kernel`` of width ``shared_experts * H``.
-    All in ``param_dtype``; router scores, bias add, top-k and weights run
-    in float32 whatever the compute dtype.
+    ``['bias']``), ``gate`` / ``up`` ``(E_held, W, H)``, ``down``
+    ``(E_held, H, W)`` (``W`` = ``latent`` or ``M``), ``latent_down`` /
+    ``latent_up`` kernels, and ``shared/{gate,up,down}/kernel`` of width
+    ``shared_hidden`` (default ``shared_experts * H``). All in
+    ``param_dtype``; router scores, bias add, top-k and weights run in
+    float32 whatever the compute dtype.
 
     ``count``: keep cumulative ``moe_stats`` ``(3,)`` int32 in the
-    ``"cache"`` collection (assignments routed, experts read, layer-steps):
-    the serving engine returns their growth with each dispatch's readback.
+    ``"cache"`` collection (assignments routed to held experts, held
+    experts read, layer-steps): the serving engine returns their growth
+    with each dispatch's readback.
     """
 
     features: int
@@ -336,7 +365,13 @@ class DroplessMoE(nn.Module):
     num_experts: int
     top_k: int
     shared_experts: int = 0
+    shared_hidden: int | None = None
     routed_scaling: float = 1.0
+    held: tuple | None = None
+    gated: bool = True
+    latent: int = 0
+    expert_init_scale: float = 1.0
+    centred_down: bool = False
     experts: str = "auto"
     count: bool = False
     dtype: jnp.dtype = jnp.float32
@@ -347,7 +382,10 @@ class DroplessMoE(nn.Module):
 
     @nn.compact
     def __call__(self, x: jax.Array, *, valid: jax.Array | None = None) -> jax.Array:
-        from learning_jax_sharding_tpu.models.transformer import FeedForward
+        from learning_jax_sharding_tpu.models.transformer import (
+            FeedForward,
+            zero_mean,
+        )
         from learning_jax_sharding_tpu.ops.moe_experts import routed_experts
 
         if not 1 <= self.top_k <= self.num_experts:
@@ -383,21 +421,54 @@ class DroplessMoE(nn.Module):
                 jnp.sum(picked, axis=-1, keepdims=True) + 1e-20
             )
 
-        def experts(name, shape, axes):
+        def experts(name, shape, axes, init=self.kernel_init):
             return self.param(
-                name, nn.with_logical_partitioning(self.kernel_init, axes),
+                name, nn.with_logical_partitioning(init, axes),
                 shape, self.param_dtype,
             )
 
-        w_gate = experts("gate", (e, m, self.hidden), (EXPERT, EMBED, MLP))
-        w_up = experts("up", (e, m, self.hidden), (EXPERT, EMBED, MLP))
-        w_down = experts("down", (e, self.hidden, m), (EXPERT, MLP, EMBED))
-        out, stats = routed_experts(
-            x.reshape(t, m).astype(self.dtype), idx, weights, w_gate, w_up,
-            w_down, valid=None if valid is None else valid.reshape(t),
-            backend=self.experts,
+        first, n_held = self.held or (None, e)
+        if self.held and not 0 <= first <= first + n_held <= e:
+            raise ValueError(f"held={self.held} is not a range of {e} experts")
+
+        def latent_proj(features, axes, name):
+            return nn.Dense(
+                features, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                kernel_init=nn.with_logical_partitioning(self.kernel_init, axes),
+                name=name,
+            )
+
+        xe, w = x.astype(self.dtype), self.latent or m
+        if self.latent:
+            with jax.named_scope("moe.latent_down"):
+                xe = latent_proj(w, (EMBED, None), "latent_down")(xe)
+        w_gate = None
+        up_init = down_init = self.kernel_init
+        if self.gated:
+            w_gate = experts("gate", (n_held, w, self.hidden), (EXPERT, EMBED, MLP))
+        else:
+            up_init, down_init = (
+                nn.initializers.variance_scaling(
+                    scale, "fan_in", "truncated_normal", batch_axis=(0,)
+                )
+                for scale in (1.0, self.expert_init_scale ** 2)
+            )
+        if self.centred_down:
+            down_init = zero_mean(down_init, 1)
+        w_up = experts("up", (n_held, w, self.hidden), (EXPERT, EMBED, MLP), up_init)
+        w_down = experts(
+            "down", (n_held, self.hidden, w), (EXPERT, MLP, EMBED), down_init
         )
-        out = out.reshape(b, s, m)
+        out, stats = routed_experts(
+            xe.reshape(t, w), idx, weights, w_gate, w_up,
+            w_down, valid=None if valid is None else valid.reshape(t),
+            backend=self.experts, first=first,
+        )
+        out = out.reshape(b, s, w)
+        if self.latent:
+            with jax.named_scope("moe.latent_up"):
+                out = latent_proj(m, (None, EMBED), "latent_up")(out)
         if self.count:
             seen = self.variable(
                 "cache", "moe_stats", jnp.zeros, (3,), jnp.int32
@@ -406,8 +477,13 @@ class DroplessMoE(nn.Module):
         if self.shared_experts:
             with jax.named_scope("moe.shared"):
                 out = out + FeedForward(
-                    features=m, hidden=self.shared_experts * self.hidden,
-                    gated=True, dtype=self.dtype, param_dtype=self.param_dtype,
+                    features=m,
+                    hidden=self.shared_hidden or self.shared_experts * self.hidden,
+                    gated=self.gated, activation="gelu" if self.gated else "relu2",
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    down_init=(
+                        zero_mean(self.kernel_init, 0) if self.centred_down else None
+                    ),
                     name="shared",
                 )(x)
         return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))

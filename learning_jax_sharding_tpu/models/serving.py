@@ -105,6 +105,7 @@ from learning_jax_sharding_tpu.models.decoding import (
 from learning_jax_sharding_tpu.models.attention import resolve_decode_backend
 from learning_jax_sharding_tpu.models.engine_programs import (
     _PAGE_LEAF_KEYS,
+    _SLOT_STATE_KEYS,
     Program,
     build_drift_probe,
     build_programs,
@@ -610,40 +611,58 @@ class ContinuousEngine:
                     "probes at fused-dispatch granularity"
                 )
 
-        # A latent-attention config caches ONE row [c_kv | k_rope] a token
-        # and a dropless expert layer reports its counts with the split
-        # programs' readbacks: what has not been taught either yet is
-        # refused here, by name, never served wrong.
+        # A latent-attention config caches ONE row [c_kv | k_rope] a token,
+        # a dropless expert layer reports its counts with the split
+        # programs' readbacks, and a state-space layer keeps a fixed-size
+        # recurrent state a slot beside the pages: what has not been taught
+        # each of them yet is refused here, by name, never served wrong.
         latent = bool(config.latent_kv_rank)
         moe_counted = (
             config.num_experts > 0 and config.moe_routing == "sigmoid_dropless"
         )
-        if latent or moe_counted:
-            what = "latent attention" if latent else "dropless experts"
+        ssm = "M" in (config.layer_pattern or "")
+        # A reason a kind: the first kind the config has names the refusal.
+        kinds = {
+            "state-space layers": ssm, "latent attention": latent,
+            "dropless experts": moe_counted,
+        }
+        if any(kinds.values()):
+            what = next(k for k, has in kinds.items() if has)
             refused = {
                 "draft_config (speculative decoding)": (
-                    speculative, "rollback and the draft's lockstep cache "
-                    "assume K and V per head",
+                    speculative,
+                    "a rejected draft rewinds cache_index, and a recurrent "
+                    "state has no rollback (no snapshot to return to)"
+                    if ssm else
+                    "rollback and the draft's lockstep cache assume K and V "
+                    "per head",
                 ),
                 "prefix_cache": (
-                    prefix_cache, "page copies and the registry were written "
-                    "for (P, N_kv, page, 2H) pools and are untested over "
-                    "latent rows",
+                    prefix_cache,
+                    "a shared prefix page holds K and V only: the recurrent "
+                    "state at the end of the prefix would have to be "
+                    "snapshotted with it, and no snapshot exists yet"
+                    if ssm else
+                    "page copies and the registry were written for (P, "
+                    "N_kv, page, 2H) pools and are untested over latent rows",
                 ),
                 "mixed / horizon": (
                     mixed, "the fused step families do not return the expert "
-                    "counters or skip idle rows' latent attention",
+                    "counters, skip idle rows' latent attention or carry a "
+                    "recurrent state from one chunk row to the next",
                 ),
                 "adapter_pool": (adapter_pool is not None, "it needs mixed=True"),
                 "comm_compression": (comp is not None, "it needs mixed=True"),
                 "dequantize": (
-                    bool(dequantize), "the latent projections and the expert "
-                    "matrices have no quantized form",
+                    bool(dequantize), "the latent and state-space projections "
+                    "and the expert matrices have no quantized form",
                 ),
                 "a mesh of more than one device": (
                     mesh.size > 1, "the latent row is one shared head (nothing "
-                    "to split over KV heads) and the expert kernel is not "
-                    "sharded over experts",
+                    "to split over KV heads), a recurrent state has no "
+                    "sharded scan, and an expert layer computes the experts "
+                    "it HOLDS (moe_held) without the exchange that gathers "
+                    "the other chips' parts",
                 ),
             }
             for name, (asked, why) in refused.items():
@@ -651,6 +670,12 @@ class ContinuousEngine:
                     raise ValueError(
                         f"{name} is not supported with {what}: {why}"
                     )
+        if ssm and paged and 1 < refill_chunk < config.ssm_conv_kernel - 1:
+            raise ValueError(
+                f"refill_chunk ({refill_chunk}) must cover the convolution's "
+                f"{config.ssm_conv_kernel - 1} cached inputs: a chunk row "
+                "that continues another starts from that row's last inputs"
+            )
 
         def check_paged(name, c):
             # ONE copy of the paged preconditions, applied to the target and
@@ -793,6 +818,7 @@ class ContinuousEngine:
         self._base_budget: int | None = None
         self._latent = latent
         self._moe_counted = moe_counted
+        self._ssm = ssm
         self._paged = paged
         self._paged_pages = paged_pages
         self._page_size = page_size
@@ -803,7 +829,11 @@ class ContinuousEngine:
         # (family -> Program): every report of "which programs exist"
         # maps over it.
         self._programs = build_programs(
-            apply, d_apply, adapter=adapter_pool is not None, latent=latent,
+            # A one-mixer-a-layer config refills as a latent one does: the
+            # head on one position a row, and no contiguous rows to hand off.
+            apply, d_apply, adapter=adapter_pool is not None,
+            head_on_last=latent or config.layer_pattern is not None,
+            kv_rows=not (latent or config.layer_pattern is not None),
             moe_counted=moe_counted, mixed=bool(mixed), paged=paged,
             prefix_cache=prefix_cache, temperature=temperature, top_k=top_k,
             top_p=top_p, min_p=min_p, vocab_limit=vocab_limit,
@@ -1132,6 +1162,20 @@ class ContinuousEngine:
         self._c_chunk_rows = r.counter(
             "engine_refill_chunk_rows_total",
             "rows of refill dispatches that carried prompt tokens")
+        # A state-space config only (models/ssm.py): the second kind of
+        # state in the cache tree, by slot.
+        self._c_ssm_carried = r.counter(
+            "engine_ssm_carried_rows_total",
+            "refill chunk rows whose recurrent state started from an "
+            "earlier row of the same dispatch (a long prompt's further "
+            "chunks), not from the slot's cached state")
+        self._c_ssm_resets = r.counter(
+            "engine_ssm_state_resets_total",
+            "slot admissions that zeroed a recurrent state")
+        self._g_ssm_bytes = r.gauge(
+            "engine_ssm_state_bytes",
+            "bytes of recurrent and convolution state the cache holds (all "
+            "slots, all state-space layers)")
         self._c_decode_steps = r.counter(
             "engine_decode_steps_total",
             "decode row-steps advanced (tokens emitted after the first)")
@@ -1249,6 +1293,7 @@ class ContinuousEngine:
         "table_arrays": "_c_table_arrays",
         "prefill_tokens": "_c_prefill_tok",
         "token_slots": "_c_refill_slots", "chunk_rows": "_c_chunk_rows",
+        "carried_rows": "_c_ssm_carried",
         "decode_steps": "_c_decode_steps",
         "context_tokens": "_c_decode_ctx",
     }
@@ -2066,6 +2111,12 @@ class ContinuousEngine:
     # --- disaggregated prefill/decode handoff (round 11) -------------------
 
     def _check_handoff_supported(self, what: str):
+        if self._ssm:
+            raise ValueError(
+                f"{what}: engines with state-space layers are not supported "
+                "— the transfer plans move K and V rows, and a request's "
+                "recurrent state would have to travel with them"
+            )
         if self._latent:
             raise ValueError(
                 f"{what}: latent-attention engines are not supported — the "
@@ -2127,6 +2178,14 @@ class ContinuousEngine:
     def _book_cache_creation(self, first_args):
         self.cache_creations += 1
         self._c_creations.inc()
+        if self._ssm:
+            self._g_ssm_bytes.set(sum(
+                x.nbytes
+                for path, x in jax.tree_util.tree_flatten_with_path(
+                    self._cache
+                )[0]
+                if getattr(path[-1], "key", None) in _SLOT_STATE_KEYS
+            ))
         self.recorder.record("engine.cache_create", n=self.cache_creations)
         self._programs["first_refill"].last_args = lambda: first_args
 
@@ -2380,6 +2439,12 @@ class ContinuousEngine:
         return True
 
     def _check_tier_supported(self, what: str):
+        if self._ssm:
+            raise ValueError(
+                f"{what}: engines with state-space layers are not tiered — "
+                "a spilled page holds K and V alone, and the recurrent "
+                "state that goes with a prefix has no snapshot to spill"
+            )
         if not (self._paged and self._prefix):
             raise RuntimeError(
                 f"{what} requires a paged engine with prefix_cache=True"
@@ -3225,6 +3290,7 @@ class ContinuousEngine:
             # The dispatch has its own copy of the admission resets, so
             # consume the flags (every flagged slot had pending tokens and
             # therefore rode this dispatch).
+            n_reset = int(self._needs_reset.sum())
             self._needs_reset[:] = False
             self._reset_to[:] = 0
             # Advance the host-side pending views NOW (later dispatches in
@@ -3239,6 +3305,9 @@ class ContinuousEngine:
             self._c_prefill_tok.inc(sum(took.values()))
             self._c_refill_slots.inc(chunk.size)
             self._c_chunk_rows.inc(len(firsts) + len(extra))
+            if self._ssm:
+                self._c_ssm_carried.inc(len(extra))
+                self._c_ssm_resets.inc(n_reset)
         if not segs:
             return False
         for i, (tok_new, seg_completes, last_row, seg_fam, moe) in enumerate(

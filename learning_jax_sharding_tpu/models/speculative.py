@@ -189,6 +189,12 @@ def make_speculative_generate_fn(
         )
     if num_draft < 1:
         raise ValueError(f"num_draft must be >= 1, got {num_draft}")
+    for name, c in (("target", target_config), ("draft", draft_config)):
+        if "M" in (c.layer_pattern or ""):
+            raise ValueError(
+                f"the {name} config has state-space layers: a rejected draft "
+                "rewinds cache_index, and a recurrent state has no rollback"
+            )
 
     t_cfg = derive_decode_config(target_config, inference_dtype, mesh=mesh, rules=rules)
     d_cfg = derive_decode_config(draft_config, inference_dtype, mesh=mesh, rules=rules)

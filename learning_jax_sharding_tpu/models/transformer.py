@@ -25,7 +25,10 @@ Block kinds (a config picks one attention and, per layer, one feed-forward):
 * feed-forward: two matrices with GELU (GPT-2), or three with a SiLU gate
   (``ff_gated``); or an expert layer from ``models/moe.py`` when
   ``num_experts > 0`` — in every block, or from block ``first_k_dense`` on
-  (JoyAI-LLM-Flash: one dense layer, then expert layers).
+  (JoyAI-LLM-Flash: one dense layer, then expert layers);
+* or, with ``layer_pattern``, ONE mixer a layer (:class:`MixerBlock`,
+  ``x + Mixer(norm(x))``): ``M`` a Mamba-2 layer (``models/ssm.py``), ``E``
+  an expert layer, ``*`` attention (the ``nemotron_h`` family).
 
 Everything is dtype-parameterized: bf16 compute / fp32 params is the TPU MXU
 sweet spot and the default for benchmarks.
@@ -129,6 +132,21 @@ class _CompressedDense(nn.Module):
         return y
 
 
+def zero_mean(init: Callable, axis: int) -> Callable:
+    """``init`` with its mean over ``axis`` (the input axis) removed: a
+    projection that follows a non-negative or biased activation (relu^2, a
+    silu-gated norm) then has no gain for that activation's MEAN, which a
+    plain initialiser sends to every token as the SAME vector. A trained
+    model has no such common mode; :class:`MixerBlock` gives seeded weights
+    none this way (PERF.md, PR 33)."""
+
+    def centred(key, shape, dtype=jnp.float32):
+        w = init(key, shape, jnp.float32)
+        return (w - w.mean(axis, keepdims=True)).astype(dtype)
+
+    return centred
+
+
 class FeedForward(nn.Module):
     """Position-wise FF: up-project → GELU → down-project; with ``gated``
     the three-matrix SiLU form ``(silu(x W_gate) * x W_up) W_down``.
@@ -144,9 +162,12 @@ class FeedForward(nn.Module):
     hidden: int
     use_bias: bool = False
     gated: bool = False
+    activation: str = "gelu"      # the two-matrix form's: "gelu" | "relu2"
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
     kernel_init: Callable = nn.initializers.lecun_normal()
+    down_init: Optional[Callable] = None     # the down projection's own
+                                             # (None: kernel_init)
     quantization: Optional[str] = None       # "int4" → fused-kernel serving
     quantization_group: int = 128
     quantized_matmul_fn: Optional[Callable] = None
@@ -155,7 +176,7 @@ class FeedForward(nn.Module):
                                   # all-reduce site); built by
                                   # parallel.compression.make_compressed_matmul_fn
 
-    def _dense(self, features: int, kernel_axes, name: str):
+    def _dense(self, features: int, kernel_axes, name: str, kernel_init=None):
         from learning_jax_sharding_tpu.models.quantize import projection_dense
 
         return projection_dense(
@@ -165,7 +186,7 @@ class FeedForward(nn.Module):
             use_bias=self.use_bias,
             dtype=self.dtype,
             param_dtype=self.param_dtype,
-            kernel_init=self.kernel_init,
+            kernel_init=kernel_init or self.kernel_init,
             group_size=self.quantization_group,
             quantized_matmul_fn=self.quantized_matmul_fn,
             name=name,
@@ -202,8 +223,14 @@ class FeedForward(nn.Module):
         if self.gated:
             g = self._dense(self.hidden, (EMBED, MLP), "gate")(x)
             h = nn.silu(nn.with_logical_constraint(g, (BATCH, SEQ, HIDDEN))) * h
-        else:
+        elif self.activation == "relu2":
+            h = jnp.square(nn.relu(h))
+        elif self.activation == "gelu":
             h = nn.gelu(h)
+        else:
+            raise ValueError(
+                f"unknown activation {self.activation!r}: 'gelu' or 'relu2'"
+            )
         if self.comm_compress_fn is not None and self.quantization is None:
             # The down projection is the block's one all-reduce site (the
             # up projection is column-parallel, collective-free): swap in
@@ -215,12 +242,14 @@ class FeedForward(nn.Module):
                 use_bias=self.use_bias,
                 dtype=self.dtype,
                 param_dtype=self.param_dtype,
-                kernel_init=self.kernel_init,
+                kernel_init=self.down_init or self.kernel_init,
                 compress_fn=self.comm_compress_fn,
                 name="down",
             )(h)
         else:
-            out = self._dense(self.features, (MLP, EMBED), "down")(h)
+            out = self._dense(
+                self.features, (MLP, EMBED), "down", self.down_init
+            )(h)
         return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))
 
     def _use_fused_ff(self, k: int) -> bool:
@@ -231,6 +260,7 @@ class FeedForward(nn.Module):
             and self.quantized_matmul_fn is None
             and not self.use_bias
             and not self.gated
+            and self.activation == "gelu"
             and self.features == k
             and int4_ff_eligible(k, self.hidden, self.quantization_group)
         )
@@ -510,6 +540,12 @@ class TransformerBlock(nn.Module):
         return (x, None) if self.scan else x
 
 
+#: ``TransformerConfig.layer_pattern``'s characters (the ``nemotron_h``
+#: family's ``hybrid_override_pattern``; its "-", a dense MLP layer, is not
+#: built).
+MIXER_KINDS = {"M": "Mamba-2", "E": "expert layer", "*": "attention"}
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     """Model hyperparameters (the reference hard-codes its dims inline,
@@ -639,6 +675,33 @@ class TransformerConfig:
     moe_experts: str = "auto"        # dropless expert compute: "pallas"
                                      # (ops/moe_experts.py), "ragged" (sorted
                                      # XLA ragged_dot) or "auto" (pallas on TPU)
+    moe_held: Optional[tuple] = None  # (first, count): the experts THIS chip
+                                     # holds of num_experts (an expert-parallel
+                                     # group's share); the router keeps
+                                     # num_experts outputs, a pick outside the
+                                     # range is computed nowhere here
+    moe_expert_act: str = "silu_gated"  # DroplessMoE's expert form: that, or
+                                     # "relu2" (relu(x W_up)^2 W_down, no gate)
+    moe_latent: int = 0              # > 0: routed experts live in a latent of
+                                     # this width (features -> latent before
+                                     # them, latent -> features after)
+    moe_shared_hidden: Optional[int] = None  # the shared expert's width
+                                     # (None: moe_shared_experts x moe_hidden)
+    moe_expert_init_scale: float = 1.0  # the relu2 experts' down projection,
+                                     # as a multiple of each expert's own
+                                     # fan-in initialiser (initialisation only)
+    # --- one mixer a layer (MixerBlock) ------------------------------------
+    layer_pattern: Optional[str] = None  # one character a layer: "M" Mamba-2,
+                                     # "E" expert layer, "*" attention; None:
+                                     # every block is attention + feed-forward
+    no_positions: bool = False       # neither a learned table nor rotations
+                                     # (the recurrent layers carry position)
+    ssm_heads: int = 0               # Mamba-2 (models/ssm.py): heads of
+    ssm_head_dim: int = 0            # ssm_head_dim values, ssm_groups groups
+    ssm_groups: int = 1              # of B and C, a state of ssm_state_size
+    ssm_state_size: int = 0          # a (head, value), a depthwise causal
+    ssm_conv_kernel: int = 4         # convolution, the chunked scan's tile
+    ssm_chunk: int = 128
 
     def __post_init__(self):
         # Fail fast on typos; 'nothing' IS the default, so only a policy that
@@ -653,6 +716,40 @@ class TransformerConfig:
                 f"unknown moe_routing {self.moe_routing!r}: "
                 f"'softmax_capacity' or 'sigmoid_dropless'"
             )
+        if self.moe_expert_act not in ("silu_gated", "relu2"):
+            raise ValueError(
+                f"unknown moe_expert_act {self.moe_expert_act!r}: "
+                f"'silu_gated' or 'relu2'"
+            )
+        if self.no_positions and self.rope:
+            raise ValueError("no_positions and rope exclude each other")
+        if self.layer_pattern is not None:
+            unknown = sorted(set(self.layer_pattern) - set(MIXER_KINDS))
+            if unknown or len(self.layer_pattern) != self.num_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r} must hold "
+                    f"num_layers = {self.num_layers} characters of "
+                    f"{sorted(MIXER_KINDS)} ({MIXER_KINDS}); unknown: {unknown}"
+                )
+            if self.scan_layers or self.first_k_dense or self.latent_kv_rank:
+                raise ValueError(
+                    "layer_pattern builds one mixer a layer: scan_layers, "
+                    "first_k_dense and latent attention do not apply to it"
+                )
+            if "M" in self.layer_pattern and not (
+                self.ssm_heads and self.ssm_head_dim and self.ssm_state_size
+            ):
+                raise ValueError(
+                    "an 'M' layer needs ssm_heads, ssm_head_dim and "
+                    "ssm_state_size"
+                )
+            if "E" in self.layer_pattern and not (
+                self.num_experts and self.moe_routing == "sigmoid_dropless"
+            ):
+                raise ValueError(
+                    "an 'E' layer needs num_experts and "
+                    "moe_routing='sigmoid_dropless'"
+                )
         if self.first_k_dense and self.scan_layers:
             raise ValueError(
                 "first_k_dense makes the blocks differ by layer: scan_layers "
@@ -711,19 +808,27 @@ class TransformerConfig:
         token's ACTIVATED parameters (its picks, the shared experts, the
         router); latent attention its expanded form.
         """
-        matmul_params = (
-            sum(
+        if self.layer_pattern is not None:
+            # A Mamba layer's scan is linear in seq and small beside its
+            # projections: not counted.
+            blocks = sum(
+                self._mixer_params(kind, activated=True)[0]
+                for kind in self.layer_pattern
+            )
+            attn_layers = self.layer_pattern.count("*")
+        else:
+            blocks = sum(
                 self._attn_proj_params + self._ff_params(i, activated=True)
                 for i in range(self.num_layers)
             )
-            + self.features * self.vocab_size        # lm_head
-        )
+            attn_layers = self.num_layers
+        matmul_params = blocks + self.features * self.vocab_size   # lm_head
         qk_v = (
             self.qk_nope_dim + self.qk_rope_dim + self.v_head_dim
             if self.latent_kv_rank else 2 * self.head_dim
         )
         attn_per_token = (
-            2 * seq * self.num_heads * qk_v * self.num_layers
+            2 * seq * self.num_heads * qk_v * attn_layers
         ) * (0.5 if self.causal else 1.0)
         per_token = 6 * matmul_params + 3 * attn_per_token
         return float(per_token) * batch * seq
@@ -761,15 +866,46 @@ class TransformerConfig:
         held = self.moe_top_k if activated else self.num_experts
         return router + expert * (held + self.moe_shared_experts)
 
+    def _mixer_params(self, kind: str, *, activated: bool = False) -> tuple:
+        """``(matrix, vector)`` parameters of one ``layer_pattern`` layer of
+        ``kind``, its norm among the vectors; with ``activated`` the
+        matrices one token multiplies by (its picks, not the experts held)."""
+        m = self.features
+        if kind == "*":
+            return self._attn_proj_params, m
+        if kind == "M":
+            d_inner = self.ssm_heads * self.ssm_head_dim
+            conv_dim = d_inner + 2 * self.ssm_groups * self.ssm_state_size
+            return (
+                m * (d_inner + conv_dim + self.ssm_heads) + d_inner * m,
+                m + (self.ssm_conv_kernel + 1) * conv_dim
+                + 3 * self.ssm_heads + d_inner,
+            )
+        mats = 3 if self.moe_expert_act == "silu_gated" else 2
+        width, hidden = self.moe_latent or m, self.moe_hidden or self.hidden
+        held = self.moe_held[1] if self.moe_held else self.num_experts
+        shared = self.moe_shared_experts and (
+            self.moe_shared_hidden or self.moe_shared_experts * hidden
+        )
+        return (
+            m * self.num_experts + 2 * m * self.moe_latent + mats * m * shared
+            + mats * width * hidden * (self.moe_top_k if activated else held),
+            m + self.num_experts,
+        )
+
     @property
     def param_count(self) -> int:
         """Approximate parameter count (embeddings + blocks + head; a norm
-        counted as 2 vectors whichever kind it is)."""
+        counted as 2 vectors whichever kind it is; a ``layer_pattern``
+        model's is exact)."""
+        if self.layer_pattern is not None:
+            blocks = sum(sum(self._mixer_params(k)) for k in self.layer_pattern)
+            return 2 * self.vocab_size * self.features + blocks + self.features
         blocks = sum(
             self._attn_proj_params + self._ff_params(i) + 4 * self.features
             for i in range(self.num_layers)
         )
-        pos = 0 if self.rope else self.max_seq_len * self.features
+        pos = 0 if self.rope or self.no_positions else self.max_seq_len * self.features
         embed = self.vocab_size * self.features + pos
         head = self.features * self.vocab_size
         return embed + blocks + 2 * self.features + head
@@ -794,6 +930,99 @@ CONFIG_TINY = TransformerConfig(
 #: Tiny MoE variant: 4 experts, top-2 routing (expert-parallel under
 #: RULES_DP_TP_EP).
 CONFIG_TINY_MOE = dataclasses.replace(CONFIG_TINY, num_experts=4)
+
+
+class MixerBlock(nn.Module):
+    """One layer of a ``layer_pattern`` model: ``x + Mixer(norm(x))`` with
+    ONE mixer, by ``kind``: ``"M"`` :class:`~.ssm.Mamba2Mixer` (``ssm``),
+    ``"E"`` :class:`~.moe.DroplessMoE` (``moe``), ``"*"``
+    :class:`~.attention.MultiHeadAttention` (``attn``). The norm is ``ln``.
+
+    The projections that read a non-negative or biased activation (the
+    relu^2 experts' ``down``, the shared expert's, Mamba's ``out_proj`` after
+    its silu-gated norm) are initialised :func:`zero_mean`: plainly
+    initialised, relu^2's mean reached every token as the same vector, and
+    at NVIDIA-Nemotron-3-Super's widths 86-94 % of the normalised residual
+    was common to all tokens by layers 6-10 (32 tokens touched 55-111 of
+    512 experts where independent picks touch 383; PERF.md, PR 33)."""
+
+    config: TransformerConfig
+    kind: str
+
+    @nn.compact
+    def __call__(self, x, deterministic: bool = True, chunk_lengths=None):
+        cfg = self.config
+        x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
+        h = make_norm(cfg.norm, cfg.dtype, cfg.param_dtype, "ln", cfg.norm_eps)(x)
+        if self.kind == "M":
+            from learning_jax_sharding_tpu.models.ssm import Mamba2Mixer
+
+            out = Mamba2Mixer(
+                features=cfg.features,
+                num_heads=cfg.ssm_heads,
+                head_dim=cfg.ssm_head_dim,
+                groups=cfg.ssm_groups,
+                state_size=cfg.ssm_state_size,
+                conv_kernel=cfg.ssm_conv_kernel,
+                chunk=cfg.ssm_chunk,
+                norm_eps=cfg.norm_eps,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                decode=cfg.decode,
+                out_init=zero_mean(nn.initializers.lecun_normal(), 0),
+                name="ssm",
+            )(h, chunk_lengths=chunk_lengths)
+        elif self.kind == "E":
+            from learning_jax_sharding_tpu.models.moe import DroplessMoE
+
+            valid = None
+            if chunk_lengths is not None:
+                valid = jnp.arange(h.shape[1])[None, :] < chunk_lengths[:, None]
+            out = DroplessMoE(
+                features=cfg.features,
+                hidden=cfg.moe_hidden or cfg.hidden,
+                num_experts=cfg.num_experts,
+                top_k=cfg.moe_top_k,
+                shared_experts=cfg.moe_shared_experts,
+                shared_hidden=cfg.moe_shared_hidden,
+                routed_scaling=cfg.moe_routed_scaling,
+                held=cfg.moe_held,
+                gated=cfg.moe_expert_act == "silu_gated",
+                latent=cfg.moe_latent,
+                expert_init_scale=cfg.moe_expert_init_scale,
+                centred_down=True,
+                experts=cfg.moe_experts,
+                count=cfg.decode,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                name="moe",
+            )(h, valid=valid)
+        else:
+            out = MultiHeadAttention(
+                features=cfg.features,
+                num_heads=cfg.num_heads,
+                head_dim=cfg.head_dim,
+                num_kv_heads=cfg.num_kv_heads,
+                rope=cfg.rope,
+                rope_theta=cfg.rope_theta,
+                window=cfg.window,
+                causal=cfg.causal,
+                use_bias=cfg.use_bias,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                attn_fn=cfg.attn_fn,
+                decode=cfg.decode,
+                max_decode_len=cfg.max_seq_len if cfg.decode else 0,
+                kv_cache_dtype=cfg.kv_cache_dtype,
+                decode_attention=cfg.decode_attention,
+                decode_block_k=cfg.decode_block_k,
+                decode_attn_fn=cfg.decode_attn_fn,
+                decode_ragged=cfg.decode_ragged,
+                decode_paged=cfg.decode_paged,
+                decode_page_count=cfg.decode_page_count,
+                name="attn",
+            )(h, deterministic=deterministic, chunk_lengths=chunk_lengths)
+        return nn.with_logical_constraint(x + out, (BATCH, SEQ, EMBED))
 
 
 class Transformer(nn.Module):
@@ -842,10 +1071,12 @@ class Transformer(nn.Module):
             ),
             name="tok_embed",
         )
-        if cfg.rope:
+        if cfg.rope or cfg.no_positions:
             # Positions enter as rotations inside each attention layer
-            # (ops/rope.py) — no learned table, no position counter here (the
-            # per-layer KV caches track their own indices in decode mode).
+            # (ops/rope.py), or not at all (no_positions: the recurrent
+            # layers carry them) — no learned table, no position counter
+            # here (the per-layer KV caches track their own indices in
+            # decode mode).
             x = embed(tokens)
         else:
             pos_embed = self.param(
@@ -938,7 +1169,12 @@ class Transformer(nn.Module):
                 return {**block_fields, "num_experts": 0}
             return block_fields
 
-        if cfg.scan_layers:
+        if cfg.layer_pattern is not None:
+            for i, kind in enumerate(cfg.layer_pattern):
+                x = MixerBlock(cfg, kind, name=f"block_{i}")(
+                    x, deterministic, chunk_lengths
+                )
+        elif cfg.scan_layers:
             if cfg.decode:
                 raise ValueError(
                     "scan_layers does not support decode mode yet: use the "

@@ -1,5 +1,6 @@
-"""Dropless routed-expert compute: each TOUCHED expert's three matrices are
-read once, no untouched expert's at all.
+"""Dropless routed-expert compute: each TOUCHED expert's matrices (three in
+the gated form, two in the ungated) are read once, no untouched expert's at
+all.
 
 The capacity scheme of ``models/moe.py::assign_slots`` gives every expert
 ``C`` slots and multiplies by one-hot ``(T, E, C)`` tensors: every expert's
@@ -20,7 +21,11 @@ assignment would copy 9.4 MB 256 times. Here:
   so its weights are fetched once;
 * invalid tokens (a refill chunk's padding, a frozen decode row) carry the
   sentinel expert ``E``: they sort behind everything, get no tile, and
-  come back as zeros.
+  come back as zeros;
+* a chip that HOLDS a range of the experts (``held=(first, count)``: an
+  expert-parallel group's share) has weights for those alone: a pick
+  outside the range carries the sentinel too, so it is computed nowhere
+  here, reads nothing and is counted in neither assignments nor reads.
 
 :func:`routed_experts` is the whole layer-side call (plan, gather, kernel
 or plain ``ragged_dot``, combine); both backends share the plan, so the
@@ -95,6 +100,16 @@ def _kernel(te_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
     ).astype(o_ref.dtype)
 
 
+def _kernel_ungated(te_ref, x_ref, wu_ref, wd_ref, o_ref):
+    del te_ref
+    x = x_ref[...]
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    h = jnp.square(jnp.maximum(u, 0.0)).astype(x.dtype)
+    o_ref[...] = jnp.dot(
+        h, wd_ref[0], preferred_element_type=jnp.float32
+    ).astype(o_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("tm", "interpret"))
 def moe_experts(
     x_rows, tile_expert, num_tiles, w_gate, w_up, w_down, *, tm: int,
@@ -103,34 +118,34 @@ def moe_experts(
     """``silu(x W_g[e]) * (x W_u[e])) W_d[e]`` for every row tile of
     ``x_rows`` ``(M, d)`` with ``e = tile_expert[tile]``, tiles
     ``[0, num_tiles)`` only: the rows of the others come back unwritten.
-    Weights ``(E, d, f)``, ``(E, d, f)``, ``(E, f, d)``."""
+    Weights ``(E, d, f)``, ``(E, d, f)``, ``(E, f, d)``; ``w_gate`` None:
+    the ungated form ``relu(x W_u[e])^2 W_d[e]``."""
     m, d = x_rows.shape
-    e, _, f = w_gate.shape
+    e, _, f = w_up.shape
     if m % tm or tile_expert.shape != (m // tm,):
         raise ValueError(
             f"{m} rows in tiles of {tm} need tile_expert ({m // tm},), got "
             f"{tile_expert.shape}"
         )
     rows = pl.BlockSpec((tm, d), lambda i, te: (i, 0))
+    into = pl.BlockSpec((1, d, f), lambda i, te: (te[i], 0, 0))
+    out_of = pl.BlockSpec((1, f, d), lambda i, te: (te[i], 0, 0))
+    gated = w_gate is not None
     call = pl.pallas_call(
-        _kernel,
+        _kernel if gated else _kernel_ungated,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(num_tiles,),
-            in_specs=[
-                rows,
-                pl.BlockSpec((1, d, f), lambda i, te: (te[i], 0, 0)),
-                pl.BlockSpec((1, d, f), lambda i, te: (te[i], 0, 0)),
-                pl.BlockSpec((1, f, d), lambda i, te: (te[i], 0, 0)),
-            ],
+            in_specs=[rows, *([into] * (1 + gated)), out_of],
             out_specs=rows,
         ),
         out_shape=jax.ShapeDtypeStruct((m, d), x_rows.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )
+    weights = (w_gate, w_up, w_down) if gated else (w_up, w_down)
     with jax.named_scope(_TRACE_NAME):
-        return call(tile_expert, x_rows, w_gate, w_up, w_down)
+        return call(tile_expert, x_rows, *weights)
 
 
 def resolve_backend(mode: str) -> str:
@@ -147,20 +162,27 @@ def resolve_backend(mode: str) -> str:
 
 def routed_experts(
     x, idx, weights, w_gate, w_up, w_down, *, valid=None, backend="auto",
-    interpret: bool | None = None,
+    interpret: bool | None = None, first: int | None = None,
 ):
     """``out[t] = sum_k weights[t, k] * E_{idx[t, k]}(x[t])`` for ``x``
     ``(T, d)``, ``idx`` / ``weights`` ``(T, K)``; a token with ``valid``
     false is routed nowhere and comes back zero. No token is dropped.
+    With ``first`` the weights are those of experts ``[first, first + E)``
+    of a wider router: a pick outside that range adds nothing here (its
+    expert lives on another chip).
+    ``w_gate`` None: ungated experts, ``relu(x W_up)^2 W_down``.
 
     Returns ``(out (T, d) in x.dtype, stats (3,) int32)``: assignments
     routed, experts with at least one token (whose weights were read), and
     1 if anything was routed at all (a layer-step)."""
     t, d = x.shape
-    k, e = idx.shape[1], w_gate.shape[0]
+    k, e = idx.shape[1], w_up.shape[0]
     a = t * k
     backend = resolve_backend(backend)
     expert = idx.reshape(a).astype(jnp.int32)
+    if first is not None:
+        expert = expert - first
+        expert = jnp.where((expert >= 0) & (expert < e), expert, e)
     if valid is not None:
         expert = jnp.where(jnp.repeat(valid, k), expert, e)
     tm = tile_rows(a, e)
@@ -179,9 +201,12 @@ def routed_experts(
         # The same sorted runs, unpadded, through XLA's grouped matmul.
         xs = x[order // k]
         dot = functools.partial(jax.lax.ragged_dot, group_sizes=counts)
-        g = dot(xs, w_gate.astype(x.dtype), preferred_element_type=jnp.float32)
         u = dot(xs, w_up.astype(x.dtype), preferred_element_type=jnp.float32)
-        h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
+        if w_gate is None:
+            h = jnp.square(jnp.maximum(u, 0.0)).astype(x.dtype)
+        else:
+            g = dot(xs, w_gate.astype(x.dtype), preferred_element_type=jnp.float32)
+            h = (g * jax.nn.sigmoid(g) * u).astype(x.dtype)
         ys = dot(h, w_down.astype(x.dtype), preferred_element_type=jnp.float32)
         y = jnp.zeros((a, d), x.dtype).at[order].set(ys.astype(x.dtype))
     routed = (expert < e).reshape(t, k)

@@ -12,7 +12,9 @@ Its input is a post-mortem bundle, ``engine.dump_diagnostics(outdir)``:
   seconds that ended at the enqueue, ``engine.h2d`` and
   ``engine.enqueue`` seconds, block-table pushes with the leaves and the
   host-to-device arrays of a push,
-  prompt tokens, decode row-steps and the cached tokens they read;
+  prompt tokens (with the chunk rows a dispatch and, on an engine with
+  state-space layers, how many of them carried their recurrent state from
+  an earlier row), decode row-steps and the cached tokens they read;
 * from the registry, cumulative since the engine was built (set-up's
   compiles included): ``engine_device_starved_seconds_total`` by span,
   over ``engine_step_seconds_total``, with the wait, enqueue and h2d
@@ -26,7 +28,9 @@ time of the tracer's epoch, so the recorder's ``t`` maps onto profiler
 time. It then prints, for the captured interval alone: the empty-chip
 seconds the host counted beside the idle the device planes show, and
 the idle gaps by the innermost ``engine.*`` span at their middle, then
-by the innermost runtime event inside it.
+by the innermost runtime event inside it; and the device time of the
+kernels a trace names by scope (``ssm.*``, ``moe.*``, ``attn.*``), by the
+program that ran them.
 
 Usage:
     python scripts/engine_breakdown.py BUNDLE_DIR [--xplane PATH] [--json]
@@ -55,6 +59,10 @@ MOE_SUMMED = ("moe_assignments", "expert_reads")
 #: Fields of a refill dispatch since PR 30: the token slots the program ran
 #: (batch x refill_chunk a dispatch) and the rows that carried a chunk.
 REFILL_SUMMED = ("token_slots", "chunk_rows")
+#: Field of a refill dispatch since PR 33: the chunk rows whose recurrent
+#: state started from an earlier row of the same dispatch (0 on an engine
+#: without state-space layers).
+CARRY_SUMMED = ("carried_rows",)
 #: Field of a table push since PR 32: the host-to-device arrays it made (one
 #: per distinct leaf width), which every ``table_leaves`` leaf shares.
 PUSH_SUMMED = ("table_arrays",)
@@ -89,7 +97,7 @@ def by_family(dispatches: list[dict]) -> dict[str, dict]:
         row["pushes"] += bool(e["table_leaves"])
         for key in SUMMED:
             row[key] += e[key]
-        for key in MOE_SUMMED + REFILL_SUMMED + PUSH_SUMMED:
+        for key in MOE_SUMMED + REFILL_SUMMED + PUSH_SUMMED + CARRY_SUMMED:
             if key in e:
                 row[key] += e[key]
     return {family: dict(row) for family, row in out.items()}
@@ -143,6 +151,7 @@ def load_capture(path: str) -> dict:
     from jax.profiler import ProfileData
 
     engine, runtime, anchors, ops = [], [], [], []
+    kernels: dict = collections.defaultdict(lambda: [0.0, 0])
     for plane in ProfileData.from_file(_find_xplane(path)).planes:
         if plane.name.startswith("/host:CPU"):
             for line in plane.lines:
@@ -168,10 +177,40 @@ def load_capture(path: str) -> dict:
                     (float(e.start_ns), float(e.start_ns + e.duration_ns))
                     for e in line.events
                 )
+            _add_kernel_times(kernels, lines)
     return {
         "engine": [t for t in engine if t], "runtime": [t for t in runtime if t],
         "anchors": sorted(anchors), "ops": sorted(ops),
+        "kernels": {k: tuple(v) for k, v in kernels.items()},
     }
+
+
+_KERNEL = re.compile(r"^%?((?:ssm|moe|attn)\.[A-Za-z_]+(?:\.[A-Za-z_]+)*)")
+
+
+def _add_kernel_times(kernels: dict, lines: dict) -> None:
+    """Device seconds and calls of the ops a trace can name by SCOPE: the
+    Pallas kernels (``ssm.state_update``, ``ssm.chunk_scan``,
+    ``moe.experts``, ``attn.*``), whose instruction carries the scope it
+    was called under, by the program that ran them. A scope that holds
+    plain XLA ops only (``ssm.in_proj``, ``ssm.conv``, ``moe.latent_down``,
+    ``moe.latent_up``, ``moe.shared``, ``moe.route``) is in the
+    instruction's metadata, which a capture does not keep: its fusions
+    show as ``fusion.N`` and cannot be told apart here."""
+    modules = sorted(
+        (float(e.start_ns), float(e.start_ns + e.duration_ns), e.name.split("(")[0])
+        for e in (lines["XLA Modules"].events if "XLA Modules" in lines else ())
+    )
+    starts = [m[0] for m in modules]
+    for e in (lines["XLA Ops"].events if "XLA Ops" in lines else ()):
+        m = _KERNEL.match(e.name)
+        if not m:
+            continue
+        i = bisect.bisect_right(starts, float(e.start_ns)) - 1
+        module = modules[i][2] if i >= 0 and e.start_ns < modules[i][1] else "?"
+        row = kernels[f"{module} | {m.group(1)}"]
+        row[0] += e.duration_ns / 1e9
+        row[1] += 1
 
 
 def _idle_gaps(ops):
@@ -230,6 +269,9 @@ def place_on_capture(bundle: dict, capture: dict) -> dict:
     }
     if not ops:
         return out
+    out["kernel_device_s"] = dict(
+        sorted(capture.get("kernels", {}).items(), key=lambda kv: -kv[1][0])
+    )
     gaps = _idle_gaps(ops)
     out["device_idle_s"] = sum(d for _, d in gaps) / 1e9
     engine, runtime = (
@@ -279,6 +321,9 @@ def _print_families(rows: dict[str, dict]) -> None:
                f"{100 * r['prefill_tokens'] / r['token_slots']:.1f} % of "
                f"{r['token_slots'] / n:.0f} token slots"
                if r.get("token_slots") else "")
+            + (f", {r['carried_rows'] / n:.1f} of the rows carried from an "
+               f"earlier row's recurrent state"
+               if r.get("carried_rows") else "")
             + f", {r['decode_steps']:.0f} row-steps over "
             f"{r['context_tokens']:.0f} cached tokens"
             + (f"; {r['moe_assignments']:.0f} expert assignments over "
@@ -352,6 +397,11 @@ def main(argv=None) -> dict:
                if "device_idle_s" in cap else "")
         )
         _print_families(cap["by_family"])
+        for name, (seconds, calls) in cap.get("kernel_device_s", {}).items():
+            print(
+                f"  kernel: {seconds:.4f} s in {calls} calls "
+                f"({1e6 * seconds / calls:.1f} us each)  {name}"
+            )
         for key in ("idle_by_span_s", "idle_by_span_and_runtime_event_s"):
             for name, seconds in cap.get(key, {}).items():
                 print(f"  {key[:-2]}: {seconds:.4f} s  {name}")

@@ -45,6 +45,10 @@ def _rehearse(cell: str, trace: int) -> dict:
             "joyai-llm-flash.chat_backlog_2k", 1,
             {"moe_tokens_per_expert_read", "refill_host_share_pct", "refill_fill_pct"},
         ),
+        (
+            "nemotron-3-super-120b-a12b.chat_backlog_2k", 1,
+            {"moe_tokens_per_expert_read", "refill_fill_pct", "ssm_carried_rows_pct"},
+        ),
         ("gpt2-xl.chat_backlog", 0, {"serve_tok_s", "tpot_p95_ms", "setup_s"}),
         ("gpt2-xl.chat_backlog", 1, {"refill_host_share_pct", "refill_fill_pct"}),
     ],
@@ -55,7 +59,13 @@ def test_a_serving_cell_rehearses_to_a_correct_line(cell, trace, reports):
     assert "rehearsal" in line and line["device"]["platform"] == "cpu"
     assert reports <= set(line["metrics"])
     # A rehearsal never reports a device-trace metric: it has no device.
-    assert not {"mla_decode_attn_roofline", "moe_expert_roofline", "decode_attn_roofline"} & set(line["metrics"])
+    assert not {
+        "mla_decode_attn_roofline", "moe_expert_roofline", "decode_attn_roofline",
+        "ssm_state_update_roofline", "ssm_chunk_scan_roofline", "latent_moe_expert_roofline",
+    } & set(line["metrics"])
+    if "ssm_carried_rows_pct" in reports:
+        # PR 30's packing is on: long prompts' further chunks ride along.
+        assert 0.0 < line["metrics"]["ssm_carried_rows_pct"]["value"] < 100.0
     if "refill_fill_pct" in reports:
         # Prompt tokens over token slots: a share, and the dispatches carry prompts.
         assert 0.0 < line["metrics"]["refill_fill_pct"]["value"] <= 100.0

@@ -166,3 +166,54 @@ class TestGPT2Conversion:
         np.testing.assert_allclose(
             np.asarray(got, np.float32), np.asarray(full, np.float32), atol=2e-4
         )
+
+
+class TestNemotronHConfig:
+    """``config_from_hf_nemotron_h`` on the benchmark's own file (the keys of
+    the model's ``config.json``): what it maps, and what it refuses by name."""
+
+    @staticmethod
+    def _hf(**over):
+        import json
+        import pathlib
+        import types
+
+        path = pathlib.Path(__file__).resolve().parents[1] / (
+            "benchmark/configs/nemotron-3-super-120b-a12b.json"
+        )
+        keys = {k: v for k, v in json.loads(path.read_text()).items() if not isinstance(v, dict)}
+        return types.SimpleNamespace(**{**keys, **over})
+
+    def test_the_published_keys_map_onto_one_mixer_a_layer(self):
+        from learning_jax_sharding_tpu.models.convert import config_from_hf_nemotron_h
+
+        cfg = config_from_hf_nemotron_h(self._hf())
+        assert cfg.layer_pattern == "MEMEMEM*EME" and cfg.num_layers == 11
+        assert cfg.no_positions and not cfg.rope and cfg.norm == "rmsnorm"
+        assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (32, 2, 128)
+        assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_size) == (128, 64, 8, 128)
+        assert (cfg.ssm_conv_kernel, cfg.ssm_chunk, cfg.norm_eps) == (4, 128, 1e-5)
+        assert (cfg.moe_top_k, cfg.moe_hidden, cfg.moe_latent, cfg.moe_shared_hidden) == (22, 2688, 1024, 5376)
+        assert cfg.moe_expert_act == "relu2" and cfg.moe_routed_scaling == 5.0
+        # The file's count is the experts HELD; the family module gives the
+        # router its published width (benchmark/families/nemotron_h.py).
+        assert cfg.num_experts == 128 and cfg.moe_held is None
+
+    @pytest.mark.parametrize(
+        "over,match",
+        [
+            (dict(hybrid_override_pattern="MEMEMEM-EME"), "dense MLP"),
+            (dict(hybrid_override_pattern="MEMEMEMXEME"), r"unknown layer kinds \['X'\]"),
+            (dict(hybrid_override_pattern="MEM"), "hybrid_override_pattern/num_hidden_layers"),
+            (dict(n_group=2), "n_group/topk_group"),
+            (dict(mlp_hidden_act="silu"), "mlp_hidden_act"),
+            (dict(sliding_window=4096), "sliding_window"),
+            (dict(use_conv_bias=False), "use_conv_bias"),
+            (dict(expand=3), "expand"),
+        ],
+    )
+    def test_what_the_program_does_not_compute_is_refused_by_name(self, over, match):
+        from learning_jax_sharding_tpu.models.convert import config_from_hf_nemotron_h
+
+        with pytest.raises(ValueError, match=match):
+            config_from_hf_nemotron_h(self._hf(**over))

@@ -45,6 +45,17 @@ LATENT = TransformerConfig(
     moe_top_k=2, moe_hidden=32, moe_routing="sigmoid_dropless",
     moe_shared_experts=1,
 )
+#: One mixer a layer (the nemotron_h family at small widths): a recurrent
+#: state a slot beside the attention layer's pages.
+RECURRENT = TransformerConfig(
+    vocab_size=128, num_layers=4, layer_pattern="ME*M", features=32,
+    num_heads=2, head_dim=16, num_kv_heads=1, hidden=64, max_seq_len=64,
+    dtype=jnp.float32, norm="rmsnorm", no_positions=True, num_experts=4,
+    moe_top_k=2, moe_hidden=32, moe_routing="sigmoid_dropless",
+    moe_shared_experts=1, moe_expert_act="relu2", moe_latent=16,
+    moe_held=(0, 2), ssm_heads=4, ssm_head_dim=8, ssm_groups=2,
+    ssm_state_size=8, ssm_chunk=8, decode_attention="blocked",
+)
 
 SPLIT = ("first_refill", "refill_step")
 HANDOFF = ("kv_export", "kv_ingest")
@@ -108,6 +119,14 @@ def _modes():
     modes["latent-dropless"] = (
         dict(paged, cfg=LATENT),
         (False, False, False, dict(paged=True, latent=True)),
+    )
+    # No contiguous rows to hand off either: the state would have to travel.
+    modes["recurrent-paged"] = (
+        dict(paged, cfg=RECURRENT),
+        (False, False, False, dict(paged=True, latent=True)),
+    )
+    modes["recurrent-contiguous"] = (
+        dict(cfg=RECURRENT), (False, False, False, dict(latent=True)),
     )
     return modes
 
@@ -181,7 +200,7 @@ def test_contract_names_are_the_goldens(make_engine, mode):
 
 
 @pytest.mark.parametrize(
-    "mode", [m for m in MODES if "latent" not in m]
+    "mode", [m for m in MODES if "latent" not in m and "recurrent" not in m]
 )
 @pytest.mark.parametrize("collectives", [False, True])
 def test_contract_names_under_comm_compression(make_engine, mode, collectives):

@@ -638,13 +638,19 @@ def _metric_files(readers):
 
 @pytest.mark.parametrize(
     "spec",
-    _metric_files({"registry", "ledger_share", "trace_roofline_counted"}),
+    _metric_files({
+        "registry", "ledger_share", "trace_roofline_counted", "trace_roofline_calls",
+    }),
 )
 def test_metric_file_reads_what_a_served_engine_has(served, served_moe, spec):
     params = spec["params"]
     if spec["reader"] == "ledger_share":
         report = served["eng"].ledger.window_report()
         assert set(params["buckets"]) <= set(report["buckets"])
+    elif spec["reader"] == "trace_roofline_calls":
+        # Rows a call: a dispatch of the family books its token slots.
+        mine = [e for e in served["dispatches"] if e["family"] == params["family"]]
+        assert mine and all(e["token_slots"] > 0 for e in mine)
     else:
         for key in ("name", "over", "count", "per"):
             if key in params:
@@ -740,3 +746,115 @@ def test_the_breakdown_tool_prints_the_expert_counts(served_moe, tmp_path, capsy
     assert out["moe"]["decode"]["tokens_per_expert_read"] >= 1.0
     printed = capsys.readouterr().out
     assert "experts, decode:" in printed and "expert assignments over" in printed
+
+
+# --- (g) state-space layers: a recurrent state a slot, carried across chunk rows ---
+
+
+@pytest.fixture(scope="module")
+def served_ssm():
+    """One mixer a layer (``ME*M``) on four paged slots: long prompts take
+    several rows of one refill dispatch, and slots are reused."""
+    cfg = TransformerConfig(
+        vocab_size=257, num_layers=4, layer_pattern="ME*M", features=64,
+        num_heads=4, head_dim=16, num_kv_heads=2, hidden=64, max_seq_len=128,
+        dtype=jnp.float32, norm="rmsnorm", norm_eps=1e-5, no_positions=True,
+        num_experts=8, moe_top_k=2, moe_hidden=32, moe_routing="sigmoid_dropless",
+        moe_shared_experts=1, moe_shared_hidden=48, moe_expert_act="relu2",
+        moe_latent=32, moe_held=(2, 4), moe_routed_scaling=5.0, ssm_heads=4,
+        ssm_head_dim=16, ssm_groups=2, ssm_state_size=16, ssm_chunk=8,
+        decode_attention="blocked",
+    )
+    mesh = build_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
+    params = nn.meta.unbox(
+        jax.jit(lambda r, t: Transformer(cfg).init({"params": r}, t))(
+            jax.random.key(3), np.zeros((2, 8), np.int32)
+        )["params"]
+    )
+    eng = ContinuousEngine(
+        cfg, mesh, RULES_TP_SERVING, batch_size=4, max_new_tokens=MAX_NEW,
+        refill_chunk=8, paged_pages=40, page_size=8,
+        recorder=FlightRecorder(max_events=100_000),
+    )
+    rng = np.random.default_rng(5)
+    prompts = [
+        rng.integers(1, cfg.vocab_size, size=(k,)).astype(np.int32)
+        for k in (29, 11, 40, 5, 17, 33)
+    ]
+    outs = _drain(eng, params, prompts)
+    return {
+        "eng": eng, "cfg": cfg, "prompts": prompts, "outs": outs, "start": {},
+        "end": eng.registry.snapshot(),
+        "dispatches": eng.recorder.events("engine.dispatch"),
+    }
+
+
+def test_state_space_counters_are_pinned_and_add_up(served_ssm):
+    end, evs = served_ssm["end"], served_ssm["dispatches"]
+    assert {
+        "engine_ssm_carried_rows_total", "engine_ssm_state_resets_total",
+        "engine_ssm_state_bytes",
+    } <= set(end)
+    assert end["engine_ssm_state_resets_total"] == len(served_ssm["prompts"])
+    # Two Mamba layers x four slots x (4 heads x 16 x 16 float32 + 3 cached
+    # convolution inputs of 64 + 2 x 2 x 16 values, float32 here).
+    assert end["engine_ssm_state_bytes"] == 2 * 4 * (4 * 16 * 16 * 4 + 3 * 128 * 4)
+    assert all("carried_rows" in e for e in evs)
+    assert sum(e["carried_rows"] for e in evs) == end["engine_ssm_carried_rows_total"] > 0
+    assert not any(e["carried_rows"] for e in evs if e["phase"] != "refill")
+    # A carried row is a chunk row, and a prompt's first chunk is never one.
+    chunks = sum(-(-len(p) // 8) for p in served_ssm["prompts"])
+    assert end["engine_refill_chunk_rows_total"] == chunks
+    assert end["engine_ssm_carried_rows_total"] <= chunks - len(served_ssm["prompts"])
+
+
+def test_expert_counters_count_held_experts_only(served_ssm):
+    cfg, prompts = served_ssm["cfg"], served_ssm["prompts"]
+    routed = cfg.moe_top_k * sum(map(len, prompts))        # one expert layer
+    held = _moe(served_ssm, "assignments", "refill")
+    assert 0 < held < routed                               # 4 of 8 experts held
+    for phase in ("refill", "decode"):
+        assert _moe(served_ssm, "expert_reads", phase) <= 4 * _moe(
+            served_ssm, "layer_steps", phase
+        )
+
+
+def test_an_engine_without_state_space_layers_carries_nothing(served):
+    assert served["end"]["engine_ssm_carried_rows_total"] == 0
+    assert served["end"]["engine_ssm_state_bytes"] == 0
+    assert not any(e["carried_rows"] for e in served["dispatches"])
+
+
+def test_the_breakdown_tool_prints_the_carried_rows(served_ssm, tmp_path, capsys):
+    served_ssm["eng"].dump_diagnostics(tmp_path)
+    out = engine_breakdown.main([str(tmp_path)])
+    rows = out["by_family"]["refill_step"]
+    assert rows["carried_rows"] > 0
+    assert "of the rows carried from an earlier row's recurrent state" in capsys.readouterr().out
+
+
+def test_the_breakdown_tool_times_the_kernels_a_trace_names_by_scope():
+    """On lines written by hand (a CPU capture has no TPU plane): the
+    kernels' instruction names carry their scope, a fusion's does not."""
+    Event = collections.namedtuple("Event", "name start_ns duration_ns")
+    Line = collections.namedtuple("Line", "events")
+    lines = {
+        "XLA Modules": Line([
+            Event("jit_decode_block(1)", 0.0, 1000.0),
+            Event("jit_refill_step(2)", 2000.0, 1000.0),
+        ]),
+        "XLA Ops": Line([
+            Event("%ssm.state_update.3 = f32[1] custom-call()", 10.0, 100.0),
+            Event("%ssm.state_update.4 = f32[1] custom-call()", 200.0, 100.0),
+            Event("%fusion.7 = f32[1] fusion()", 400.0, 500.0),
+            Event("%ssm.chunk_scan = f32[1] custom-call()", 2100.0, 300.0),
+            Event("%moe.experts.1 = f32[1] custom-call()", 2500.0, 50.0),
+        ]),
+    }
+    kernels = collections.defaultdict(lambda: [0.0, 0])
+    engine_breakdown._add_kernel_times(kernels, lines)
+    assert {k: tuple(v) for k, v in kernels.items()} == {
+        "jit_decode_block | ssm.state_update": (pytest.approx(2e-7), 2),
+        "jit_refill_step | ssm.chunk_scan": (pytest.approx(3e-7), 1),
+        "jit_refill_step | moe.experts": (pytest.approx(5e-8), 1),
+    }

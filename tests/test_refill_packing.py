@@ -304,6 +304,11 @@ def test_rows_take_their_slots_counters_and_give_back_the_furthest():
             "cache_index": jnp.asarray([5, 0, 8, 2]),
             "cached_kv": jnp.zeros((6, 1, 2, 4)),
         },
+        # A recurrent layer: a state a SLOT, and the row each row continues.
+        "ssm": {
+            "ssm_state": 10.0 * jnp.arange(4.0)[:, None] + jnp.zeros((4, 2)),
+            "carry_from": jnp.full((4,), -1),
+        },
         "position": jnp.asarray([5, 0, 8, 2]),
     }
     rows, offsets = jnp.asarray([0, 3, 2, 3]), jnp.asarray([0, 4, 0, 0])
@@ -312,11 +317,19 @@ def test_rows_take_their_slots_counters_and_give_back_the_furthest():
     np.testing.assert_array_equal(taken["position"], [5, 6, 8, 2])
     np.testing.assert_array_equal(taken["layer"]["block_table"][1], [9, 10, 11])
     assert taken["layer"]["cached_kv"] is cache["layer"]["cached_kv"]
+    # Row 1 continues row 3 (slot 3's first chunk); every row starts from
+    # its slot's state.
+    np.testing.assert_array_equal(taken["ssm"]["carry_from"], [-1, 3, -1, -1])
+    np.testing.assert_array_equal(taken["ssm"]["ssm_state"][:, 0], [0, 30, 20, 30])
     # The model advanced each row by its length: 0, 3 (slot 3's last), 0, 4.
     ran = jax.tree.map(lambda x: x, taken)
     ran["layer"]["cache_index"] = taken["layer"]["cache_index"] + jnp.asarray([0, 3, 0, 4])
     ran["layer"]["cached_kv"] = taken["layer"]["cached_kv"] + 1
-    back = _put_rows(cache, ran, rows)
+    ran["ssm"] = dict(taken["ssm"], ssm_state=jnp.arange(1.0, 5.0)[:, None] + jnp.zeros((4, 2)))
+    back = _put_rows(cache, ran, rows, offsets)
+    # Slot 3 keeps what its LAST row (row 1) left, slot 1 (no row) its own.
+    np.testing.assert_array_equal(back["ssm"]["ssm_state"][:, 0], [1, 10, 3, 2])
+    np.testing.assert_array_equal(back["ssm"]["carry_from"], -1)
     np.testing.assert_array_equal(back["layer"]["cache_index"], [5, 0, 8, 9])
     np.testing.assert_array_equal(back["position"], [5, 0, 8, 6])
     np.testing.assert_array_equal(back["layer"]["block_table"], cache["layer"]["block_table"])

@@ -17,6 +17,7 @@ this file.
 """
 
 import dataclasses
+import functools
 import json
 import pathlib
 import re
@@ -112,26 +113,28 @@ _B, _N, _H, _L = 8, 12, 64, 1024
 
 def _decode_case(
     *, s=1, page=None, fold=False, int8=False, window=None,
-    b=_B, n=_N, pool=None,
+    b=_B, n=_N, pool=None, n_kv=None, h=_H,
 ):
     """(fn, shapes) for one decode_attention variant: at the 125M widths,
-    or with ``b``/``n``/``pool`` at a cell's own."""
+    or with ``b``/``n``/``pool`` (and ``n_kv`` KV heads of ``h``) at a
+    cell's own."""
     cache_dt = I8 if int8 else BF16
+    n_q, n = n, n if n_kv is None else n_kv
     if page is None:
-        cache = ((b, n, _L, 2 * _H), cache_dt)
+        cache = ((b, n, _L, 2 * h), cache_dt)
         scales = ((b, n, _L), F32)
     else:
         pool = b * (_L // page) + 1 if pool is None else pool
-        cache = ((pool, n, page, 2 * _H), cache_dt)
+        cache = ((pool, n, page, 2 * h), cache_dt)
         scales = ((pool, n, page), F32)
     names = ["q", "kv_cache", "index"]
-    shapes = [((b, s, n, _H), BF16), cache, ((b,), I32)]
+    shapes = [((b, s, n_q, h), BF16), cache, ((b,), I32)]
     if int8:
         names += ["k_scale", "v_scale"]
         shapes += [scales, scales]
     if fold:
         names += ["kv_new"]
-        shapes += [((b, n, 1, 2 * _H), cache_dt)]
+        shapes += [((b, n, 1, 2 * h), cache_dt)]
         if int8:
             names += ["ks_new", "vs_new"]
             shapes += [((b, n, 1), F32)] * 2
@@ -230,6 +233,74 @@ def test_moe_experts(one_chip, tokens, tm):
         ((tokens,), jnp.bool_), ((e, d, f), BF16), ((e, d, f), BF16),
         ((e, f, d), BF16),
     ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+# nemotron-3-super-120b-a12b.chat_backlog_2k's engine (PR 33): 128 HELD
+# experts of a 512-wide router, ungated (relu^2) in a 1024-wide latent, top
+# 22; a decode step's 704 assignments in tiles of 16 rows, a refill
+# dispatch's 90,112 in tiles of 128.
+@pytest.mark.parametrize(
+    "tokens,tm", [(32, 16), (4096, 128)], ids=["decode32", "refill4096"]
+)
+def test_ungated_latent_experts(one_chip, tokens, tm):
+    from learning_jax_sharding_tpu.ops.moe_experts import (
+        routed_experts,
+        tile_rows,
+    )
+
+    held, d, f, k = 128, 1024, 2688, 22
+    assert tile_rows(tokens * k, held) == tm
+
+    def fn(x, idx, w, valid, w_up, w_down):
+        return routed_experts(
+            x, idx, w, None, w_up, w_down, valid=valid, backend="pallas",
+            interpret=False, first=0,
+        )
+
+    shapes = [
+        ((tokens, d), BF16), ((tokens, k), I32), ((tokens, k), F32),
+        ((tokens,), jnp.bool_), ((held, d, f), BF16), ((held, f, d), BF16),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+# The same cell's Mamba-2 layers: 128 heads x 64 in 8 groups, state 128, the
+# state a slot as (64 pairs, 128, 128) float32; 32 rows, one token or one
+# 128-token tile a row.
+def test_ssm_state_update(one_chip):
+    from learning_jax_sharding_tpu.ops.ssm_scan import state_update
+
+    b, h, p, g, n = 32, 128, 64, 8, 128
+    shapes = [
+        ((b, h // 2, n, 2 * p), F32), ((b, h), F32), ((b, h, p), F32),
+        ((b, g, n), F32), ((b, g, n), F32),
+    ]
+    assert _compiles_to_kernel(
+        functools.partial(state_update, interpret=False), one_chip, *shapes
+    ) == 1
+
+
+def test_ssm_chunk_scan(one_chip):
+    from learning_jax_sharding_tpu.ops.ssm_scan import chunk_scan
+
+    b, q, h, p, g, n = 32, 128, 128, 64, 8, 128
+    d_inner = h * p
+    fn = functools.partial(chunk_scan, d_inner=d_inner, grp=g, interpret=False)
+    shapes = [
+        ((b, q, d_inner + 2 * g * n), BF16), ((b, q, h), F32), ((b, q, h), F32),
+        ((b, h // 2, n, 2 * p), F32), ((b,), I32),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+# The same cell's one attention layer in eleven: 32 query heads on 2 KV heads
+# of 128 (k | v rows of 256), pages of 128 in a 512-page pool.
+@pytest.mark.parametrize("s", [1, 128], ids=["fold", "chunk128"])
+def test_decode_attention_gqa16_head128(one_chip, s):
+    fn, shapes = _decode_case(
+        b=32, n=32, n_kv=2, h=128, page=128, pool=512, s=s, fold=s == 1,
+    )
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
 
 
@@ -415,6 +486,42 @@ def step_programs(topo):
                 ))
                 compiled.append(eng.program("decode_block").fn.lower(
                     latent_params, cache, ints(b), ints(b), ints(b), ints(b),
+                    rng,
+                ))
+
+        # One mixer a layer (the nemotron_h family at small widths, the
+        # Mamba heads and state whole lane tiles so that "auto" picks the
+        # kernels): ``ssm.state_update`` and ``ssm.chunk_scan`` by name.
+        ssm_cfg = TransformerConfig(
+            vocab_size=512, num_layers=3, layer_pattern="ME*", features=128,
+            num_heads=2, head_dim=64, num_kv_heads=1, hidden=256,
+            max_seq_len=256, dtype=BF16, param_dtype=BF16, norm="rmsnorm",
+            no_positions=True, num_experts=8, moe_top_k=2, moe_hidden=128,
+            moe_routing="sigmoid_dropless", moe_shared_experts=1,
+            moe_expert_act="relu2", moe_latent=128, moe_held=(0, 4),
+            ssm_heads=4, ssm_head_dim=64, ssm_groups=2, ssm_state_size=128,
+            ssm_chunk=chunk,
+        )
+        ssm_params = on_chip(jax.eval_shape(lambda: nn.meta.unbox(
+            Transformer(ssm_cfg).init(
+                {"params": jax.random.key(0)}, np.zeros((2, 8), np.int32)
+            )["params"]
+        )))
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            eng = ContinuousEngine(
+                ssm_cfg, mesh, RULES_TP_SERVING, batch_size=b,
+                max_new_tokens=8, refill_chunk=chunk, paged_pages=9,
+                page_size=64, inference_dtype=BF16,
+            )
+            first = (ssm_params, None, ints(b, chunk), ints(b), ints(b), rng)
+            with activate(mesh, RULES_TP_SERVING):
+                cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
+                compiled.append(eng.program("refill_step").fn.lower(
+                    ssm_params, None, cache, ints(b, chunk), ints(b),
+                    flags, ints(b), ints(b), rng, ints(b), ints(b),
+                ))
+                compiled.append(eng.program("decode_block").fn.lower(
+                    ssm_params, cache, ints(b), ints(b), ints(b), ints(b),
                     rng,
                 ))
 
