@@ -1176,6 +1176,13 @@ class ContinuousEngine:
             "engine_ssm_state_bytes",
             "bytes of recurrent and convolution state the cache holds (all "
             "slots, all state-space layers)")
+        self._g_attn_depth = r.gauge(
+            "engine_decode_attn_pages_in_flight",
+            "cache blocks the decode-attention kernel keeps in flight over "
+            "this engine's cache layers (the smallest over them): the depth "
+            "of the loop form's DMA ring, 0 where the pipeline-emitter form "
+            "runs (ops.decode_attention.pages_in_flight: fixed when the "
+            "programs are traced, from the cache's shape and dtype)")
         self._c_decode_steps = r.counter(
             "engine_decode_steps_total",
             "decode row-steps advanced (tokens emitted after the first)")
@@ -2178,14 +2185,34 @@ class ContinuousEngine:
     def _book_cache_creation(self, first_args):
         self.cache_creations += 1
         self._c_creations.inc()
+        leaves = [
+            (getattr(path[-1], "key", None), x)
+            for path, x in jax.tree_util.tree_flatten_with_path(self._cache)[0]
+        ]
         if self._ssm:
             self._g_ssm_bytes.set(sum(
-                x.nbytes
-                for path, x in jax.tree_util.tree_flatten_with_path(
-                    self._cache
-                )[0]
-                if getattr(path[-1], "key", None) in _SLOT_STATE_KEYS
+                x.nbytes for key, x in leaves if key in _SLOT_STATE_KEYS
             ))
+        # The blocked kernel's cache layers (the dense path names its
+        # leaves otherwise), by the rule the kernel itself applies to what
+        # one device holds of them.
+        from learning_jax_sharding_tpu.ops.decode_attention import (
+            auto_block_k,
+            pages_in_flight,
+        )
+
+        def depth(x):
+            shape = x.sharding.shard_shape(x.shape)
+            return pages_in_flight(
+                shape, x.dtype,
+                shape[2] if self._paged
+                else self._cfg.decode_block_k or auto_block_k(shape[2]),
+                latent=self._latent,
+            )
+
+        self._g_attn_depth.set(min(
+            (depth(x) for key, x in leaves if key == "cached_kv"), default=0
+        ))
         self.recorder.record("engine.cache_create", n=self.cache_creations)
         self._programs["first_refill"].last_args = lambda: first_args
 

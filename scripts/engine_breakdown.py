@@ -18,7 +18,8 @@ Its input is a post-mortem bundle, ``engine.dump_diagnostics(outdir)``:
 * from the registry, cumulative since the engine was built (set-up's
   compiles included): ``engine_device_starved_seconds_total`` by span,
   over ``engine_step_seconds_total``, with the wait, enqueue and h2d
-  shares.
+  shares; and how many cache blocks the decode-attention kernel keeps in
+  flight (``engine_decode_attn_pages_in_flight``).
 
 With ``--xplane`` (a ``jax.profiler`` capture taken while that engine
 served, a file or a directory holding one) it also places the bundle's
@@ -50,6 +51,9 @@ import statistics
 
 STARVED = "engine_device_starved_seconds_total"
 STEP = "engine_step_seconds_total"
+#: Gauge since PR 34: cache blocks the decode-attention kernel keeps in
+#: flight (0 = its pipeline-emitter form, one block beside the one computed).
+ATTN_DEPTH = "engine_decode_attn_pages_in_flight"
 SUMMED = (
     "starved_s", "enqueue_s", "wait_s", "h2d_s", "table_leaves",
     "prefill_tokens", "decode_steps", "context_tokens",
@@ -359,6 +363,7 @@ def main(argv=None) -> dict:
         "by_family": by_family(bundle["dispatches"]),
         "registry": starved_by_span(bundle["registry"]),
         "moe": moe_by_phase(bundle["registry"]),
+        "decode_attn_pages_in_flight": bundle["registry"].get(ATTN_DEPTH),
     }
     if args.xplane:
         out["capture"] = place_on_capture(bundle, load_capture(args.xplane))
@@ -378,6 +383,12 @@ def main(argv=None) -> dict:
         print("  starved by span: " + ", ".join(
             f"{k} {v:.4f}" for k, v in reg["by_span_s"].items()
         ))
+    depth = out["decode_attn_pages_in_flight"]
+    if depth is not None:
+        print(
+            f"decode attention: {depth:.0f} cache blocks in flight"
+            + (" (the loop form)" if depth else " (the emitter form)")
+        )
     for phase, row in out["moe"].items():
         print(
             f"experts, {phase}: {row.get('assignments', 0):.0f} assignments, "

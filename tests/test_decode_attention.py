@@ -481,3 +481,194 @@ class TestPagedCache:
             decode_attention(
                 q, pkv, 3, block_table=table, block_k=8, interpret=True
             )
+
+
+# --- the loop form: rows are the grid, each program fetches its own blocks ---
+
+# Whole-tile shapes, where `pages_in_flight` gives the loop form a ring: fused
+# rows of 128 lanes (head 64, float32: sublane tile 8) and of 256 (head 128,
+# 32 query heads on 2 KV heads, a bfloat16 cache: tile 16).
+_LOOP_SHAPES = {
+    "h64p64": dict(h=64, page=64, pages=12, group=1, dtype=jnp.float32),
+    "h128p128g16": dict(h=128, page=128, pages=4, group=16, dtype=jnp.bfloat16),
+}
+# ``idx``: tokens each row holds before the call, in PAGES + slots so one
+# list serves both shapes (``(p, o)`` = p pages and o tokens; -1 = the last
+# slot of the table). ``wen`` / ``ren``: write_enable / row_enable.
+_LOOP_CASES = {
+    # S = 1 with the folded write: the decode step
+    "fold-more-pages-than-ring-and-fewer": dict(idx=[(1, 6), (0, 5), (3, 60)],
+                                                ring=3),
+    "fold-one-page-exactly": dict(idx=[(0, 63), (1, 0), (0, 0)]),
+    "fold-row-at-capacity": dict(idx=[-1, (1, 36)]),
+    "fold-enables-mixed": dict(idx=[(2, 2), (0, 40), (3, 10), (0, 0)],
+                               wen=[1, 0, 1, 0], ren=[1, 1, 0, 1]),
+    "fold-window": dict(idx=[(3, 50), (1, 26), (1, 0)], window=100),
+    "fold-shared-prefix-page": dict(idx=[(1, 36), (2, 2)], share=1),
+    # S = 1 already written, and chunks (whichever form their S takes)
+    "read": dict(idx=[(1, 6), (0, 5), (3, 60)], fold=False),
+    "chunk": dict(idx=[(0, 0), (1, 10), (2, 40)], s=5),
+    "chunk-tiled-window-row-off": dict(idx=[(1, 9), (0, 20), (2, 0)], s=70,
+                                       block_q=32, window=90, ren=[1, 0, 1]),
+}
+
+
+class TestLoopForm:
+    """The loop form of the kernel (``_loop_kernel``) against the plain dense
+    reference, and against the emitter form bit for bit where it writes."""
+
+    @pytest.fixture(autouse=True)
+    def _fresh_traces(self):
+        """The form is fixed when the jitted body is traced: a test that
+        patches the rule must not meet, or leave, another's trace."""
+        from learning_jax_sharding_tpu.ops import decode_attention as da
+
+        da._decode_attention.clear_cache()
+        yield
+        da._decode_attention.clear_cache()
+
+    def _emitter(self):
+        """The same call through the emitter form (for comparison only: no
+        option chooses a form, the operands do)."""
+        from unittest import mock
+
+        from learning_jax_sharding_tpu.ops import decode_attention as da
+
+        da._decode_attention.clear_cache()
+        return mock.patch.object(da, "pages_in_flight", lambda *a, **k: 0)
+
+    @pytest.mark.parametrize(
+        "case,shape,layout",
+        [(c, sh, "paged") for c in _LOOP_CASES for sh in _LOOP_SHAPES]
+        # per-row buffers: one shape, and nothing to share
+        + [(c, "h64p64", "rows") for c, v in _LOOP_CASES.items()
+           if not v.get("share")],
+    )
+    def test_matches_dense_and_emitter(self, monkeypatch, case, shape, layout):
+        from learning_jax_sharding_tpu.ops import decode_attention as da
+
+        c = dict(s=1, fold=True, window=None, wen=None, ren=None, share=0,
+                 block_q=128, ring=None)
+        c.update(_LOOP_CASES[case])
+        sh = _LOOP_SHAPES[shape]
+        h, page, T, group, dt = (
+            sh["h"], sh["page"], sh["pages"], sh["group"], sh["dtype"]
+        )
+        s, fold, n_kv = c["s"], c["fold"] and c["s"] == 1, 2
+        L = T * page
+        # (pages, slots) at this shape's page size, scaled into the table.
+        idx = np.asarray([
+            L - s if i == -1 else min(i[0] * page + i[1] * page // 64, L - s)
+            for i in c["idx"]
+        ])
+        b = len(idx)
+        rng = np.random.default_rng(11)
+        rnd = lambda *sz: np.array(
+            jnp.asarray(rng.normal(size=sz), dt), np.float32
+        )                                    # values the cache dtype holds
+        kf, vf = rnd(b, n_kv, L, h), rnd(b, n_kv, L, h)
+        wen = np.ones(b, np.int32) if c["wen"] is None else np.asarray(c["wen"])
+        ren = np.ones(b, np.int32) if c["ren"] is None else np.asarray(c["ren"])
+        paged = layout == "paged"
+        kw = dict(window=c["window"], block_q=c["block_q"], interpret=True)
+        if paged:
+            P = b * T + 1
+            table = rng.permutation(np.arange(1, P)).reshape(b, T)
+            for t in range(c["share"]):      # a full prefix page in common
+                table[1:, t] = table[0, t]
+                kf[1:, :, t*page:(t+1)*page] = kf[0, :, t*page:(t+1)*page]
+                vf[1:, :, t*page:(t+1)*page] = vf[0, :, t*page:(t+1)*page]
+            for bi in range(b):              # unallocated entries: scratch
+                table[bi, -(-(idx[bi] + s) // page):] = 0
+            cache = np.full((P, n_kv, page, 2 * h), 1e4, np.float32)
+            for bi in range(b):
+                for t in range(T):
+                    if table[bi, t]:
+                        at = np.s_[bi, :, t*page:(t+1)*page]
+                        cache[table[bi, t]] = np.concatenate([kf[at], vf[at]], -1)
+            kw.update(block_table=jnp.asarray(table, jnp.int32))
+        else:
+            cache = np.concatenate([kf, vf], -1)
+            kw.update(block_k=page)
+        cache = jnp.asarray(cache, dt)
+        depth = da.pages_in_flight(cache.shape, cache.dtype, page)
+        assert depth == 8                    # these shapes take the loop
+        if c["ring"]:
+            monkeypatch.setattr(da, "_MAX_IN_FLIGHT", c["ring"])
+        q = jnp.asarray(rng.normal(size=(b, s, n_kv * group, h)), jnp.float32)
+        if c["ren"] is not None:
+            kw.update(row_enable=jnp.asarray(ren))
+        if fold:
+            kv_new = rnd(b, n_kv, 1, 2 * h)
+            kw.update(kv_new=jnp.asarray(kv_new, dt))
+            if c["wen"] is not None:
+                kw.update(write_enable=jnp.asarray(wen))
+
+        def call():
+            with jax.default_matmul_precision("float32"):
+                return decode_attention(
+                    q, cache, jnp.asarray(idx, jnp.int32), **kw
+                )
+
+        result = call()
+        out = np.asarray(result[0] if fold else result)
+        for bi in range(b):
+            if not ren[bi]:
+                np.testing.assert_array_equal(out[bi], 0)
+                continue
+            if not wen[bi]:
+                continue                     # a frozen row's output is unused
+            kr, vr = kf[bi:bi+1].copy(), vf[bi:bi+1].copy()
+            if fold:
+                kr[0, :, idx[bi]] = kv_new[bi, :, 0, :h]
+                vr[0, :, idx[bi]] = kv_new[bi, :, 0, h:]
+            with jax.default_matmul_precision("float32"):
+                ref = _dense_oracle(
+                    q[bi:bi+1], jnp.asarray(kr), jnp.asarray(vr), int(idx[bi]),
+                    window=c["window"],
+                )
+            np.testing.assert_allclose(out[bi], np.asarray(ref)[0], atol=2e-5)
+        if not fold:
+            return
+        # The cache afterwards: the slot of every row that writes holds its
+        # token; everything else (frozen and disabled rows' pages, shared
+        # pages, scratch page 0) is bit-identical. And the emitter form
+        # leaves the same cache, bit for bit.
+        want = np.asarray(cache, np.float32).copy()
+        for bi in np.flatnonzero(wen * ren):
+            if paged:
+                want[table[bi, idx[bi] // page], :, idx[bi] % page] = kv_new[bi, :, 0]
+            else:
+                want[bi, :, idx[bi]] = kv_new[bi, :, 0]
+        np.testing.assert_array_equal(np.asarray(result[1], np.float32), want)
+        with self._emitter():
+            e_out, e_cache = call()
+        np.testing.assert_array_equal(
+            np.asarray(result[1], np.float32), np.asarray(e_cache, np.float32)
+        )
+        live = (wen * ren).astype(bool)
+        np.testing.assert_allclose(out[live], np.asarray(e_out)[live], atol=2e-5)
+
+    def test_form_rule(self):
+        """Which operands take which form, from shape and dtype alone (the
+        compiler's own alignment rule: the same answer on every backend)."""
+        from learning_jax_sharding_tpu.ops.decode_attention import pages_in_flight
+
+        bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
+        # gpt2-xl's pool: 8 x 409,600 B = 3.3 MB of ring.
+        assert pages_in_flight((96, 25, 64, 128), bf16, 64) == 8
+        # nemotron's one attention layer: 2 KV heads of 128.
+        assert pages_in_flight((512, 2, 128, 256), bf16, 128) == 8
+        # per-row buffers in blocks of 256: 12 x 256 x 128 x 2 B = 786 KB.
+        assert pages_in_flight((8, 12, 1024, 128), bf16, 256) == 5
+        # a block over half the ring: two, the emitter's own depth.
+        assert pages_in_flight((4, 64, 1024, 256), f32, 256) == 2
+        # not whole tiles: lanes, sublanes (bf16 packs 16 rows a tile).
+        assert pages_in_flight((9, 2, 8, 32), f32, 8) == 0
+        assert pages_in_flight((96, 25, 64, 64), bf16, 64) == 0
+        assert pages_in_flight((33, 2, 8, 128), bf16, 8) == 0
+        assert pages_in_flight((33, 2, 8, 128), f32, 8) == 8
+        # int8 (float32 scales of (..., N_kv, page)) and the latent row.
+        assert pages_in_flight((96, 25, 64, 128), i8, 64, quantized=True) == 0
+        assert pages_in_flight((512, 1, 128, 576), bf16, 128, latent=True) == 0
+        assert pages_in_flight((512, 1, 128, 640), bf16, 128, latent=True) == 0

@@ -825,6 +825,29 @@ def test_an_engine_without_state_space_layers_carries_nothing(served):
     assert not any(e["carried_rows"] for e in served["dispatches"])
 
 
+def test_the_decode_attention_gauge_is_the_kernels_own_rule(served, tmp_path, capsys):
+    """``engine_decode_attn_pages_in_flight``: what the kernel's rule gives
+    for the cache one device holds (heads of 16 here: rows of 32 lanes are
+    not whole tiles, so the emitter form, 0), printed by the tool."""
+    from learning_jax_sharding_tpu.ops.decode_attention import pages_in_flight
+
+    eng = served["eng"]
+    pools = [
+        x for path, x in jax.tree_util.tree_flatten_with_path(eng._cache)[0]
+        if getattr(path[-1], "key", None) == "cached_kv"
+    ]
+    assert len(pools) == CONFIG_TINY.num_layers
+    shape = pools[0].sharding.shard_shape(pools[0].shape)
+    assert shape == (12, 2, 8, 32)
+    assert served["end"]["engine_decode_attn_pages_in_flight"] == (
+        pages_in_flight(shape, pools[0].dtype, 8)
+    ) == 0
+    eng.dump_diagnostics(tmp_path)
+    out = engine_breakdown.main([str(tmp_path)])
+    assert out["decode_attn_pages_in_flight"] == 0
+    assert "0 cache blocks in flight (the emitter form)" in capsys.readouterr().out
+
+
 def test_the_breakdown_tool_prints_the_carried_rows(served_ssm, tmp_path, capsys):
     served_ssm["eng"].dump_diagnostics(tmp_path)
     out = engine_breakdown.main([str(tmp_path)])

@@ -59,6 +59,45 @@ class TestPagedKVCache:
         assert stats["page_high_water"] < 2 * (cfg.max_seq_len // self.PAGE)
         assert stats["page_high_water"] >= 1
 
+    def test_whole_tile_heads_take_the_loop_form(self, mesh22):
+        """Heads of 64 make the cache's fused k | v rows whole 128-lane
+        tiles, so every cached-attention call of the engine (refill chunks,
+        folded decode writes, frozen rows on scratch page 0) takes the
+        kernel's loop form: same tokens as the dense contiguous engine."""
+        import flax.linen as nn
+
+        from learning_jax_sharding_tpu.models.transformer import (
+            CONFIG_TINY,
+            Transformer,
+        )
+
+        cfg = dataclasses.replace(CONFIG_TINY, head_dim=64, dtype=jnp.float32)
+        params = nn.meta.unbox(
+            jax.jit(lambda r, t: Transformer(cfg).init({"params": r}, t))(
+                jax.random.key(5), np.zeros((2, 8), np.int32)
+            )["params"]
+        )
+        rng = np.random.default_rng(5)
+        prompts = [
+            rng.integers(1, cfg.vocab_size, size=(n,)).astype(np.int32)
+            for n in (3, 17, 5, 1, 30, 9)
+        ]
+        with jax.default_matmul_precision("float32"):
+            dense = self._engine(
+                dataclasses.replace(cfg, decode_attention="dense"), mesh22
+            )
+            ref = dense(params, prompts)
+            paged = self._engine(
+                dataclasses.replace(cfg, decode_attention="blocked"), mesh22,
+                paged_pages=9, page_size=self.PAGE,
+            )
+            got = paged(params, prompts)
+        for r, g in zip(ref, got):
+            np.testing.assert_array_equal(g, r)
+        gauge = "engine_decode_attn_pages_in_flight"
+        assert paged.engine.registry.snapshot()[gauge] == 8
+        assert dense.engine.registry.snapshot()[gauge] == 0  # no blocked cache
+
     def test_high_water_tracks_in_flight_tokens(self, setup, mesh22):
         """Short requests (1 page each) vs long requests (2+ pages each)
         must show different high-water marks — the footprint follows the

@@ -28,7 +28,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from learning_jax_sharding_tpu.ops.decode_attention import decode_attention
+from learning_jax_sharding_tpu.ops.decode_attention import (
+    auto_block_k,
+    decode_attention,
+    pages_in_flight,
+)
 from learning_jax_sharding_tpu.ops.flash_attention import flash_attention
 from learning_jax_sharding_tpu.ops.fused_norm import fused_residual_norm
 from learning_jax_sharding_tpu.ops.int4_ff import int4_ff
@@ -113,11 +117,12 @@ _B, _N, _H, _L = 8, 12, 64, 1024
 
 def _decode_case(
     *, s=1, page=None, fold=False, int8=False, window=None,
-    b=_B, n=_N, pool=None, n_kv=None, h=_H,
+    b=_B, n=_N, pool=None, n_kv=None, h=_H, row_enable=False,
 ):
     """(fn, shapes) for one decode_attention variant: at the 125M widths,
     or with ``b``/``n``/``pool`` (and ``n_kv`` KV heads of ``h``) at a
-    cell's own."""
+    cell's own. Which form of the kernel a case compiles is the operands'
+    to say (``_in_flight``)."""
     cache_dt = I8 if int8 else BF16
     n_q, n = n, n if n_kv is None else n_kv
     if page is None:
@@ -143,6 +148,9 @@ def _decode_case(
     if page is not None:
         names += ["block_table"]
         shapes += [((b, _L // page), I32)]
+    if row_enable:
+        names += ["row_enable"]
+        shapes += [((b,), I32)]
 
     def fn(*args):
         kw = dict(zip(names, args))
@@ -152,6 +160,15 @@ def _decode_case(
         )
 
     return fn, shapes
+
+
+def _in_flight(shapes, page=None, int8=False):
+    """Cache blocks in flight in a ``_decode_case``: the loop form's ring
+    depth, 0 = the emitter form."""
+    cache, dtype = shapes[1]
+    return pages_in_flight(
+        cache, dtype, page or auto_block_k(_L), quantized=int8
+    )
 
 
 # gpt2-xl.chat_backlog's engine: 16 slots, 25 heads x 64, pages of 64, a
@@ -173,15 +190,24 @@ _XL = dict(b=16, n=25, page=64, pool=96)
         dict(window=256),
         dict(fold=True, **_XL),
         dict(s=128, **_XL),
+        dict(fold=True, row_enable=True, **_XL),
+        dict(s=128, row_enable=True, **_XL),
     ],
     ids=[
         "per-row", "paged16", "paged64", "paged64-fold", "paged64-chunk128",
         "int8", "paged64-int8-fold", "int8-fold", "window256",
         "xl-cell-fold", "xl-cell-chunk128",
+        "xl-cell-fold-row-enable", "xl-cell-chunk128-row-enable",
     ],
 )
 def test_decode_attention(one_chip, case):
+    """bf16 caches of whole tiles compile the LOOP form (its own DMAs from a
+    cache left in HBM, eight blocks in flight at xl's 409,600 B a page), int8
+    caches the emitter form: their float32 scale arrays are not whole tiles."""
     fn, shapes = _decode_case(**case)
+    page, int8 = case.get("page"), case.get("int8", False)
+    # Per-row blocks of 256 x 12 heads: 786 KB each, five in the ring.
+    assert _in_flight(shapes, page, int8) == (0 if int8 else 8 if page else 5)
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
 
 
@@ -301,6 +327,7 @@ def test_decode_attention_gqa16_head128(one_chip, s):
     fn, shapes = _decode_case(
         b=32, n=32, n_kv=2, h=128, page=128, pool=512, s=s, fold=s == 1,
     )
+    assert _in_flight(shapes, 128) == 8      # the loop form: 131 KB a page
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
 
 
