@@ -198,6 +198,11 @@ class MultiHeadAttention(nn.Module):
     num_kv_heads: Optional[int] = None   # < num_heads → GQA; 1 → MQA
     rope: bool = False                   # rotary positions on q/k
     rope_theta: float = 10_000.0
+    qk_norm: bool = False                # RMSNorm over each head's values of
+                                         # q and of k (one learned head_dim
+                                         # vector each: q_norm, k_norm),
+                                         # before the rotation
+    norm_eps: float = 1e-6               # qk_norm's
     window: Optional[int] = None         # causal sliding-window size (SWA)
     dropout_rate: float = 0.0
     causal: bool = False
@@ -368,6 +373,15 @@ class MultiHeadAttention(nn.Module):
         q = nn.with_logical_constraint(q, (BATCH, SEQ, HEADS, KV))
         k = nn.with_logical_constraint(k, (BATCH, SEQ, HEADS, KV))
         v = nn.with_logical_constraint(v, (BATCH, SEQ, HEADS, KV))
+
+        if self.qk_norm:
+            q, k = (
+                nn.RMSNorm(
+                    epsilon=self.norm_eps, dtype=self.dtype,
+                    param_dtype=self.param_dtype, name=name,
+                )(t)
+                for name, t in (("q_norm", q), ("k_norm", k))
+            )
 
         if self.rope:
             # Rotate BEFORE caching so cached keys carry their absolute

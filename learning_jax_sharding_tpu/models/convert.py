@@ -255,6 +255,88 @@ def config_from_hf_nemotron_h(hf_config: Any, **overrides) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+def config_from_hf_lfm2_moe(hf_config: Any, **overrides) -> TransformerConfig:
+    """TransformerConfig for an ``lfm2_moe`` ``config.json`` (LiquidAI
+    LFM2-8B-A1B): per layer an OPERATOR from ``layer_types`` (``conv``: a
+    gated short convolution of ``conv_L_cache`` taps; ``full_attention``:
+    grouped-query attention with an RMSNorm over each head of q and k before
+    RoPE) and a feed-forward, dense SwiGLU of ``intermediate_size`` below
+    ``num_dense_layers`` and ``num_experts`` sigmoid-routed SwiGLU experts
+    of ``moe_intermediate_size`` from there on (top ``num_experts_per_tok``
+    of score + a selection bias, weights renormalised over the picks with
+    1e-6 in the sum, no shared expert); RMSNorm throughout, no bias, a head
+    tied to the embedding unless ``tie_word_embeddings`` says otherwise.
+
+    Refuses what the program does not compute rather than approximate it:
+    ``conv_bias``, an operator it does not build, unnormalised pick weights,
+    a router without its selection bias, RoPE scaling. The routing follows
+    ``transformers``' ``modeling_lfm2_moe.py`` as recalled and the config's
+    keys (that file is not on this machine; the operator, attention, dense
+    feed-forward and layer are checked against ``modeling_lfm2.py``,
+    ``tests/test_lfm2_moe.py``).
+    """
+    from learning_jax_sharding_tpu.models.transformer import OPERATOR_KINDS
+
+    c = hf_config
+    layer_types = tuple(c.layer_types)
+    unknown = sorted(set(layer_types) - set(OPERATOR_KINDS))
+    if unknown:
+        raise ValueError(
+            f"layer_types holds unknown operators {unknown} (known: "
+            f"{OPERATOR_KINDS})"
+        )
+    unsupported = {
+        "layer_types/num_hidden_layers": len(layer_types) != c.num_hidden_layers,
+        "conv_bias": bool(c.conv_bias),
+        "norm_topk_prob": not c.norm_topk_prob,
+        "use_expert_bias": not c.use_expert_bias,
+        "rope_scaling": getattr(c, "rope_scaling", None) is not None,
+        "num_dense_layers": not 0 <= c.num_dense_layers <= c.num_hidden_layers,
+    }
+    bad = sorted(k for k, v in unsupported.items() if v)
+    if bad:
+        raise ValueError(
+            f"unsupported lfm2_moe settings: {bad} (the program computes "
+            "bias-free short convolutions, sigmoid routing with a selection "
+            "bias and renormalised picks, un-scaled RoPE)"
+        )
+    import jax.numpy as jnp
+
+    defaults = dict(
+        vocab_size=c.vocab_size,
+        num_layers=c.num_hidden_layers,
+        layer_types=layer_types,
+        conv_kernel=c.conv_L_cache,
+        features=c.hidden_size,
+        num_heads=c.num_attention_heads,
+        num_kv_heads=c.num_key_value_heads,
+        head_dim=getattr(c, "head_dim", None)
+        or c.hidden_size // c.num_attention_heads,
+        hidden=c.intermediate_size,
+        max_seq_len=c.max_position_embeddings,
+        use_bias=False,
+        norm="rmsnorm",
+        norm_eps=c.norm_eps,
+        rope=True,
+        rope_theta=float(c.rope_theta),
+        qk_norm=True,
+        causal=True,
+        ff_gated=True,
+        first_k_dense=c.num_dense_layers,
+        num_experts=c.num_experts,
+        moe_top_k=c.num_experts_per_tok,
+        moe_routing="sigmoid_dropless",
+        moe_hidden=c.moe_intermediate_size,
+        moe_routed_scaling=float(c.routed_scaling_factor),
+        moe_renorm_eps=1e-6,
+        tie_embeddings=bool(getattr(c, "tie_word_embeddings", True)),
+        dtype=jnp.float32,
+        param_dtype=jnp.float32,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
 def params_from_hf_gpt2(hf_model: Any) -> dict:
     """Map a ``transformers.GPT2LMHeadModel`` state dict onto this
     framework's ``Transformer`` param tree (plain numpy leaves — shard with

@@ -52,7 +52,13 @@ from learning_jax_sharding_tpu.parallel.logical import (
     EXPERT,
     MLP,
     SEQ,
+    with_unstepped_partitioning,
 )
+
+
+#: The collection :class:`DroplessMoE` sows a layer's routing counts into
+#: when a caller makes it mutable (``make_train_step(routing_stats=True)``).
+ROUTING_STATS = "routing_stats"
 
 
 def assign_slots(probs: jax.Array, top_k: int, capacity: int):
@@ -310,8 +316,11 @@ class DroplessMoE(nn.Module):
 
         s = sigmoid(x W_r)                      float32, (T, E)
         picks = top_k(s + bias)                 the bias only SELECTS
-        w_i = routed_scaling * s_i / (sum_picks s + 1e-20)
+        w_i = routed_scaling * s_i / (sum_picks s + renorm_eps)
         y = sum_i w_i E_i(x) + E_shared(x)      E = (silu(x W_g) * x W_u) W_d
+
+    (``renorm_eps`` is the configuration's: 1e-20 where the DeepSeek-V3
+    rule is published, 1e-6 in the ``lfm2_moe`` family.)
 
     Every pick is computed: there is no capacity, so however uneven the
     routing no token's output lacks an expert. ``valid`` ``(B, S)`` marks
@@ -335,14 +344,16 @@ class DroplessMoE(nn.Module):
 
           y = (sum_i w_i E_i(x W_down_latent)) W_up_latent + E_shared(x)
 
-    Initialisers: the gated form's expert tensors take ``kernel_init`` as
-    it is (``lecun_normal`` counts the expert axis of ``(E, in, out)`` into
-    the fan-in: a routed sum far below the shared expert's output, as
-    ``joyai-llm-flash``'s cell was measured); the ungated form's take each
-    expert's OWN fan-in, ``down`` times ``expert_init_scale`` (a benchmark
+    Initialisers: with ``expert_init_scale`` a number the expert tensors
+    take each expert's OWN fan-in, ``down`` times that number (a benchmark
     lowers it where its float32 reference cannot follow the program's
     picks: a bf16 flip of one pick then moves the output by that much
-    less). ``centred_down``: the down projections (routed and shared) have
+    less; the ``lfm2_moe`` family, with no shared expert to carry the
+    layer, passes 1.0). ``None``: the ungated form still takes its own
+    fan-in (scale 1); the gated form takes ``kernel_init`` as it is
+    (``lecun_normal`` counts the expert axis of ``(E, in, out)`` into the
+    fan-in: a routed sum far below the shared expert's output, which is
+    how ``joyai-llm-flash``'s cell was measured and admitted). ``centred_down``: the down projections (routed and shared) have
     no gain for the mean of what they read (``transformer.zero_mean``).
 
     Parameters: ``router/kernel`` ``(M, E)``, ``bias`` ``(E,)`` (the
@@ -357,7 +368,16 @@ class DroplessMoE(nn.Module):
     ``count``: keep cumulative ``moe_stats`` ``(3,)`` int32 in the
     ``"cache"`` collection (assignments routed to held experts, held
     experts read, layer-steps): the serving engine returns their growth
-    with each dispatch's readback.
+    with each dispatch's readback. A caller that makes the collection
+    :data:`ROUTING_STATS` mutable (``make_train_step(routing_stats=True)``)
+    gets one ``(3,)`` int32 a layer sown there: assignments routed to held
+    experts, held experts touched, and the largest load of one held expert.
+
+    ``bias_init_std``: the selection bias starts N(0, that) and not zero
+    (a seeded benchmark exercises the term so); the bias only selects, so
+    its gradient is zero, and its box (``parallel.logical.Unstepped``) has
+    ``training.pipeline.sharded_train_state`` keep every optimizer's step,
+    weight decay included, off it.
     """
 
     features: int
@@ -367,10 +387,12 @@ class DroplessMoE(nn.Module):
     shared_experts: int = 0
     shared_hidden: int | None = None
     routed_scaling: float = 1.0
+    renorm_eps: float = 1e-20
+    bias_init_std: float = 0.0
     held: tuple | None = None
     gated: bool = True
     latent: int = 0
-    expert_init_scale: float = 1.0
+    expert_init_scale: float | None = None
     centred_down: bool = False
     experts: str = "auto"
     count: bool = False
@@ -405,9 +427,12 @@ class DroplessMoE(nn.Module):
                 ),
                 name="router",
             )
+            bias_init = (
+                nn.initializers.normal(self.bias_init_std)
+                if self.bias_init_std else nn.initializers.zeros_init()
+            )
             bias = self.param(
-                "bias",
-                nn.with_logical_partitioning(nn.initializers.zeros_init(), (EXPERT,)),
+                "bias", with_unstepped_partitioning(bias_init, (EXPERT,)),
                 (e,), self.param_dtype,
             )
             scores = jax.nn.sigmoid(
@@ -418,7 +443,7 @@ class DroplessMoE(nn.Module):
             )
             picked = jnp.take_along_axis(scores, idx, axis=-1)
             weights = self.routed_scaling * picked / (
-                jnp.sum(picked, axis=-1, keepdims=True) + 1e-20
+                jnp.sum(picked, axis=-1, keepdims=True) + self.renorm_eps
             )
 
         def experts(name, shape, axes, init=self.kernel_init):
@@ -445,14 +470,19 @@ class DroplessMoE(nn.Module):
                 xe = latent_proj(w, (EMBED, None), "latent_down")(xe)
         w_gate = None
         up_init = down_init = self.kernel_init
-        if self.gated:
-            w_gate = experts("gate", (n_held, w, self.hidden), (EXPERT, EMBED, MLP))
-        else:
+        init_scale = self.expert_init_scale
+        if init_scale is None and not self.gated:
+            init_scale = 1.0
+        if init_scale is not None:
             up_init, down_init = (
                 nn.initializers.variance_scaling(
                     scale, "fan_in", "truncated_normal", batch_axis=(0,)
                 )
-                for scale in (1.0, self.expert_init_scale ** 2)
+                for scale in (1.0, init_scale ** 2)
+            )
+        if self.gated:
+            w_gate = experts(
+                "gate", (n_held, w, self.hidden), (EXPERT, EMBED, MLP), up_init
             )
         if self.centred_down:
             down_init = zero_mean(down_init, 1)
@@ -474,6 +504,16 @@ class DroplessMoE(nn.Module):
                 "cache", "moe_stats", jnp.zeros, (3,), jnp.int32
             )
             seen.value = seen.value + stats
+        if self.is_mutable_collection(ROUTING_STATS) and not self.is_initializing():
+            here = idx if valid is None else jnp.where(
+                valid.reshape(t, 1), idx, e
+            )
+            load = jnp.zeros((e + 1,), jnp.int32).at[here.reshape(-1)].add(1)
+            lo = first or 0
+            self.sow(
+                ROUTING_STATS, "routing",
+                jnp.stack([stats[0], stats[1], jnp.max(load[lo:lo + n_held])]),
+            )
         if self.shared_experts:
             with jax.named_scope("moe.shared"):
                 out = out + FeedForward(

@@ -35,6 +35,9 @@ in pairs, the layout of ``ops/ssm_scan.py``'s kernels: ``pack_state``),
 ``conv_state`` ``(B, K - 1, d_inner + 2GN)`` in the compute dtype, and
 ``carry_from`` ``(B,)`` int32 (-1 at rest; ``engine_programs._take_rows``
 fills it for a refill dispatch's chunk rows).
+
+Beside it, :class:`ShortConv`: the ``lfm2`` family's gated short convolution
+(no recurrent state; the trainer's whole-sequence form only).
 """
 
 from __future__ import annotations
@@ -70,11 +73,13 @@ def _dt_bias_init(dt_min: float, dt_max: float, dt_floor: float) -> Callable:
 
 class _Conv(nn.Module):
     """The depthwise convolution's ``kernel`` ``(K, C)`` (tap ``K - 1``
-    multiplies the current position) and ``bias`` ``(C,)``."""
+    multiplies the current position) and ``bias`` ``(C,)`` (None without
+    ``use_bias``)."""
 
     width: int
     channels: int
     param_dtype: jnp.dtype = jnp.float32
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self):
@@ -86,6 +91,8 @@ class _Conv(nn.Module):
             ),
             (self.width, self.channels), self.param_dtype,
         )
+        if not self.use_bias:
+            return kernel, None
         bias = self.param(
             "bias",
             nn.with_logical_partitioning(nn.initializers.zeros_init(), (MLP,)),
@@ -411,4 +418,54 @@ class Mamba2Mixer(nn.Module):
             out = self._dense(
                 self.features, (MLP, EMBED), "out_proj", self.out_init
             )(y)
+        return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution of the ``lfm2`` family's ``conv`` layers:
+    no recurrent state, a depthwise causal convolution of ``kernel`` taps
+    between two element-wise gates::
+
+        [B | C | u] = x W_in             three thirds of width M, that order
+        z = B * u
+        c_t = sum_j w[j] * z_{t - (K - 1) + j}     zeros left of the sequence
+        out = (C * c) W_out
+
+    No activation and no bias. Parameters: ``in_proj/kernel`` ``(M, 3M)``,
+    ``conv/kernel`` ``(K, M)``, ``out_proj/kernel`` ``(M, M)``. Whole
+    sequences only (the trainer's path): served, the ``K - 1`` inputs before
+    the next token would be a slot's state beside the paged cache, as
+    :class:`Mamba2Mixer`'s ``conv_state`` is; that is not built."""
+
+    features: int
+    kernel: int = 3
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+    kernel_init: Callable = nn.initializers.lecun_normal()
+
+    def _dense(self, features: int, axes, name: str) -> nn.Module:
+        return nn.Dense(
+            features, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype,
+            kernel_init=nn.with_logical_partitioning(self.kernel_init, axes),
+            name=name,
+        )
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:
+        m, k, s = self.features, self.kernel, x.shape[1]
+        x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
+        with jax.named_scope("conv.in_proj"):
+            bcu = self._dense(3 * m, (EMBED, MLP), "in_proj")(x)
+        taps, _ = _Conv(k, m, self.param_dtype, use_bias=False, name="conv")()
+        with jax.named_scope("conv.taps"):
+            gate_b, gate_c, u = bcu[..., :m], bcu[..., m:2 * m], bcu[..., 2 * m:]
+            z = jnp.pad(gate_b * u, ((0, 0), (k - 1, 0), (0, 0)))
+            c = sum(
+                z[:, tap:tap + s].astype(jnp.float32) * taps[tap].astype(jnp.float32)
+                for tap in range(k)
+            )
+            y = gate_c * c.astype(self.dtype)
+        with jax.named_scope("conv.out_proj"):
+            out = self._dense(m, (MLP, EMBED), "out_proj")(y)
         return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))

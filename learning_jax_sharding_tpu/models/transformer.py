@@ -28,7 +28,11 @@ Block kinds (a config picks one attention and, per layer, one feed-forward):
   (JoyAI-LLM-Flash: one dense layer, then expert layers);
 * or, with ``layer_pattern``, ONE mixer a layer (:class:`MixerBlock`,
   ``x + Mixer(norm(x))``): ``M`` a Mamba-2 layer (``models/ssm.py``), ``E``
-  an expert layer, ``*`` attention (the ``nemotron_h`` family).
+  an expert layer, ``*`` attention (the ``nemotron_h`` family);
+* or, with ``layer_types``, the block's OPERATOR by layer: ``"conv"`` a gated
+  short convolution (``models/ssm.py::ShortConv``) or ``"full_attention"``,
+  each followed by the layer's feed-forward as above (the ``lfm2_moe``
+  family: q / k RMSNorm a head, a head tied to the embedding).
 
 Everything is dtype-parameterized: bf16 compute / fp32 params is the TPU MXU
 sweet spot and the default for benchmarks.
@@ -402,6 +406,14 @@ class TransformerBlock(nn.Module):
     moe_shared_experts: int = 0
     moe_routed_scaling: float = 1.0
     moe_experts: str = "auto"
+    moe_held: Optional[tuple] = None
+    moe_renorm_eps: float = 1e-20
+    moe_bias_init_std: float = 0.0
+    moe_expert_init_scale: Optional[float] = None
+    operator: str = "full_attention"   # or "conv": ShortConv in the
+                                  # attention's place (norm ``ln_conv``)
+    conv_kernel: int = 3
+    qk_norm: bool = False
 
     def _norm(self, name: str):
         if self.fused_norm:
@@ -421,6 +433,19 @@ class TransformerBlock(nn.Module):
         self, x: jax.Array, deterministic: bool = True, chunk_lengths=None
     ):
         x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
+        if self.operator == "conv":
+            from learning_jax_sharding_tpu.models.ssm import ShortConv
+
+            h, _ = self._norm("ln_conv")(x)
+            return self._finish(
+                x,
+                ShortConv(
+                    features=self.features, kernel=self.conv_kernel,
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="conv",
+                )(h),
+                deterministic, chunk_lengths,
+            )
         h, _ = self._norm("ln_attn")(x)
         if self.latent_kv_rank:
             return self._finish(
@@ -476,6 +501,8 @@ class TransformerBlock(nn.Module):
             quantization=self.quantization,
             quantization_group=self.quantization_group,
             quantized_matmul_fn=self.quantized_matmul_fn,
+            qk_norm=self.qk_norm,
+            norm_eps=self.norm_eps,
             name="attn",
         )(h, deterministic=deterministic, chunk_lengths=chunk_lengths)
         return self._finish(x, attn_out, deterministic, chunk_lengths)
@@ -500,6 +527,10 @@ class TransformerBlock(nn.Module):
                 top_k=self.moe_top_k,
                 shared_experts=self.moe_shared_experts,
                 routed_scaling=self.moe_routed_scaling,
+                held=self.moe_held,
+                renorm_eps=self.moe_renorm_eps,
+                bias_init_std=self.moe_bias_init_std,
+                expert_init_scale=self.moe_expert_init_scale,
                 experts=self.moe_experts,
                 count=self.decode,
                 dtype=self.dtype,
@@ -539,6 +570,9 @@ class TransformerBlock(nn.Module):
         # nn.scan's carry protocol wants (carry, per-step output) pairs.
         return (x, None) if self.scan else x
 
+
+#: ``TransformerConfig.layer_types``' entries (the ``lfm2`` family's key).
+OPERATOR_KINDS = ("conv", "full_attention")
 
 #: ``TransformerConfig.layer_pattern``'s characters (the ``nemotron_h``
 #: family's ``hybrid_override_pattern``; its "-", a dense MLP layer, is not
@@ -687,9 +721,29 @@ class TransformerConfig:
                                      # them, latent -> features after)
     moe_shared_hidden: Optional[int] = None  # the shared expert's width
                                      # (None: moe_shared_experts x moe_hidden)
-    moe_expert_init_scale: float = 1.0  # the relu2 experts' down projection,
-                                     # as a multiple of each expert's own
-                                     # fan-in initialiser (initialisation only)
+    moe_expert_init_scale: Optional[float] = None  # a number: DroplessMoE's
+                                     # experts start at each expert's OWN
+                                     # fan-in, `down` times this; None: the
+                                     # relu2 experts the same at 1, the gated
+                                     # ones lecun_normal over the (E, in, out)
+                                     # tensor (initialisation only)
+    moe_renorm_eps: float = 1e-20    # added to the picks' score sum before
+                                     # the weights are divided by it
+    moe_bias_init_std: float = 0.0   # the selection bias is N(0, this) at
+                                     # initialisation (0: zeros); it only
+                                     # selects, and no optimizer step moves
+                                     # it (its box: parallel.logical.Unstepped)
+    # --- the block's operator by layer (TransformerBlock.operator) ---------
+    layer_types: Optional[tuple] = None  # one entry a layer: "conv" (gated
+                                     # short convolution, models/ssm.py) or
+                                     # "full_attention"; None: attention in
+                                     # every block. The feed-forward follows
+                                     # first_k_dense / num_experts as ever
+    conv_kernel: int = 3             # the short convolution's taps
+    qk_norm: bool = False            # RMSNorm over each head of q and of k
+                                     # (one learned vector each), before RoPE
+    tie_embeddings: bool = False     # logits = hidden . tok_embed^T: no
+                                     # lm_head parameters
     # --- one mixer a layer (MixerBlock) ------------------------------------
     layer_pattern: Optional[str] = None  # one character a layer: "M" Mamba-2,
                                      # "E" expert layer, "*" attention; None:
@@ -755,6 +809,28 @@ class TransformerConfig:
                 "first_k_dense makes the blocks differ by layer: scan_layers "
                 "stacks ONE block"
             )
+        if self.layer_types is not None:
+            unknown = sorted(set(self.layer_types) - set(OPERATOR_KINDS))
+            if unknown or len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types must hold num_layers = {self.num_layers} "
+                    f"entries of {OPERATOR_KINDS}; unknown: {unknown}"
+                )
+            if (
+                self.scan_layers or self.layer_pattern is not None
+                or self.latent_kv_rank
+            ):
+                raise ValueError(
+                    "layer_types makes the blocks differ by layer and picks "
+                    "between a convolution and grouped-query attention: "
+                    "scan_layers, layer_pattern and latent attention do not "
+                    "apply to it"
+                )
+            if self.decode and "conv" in self.layer_types:
+                raise ValueError(
+                    "a 'conv' layer has no cached form: the short "
+                    "convolution's last inputs are not a slot's state yet"
+                )
         if self.latent_kv_rank and not (
             self.latent_q_rank and self.qk_nope_dim and self.qk_rope_dim
             and self.v_head_dim
@@ -818,10 +894,13 @@ class TransformerConfig:
             attn_layers = self.layer_pattern.count("*")
         else:
             blocks = sum(
-                self._attn_proj_params + self._ff_params(i, activated=True)
+                self._operator_params(i) + self._ff_params(i, activated=True)
                 for i in range(self.num_layers)
             )
-            attn_layers = self.num_layers
+            attn_layers = sum(
+                self._operator(i) == "full_attention"
+                for i in range(self.num_layers)
+            )
         matmul_params = blocks + self.features * self.vocab_size   # lm_head
         qk_v = (
             self.qk_nope_dim + self.qk_rope_dim + self.v_head_dim
@@ -852,9 +931,21 @@ class TransformerConfig:
             + 2 * self.features * kv_heads * self.head_dim       # k + v
         )
 
+    def _operator(self, layer: int) -> str:
+        return "full_attention" if self.layer_types is None else self.layer_types[layer]
+
+    def _operator_params(self, layer: int) -> int:
+        """Matrix parameters of block ``layer``'s operator: the attention
+        projections, or the short convolution's ``in_proj`` and ``out_proj``."""
+        if self._operator(layer) == "conv":
+            return 4 * self.features * self.features
+        return self._attn_proj_params
+
     def _ff_params(self, layer: int, *, activated: bool = False) -> int:
-        """Feed-forward matmul parameters of block ``layer``: all of them,
-        or with ``activated`` those one token multiplies by."""
+        """Feed-forward matmul parameters of block ``layer``: all of them
+        that live here, or with ``activated`` those one token multiplies by
+        here (with ``moe_held`` the uniform expectation of its picks that
+        fall on a held expert)."""
         mats = 3 if self.ff_gated else 2
         if not self.num_experts or layer < self.first_k_dense:
             return mats * self.features * self.hidden
@@ -863,8 +954,10 @@ class TransformerConfig:
             expert = 2 * self.features * self.hidden
             return router + expert * (self.moe_top_k if activated else self.num_experts)
         expert = 3 * self.features * (self.moe_hidden or self.hidden)
-        held = self.moe_top_k if activated else self.num_experts
-        return router + expert * (held + self.moe_shared_experts)
+        held = self.moe_held[1] if self.moe_held else self.num_experts
+        if activated:
+            held = self.moe_top_k * held / self.num_experts
+        return int(router + expert * (held + self.moe_shared_experts))
 
     def _mixer_params(self, kind: str, *, activated: bool = False) -> tuple:
         """``(matrix, vector)`` parameters of one ``layer_pattern`` layer of
@@ -901,6 +994,8 @@ class TransformerConfig:
         if self.layer_pattern is not None:
             blocks = sum(sum(self._mixer_params(k)) for k in self.layer_pattern)
             return 2 * self.vocab_size * self.features + blocks + self.features
+        if self.layer_types is not None:
+            return self._layer_types_param_count()
         blocks = sum(
             self._attn_proj_params + self._ff_params(i) + 4 * self.features
             for i in range(self.num_layers)
@@ -909,6 +1004,24 @@ class TransformerConfig:
         embed = self.vocab_size * self.features + pos
         head = self.features * self.vocab_size
         return embed + blocks + 2 * self.features + head
+
+
+    def _layer_types_param_count(self) -> int:
+        """Exact count of a ``layer_types`` model with RMSNorm: embedding
+        (and head unless tied), final norm, and a layer's two norms,
+        operator (the convolution's taps, the q / k norms) and feed-forward
+        (the router's selection bias)."""
+        m, total = self.features, 0
+        for i in range(self.num_layers):
+            total += 2 * m + self._operator_params(i) + self._ff_params(i)
+            if self._operator(i) == "conv":
+                total += self.conv_kernel * m
+            elif self.qk_norm:
+                total += 2 * self.head_dim
+            if self.num_experts and i >= self.first_k_dense:
+                total += self.num_experts            # the selection bias
+        heads = 1 if self.tie_embeddings else 2
+        return total + heads * self.vocab_size * m + m
 
 
 #: The BASELINE.json flagship: "case4+case6 composed 125M transformer".
@@ -1161,13 +1274,23 @@ class Transformer(nn.Module):
             moe_shared_experts=cfg.moe_shared_experts,
             moe_routed_scaling=cfg.moe_routed_scaling,
             moe_experts=cfg.moe_experts,
+            moe_held=cfg.moe_held,
+            moe_renorm_eps=cfg.moe_renorm_eps,
+            moe_bias_init_std=cfg.moe_bias_init_std,
+            moe_expert_init_scale=cfg.moe_expert_init_scale,
+            conv_kernel=cfg.conv_kernel,
+            qk_norm=cfg.qk_norm,
         )
 
         def fields_of(i):
-            # Blocks differ by layer only in whether the FF is routed.
+            # Blocks differ by layer in whether the FF is routed and in
+            # their operator.
+            fields = block_fields
             if cfg.num_experts and i < cfg.first_k_dense:
-                return {**block_fields, "num_experts": 0}
-            return block_fields
+                fields = {**fields, "num_experts": 0}
+            if cfg.layer_types is not None:
+                fields = {**fields, "operator": cfg.layer_types[i]}
+            return fields
 
         if cfg.layer_pattern is not None:
             for i, kind in enumerate(cfg.layer_pattern):
@@ -1254,6 +1377,9 @@ class Transformer(nn.Module):
             # chunk so the full (B, S, V) logits never materialize. (Init
             # runs with the default False, so lm_head params always exist.)
             return x
+        if cfg.tie_embeddings:
+            logits = embed.attend(x)
+            return nn.with_logical_constraint(logits, (BATCH, SEQ, VOCAB))
         from learning_jax_sharding_tpu.models.quantize import projection_dense
 
         logits = projection_dense(
@@ -1297,7 +1423,10 @@ def fused_next_token_loss(
     b, s, m = hidden.shape
     if s % chunk_size:
         raise ValueError(f"seq len {s} not divisible by chunk_size {chunk_size}")
-    kernel = params["lm_head"]["kernel"]
+    if "lm_head" in params:
+        kernel = params["lm_head"]["kernel"]
+    else:                            # tie_embeddings: the embedding IS the head
+        kernel = params["tok_embed"]["embedding"].T
 
     @jax.checkpoint
     def chunk_total(h_chunk, t_chunk):

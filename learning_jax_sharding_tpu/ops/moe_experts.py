@@ -32,8 +32,26 @@ or plain ``ragged_dot``, combine); both backends share the plan, so the
 counts the engine reports (assignments, experts read) are the kernel's own
 work list whichever runs.
 
-Inference and forward only: no VJP for the kernel (training uses the
-``ragged_dot`` backend, which XLA differentiates).
+**Training.** The gated form differentiates under either backend. The
+Pallas path carries a VJP (:func:`_tiled_experts`) whose forward is the
+serving call above and whose backward is two more walks of the SAME tile
+list: :func:`moe_experts_dx` (traced ``moe.experts_dx``: the gate and up
+recomputed from the tile, ``dH = dY W_d^T``, ``dX = dG W_g^T + dU W_u^T``;
+it also writes ``dG``, ``dU`` and ``H`` for the next call) and
+:func:`moe_experts_dw` (``moe.experts_dw``: ``dW[e] = X_e^T d._e``
+accumulated in float32 over an expert's consecutive tiles, a block of the
+expert width at a time; an expert nobody was routed to gets zeros). The
+gathers around them are transposed as gathers (``src`` and ``pos`` invert
+each other), never as scatters; the combine weights' cotangent comes from
+plain autodiff of the combine, so the router trains. A pick outside
+``held`` is on no tile: it reads nothing and sends no gradient. The
+``ragged`` backend is differentiated by XLA; on a TPU ``auto`` never takes
+it. Measured on the chip at ``lfm2-8b-a1b.pretrain_8k``'s shapes (16,384
+tokens x top 4 of 32, 8 held, 2048 x 1792, float32 weights; PERF.md, PR
+35): forward + backward 24.3 ms through these calls against 57.8 ms through
+XLA's transposes of ``ragged_dot``, whose ``dX`` also read 9.6 % off a
+float32 dense loop where this one reads 0.45 %. The ungated (relu^2) kernel
+has no VJP: that form is served, not trained, so far.
 """
 
 from __future__ import annotations
@@ -49,6 +67,10 @@ _TRACE_NAME = "moe.experts"
 # One expert's three matrices are double-buffered whole (2 x 9.4 MB at
 # d 2048, f 768 in bf16) beside the row tiles: past Mosaic's default 16 MiB.
 _VMEM_LIMIT = 64 * 1024 * 1024
+# The backward calls: the same three matrices beside four row tiles out
+# (dx), or three float32 blocks of an expert's gradient (dw, _dw_block).
+_VMEM_LIMIT_BWD = 100 * 1024 * 1024
+_DW_VMEM = 48 * 1024 * 1024
 
 
 def tile_rows(assignments: int, num_experts: int) -> int:
@@ -148,6 +170,202 @@ def moe_experts(
         return call(tile_expert, x_rows, *weights)
 
 
+def _dx_kernel(
+    te_ref, x_ref, dy_ref, wg_ref, wu_ref, wd_ref, dx_ref, dg_ref, du_ref, h_ref
+):
+    del te_ref
+    nt = (((1,), (1,)), ((), ()))                   # a . b^T
+    x, dy = x_ref[...], dy_ref[...]
+    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    sig = jax.nn.sigmoid(g)
+    act = g * sig
+    h_ref[...] = (act * u).astype(h_ref.dtype)
+    dh = jax.lax.dot_general(dy, wd_ref[0], nt, preferred_element_type=jnp.float32)
+    dg = (dh * u * (sig * (1.0 + g * (1.0 - sig)))).astype(x.dtype)
+    du = (dh * act).astype(x.dtype)
+    dg_ref[...], du_ref[...] = dg, du
+    dx_ref[...] = (
+        jax.lax.dot_general(dg, wg_ref[0], nt, preferred_element_type=jnp.float32)
+        + jax.lax.dot_general(du, wu_ref[0], nt, preferred_element_type=jnp.float32)
+    ).astype(dx_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def moe_experts_dx(
+    x_rows, dy_rows, tile_expert, num_tiles, w_gate, w_up, w_down, *, tm: int,
+    interpret: bool = False,
+):
+    """The row side of :func:`moe_experts`' backward, tile by tile:
+    ``(dx_rows (M, d), dg, du, h (M, f))`` for the cotangent ``dy_rows`` of
+    its output, all in ``x_rows.dtype``; rows of tiles past ``num_tiles``
+    come back unwritten."""
+    m, d = x_rows.shape
+    f = w_up.shape[2]
+    rows = pl.BlockSpec((tm, d), lambda i, te: (i, 0))
+    wide = pl.BlockSpec((tm, f), lambda i, te: (i, 0))
+    into = pl.BlockSpec((1, d, f), lambda i, te: (te[i], 0, 0))
+    out_of = pl.BlockSpec((1, f, d), lambda i, te: (te[i], 0, 0))
+    call = pl.pallas_call(
+        _dx_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(num_tiles,),
+            in_specs=[rows, rows, into, into, out_of],
+            out_specs=[rows, wide, wide, wide],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((m, d), x_rows.dtype),
+            *([jax.ShapeDtypeStruct((m, f), x_rows.dtype)] * 3),
+        ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BWD),
+        interpret=interpret,
+    )
+    with jax.named_scope(_TRACE_NAME + "_dx"):
+        return call(tile_expert, x_rows, dy_rows, w_gate, w_up, w_down)
+
+
+def _dw_kernel(
+    te_ref, x_ref, dy_ref, dg_ref, du_ref, h_ref, dwg_ref, dwu_ref, dwd_ref
+):
+    i = pl.program_id(1)
+    tn = (((0,), (0,)), ((), ()))                   # a^T . b
+
+    @pl.when((i == 0) | (te_ref[i] != te_ref[jnp.maximum(i - 1, 0)]))
+    def _first_tile_of_an_expert():
+        dwg_ref[...] = jnp.zeros_like(dwg_ref)
+        dwu_ref[...] = jnp.zeros_like(dwu_ref)
+        dwd_ref[...] = jnp.zeros_like(dwd_ref)
+
+    x = x_ref[...]
+    dwg_ref[0] += jax.lax.dot_general(
+        x, dg_ref[...], tn, preferred_element_type=jnp.float32
+    )
+    dwu_ref[0] += jax.lax.dot_general(
+        x, du_ref[...], tn, preferred_element_type=jnp.float32
+    )
+    dwd_ref[0] += jax.lax.dot_general(
+        h_ref[...], dy_ref[...], tn, preferred_element_type=jnp.float32
+    )
+
+
+def _dw_block(d: int, f: int) -> int:
+    """Columns of the expert width one :func:`moe_experts_dw` pass
+    accumulates: the widest whole-lane-tile divisor of ``f`` whose three
+    float32 blocks, double-buffered, stay under ``_DW_VMEM``; ``f`` itself
+    when it has none (a test's narrow experts)."""
+    fits = [
+        fb for fb in range(128, f + 1, 128)
+        if f % fb == 0 and 6 * d * fb * 4 <= _DW_VMEM
+    ]
+    return max(fits) if fits else f
+
+
+@functools.partial(jax.jit, static_argnames=("tm", "num_experts", "interpret"))
+def moe_experts_dw(
+    x_rows, dy_rows, dg, du, h, tile_expert, num_tiles, *, tm: int,
+    num_experts: int, interpret: bool = False,
+):
+    """The weight side of :func:`moe_experts`' backward: ``(dW_gate, dW_up
+    (E, d, f), dW_down (E, f, d))`` in float32, ``dW_gate[e] = X_e^T dG_e``
+    and so on, summed over expert ``e``'s consecutive tiles. The grid is
+    ``(f // block, num_tiles)``: a block of the expert width stays in VMEM
+    while its expert's tiles go by. An expert with no tile is NOT written
+    (the caller zeroes it)."""
+    m, d = x_rows.shape
+    f = h.shape[1]
+    fb = _dw_block(d, f)
+    rows = pl.BlockSpec((tm, d), lambda j, i, te: (i, 0))
+    wide = pl.BlockSpec((tm, fb), lambda j, i, te: (i, j))
+    into = pl.BlockSpec((1, d, fb), lambda j, i, te: (te[i], 0, j))
+    out_of = pl.BlockSpec((1, fb, d), lambda j, i, te: (te[i], j, 0))
+    call = pl.pallas_call(
+        _dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(f // fb, num_tiles),
+            in_specs=[rows, rows, wide, wide, wide],
+            out_specs=[into, into, out_of],
+        ),
+        out_shape=[
+            *([jax.ShapeDtypeStruct((num_experts, d, f), jnp.float32)] * 2),
+            jax.ShapeDtypeStruct((num_experts, f, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BWD,
+            dimension_semantics=("arbitrary", "arbitrary"),
+        ),
+        interpret=interpret,
+    )
+    with jax.named_scope(_TRACE_NAME + "_dw"):
+        return call(tile_expert, x_rows, dy_rows, dg, du, h)
+
+
+def _gather_rows(x, src, k: int):
+    """``x`` ``(T, d)`` laid out on the plan's padded rows: row ``r``
+    holds the token of assignment ``src[r]``, or zeros where it holds none."""
+    t, d = x.shape
+    x_ext = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
+    return x_ext[jnp.minimum(src // k, t)]          # A // k == t: the zero row
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _tiled_experts(
+    tm, k, interpret, x, w_gate, w_up, w_down, src, pos, tile_expert,
+    num_tiles, counts,
+):
+    """``y`` ``(A, d)``: each assignment's expert output, through the
+    Pallas call (an unrouted assignment's row holds anything). The weights
+    may be wider than ``x.dtype`` (a trainer's float32 parameters): the
+    kernel reads them in ``x.dtype``."""
+    del counts
+    w_gate, w_up, w_down = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    y_rows = moe_experts(
+        _gather_rows(x, src, k), tile_expert, num_tiles, w_gate, w_up, w_down,
+        tm=tm, interpret=interpret,
+    )
+    return y_rows[jnp.minimum(pos, y_rows.shape[0] - 1)]
+
+
+def _tiled_experts_fwd(tm, k, interpret, x, w_gate, w_up, w_down, *layout):
+    y = _tiled_experts(tm, k, interpret, x, w_gate, w_up, w_down, *layout)
+    return y, (x, w_gate, w_up, w_down, *layout)
+
+
+def _tiled_experts_bwd(tm, k, interpret, saved, dy):
+    x, w_gate, w_up, w_down, src, pos, tile_expert, num_tiles, counts = saved
+    (t, d), a = x.shape, dy.shape[0]
+    wg, wu, wd = (w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    x_rows = _gather_rows(x, src, k)
+    # The transpose of ``y_rows[pos]`` as a gather: row r takes the
+    # cotangent of the assignment it holds.
+    dy_ext = jnp.concatenate([dy.astype(x.dtype), jnp.zeros((1, d), x.dtype)])
+    dy_rows = dy_ext[src]
+    dx_rows, dg, du, h = moe_experts_dx(
+        x_rows, dy_rows, tile_expert, num_tiles, wg, wu, wd, tm=tm,
+        interpret=interpret,
+    )
+    grads = moe_experts_dw(
+        x_rows, dy_rows, dg, du, h, tile_expert, num_tiles, tm=tm,
+        num_experts=w_up.shape[0], interpret=interpret,
+    )
+    touched = (counts > 0)[:, None, None]
+    d_gate, d_up, d_down = (
+        jnp.where(touched, g, 0).astype(w.dtype)
+        for g, w in zip(grads, (w_gate, w_up, w_down))
+    )
+    # ... and of ``x_ext[src // k]``: a token sums its routed assignments'
+    # rows (an unrouted one has pos == M and no row).
+    m = dx_rows.shape[0]
+    mine = dx_rows[jnp.minimum(pos, m - 1)].astype(jnp.float32)
+    mine = jnp.where((pos < m)[:, None], mine, 0.0).reshape(t, k, d)
+    dx = jnp.sum(mine, axis=1).astype(x.dtype)
+    return dx, d_gate, d_up, d_down, None, None, None, None, None
+
+
+_tiled_experts.defvjp(_tiled_experts_fwd, _tiled_experts_bwd)
+
+
 def resolve_backend(mode: str) -> str:
     """``"auto"``: the Pallas call on a TPU, sorted ``ragged_dot``
     elsewhere (off the TPU the kernel runs under the interpreter)."""
@@ -190,13 +408,17 @@ def routed_experts(
     if backend == "pallas":
         if interpret is None:
             interpret = jax.default_backend() != "tpu"
-        x_ext = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])
-        x_rows = x_ext[jnp.minimum(src // k, t)]       # A // k == t: the zero row
-        y_rows = moe_experts(
-            x_rows, tile_expert, num_tiles, w_gate, w_up, w_down, tm=tm,
-            interpret=interpret,
-        )
-        y = y_rows[jnp.minimum(pos, y_rows.shape[0] - 1)]
+        if w_gate is None:                              # no VJP: served only
+            y_rows = moe_experts(
+                _gather_rows(x, src, k), tile_expert, num_tiles, None, w_up,
+                w_down, tm=tm, interpret=interpret,
+            )
+            y = y_rows[jnp.minimum(pos, y_rows.shape[0] - 1)]
+        else:
+            y = _tiled_experts(
+                tm, k, interpret, x, w_gate, w_up, w_down, src, pos,
+                tile_expert, num_tiles, counts,
+            )
     else:
         # The same sorted runs, unpadded, through XLA's grouped matmul.
         xs = x[order // k]

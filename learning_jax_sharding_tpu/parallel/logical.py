@@ -171,6 +171,25 @@ def tree_shardings(abstract_tree: Any, mesh: Mesh, rules: Rules) -> Any:
     return nn.logical_to_mesh_sharding(spec, mesh, tuple(rules))
 
 
+class Unstepped(nn.LogicallyPartitioned):
+    """The box of a parameter that NO optimizer step moves: a buffer that
+    lives among the parameters (``models.moe.DroplessMoE``'s selection bias,
+    which only picks experts: its gradient is zero, and weight decay must
+    not pull it to zero either). A ``LogicallyPartitioned`` box in every
+    other way; ``training.pipeline.sharded_train_state`` reads the mark off
+    the abstract tree and gives whatever optimizer it was handed a zero
+    update for these leaves, wherever the module is mounted."""
+
+
+def with_unstepped_partitioning(init, names):
+    """``nn.with_logical_partitioning`` whose box is :class:`Unstepped`."""
+
+    def boxed(*args, **kwargs):
+        return Unstepped(init(*args, **kwargs), names)
+
+    return boxed
+
+
 def attention_mesh_axes(
     rules: Rules, axis: str | None = None
 ) -> tuple[str | None, str, str | None]:

@@ -155,6 +155,16 @@ def fit(
         step_kwargs: extra kwargs for :func:`training.pipeline.make_train_step`
             (e.g. ``aux_loss_collection="losses"`` for MoE models,
             ``apply_kwargs={"return_hidden": True}`` for the fused CE loss).
+            ``routing_stats=True`` makes the step return what its dropless
+            expert layers counted; with a ``registry`` they are booked as
+            ``train_moe_held_assignments_total`` and
+            ``train_moe_experts_touched_total`` (counters) and
+            ``train_moe_expert_max_load`` (a gauge: the largest load of one
+            expert in one layer of the last step). They are counted on the
+            device inside the step and read with its loss, so they cost no
+            sync of their own; there is no ``train_step.moe`` span, because
+            a host span cannot see inside a jitted step (the ``train_step``
+            span covers the whole of it).
         registry: optional
             :class:`~learning_jax_sharding_tpu.telemetry.MetricsRegistry`
             — per-step metrics are mirrored into it as ``train_*``
@@ -508,10 +518,11 @@ def fit(
                 with led.measure("device", family="train_step") as frame, \
                         tr.span("train_step", step=i + 1), hb:
                     state, loss = step_fn(state, batch)
-                    loss, gnorm = (
-                        (loss["loss"], loss.get("grad_norm"))
-                        if isinstance(loss, dict) else (loss, None)
-                    )
+                    out = loss if isinstance(loss, dict) else {"loss": loss}
+                    loss, gnorm = out["loss"], out.get("grad_norm")
+                    routing = {
+                        k: v for k, v in out.items() if k.startswith("moe_")
+                    }
                     # metrics.log's float(loss) is the step's honest sync
                     # point — inside the span (and the heartbeat's armed
                     # window), so the span measures the step, not its
@@ -530,6 +541,17 @@ def fit(
                     )
                 with led.measure("telemetry"):
                     rec.record("train_step", step=i + 1, loss=loss_f)
+                    if routing and registry is not None:
+                        # Outputs of the step whose loss was just read.
+                        for key in ("held_assignments", "experts_touched"):
+                            registry.counter(
+                                f"train_moe_{key}_total",
+                                f"dropless expert layers: {key} over layers and steps",
+                            ).inc(int(routing[f"moe_{key}"]))
+                        registry.gauge(
+                            "train_moe_expert_max_load",
+                            "largest load of one held expert in one layer, last step",
+                        ).set(int(routing["moe_expert_max_load"]))
                 if resilience is not None:
                     nonfinite = not math.isfinite(loss_f) or (
                         gnorm is not None

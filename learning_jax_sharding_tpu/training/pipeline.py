@@ -22,6 +22,7 @@ mesh/rules handled by one context helper instead of repeated ``with`` pairs.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -33,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding
 
 from learning_jax_sharding_tpu.parallel.logical import (
     Rules,
+    Unstepped,
     activate,
     tree_shardings,
 )
@@ -55,6 +57,41 @@ def _inputs_of(batch: Any) -> jax.Array:
     """A batch is either the bare input array (the reference's convention) or
     a dict with an ``"inputs"`` entry (plus e.g. ``"targets"``)."""
     return batch["inputs"] if isinstance(batch, dict) else batch
+
+
+def _keep_unstepped(
+    optimizer: optax.GradientTransformation, boxed_params: Any
+) -> optax.GradientTransformation:
+    """``optimizer`` with a zero update for every parameter its module boxed
+    as :class:`~learning_jax_sharding_tpu.parallel.logical.Unstepped` (no
+    gradient step, no weight decay). The state tree is ``optimizer``'s own,
+    and a model with no such leaf gets ``optimizer`` back as it is."""
+    marks, treedef = jax.tree.flatten(
+        jax.tree.map(
+            lambda box: isinstance(box, Unstepped), boxed_params,
+            is_leaf=lambda box: isinstance(box, nn.meta.AxisMetadata),
+        )
+    )
+    if not any(marks):
+        return optimizer
+    return _zero_updates_at(optimizer, treedef, tuple(marks))
+
+
+@functools.lru_cache(maxsize=16)
+def _zero_updates_at(optimizer, treedef, marks):
+    # Cached: ``TrainState.tx`` is part of the state's tree TYPE, so a state
+    # made again from the same optimizer and model must carry the SAME object
+    # (a jitted step would otherwise see a new type and compile again).
+    unstepped = jax.tree.unflatten(treedef, marks)
+
+    def update(updates, state, params=None):
+        updates, state = optimizer.update(updates, state, params)
+        updates = jax.tree.map(
+            lambda keep, u: jnp.zeros_like(u) if keep else u, unstepped, updates
+        )
+        return updates, state
+
+    return optax.GradientTransformation(optimizer.init, update)
 
 
 def sharded_train_state(
@@ -88,21 +125,27 @@ def sharded_train_state(
         sharding tree (reused as in/out shardings for the step functions).
     """
 
-    def boxed_init(rngs, x):
-        variables = model.init(rngs, x)
-        return TrainState.create(
-            apply_fn=model.apply, params=variables["params"], tx=optimizer
-        )
-
-    def init_fn(rngs, x):
-        # The logical axis names live in flax's LogicallyPartitioned boxes;
-        # they are read off the *abstract* tree below, so the real state can
-        # carry plain arrays (unboxed) — optimizer and step functions then see
-        # ordinary pytrees.
-        return nn.meta.unbox(boxed_init(rngs, x))
+    def boxed_state(params, tx):
+        return TrainState.create(apply_fn=model.apply, params=params, tx=tx)
 
     with activate(mesh, rules):
-        abstract = jax.eval_shape(boxed_init, rngs, x)
+        # The logical axis names live in flax's LogicallyPartitioned boxes;
+        # they are read off the *abstract* tree, so the real state can carry
+        # plain arrays (unboxed): optimizer and step functions then see
+        # ordinary pytrees. The boxes also say which leaves no step may move.
+        abstract_params = jax.eval_shape(
+            lambda rngs, x: model.init(rngs, x)["params"], rngs, x
+        )
+        optimizer = _keep_unstepped(optimizer, abstract_params)
+        abstract = jax.eval_shape(
+            lambda params: boxed_state(params, optimizer), abstract_params
+        )
+
+        def init_fn(rngs, x):
+            return nn.meta.unbox(
+                boxed_state(model.init(rngs, x)["params"], optimizer)
+            )
+
         state_shardings = tree_shardings(abstract, mesh, rules)
         # Optimizers with FACTORED state (e.g. adafactor's rank-1 v_row /
         # v_col, reduced from rank-2 kernels) inherit the param's logical
@@ -156,6 +199,7 @@ def make_train_step(
     steps_per_call: int = 1,
     with_grad_norm: bool = False,
     skip_nonfinite: bool = False,
+    routing_stats: bool = False,
 ) -> Callable[[TrainState, Any], tuple[TrainState, jax.Array]]:
     """Build the jitted SPMD train step: grad → apply_gradients → (state, loss).
 
@@ -207,6 +251,14 @@ def make_train_step(
     reads the non-finite loss/grad-norm and knows the step was skipped);
     ``training/loop.py::fit(resilience=...)`` drives it.
 
+    ``routing_stats``: return ``(state, {"loss": ..., "moe_held_assignments":
+    ..., "moe_experts_touched": ..., "moe_expert_max_load": ...})``: what the
+    dropless expert layers (``models.moe.DroplessMoE``) counted in this step,
+    summed over the layers (the largest load of one expert in one layer for
+    the last), int32 scalars computed on the device beside the loss and read
+    with it: no sync of their own. Not with ``grad_accum_steps`` or
+    ``aux_loss_collection``.
+
     ``steps_per_call``: run this many FULL optimizer steps per jitted call
     (a ``lax.scan``); the batch then carries a leading ``(steps_per_call,)``
     dim of per-step batches and the returned loss is the per-step
@@ -217,6 +269,12 @@ def make_train_step(
     single-call no-donate timing reads 66.5 ms/step, the scanned in-place
     regime 63.0 — the honest sustained-training number).
     """
+
+    if routing_stats and (grad_accum_steps != 1 or aux_loss_collection):
+        raise ValueError(
+            "routing_stats counts one forward pass a step: not with "
+            "grad_accum_steps or aux_loss_collection"
+        )
 
     def step(state: TrainState, batch: Any):
         def loss_of_params(params, batch, micro_idx=0):
@@ -230,7 +288,7 @@ def make_train_step(
                     deterministic=False,
                     rngs={"dropout": jax.random.fold_in(key, micro_idx)},
                 )
-            aux = 0.0
+            aux, counted = 0.0, {}
             if aux_loss_collection is not None:
                 y, mut = state.apply_fn(
                     {"params": params},
@@ -240,14 +298,29 @@ def make_train_step(
                 )
                 for leaf in jax.tree.leaves(mut):
                     aux = aux + jnp.sum(leaf)
+            elif routing_stats:
+                from learning_jax_sharding_tpu.models.moe import ROUTING_STATS
+
+                y, mut = state.apply_fn(
+                    {"params": params}, _inputs_of(batch),
+                    mutable=(ROUTING_STATS,), **kwargs,
+                )
+                layers = jnp.stack(jax.tree.leaves(mut))       # (layers, 3)
+                counted = {
+                    "moe_held_assignments": jnp.sum(layers[:, 0]),
+                    "moe_experts_touched": jnp.sum(layers[:, 1]),
+                    "moe_expert_max_load": jnp.max(layers[:, 2]),
+                }
             else:
                 y = state.apply_fn({"params": params}, _inputs_of(batch), **kwargs)
             loss_args = (y, batch, params) if loss_needs_params else (y, batch)
-            return loss_fn(*loss_args) + aux
+            return loss_fn(*loss_args) + aux, counted
 
-        grad_fn = jax.value_and_grad(loss_of_params)
+        # ``counted`` rides as the auxiliary output: {} without routing_stats.
+        grad_fn = jax.value_and_grad(loss_of_params, has_aux=True)
+        counted = {}
         if grad_accum_steps == 1:
-            loss, grads = grad_fn(state.params, batch)
+            (loss, counted), grads = grad_fn(state.params, batch)
         else:
             accum_idx = jnp.arange(grad_accum_steps)
             def to_micro(x):
@@ -264,7 +337,7 @@ def make_train_step(
 
             def body(acc, idx_mb):
                 idx, mb = idx_mb
-                loss_i, grads_i = grad_fn(state.params, mb, idx)
+                (loss_i, _), grads_i = grad_fn(state.params, mb, idx)
                 return (
                     acc[0] + loss_i,
                     jax.tree.map(jnp.add, acc[1], grads_i),
@@ -295,8 +368,9 @@ def make_train_step(
                         sel, new_state.opt_state, state.opt_state
                     ),
                 )
-            return new_state, {"loss": loss, "grad_norm": gnorm}
-        return state.apply_gradients(grads=grads), loss
+            return new_state, {"loss": loss, "grad_norm": gnorm, **counted}
+        new_state = state.apply_gradients(grads=grads)
+        return new_state, {"loss": loss, **counted} if routing_stats else loss
 
     scalar_sh = NamedSharding(mesh, jax.sharding.PartitionSpec())
     if steps_per_call == 1:

@@ -217,3 +217,97 @@ class TestNemotronHConfig:
 
         with pytest.raises(ValueError, match=match):
             config_from_hf_nemotron_h(self._hf(**over))
+
+
+def test_lfm2_reference_and_program_match_transformers_at_a_tiny_dense_size():
+    """``transformers``' ``Lfm2Model`` (the dense sibling of ``lfm2_moe``: the
+    same gated short convolution, attention with q / k norms before RoPE,
+    SwiGLU, layer and final norm; 4.57.6 has no ``lfm2_moe``) on seeded
+    weights against the benchmark's plain reference with every layer dense,
+    and against the program reading the same tree. float32 on both sides,
+    two libraries' summation orders: 2e-5 of the largest logit (measured
+    3e-7)."""
+    import dataclasses
+    import pathlib
+    import sys
+
+    lfm2 = pytest.importorskip("transformers.models.lfm2")
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    from benchmark.families.lfm2_moe_reference import reference_fn
+    from learning_jax_sharding_tpu.models.transformer import TransformerConfig
+
+    d, heads, kv, inter, vocab = 64, 4, 2, 128, 96
+    kinds = ("conv", "full_attention", "conv")
+    hf_cfg = lfm2.Lfm2Config(
+        vocab_size=vocab, hidden_size=d, intermediate_size=inter,
+        num_hidden_layers=len(kinds), num_attention_heads=heads,
+        num_key_value_heads=kv, max_position_embeddings=128, norm_eps=1e-5,
+        rope_theta=1e6, conv_bias=False, conv_L_cache=3,
+        block_auto_adjust_ff_dim=False, layer_types=list(kinds),
+        tie_word_embeddings=True,
+    )
+    hf_cfg._attn_implementation = "eager"
+    torch.manual_seed(0)
+    model = lfm2.Lfm2Model(hf_cfg).eval().float()
+    with torch.no_grad():          # norm weights are ones at init: make them matter
+        for name, p in model.named_parameters():
+            if "norm" in name:
+                p.add_(0.1 * torch.randn_like(p))
+    sd = {k: v.detach().numpy() for k, v in model.state_dict().items()}
+
+    def lin(name):
+        return {"kernel": sd[name + ".weight"].T}
+
+    params = {
+        "tok_embed": {"embedding": sd["embed_tokens.weight"]},
+        "ln_out": {"scale": sd["embedding_norm.weight"]},
+    }
+    for i, kind in enumerate(kinds):
+        p = f"layers.{i}."
+        blk = {
+            "ln_ff": {"scale": sd[p + "ffn_norm.weight"]},
+            "ff": {
+                "gate": lin(p + "feed_forward.w1"), "up": lin(p + "feed_forward.w3"),
+                "down": lin(p + "feed_forward.w2"),
+            },
+        }
+        if kind == "conv":
+            blk["ln_conv"] = {"scale": sd[p + "operator_norm.weight"]}
+            blk["conv"] = {
+                "in_proj": lin(p + "conv.in_proj"), "out_proj": lin(p + "conv.out_proj"),
+                "conv": {"kernel": sd[p + "conv.conv.weight"][:, 0, :].T},   # (M,1,L) -> (L,M)
+            }
+        else:
+            blk["ln_attn"] = {"scale": sd[p + "operator_norm.weight"]}
+            blk["attn"] = {
+                "query": lin(p + "self_attn.q_proj"), "key": lin(p + "self_attn.k_proj"),
+                "value": lin(p + "self_attn.v_proj"), "out": lin(p + "self_attn.out_proj"),
+                "q_norm": {"scale": sd[p + "self_attn.q_layernorm.weight"]},
+                "k_norm": {"scale": sd[p + "self_attn.k_layernorm.weight"]},
+            }
+        params[f"block_{i}"] = blk
+    dims = {
+        "layer_types": kinds, "num_dense_layers": len(kinds), "num_heads": heads,
+        "num_kv_heads": kv, "head_dim": d // heads, "norm_eps": 1e-5, "rope_theta": 1e6,
+    }
+    tokens = _tokens(2, 24, v=vocab)
+    with torch.no_grad():
+        hidden = model(torch.tensor(tokens)).last_hidden_state.numpy()
+    want = hidden @ sd["embed_tokens.weight"].T
+
+    def rel(got):
+        return np.max(np.abs(np.asarray(got) - want)) / np.max(np.abs(want))
+
+    assert rel(reference_fn(dims)(params, jnp.asarray(tokens))) < 2e-5
+    cfg = TransformerConfig(
+        vocab_size=vocab, num_layers=len(kinds), layer_types=kinds, features=d,
+        num_heads=heads, num_kv_heads=kv, head_dim=d // heads, hidden=inter,
+        max_seq_len=128, norm="rmsnorm", norm_eps=1e-5, rope=True, rope_theta=1e6,
+        qk_norm=True, ff_gated=True, tie_embeddings=True, dtype=jnp.float32,
+    )
+    assert dataclasses.replace(cfg).param_count == sum(
+        np.size(x) for x in jax.tree.leaves(params)
+    )
+    with jax.default_matmul_precision("highest"):
+        logits = Transformer(cfg).apply({"params": params}, jnp.asarray(tokens))
+    assert rel(logits) < 2e-5

@@ -291,6 +291,35 @@ def test_ungated_latent_experts(one_chip, tokens, tm):
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
 
 
+# lfm2-8b-a1b.pretrain_8k's expert layer under a train step (PR 35): 8 HELD
+# experts of a 32-wide router at 2048 x 1792, float32 parameters read in
+# bf16, 2 x 8,192 tokens x top 4 = 65,536 assignments in tiles of 128. The
+# forward is the serving call; the backward adds moe.experts_dx and
+# moe.experts_dw over the same tile list.
+def test_moe_experts_train_step_kernels(one_chip):
+    from learning_jax_sharding_tpu.ops.moe_experts import (
+        routed_experts,
+        tile_rows,
+    )
+
+    tokens, held, d, f, k = 16384, 8, 2048, 1792, 4
+    assert tile_rows(tokens * k, held) == 128
+
+    def loss(x, w, w_gate, w_up, w_down, idx):
+        out, _ = routed_experts(
+            x, idx, w, w_gate, w_up, w_down, backend="pallas",
+            interpret=False, first=0,
+        )
+        return out.astype(F32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2, 3, 4))
+    shapes = [
+        ((tokens, d), BF16), ((tokens, k), F32), ((held, d, f), F32),
+        ((held, d, f), F32), ((held, f, d), F32), ((tokens, k), I32),
+    ]
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 3
+
+
 # The same cell's Mamba-2 layers: 128 heads x 64 in 8 groups, state 128, the
 # state a slot as (64 pairs, 128, 128) float32; 32 rows, one token or one
 # 128-token tile a row.
@@ -395,6 +424,10 @@ def _named_by_trace_metrics():
             out.append(pytest.param(
                 module, params.get("op"), id=f"{path.stem}-{module}"
             ))
+            # A reader that tells a kernel's backward calls from its forward
+            # (``trace_roofline_expert_passes``) names them too.
+            for op in params.get("backward", []):
+                out.append(pytest.param(module, op, id=f"{path.stem}-{module}-{op}"))
     return out
 
 
@@ -581,6 +614,42 @@ def step_programs(topo):
                 k: jax.ShapeDtypeStruct(tokens.shape, I32, sharding=batch_sh)
                 for k in ("inputs", "targets")
             }
+            step = make_train_step(
+                state_sh, {k: batch_sh for k in batch}, mesh, RULES_DP_TP,
+                loss_fn=fused_next_token_loss, loss_needs_params=True,
+                apply_kwargs={"return_hidden": True},
+            )
+            compiled.append(step.jitted.lower(state, batch))
+
+        # The lfm2_moe family's train step at small widths (PR 35): a short
+        # convolution, GQA with q / k norms, a dense layer then dropless
+        # experts held in part, a tied head, remat: ``moe.experts`` forward
+        # and its two backward calls by name, inside ``jit_step``.
+        lfm2_cfg = TransformerConfig(
+            vocab_size=512, num_layers=3, features=128, num_heads=4,
+            num_kv_heads=2, head_dim=64, hidden=256, max_seq_len=1024,
+            dtype=BF16, param_dtype=F32, norm="rmsnorm", rope=True,
+            qk_norm=True, layer_types=("conv", "full_attention", "conv"),
+            ff_gated=True, first_k_dense=1, num_experts=8, moe_top_k=2,
+            moe_hidden=128, moe_routing="sigmoid_dropless", moe_held=(0, 4),
+            moe_renorm_eps=1e-6, tie_embeddings=True, remat=True,
+            attn_fn=make_flash_attn_fn(interpret=False),
+        )
+        lfm2_module = Transformer(lfm2_cfg)
+
+        def lfm2_init(key, x):      # its own function: eval_shape caches by it
+            return TrainState.create(
+                apply_fn=lfm2_module.apply, tx=optimizer,
+                params=lfm2_module.init({"params": key}, x)["params"],
+            )
+
+        with activate(mesh, RULES_DP_TP):
+            abstract = jax.eval_shape(lfm2_init, jax.random.key(0), tokens)
+            state_sh = tree_shardings(abstract, mesh, RULES_DP_TP)
+            state = jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                nn.meta.unbox(abstract), state_sh,
+            )
             step = make_train_step(
                 state_sh, {k: batch_sh for k in batch}, mesh, RULES_DP_TP,
                 loss_fn=fused_next_token_loss, loss_needs_params=True,
