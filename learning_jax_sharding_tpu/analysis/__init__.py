@@ -164,38 +164,54 @@ def run_jaxpr_pass(
     programs: list | None = None,
     program_seconds: dict | None = None,
 ) -> list[Finding]:
-    """Jaxpr + donation lint over the train-shaped entry points (serving
-    programs manage buffers through the engine's slot pool, not
-    donation). The jaxpr rules (f32 promotions, f32 dots in bf16 graphs,
-    dead equations) gate through per-program budgets in the baseline
-    file's ``jaxpr_budgets`` section — the framework's own traces carry
-    a known population of trivially-DCE'd flax/optax internals (recorded
-    as a ceiling, so NEW dead compute still fails), while the precision
-    rules run at zero budget."""
+    """Jaxpr lint over the train-shaped entry points and the donation
+    audit over every entry point that has one: the train steps AND the
+    engine's step programs, which donate their cache
+    (``models/engine_programs.py::_donating``). The jaxpr rules (f32
+    promotions, f32 dots in bf16 graphs, dead equations) gate through
+    per-program budgets in the baseline file's ``jaxpr_budgets`` section —
+    the framework's own traces carry a known population of trivially-DCE'd
+    flax/optax internals (recorded as a ceiling, so NEW dead compute still
+    fails), while the precision rules run at zero budget. The donation
+    rules gate the same way through ``donation_budgets``:
+    ``donation-not-applied`` at zero everywhere (a cache leaf the
+    executable does not alias is a second pool in HBM), ``donation-missed``
+    at the count of a program's small per-dispatch operands that happen to
+    match an output (recorded with its reason)."""
     import json
 
     from learning_jax_sharding_tpu.analysis.entrypoints import (
         build_entry_programs,
     )
 
-    budgets: dict = {}
+    sections: dict = {}
     if baseline is not None:
         p = pathlib.Path(baseline)
         if p.exists() and p.read_text().strip():
-            budgets = json.loads(p.read_text()).get("jaxpr_budgets", {})
+            sections = json.loads(p.read_text())
+
+    def over_budget(found, section, name):
+        # Findings beyond the program's per-rule ceiling in ``section``.
+        allowed = sections.get(section, {}).get(name, {})
+        used: dict[str, int] = {}
+        for f in found:
+            used[f.rule] = used.get(f.rule, 0) + 1
+            if used[f.rule] > int(allowed.get(f.rule, 0)):
+                yield f
+
     findings: list[Finding] = []
     for prog in (programs if programs is not None
                  else build_entry_programs(names)):
         with _program_timer(program_seconds, prog.name):
             if prog.donation is not None:
-                findings.extend(prog.donation()["findings"])
+                findings.extend(over_budget(
+                    prog.donation()["findings"], "donation_budgets",
+                    prog.name,
+                ))
             if prog.jaxpr is not None:
-                used: dict[str, int] = {}
-                allowed = budgets.get(prog.name, {})
-                for f in prog.jaxpr():
-                    used[f.rule] = used.get(f.rule, 0) + 1
-                    if used[f.rule] > int(allowed.get(f.rule, 0)):
-                        findings.append(f)
+                findings.extend(
+                    over_budget(prog.jaxpr(), "jaxpr_budgets", prog.name)
+                )
     return findings
 
 

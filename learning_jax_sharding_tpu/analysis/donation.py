@@ -52,6 +52,19 @@ def aliased_params(compiled_text: str) -> set[int]:
     return set()
 
 
+def _kept_args(lowered: Any, n_in: int) -> list[int]:
+    """Flat argument index of each HLO parameter, in parameter order.
+    ``jax.jit`` drops the arguments its jaxpr never reads before it lowers
+    (an engine's greedy programs never read ``rng``, a speculative refill
+    never reads the draft's head), so parameter ``p`` of the compiled
+    module is the ``p``-th KEPT argument, not argument ``p``."""
+    try:
+        kept = sorted(lowered._lowering.compile_args["kept_var_idx"])
+    except (AttributeError, KeyError, TypeError):
+        return list(range(n_in))
+    return kept if kept and kept[-1] < n_in else list(range(n_in))
+
+
 def _device_bytes(info: Any, sharding: Any = None) -> int:
     """Per-device bytes of one buffer: the shard's shape when the
     compiled sharding is known, the logical shape otherwise (identical on
@@ -139,7 +152,10 @@ def report_from_lowered(lowered: Any, compiled_text: str, *,
         lowered.out_info,
         is_leaf=lambda x: hasattr(x, "shape") and hasattr(x, "dtype"),
     )
-    aliases = aliased_params(compiled_text)
+    kept = _kept_args(lowered, len(in_leaves))
+    aliases = {
+        kept[p] for p in aliased_params(compiled_text) if p < len(kept)
+    }
     in_sh, out_sh = _flat_shardings(compiled, len(in_leaves),
                                     len(out_leaves))
 

@@ -204,6 +204,24 @@ def _sharded_serving_params(model, mesh, rules):
         )(jax.random.key(0), probe)["params"]
 
 
+def _engine_audit(built: dict, ensure: Callable) -> Callable[[], dict]:
+    """The donation hook of the entries one live engine contributes:
+    ``ContinuousEngine.donation_audit`` under the contract names, made
+    once (``ensure`` builds and serves the engine into ``built``)."""
+
+    def audit():
+        if "donation" not in built:
+            ensure()
+            eng = built["eng"]
+            built["donation"] = {
+                eng.contract_name(k): v
+                for k, v in eng.donation_audit().items()
+            }
+        return built["donation"]
+
+    return audit
+
+
 def _engine_programs(
     *, speculative: bool, mixed: bool = False, adapters: bool = False,
     horizon: int = 1, compression: bool = False,
@@ -316,6 +334,8 @@ def _engine_programs(
             built["sf"] = built["eng"].explain_collectives()
         return built["sf"]
 
+    audit = _engine_audit(built, ensure)
+
     if compression:
         # The q8 engines contribute only their fused-family golden (the
         # engine names them itself: contract_name suffixes _q8 while the
@@ -343,6 +363,7 @@ def _engine_programs(
     return [
         EntryProgram(
             name, mesh, lambda name=name: ensure()[name],
+            donation=lambda name=name: audit()[name],
             shardflow=lambda name=name: explain()[name],
         )
         for name in names
@@ -430,9 +451,12 @@ def _kv_transfer_programs() -> list[EntryProgram]:
             built["sf"] = built["eng"].explain_collectives()
         return built["sf"]
 
+    audit = _engine_audit(built, ensure)
+
     return [
         EntryProgram(
             name, mesh, lambda name=name: ensure()[name],
+            donation=lambda name=name: audit()[name],
             shardflow=lambda name=name: explain()[name],
         )
         for name in ("kv_export", "kv_ingest")
@@ -504,9 +528,12 @@ def _kv_page_programs(*, compression: bool = False) -> list[EntryProgram]:
             built["sf"] = built["eng"].explain_collectives()
         return built["sf"]
 
+    audit = _engine_audit(built, ensure)
+
     return [
         EntryProgram(
             name, mesh, lambda name=name: ensure()[name],
+            donation=lambda name=name: audit()[name],
             shardflow=lambda name=name: explain()[name],
         )
         for name in (

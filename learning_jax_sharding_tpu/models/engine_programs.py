@@ -66,6 +66,47 @@ _SLOT_STATE_KEYS = ("ssm_state", "conv_state")
 _ROW_CARRY_KEY = "carry_from"
 
 
+def _is_table(path) -> bool:
+    return getattr(path[-1], "key", None) == "block_table"
+
+
+@functools.cache
+def _table_mask(treedef) -> tuple[bool, ...]:
+    # Which leaves of a tree of this shape are block tables. By treedef,
+    # once: the engine splits its cache in front of every dispatch, and
+    # flattening WITH paths is most of what that costs.
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        treedef.unflatten(range(treedef.num_leaves))
+    )
+    return tuple(_is_table(path) for path, _ in flat)
+
+
+def split_cache(tree: Any) -> tuple[Any, list]:
+    """``(tree with None in place of every block_table leaf, those leaves
+    in flatten order)``: the two halves a step program's jit boundary
+    takes a cache in (:func:`_donating`). ``tree`` is anything that holds
+    cache leaves (one cache, a speculative pair, a program's whole output);
+    a contiguous cache has no tables and comes back as it is."""
+    flat, treedef = jax.tree.flatten(tree)
+    mask = _table_mask(treedef)
+    if not any(mask):
+        return tree, []
+    return treedef.unflatten(
+        None if table else x for x, table in zip(flat, mask)
+    ), [x for x, table in zip(flat, mask) if table]
+
+
+def merge_cache(tree: Any, tables: list) -> Any:
+    """:func:`split_cache`'s inverse: ``tables`` back where the Nones are."""
+    if not tables:
+        return tree
+    it = iter(tables)
+    return jax.tree.map(
+        lambda x: next(it) if x is None else x, tree,
+        is_leaf=lambda x: x is None,
+    )
+
+
 @dataclasses.dataclass
 class Program:
     """One row of an engine's program table."""
@@ -832,7 +873,7 @@ class _Bodies:
 
 
 def _fused(name, body, apply, adapter):
-    """The jitted fused program ``name`` over ``body(apply_fn, params,
+    """The fused program ``name`` over ``body(apply_fn, params,
     *operands)``: a multi-LoRA engine's takes ``(pool, aidx)`` after
     ``params`` and runs the body over the adapter-gathered apply; the
     other operands (and the outputs) are the body's own."""
@@ -849,7 +890,33 @@ def _fused(name, body, apply, adapter):
             return body(apply, params, *operands)
 
     program.__name__ = program.__qualname__ = name
-    return jax.jit(program)
+    return program
+
+
+def _donating(program, *cache_args):
+    """THE donation rule: ``program`` jitted so that every cache it takes
+    and returns is updated in place. ``cache_args`` are the (adjacent)
+    positions of its cache arguments; the jitted function takes one
+    argument more, right after the last of them: the caches' ``block_table``
+    leaves (:func:`split_cache`), which stay OUT of the donated trees and
+    are not returned. One device array sits under every layer's table leaf
+    (``ContinuousEngine._set_tables``) and a buffer cannot be donated
+    twice; no program changes a table, so the host puts back the ones it
+    holds. Everything else in a cache (pools, counters, scales,
+    ``moe_stats``, recurrent state) is donated: XLA aliases each to the
+    output that replaces it, and a dispatch neither allocates nor copies a
+    second pool. ``program`` itself is traced over the merged trees, as it
+    always was. A contiguous cache has no tables and donates whole."""
+    lo, at = cache_args[0], cache_args[-1] + 1
+
+    @functools.wraps(program)
+    def split_program(*args):
+        args = list(args)
+        tables = args.pop(at)
+        args[lo:at] = merge_cache(args[lo:at], tables)
+        return split_cache(program(*args))[0]
+
+    return jax.jit(split_program, donate_argnums=cache_args)
 
 
 def _kv_programs():
@@ -971,38 +1038,46 @@ def build_programs(
         table[family] = Program(family, fn, contract or family, **flags)
 
     add("first_refill", jax.jit(counted(b.first_refill, None)), "first_prefill")
-    add("refill_step", jax.jit(counted(b.refill_step, 2)), "prefill")
+    add("refill_step", _donating(counted(b.refill_step, 2), 2), "prefill")
     if speculative:
-        add("decode_block_spec", jax.jit(b.decode_block_spec), "decode_step")
+        add(
+            "decode_block_spec", _donating(b.decode_block_spec, 2, 3),
+            "decode_step",
+        )
     # On a speculative engine the degradation ladder's target-only decode:
     # the same program a plain engine runs, under the plain golden.
     add(
-        "decode_block", jax.jit(counted(b.decode_block, 1)), "decode_step",
-        steady=not speculative,
+        "decode_block", _donating(counted(b.decode_block, 1), 1),
+        "decode_step", steady=not speculative,
     )
     if mixed:
         tenant = "adapter_" if adapter else ""
         spec = "spec_" if speculative else ""
-        add(f"{tenant}mixed_step", _fused(
+        # After params (and a pool's two operands): the cache, or the
+        # draft's params and the pair's two.
+        at = 3 if adapter else 1
+        caches = (at + 1, at + 2) if speculative else (at,)
+        add(f"{tenant}mixed_step", _donating(_fused(
             f"{tenant}{spec}mixed_step",
             b.spec_mixed_core if speculative else b.mixed_core,
             apply, adapter,
-        ))
-        add(f"{tenant}multi_step", _fused(
+        ), *caches))
+        add(f"{tenant}multi_step", _donating(_fused(
             f"{tenant}{spec}multi_step",
             b.spec_multi_scan if speculative else b.multi_scan,
             apply, adapter,
-        ), steady=False)
+        ), *caches), steady=False)
     kv_export, kv_ingest, kv_page_spill, kv_page_fill = _kv_programs()
     if kv_rows and not (speculative or paged or adapter):
         # The disaggregated handoff moves contiguous (B, L, N_kv, H) rows.
         add("kv_export", jax.jit(kv_export), steady=False, applies=False)
-        add("kv_ingest", jax.jit(kv_ingest), steady=False, applies=False)
+        add("kv_ingest", _donating(kv_ingest, 0), steady=False,
+            applies=False)
     if paged and prefix_cache and not speculative:
         # The tier ladder spills and fills retained prefix pages.
         add("kv_page_spill", jax.jit(kv_page_spill), steady=False,
             applies=False)
-        add("kv_page_fill", jax.jit(kv_page_fill), steady=False,
+        add("kv_page_fill", _donating(kv_page_fill, 0), steady=False,
             applies=False)
     return table
 

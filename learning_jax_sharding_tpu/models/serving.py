@@ -107,8 +107,11 @@ from learning_jax_sharding_tpu.models.engine_programs import (
     _PAGE_LEAF_KEYS,
     _SLOT_STATE_KEYS,
     Program,
+    _is_table,
     build_drift_probe,
     build_programs,
+    merge_cache,
+    split_cache,
 )
 from learning_jax_sharding_tpu.models.transformer import (
     Transformer,
@@ -1183,6 +1186,12 @@ class ContinuousEngine:
             "of the loop form's DMA ring, 0 where the pipeline-emitter form "
             "runs (ops.decode_attention.pages_in_flight: fixed when the "
             "programs are traced, from the cache's shape and dtype)")
+        self._g_donated_bytes = r.gauge(
+            "engine_cache_donated_bytes",
+            "bytes of the cache leaves the step programs update in place "
+            "(donated and aliased to their successors: pools, counters, "
+            "scales, expert counts, recurrent state; the block tables are "
+            "kept out); 0 before a cache exists")
         self._c_decode_steps = r.counter(
             "engine_decode_steps_total",
             "decode row-steps advanced (tokens emitted after the first)")
@@ -1359,6 +1368,38 @@ class ContinuousEngine:
                 self._in_flight = in_flight
                 if not in_flight:
                     self.ledger.device_empty()
+
+    def _cache_args(self):
+        # The engine's cache as a program's cache arguments: the one
+        # tree, or a speculative engine's (target, draft) pair as two.
+        return self._cache if self._speculative else (self._cache,)
+
+    def _enqueue(self, prog, head, tail, *, cache=None, frame=True):
+        """Call a step program that DONATES its cache: ``prog.fn(*head,
+        <the cache arguments without their block tables>, <the tables>,
+        *tail)`` (``engine_programs._donating``). ``cache()`` gives the
+        cache arguments as they stand (default: ``_cache_args``). The
+        call consumes what it is given; its outputs come back with the
+        tables the host holds put back under the caches that replace
+        them, and the caller installs those in ``self._cache`` before it
+        does anything else: the engine never keeps a consumed tree across
+        a statement that can raise (a call that raises after it took the
+        cache: ``_on_dispatch_fault``). ``prog.last_args`` becomes this
+        call's arguments with the cache read LIVE at relower time (a
+        captured tree would be a consumed one, or pin a stale pool in
+        HBM). Splitting and merging are host work outside the enqueue
+        span."""
+        cache = cache or self._cache_args
+
+        def args():
+            caches, tables = split_cache(tuple(cache()))
+            return (*head, *caches, tables, *tail), tables
+
+        operands, tables = args()
+        with self._led_device(prog) if frame else contextlib.nullcontext():
+            out = prog.fn(*operands)
+        prog.last_args = lambda: args()[0]
+        return merge_cache(out, tables)
 
     def _win_delta(self, counter):
         # The stats window (reset_stats → snapshot) over a cumulative
@@ -1683,10 +1724,11 @@ class ContinuousEngine:
         # narrower — same prefix, same page ids — so a speculative
         # engine pushes one or two arrays, every other engine one). The
         # cost of a push is per array (allocate, linearize, transfer),
-        # not per byte. Sharing one buffer under many arguments is legal
-        # because no step program donates its cache: the PR that brings
-        # donation (ROADMAP S3b) must keep the tables out of the donated
-        # tree — a buffer cannot be donated twice. Skipped entirely when
+        # not per byte. Sharing one buffer under many leaves is legal
+        # because the tables are never donated: the step programs take
+        # them beside the donated cache and do not return them
+        # (engine_programs._donating), so what is installed here stays
+        # under its leaves until the next push. Skipped entirely when
         # no allocation changed since the last push — the steady-state
         # decode loop mostly doesn't allocate. The push is the frame
         # ``engine.h2d``; ``frame=False`` is for a caller outside step().
@@ -1694,16 +1736,13 @@ class ContinuousEngine:
             return cache
         self._tables_dirty = False
 
-        def is_table(path):
-            return getattr(path[-1], "key", None) == "block_table"
-
         if self._table_widths is None:
             # Counted once, the cache's tree never changes shape: the
             # width of every table leaf (one leaf a layer).
             self._table_widths = Counter(
                 x.shape[1]
                 for path, x in jax.tree_util.tree_flatten_with_path(cache)[0]
-                if is_table(path)
+                if _is_table(path)
             )
         leaves, arrays = self._table_widths.total(), len(self._table_widths)
         self._c_table_leaves.inc(leaves)
@@ -1720,7 +1759,7 @@ class ContinuousEngine:
                 for width in self._table_widths
             }
             return jax.tree_util.tree_map_with_path(
-                lambda path, x: pushed[x.shape[1]] if is_table(path) else x,
+                lambda path, x: pushed[x.shape[1]] if _is_table(path) else x,
                 cache,
             )
 
@@ -2189,6 +2228,9 @@ class ContinuousEngine:
             (getattr(path[-1], "key", None), x)
             for path, x in jax.tree_util.tree_flatten_with_path(self._cache)[0]
         ]
+        self._g_donated_bytes.set(sum(
+            x.nbytes for key, x in leaves if key != "block_table"
+        ))
         if self._ssm:
             self._g_ssm_bytes.set(sum(
                 x.nbytes for key, x in leaves if key in _SLOT_STATE_KEYS
@@ -2350,11 +2392,11 @@ class ContinuousEngine:
             slot_j, idx_j = jnp.int32(slot), jnp.int32(int(p.size))
             prog = self._programs["kv_ingest"]
             with activate(self._mesh, self._rules):
-                self._cache = prog.fn(self._cache, rows, slot_j, idx_j)
-            # Live-cache closure (see export_kv): only the one transferred
-            # row tree stays retained for relowering, never a stale copy of
-            # the whole pre-ingest cache.
-            prog.last_args = lambda: (self._cache, rows, slot_j, idx_j)
+                # (last_args: only the one transferred row tree stays
+                # retained for relowering, see export_kv.)
+                self._cache = self._enqueue(
+                    prog, (), (rows, slot_j, idx_j), frame=False
+                )
             now = time.perf_counter()
             r = _Request(
                 rid=rid, prompt=p,
@@ -2659,10 +2701,11 @@ class ContinuousEngine:
             pid_j = jnp.int32(pid)
             prog = self._programs["kv_page_fill"]
             with activate(self._mesh, self._rules):
-                self._cache = prog.fn(self._cache, dev_rows, pid_j)
-            # Only the one promoted row list stays retained for
-            # relowering, never a stale copy of the whole cache.
-            prog.last_args = lambda: (self._cache, dev_rows, pid_j)
+                # (last_args: only the one promoted row list stays
+                # retained for relowering.)
+                self._cache = self._enqueue(
+                    prog, (), (dev_rows, pid_j), frame=False
+                )
             self._prefix_registry[key] = pid
             self._key_of_page[pid] = key
             self._refcnt[pid] = 0
@@ -2933,7 +2976,11 @@ class ContinuousEngine:
         ``_unadmit``) and re-admitted ONE AT A TIME (probation, see
         ``_admit``) so the poison trips alone instead of striking its
         batchmates to death. The engine's device state needs no repair:
-        re-admission resets every per-row counter."""
+        re-admission resets every per-row counter. The one exception is a
+        program that raised AFTER it took the cache (a donated argument is
+        gone whether or not the call returns): that cache is dropped whole,
+        with the page pool's host state as ``close()`` drops it, and the
+        next dispatch creates a new one."""
         with self.ledger.measure("recovery", span="engine.recovery"):
             self._c_dispatch_faults.inc()
             self.recorder.record(
@@ -2955,6 +3002,13 @@ class ContinuousEngine:
                     self._fail_slot(slot, "poisoned", str(e), now)
                 else:
                     self._unadmit(slot)
+            if self._cache is not None and any(
+                x.is_deleted() for x in jax.tree.leaves(self._cache)
+            ):
+                self._cache = None
+                self._export_ok = {}
+                if self._paged:
+                    self._init_pool()
 
     def _consume(self, slot, tokens, now, retired):
         # Append a decode dispatch's tokens for one slot; retire at
@@ -3304,15 +3358,12 @@ class ContinuousEngine:
                     rows_d = jnp.asarray(rows)
                     offsets_d = jnp.asarray(offsets)
                 prog = self._programs["refill_step"]
-                with self._led_device(prog):
-                    tok_new, self._cache, *moe = prog.fn(
-                        params, d_params, self._cache, chunk_d, lengths_d,
-                        reset_d, reset_to_d, rid_d, self.rng, rows_d,
-                        offsets_d,
-                    )
-                prog.last_args = lambda: (
-                    params, d_params, self._cache, chunk_d, lengths_d,
-                    reset_d, reset_to_d, rid_d, self.rng, rows_d, offsets_d,
+                # The speculative pair is ONE argument of this program.
+                tok_new, self._cache, *moe = self._enqueue(
+                    prog, (params, d_params), (
+                        chunk_d, lengths_d, reset_d, reset_to_d, rid_d,
+                        self.rng, rows_d, offsets_d,
+                    ), cache=lambda: (self._cache,),
                 )
             # The dispatch has its own copy of the admission resets, so
             # consume the flags (every flagged slot had pending tokens and
@@ -3483,21 +3534,17 @@ class ContinuousEngine:
                 )
         if spec:
             prog = self._programs["decode_block_spec"]
-            t_cache, d_cache = self._cache
             segs = []
             for _ in range(chain):
-                with self._led_device(prog):
-                    (buffer, counts, acc, prop, tok_d, pos_d, active_d,
-                     remaining_d, t_cache, d_cache) = prog.fn(
-                        params, d_params, t_cache, d_cache, tok_d,
-                        active_d, pos_d, remaining_d, rid, self.rng,
-                    )
+                # Each link's caches are installed as it returns: the ones
+                # it was given are gone, and a later link may raise.
+                (buffer, counts, acc, prop, tok_d, pos_d, active_d,
+                 remaining_d, t_cache, d_cache) = self._enqueue(
+                    prog, (params, d_params),
+                    (tok_d, active_d, pos_d, remaining_d, rid, self.rng),
+                )
+                self._cache = (t_cache, d_cache)
                 segs.append((buffer, counts, acc, prop))
-            self._cache = (t_cache, d_cache)
-            prog.last_args = lambda: (
-                params, d_params, self._cache[0], self._cache[1], tok_d,
-                active_d, pos_d, remaining_d, rid, self.rng,
-            )
             # ONE sync for the whole chain.
             with self._led_device(family="decode_block_spec"):
                 segs = [
@@ -3519,38 +3566,28 @@ class ContinuousEngine:
                                 now, retired,
                             )
         else:
-            if self._speculative:
-                # Degraded: advance the TARGET cache only; the idle
-                # draft cache rides along untouched.
-                cache, d_cache = self._cache
-            else:
-                cache, d_cache = self._cache, None
+            def target():
+                # Degraded (a speculative engine): advance the TARGET
+                # cache only; the idle draft cache rides along untouched.
+                return (self._cache[0] if self._speculative else self._cache,)
+
             prog = self._programs["decode_block"]
             segs, moe_segs = [], []
             for _ in range(chain):
-                with self._led_device(prog):
-                    toks, active_d, remaining_d, cache, *moe = prog.fn(
-                        params, cache, tok_d, active_d, remaining_d, rid,
-                        self.rng,
-                    )
+                toks, active_d, remaining_d, cache, *moe = self._enqueue(
+                    prog, (params,),
+                    (tok_d, active_d, remaining_d, rid, self.rng),
+                    cache=target,
+                )
+                # Installed as each link returns (see the speculative arm).
+                self._cache = (
+                    (cache, self._cache[1]) if self._speculative else cache
+                )
                 # Next block's pending token: each row's last emitted
                 # (frozen rows repeat their token — correct carry).
                 tok_d = toks[:, -1]
                 segs.append(toks)
                 moe_segs += moe
-            # The slot reads the LIVE target cache at relower time.
-            if self._speculative:
-                self._cache = (cache, d_cache)
-                prog.last_args = lambda: (
-                    params, self._cache[0], tok_d, active_d, remaining_d,
-                    rid, self.rng,
-                )
-            else:
-                self._cache = cache
-                prog.last_args = lambda: (
-                    params, self._cache, tok_d, active_d, remaining_d,
-                    rid, self.rng,
-                )
             with self._led_device(family="decode_block"):
                 segs = [np.asarray(t) for t in segs]   # ONE sync
                 moe_segs = jax.device_get(moe_segs)    # the same programs' counts
@@ -3769,7 +3806,6 @@ class ContinuousEngine:
                 )
             if self._adapter_pool is not None:
                 aidx_d = jnp.asarray(self._aidx)
-        t_cache, d_cache = self._cache if self._speculative else (None, None)
         with self.ledger.measure("recovery", span="engine.recovery"):
             # Armed chaos delay books as recovery, never device.
             chaos_hook(
@@ -3798,27 +3834,22 @@ class ContinuousEngine:
                 per_link=per_link, chain_dec=chain_dec,
                 was_active=was_active, n_active=n_active, tok_d=tok_d,
                 active_d=active_d, remaining_d=remaining_d, rid=rid,
-                pos_d=pos_d, t_cache=t_cache, d_cache=d_cache,
-                adapter_ops=adapter_ops,
+                pos_d=pos_d, adapter_ops=adapter_ops,
             )
         prog = self._programs[
             "adapter_mixed_step" if adapter_ops else "mixed_step"
         ]
 
         def operands():
-            # As the locals stand: the call's operands before it, its
-            # results (the LIVE caches and carries, never the trees they
-            # replaced) after it.
+            # ``(head, tail)`` around the cache, as the locals stand.
             if self._speculative:
-                return (
-                    params, *adapter_ops, d_params, t_cache, d_cache,
+                return (params, *adapter_ops, d_params), (
                     chunk_d, lengths_d, reset_d, reset_to_d, tok_d,
                     active_d, pos_d, remaining_d, rid, self.rng,
                 )
-            return (
-                params, *adapter_ops, self._cache, chunk_d, lengths_d,
-                reset_d, reset_to_d, tok_d, active_d, remaining_d, rid,
-                self.rng,
+            return (params, *adapter_ops), (
+                chunk_d, lengths_d, reset_d, reset_to_d, tok_d, active_d,
+                remaining_d, rid, self.rng,
             )
 
         segs = []
@@ -3840,12 +3871,7 @@ class ContinuousEngine:
             starved_total += starved
             refill_scheduled += int(lengths.sum())
             if self._paged:
-                self._cache = (
-                    (t_cache, d_cache) if self._speculative else self._cache
-                )
                 self._cache = self._set_tables(self._cache)
-                if self._speculative:
-                    t_cache, d_cache = self._cache
             # COPIES of the admission resets (see _refill_dispatch: the
             # dispatch is async; an aliased in-place clear would corrupt
             # it). Link 0 carries every pending reset — including rows the
@@ -3857,18 +3883,17 @@ class ContinuousEngine:
                 lengths_d = jnp.asarray(lengths)
                 reset_d = jnp.asarray(self._needs_reset.copy())
                 reset_to_d = jnp.asarray(self._reset_to.copy())
-            with self._led_device(prog):
-                if self._speculative:
-                    (first_tok, buffer, counts, acc, prop, tok_d, pos_d,
-                     active_d, remaining_d, t_cache, d_cache) = prog.fn(
-                        *operands()
-                    )
-                else:
-                    first_tok, tok_d, active_d, remaining_d, self._cache = (
-                        prog.fn(*operands())
-                    )
-                    buffer = counts = acc = prop = None
-            prog.last_args = lambda a=operands(): a
+            if self._speculative:
+                (first_tok, buffer, counts, acc, prop, tok_d, pos_d,
+                 active_d, remaining_d, t_cache, d_cache) = self._enqueue(
+                    prog, *operands()
+                )
+                self._cache = (t_cache, d_cache)
+            else:
+                first_tok, tok_d, active_d, remaining_d, self._cache = (
+                    self._enqueue(prog, *operands())
+                )
+                buffer = counts = acc = prop = None
             self._needs_reset[:] = False
             self._reset_to[:] = 0
             # Advance the host-side pending views NOW (later links read
@@ -3890,8 +3915,6 @@ class ContinuousEngine:
         if not segs:
             return False
         self._c_prefill_tok.inc(refill_scheduled)
-        if self._speculative:
-            self._cache = (t_cache, d_cache)
         self.recorder.record(
             "engine.mixed_schedule", links=len(segs),
             decode_rows=n_active, refill_tokens=refill_scheduled,
@@ -4061,7 +4084,7 @@ class ContinuousEngine:
     def _multi_dispatch(
         self, params, d_params, retired, *, n_links, per_link, chain_dec,
         was_active, n_active, tok_d, active_d, remaining_d, rid,
-        pos_d=None, t_cache=None, d_cache=None, adapter_ops=(),
+        pos_d=None, adapter_ops=(),
     ):
         # The DEVICE-RESIDENT steady-state loop (horizon > 1): plan the
         # whole horizon's refill schedule host-side, dispatch ONE scanned
@@ -4109,12 +4132,7 @@ class ContinuousEngine:
             # All page allocation for the horizon happened in the plan
             # (refill) and the preamble's pre-ensure (decode): push the
             # final tables once for the whole horizon.
-            self._cache = (
-                (t_cache, d_cache) if self._speculative else self._cache
-            )
             self._cache = self._set_tables(self._cache)
-            if self._speculative:
-                t_cache, d_cache = self._cache
         live = np.zeros((n_links,), np.int32)
         live[:n_live] = 1
         with self._led_h2d():
@@ -4127,35 +4145,27 @@ class ContinuousEngine:
             "adapter_multi_step" if adapter_ops else "multi_step"
         ]
 
-        def operands():
-            # As in _mixed_dispatch: operands before the call, the live
-            # results after it.
-            if self._speculative:
-                return (
-                    params, *adapter_ops, d_params, t_cache, d_cache,
-                    chunks_d, lens_d, resets_d, reset_tos_d, live_d, tok_d,
-                    active_d, pos_d, remaining_d, rid, self.rng,
-                )
-            return (
-                params, *adapter_ops, self._cache, chunks_d, lens_d,
-                resets_d, reset_tos_d, live_d, tok_d, active_d, remaining_d,
-                rid, self.rng,
-            )
-
-        with self._led_device(prog):
-            if self._speculative:
-                (first_toks, buffers, counts, accs, props, tok_d, pos_d,
-                 active_d, remaining_d, t_cache, d_cache) = prog.fn(
-                    *operands()
-                )
-            else:
-                first_toks, tok_d, active_d, remaining_d, self._cache = (
-                    prog.fn(*operands())
-                )
-                buffers = counts = accs = props = None
-        prog.last_args = lambda a=operands(): a
         if self._speculative:
+            head, tail = (params, *adapter_ops, d_params), (
+                chunks_d, lens_d, resets_d, reset_tos_d, live_d, tok_d,
+                active_d, pos_d, remaining_d, rid, self.rng,
+            )
+        else:
+            head, tail = (params, *adapter_ops), (
+                chunks_d, lens_d, resets_d, reset_tos_d, live_d, tok_d,
+                active_d, remaining_d, rid, self.rng,
+            )
+        if self._speculative:
+            (first_toks, buffers, counts, accs, props, tok_d, pos_d,
+             active_d, remaining_d, t_cache, d_cache) = self._enqueue(
+                prog, head, tail
+            )
             self._cache = (t_cache, d_cache)
+        else:
+            first_toks, tok_d, active_d, remaining_d, self._cache = (
+                self._enqueue(prog, head, tail)
+            )
+            buffers = counts = accs = props = None
         self._needs_reset[:] = False
         self._reset_to[:] = 0
         self.recorder.record(
@@ -4723,6 +4733,22 @@ class ContinuousEngine:
         with activate(self._mesh, self._rules):
             return {
                 name: compiled_hlo(fn, *args)
+                for name, fn, args in self._dispatched_programs()
+            }
+
+    def donation_audit(self) -> dict[str, dict]:
+        """``analysis.donation.donation_report`` per dispatched engine
+        program: what each asked XLA to update in place (every cache leaf
+        but the block tables, ``engine_programs._donating``) and whether
+        the executable aliases it. Same AOT-relower cost and coverage as
+        :meth:`program_hlo`."""
+        from learning_jax_sharding_tpu.analysis.donation import (
+            donation_report,
+        )
+
+        with activate(self._mesh, self._rules):
+            return {
+                name: donation_report(fn, *args)
                 for name, fn, args in self._dispatched_programs()
             }
 

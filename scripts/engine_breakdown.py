@@ -18,8 +18,9 @@ Its input is a post-mortem bundle, ``engine.dump_diagnostics(outdir)``:
 * from the registry, cumulative since the engine was built (set-up's
   compiles included): ``engine_device_starved_seconds_total`` by span,
   over ``engine_step_seconds_total``, with the wait, enqueue and h2d
-  shares; and how many cache blocks the decode-attention kernel keeps in
-  flight (``engine_decode_attn_pages_in_flight``).
+  shares; how many cache blocks the decode-attention kernel keeps in
+  flight (``engine_decode_attn_pages_in_flight``) and the bytes of cache
+  the step programs update in place (``engine_cache_donated_bytes``).
 
 With ``--xplane`` (a ``jax.profiler`` capture taken while that engine
 served, a file or a directory holding one) it also places the bundle's
@@ -54,6 +55,8 @@ STEP = "engine_step_seconds_total"
 #: Gauge since PR 34: cache blocks the decode-attention kernel keeps in
 #: flight (0 = its pipeline-emitter form, one block beside the one computed).
 ATTN_DEPTH = "engine_decode_attn_pages_in_flight"
+#: Gauge since PR 36: bytes of the cache leaves the step programs donate.
+DONATED = "engine_cache_donated_bytes"
 SUMMED = (
     "starved_s", "enqueue_s", "wait_s", "h2d_s", "table_leaves",
     "prefill_tokens", "decode_steps", "context_tokens",
@@ -364,6 +367,7 @@ def main(argv=None) -> dict:
         "registry": starved_by_span(bundle["registry"]),
         "moe": moe_by_phase(bundle["registry"]),
         "decode_attn_pages_in_flight": bundle["registry"].get(ATTN_DEPTH),
+        "cache_donated_bytes": bundle["registry"].get(DONATED),
     }
     if args.xplane:
         out["capture"] = place_on_capture(bundle, load_capture(args.xplane))
@@ -388,6 +392,12 @@ def main(argv=None) -> dict:
         print(
             f"decode attention: {depth:.0f} cache blocks in flight"
             + (" (the loop form)" if depth else " (the emitter form)")
+        )
+    donated = out["cache_donated_bytes"]
+    if donated is not None:
+        print(
+            f"cache: {donated / 1e9:.3f} GB updated in place (donated to the "
+            "step programs; the block tables ride beside it)"
         )
     for phase, row in out["moe"].items():
         print(

@@ -270,3 +270,156 @@ def test_two_engines_share_no_executable_cache(make_engine):
     a.program("kv_export").fn({"x": jnp.zeros((2, 3))}, jnp.int32(0))
     assert cache_size(a.program("kv_export").fn) == 1
     assert cache_size(b.program("kv_export").fn) == 0
+
+
+# --- donation: a dispatch updates the cache in place -------------------------
+
+#: kind -> (config, engine keywords, the draft's ``max_seq_len`` or None).
+_PAGED = dict(paged_pages=9, page_size=16)
+DONATING = {
+    "split": (TINY, _PAGED, None),
+    "contiguous": (TINY, {}, None),
+    "int8_kv": (dataclasses.replace(TINY, kv_cache_dtype=jnp.int8), _PAGED, None),
+    "speculative_narrower_draft": (TINY, dict(_PAGED, num_draft=2), 32),
+    "mixed": (TINY, dict(_PAGED, mixed=True), None),
+    "mixed_horizon": (TINY, dict(_PAGED, mixed=True, horizon=4), None),
+    "latent_dropless": (LATENT, _PAGED, None),
+    "one_mixer_a_layer": (RECURRENT, _PAGED, None),
+}
+
+
+def _init(cfg, seed=0):
+    return nn.meta.unbox(
+        Transformer(cfg).init(
+            {"params": jax.random.key(seed)}, np.zeros((2, 8), np.int32)
+        )["params"]
+    )
+
+
+def _is_table(path):
+    return getattr(path[-1], "key", None) == "block_table"
+
+
+def _leaves(tree, tables):
+    """``tree``'s block tables, or everything else in it."""
+    return [
+        x for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
+        if _is_table(path) == tables
+    ]
+
+
+def _donating_engine(kind, mesh):
+    cfg, kw, draft_len = DONATING[kind]
+    d_params = None
+    if draft_len:
+        draft = dataclasses.replace(DRAFT, max_seq_len=draft_len)
+        kw, d_params = dict(kw, draft_config=draft), _init(draft, seed=7)
+    eng = ContinuousEngine(
+        cfg, mesh, RULES_TP_SERVING, batch_size=2, max_new_tokens=4,
+        refill_chunk=8, decode_block_steps=2, **kw,
+    )
+    rng = np.random.default_rng(5)
+    prompts = [
+        rng.integers(1, cfg.vocab_size, size=(n,)).astype(np.int32)
+        for n in (11, 3, 6)
+    ]
+    return eng, _init(cfg), d_params, prompts
+
+
+@pytest.fixture(scope="module")
+def mesh22():
+    return build_mesh((2, 2), ("data", "model"), devices=jax.devices()[:4])
+
+
+@pytest.mark.parametrize("kind", [*DONATING, "split@2x2", "contiguous@2x2"])
+def test_every_step_program_aliases_every_donated_cache_leaf(
+    kind, mesh, mesh22
+):
+    """The compiled executable of every program that takes the cache and
+    returns its successor aliases EVERY cache leaf but the block tables to
+    an output (``analysis/donation.py``: asked for and applied, none
+    dropped); no table is donated or returned; and the gauge
+    ``engine_cache_donated_bytes`` is the bytes of those leaves."""
+    from learning_jax_sharding_tpu.analysis.donation import report_from_lowered
+    from learning_jax_sharding_tpu.parallel.logical import activate
+
+    kind, _, on = kind.partition("@")
+    eng, params, d_params, prompts = _donating_engine(
+        kind, mesh22 if on else mesh
+    )
+    assert eng.registry.snapshot()["engine_cache_donated_bytes"] == 0
+    eng.serve(params, prompts, draft_params=d_params)
+    donated = _leaves(eng._cache, tables=False)
+    assert eng.registry.snapshot()["engine_cache_donated_bytes"] == sum(
+        x.nbytes for x in donated
+    ) > 0
+    tables = _leaves(eng._cache, tables=True)
+    assert bool(tables) == ("paged_pages" in DONATING[kind][1])
+    ran = {name: (fn, args) for name, fn, args in eng._dispatched_programs()}
+    assert set(ran) >= (
+        {"mixed_step"} if kind == "mixed" else {"multi_step"}
+        if kind == "mixed_horizon" else {"refill_step", "decode_block_spec"}
+        if "speculative" in kind else {"refill_step", "decode_block"}
+    )
+    for name, (fn, args) in ran.items():
+        if name == "first_refill":
+            continue                    # creates the cache, takes none
+        with activate(eng._mesh, eng._rules):
+            lowered = fn.lower(*args)
+            compiled = lowered.compile()
+        inputs = report_from_lowered(
+            lowered, compiled.as_text(), compiled=compiled
+        )["inputs"]
+        asked = [i for i in inputs if i["donated"]]
+        assert len(asked) == len(donated), name
+        assert all(i["verdict"] == "donated" for i in asked), (name, [
+            i for i in asked if i["verdict"] != "donated"
+        ])
+        # The tables ride beside the donated tree, as a list of their own:
+        # as many as the cache has, none donated, none among the outputs.
+        (beside,) = [a for a in lowered.args_info[0] if isinstance(a, list)]
+        assert len(beside) == len(tables), name
+        assert not any(i.donated for i in beside), name
+        assert not _leaves(lowered.out_info, tables=True), name
+
+
+@pytest.mark.parametrize("kind", ["split", "speculative_narrower_draft", "mixed"])
+def test_a_dispatch_consumes_the_cache_it_was_given(kind, mesh):
+    """After a dispatch the tree the engine held before it is gone (every
+    leaf but the tables, which were never the program's to take) and
+    ``eng._cache`` is the live one."""
+    eng, params, d_params, prompts = _donating_engine(kind, mesh)
+    for p in prompts:
+        eng.add_request(p)
+    eng.step(params, d_params)
+    while eng.has_work():
+        before = eng._cache
+        eng.step(params, d_params)
+        assert all(x.is_deleted() for x in _leaves(before, tables=False))
+        assert not any(x.is_deleted() for x in _leaves(before, tables=True))
+        assert not any(x.is_deleted() for x in jax.tree.leaves(eng._cache))
+
+
+def test_the_cache_moving_programs_donate_too(make_engine, tiny_params):
+    """``kv_ingest`` and ``kv_page_fill`` replace the engine's cache and
+    take it donated; ``kv_export`` and ``kv_page_spill`` only read it."""
+    eng = make_engine({})
+    rng = np.random.default_rng(2)
+    prompt = rng.integers(1, TINY.vocab_size, size=(9,)).astype(np.int32)
+    (out,) = eng.serve(tiny_params, [prompt])
+    rows, _ = eng.export_kv(0)
+    before = eng._cache
+    assert not any(x.is_deleted() for x in jax.tree.leaves(before))
+    eng.ingest_kv(tiny_params, prompt, int(out[len(prompt)]), rows, rid=1)
+    assert all(x.is_deleted() for x in jax.tree.leaves(before))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng._cache))
+
+    eng = make_engine(dict(paged_pages=10, page_size=4, prefix_cache=True))
+    eng.serve(tiny_params, [prompt])
+    (key, *_) = eng.retained_prefixes()
+    before = eng._cache
+    page, _ = eng.spill_page(key, drop=True)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(before))
+    eng.fill_page(key, page)
+    assert all(x.is_deleted() for x in _leaves(before, tables=False))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng._cache))

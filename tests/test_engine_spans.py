@@ -848,6 +848,23 @@ def test_the_decode_attention_gauge_is_the_kernels_own_rule(served, tmp_path, ca
     assert "0 cache blocks in flight (the emitter form)" in capsys.readouterr().out
 
 
+def test_the_donated_bytes_gauge_is_the_cache_without_its_tables(served, tmp_path, capsys):
+    """``engine_cache_donated_bytes``: every cache leaf but the block tables
+    (what the step programs update in place), printed by the tool."""
+    eng = served["eng"]
+    flat = jax.tree_util.tree_flatten_with_path(eng._cache)[0]
+    want = sum(
+        x.nbytes for path, x in flat
+        if getattr(path[-1], "key", None) != "block_table"
+    )
+    assert 0 < want < sum(x.nbytes for _, x in flat)
+    assert served["end"]["engine_cache_donated_bytes"] == want
+    eng.dump_diagnostics(tmp_path)
+    out = engine_breakdown.main([str(tmp_path)])
+    assert out["cache_donated_bytes"] == want
+    assert f"cache: {want / 1e9:.3f} GB updated in place" in capsys.readouterr().out
+
+
 def test_the_breakdown_tool_prints_the_carried_rows(served_ssm, tmp_path, capsys):
     served_ssm["eng"].dump_diagnostics(tmp_path)
     out = engine_breakdown.main([str(tmp_path)])

@@ -334,3 +334,104 @@ def test_rows_take_their_slots_counters_and_give_back_the_furthest():
     np.testing.assert_array_equal(back["position"], [5, 0, 8, 6])
     np.testing.assert_array_equal(back["layer"]["block_table"], cache["layer"]["block_table"])
     assert float(back["layer"]["cached_kv"].min()) == 1.0
+
+
+# --- faults under donation: the engine never keeps a consumed cache ----------
+
+def _raising_after(eng, family, nth, error):
+    """Make the ``nth`` call of ``family``'s program raise AFTER it ran: the
+    cache it was given is consumed and its successor is lost (what a trap
+    on a program's outputs does)."""
+    prog, calls = eng.program(family), []
+    real = prog.fn
+
+    def fn(*args):
+        calls.append(None)
+        out = real(*args)
+        if len(calls) == nth:
+            prog.fn = real
+            raise error("trapped after the call")
+        return out
+
+    prog.fn = fn
+
+
+@pytest.mark.parametrize("family,nth,chain", [
+    ("refill_step", 1, 1),      # the packed dispatch of the wave
+    ("refill_step", 2, 2),      # the second link of a chained refill
+    ("decode_block", 2, 2),     # the second link of a chained decode
+])
+def test_a_program_that_raises_after_it_took_the_cache_costs_a_recompute(
+    mesh11, family, nth, chain
+):
+    """The program consumed the engine's cache and its result never came
+    back: the fault handler drops the dead tree with the pool's host state,
+    the next dispatch creates a cache, and every request is recomputed to
+    the tokens of an unfaulted run."""
+    eng, params, prompts = _four_slots(
+        mesh11, decode_chain=chain, decode_block_steps=2
+    )
+    clean = eng.serve(params, prompts)
+    created = eng.cache_creations
+    for p in prompts:
+        eng.add_request(p)                                   # rids 4..7
+    if family == "refill_step" and chain == 1:
+        eng.step(params)        # the first chunks; the packed dispatch next
+    _raising_after(eng, family, nth, FloatingPointError)
+    while eng.registry.counter("engine_dispatch_faults_total").value == 0:
+        eng.step(params)
+    assert eng._cache is None and eng.cache_creations == created
+    assert len(eng._free_pages) == 23 and not any(eng._held)
+    assert [r.strikes for r in eng._queue] == [1, 1, 1, 1]
+    out = _drain(eng, params)
+    assert eng.cache_creations == created + 1
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng._cache))
+    for rid, want in zip((4, 5, 6, 7), clean):
+        np.testing.assert_array_equal(out[rid], want)
+    for g, w in zip(eng.serve(params, prompts), clean):     # a later clean run
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("phase", ["refill", "decode"])
+def test_a_fault_between_two_links_of_a_chain_finds_the_live_cache(mesh11, phase):
+    """A recoverable fault raised mid-chain, after a link that consumed the
+    cache it was given: the engine holds that link's result (installed as
+    the link returned, not after the loop), keeps it, and re-admits."""
+    eng, params, prompts = _four_slots(
+        mesh11, decode_chain=2, decode_block_steps=2
+    )
+    clean = eng.serve(params, prompts)
+    created = eng.cache_creations
+    for p in prompts:
+        eng.add_request(p)                                   # rids 4..7
+    if phase == "refill":
+        # The seam sits in front of every link of a refill chain.
+        with ChaosInjector(Fault("engine.dispatch", "hang", at=1)) as chaos:
+            eng.step(params)
+        assert chaos.injections[0]["phase"] == "refill"
+    else:
+        # A decode chain has one seam, in front of its first link: the
+        # second link's program raises before it runs.
+        eng.step(params)        # admission and the whole refill chain
+        assert eng._active.all()
+        prog, real = eng.program("decode_block"), eng.program("decode_block").fn
+
+        def fn(*args):
+            prog.fn = real
+            if fn.calls:
+                raise FloatingPointError("trapped before the call")
+            fn.calls += 1
+            prog.fn = fn
+            return real(*args)
+
+        fn.calls = 0
+        prog.fn = fn
+        eng.step(params)
+        assert prog.fn is real
+    assert eng.registry.counter("engine_dispatch_faults_total").value == 1
+    assert eng._cache is not None and eng.cache_creations == created
+    assert not any(x.is_deleted() for x in jax.tree.leaves(eng._cache))
+    out = _drain(eng, params)
+    assert eng.cache_creations == created
+    for rid, want in zip((4, 5, 6, 7), clean):
+        np.testing.assert_array_equal(out[rid], want)

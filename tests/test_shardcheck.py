@@ -282,6 +282,41 @@ class TestDonationPass:
         assert donated and all(i["verdict"] == "donated" for i in donated)
 
 
+    def test_a_pruned_argument_does_not_shift_the_alias_numbers(self):
+        """``jax.jit`` drops an argument its jaxpr never reads, so the
+        compiled module's parameter numbers run over the KEPT ones: the
+        donated state behind an unread argument is still ``donated``."""
+        f = jax.jit(lambda unread, s, x: s + x, donate_argnums=(1,))
+        r = donation_report(f, jnp.ones((3,)), jnp.ones((8, 8)), jnp.ones((8, 8)))
+        assert [i["verdict"] for i in r["inputs"]] == ["ok", "donated", "ok"]
+        assert r["aliased_params"] == [1] and r["findings"] == []
+
+    @pytest.mark.parametrize("names", [
+        ("prefill", "decode_step"), ("spec_prefill", "spec_decode_step"),
+        ("kv_ingest",), ("kv_page_fill",),
+    ])
+    def test_the_audit_runs_over_the_engine_programs(self, names):
+        """The serving programs are under the donation audit (PR 36: they
+        donate their cache): every leaf a program asks to donate is
+        aliased on the 2x4 mesh, the pass is clean under the baseline's
+        ``donation_budgets``, and without the baseline the only findings
+        are the few-byte operands those budgets name."""
+        from learning_jax_sharding_tpu.analysis import run_jaxpr_pass
+        from learning_jax_sharding_tpu.analysis.entrypoints import (
+            build_entry_programs,
+        )
+
+        programs = build_entry_programs(list(names))
+        assert [p.name for p in programs] == list(names)
+        for prog in programs:
+            asked = [i for i in prog.donation()["inputs"] if i["donated"]]
+            assert asked and all(i["verdict"] == "donated" for i in asked)
+        assert run_jaxpr_pass(programs=programs) == []
+        loose = run_jaxpr_pass(programs=programs, baseline=None)
+        assert {f.rule for f in loose} <= {"donation-missed"}
+        assert all(f.data["device_bytes"] <= 32 for f in loose)
+
+
 class TestJaxprLint:
     def test_f32_promotion_in_bf16_graph(self):
         def f(x):

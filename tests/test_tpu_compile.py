@@ -440,6 +440,7 @@ def step_programs(topo):
     from flax.training.train_state import TrainState
     from jax.sharding import Mesh
 
+    from learning_jax_sharding_tpu.models.engine_programs import split_cache
     from learning_jax_sharding_tpu.models.serving import ContinuousEngine
     from learning_jax_sharding_tpu.models.transformer import (
         Transformer,
@@ -503,17 +504,18 @@ def step_programs(topo):
                 cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
                 if mixed:
                     compiled.append(eng.program("mixed_step").fn.lower(
-                        params, cache, ints(b, chunk), ints(b), flags,
-                        ints(b), ints(b), ints(b), ints(b), ints(b), rng,
+                        params, *split_cache(cache), ints(b, chunk), ints(b),
+                        flags, ints(b), ints(b), ints(b), ints(b), ints(b), rng,
                     ))
                     continue
                 compiled.append(eng.program("first_refill").fn.lower(*first))
                 compiled.append(eng.program("refill_step").fn.lower(
-                    params, None, cache, ints(b, chunk), ints(b), flags,
-                    ints(b), ints(b), rng, ints(b), ints(b),
+                    params, None, *split_cache(cache), ints(b, chunk), ints(b),
+                    flags, ints(b), ints(b), rng, ints(b), ints(b),
                 ))
                 compiled.append(eng.program("decode_block").fn.lower(
-                    params, cache, ints(b), ints(b), ints(b), ints(b), rng,
+                    params, *split_cache(cache), ints(b), ints(b), ints(b),
+                    ints(b), rng,
                 ))
 
         # A latent-attention, dropless-expert engine (the joyai-llm-flash
@@ -541,12 +543,12 @@ def step_programs(topo):
             with activate(mesh, RULES_TP_SERVING):
                 cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
                 compiled.append(eng.program("refill_step").fn.lower(
-                    latent_params, None, cache, ints(b, chunk), ints(b),
-                    flags, ints(b), ints(b), rng, ints(b), ints(b),
+                    latent_params, None, *split_cache(cache), ints(b, chunk),
+                    ints(b), flags, ints(b), ints(b), rng, ints(b), ints(b),
                 ))
                 compiled.append(eng.program("decode_block").fn.lower(
-                    latent_params, cache, ints(b), ints(b), ints(b), ints(b),
-                    rng,
+                    latent_params, *split_cache(cache), ints(b), ints(b),
+                    ints(b), ints(b), rng,
                 ))
 
         # One mixer a layer (the nemotron_h family at small widths, the
@@ -577,12 +579,12 @@ def step_programs(topo):
             with activate(mesh, RULES_TP_SERVING):
                 cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
                 compiled.append(eng.program("refill_step").fn.lower(
-                    ssm_params, None, cache, ints(b, chunk), ints(b),
-                    flags, ints(b), ints(b), rng, ints(b), ints(b),
+                    ssm_params, None, *split_cache(cache), ints(b, chunk),
+                    ints(b), flags, ints(b), ints(b), rng, ints(b), ints(b),
                 ))
                 compiled.append(eng.program("decode_block").fn.lower(
-                    ssm_params, cache, ints(b), ints(b), ints(b), ints(b),
-                    rng,
+                    ssm_params, *split_cache(cache), ints(b), ints(b), ints(b),
+                    ints(b), rng,
                 ))
 
         # The train step as benchmark/train.py builds it, its state abstract.
@@ -657,17 +659,20 @@ def step_programs(topo):
             )
             compiled.append(step.jitted.lower(state, batch))
     texts = [low.compile().as_text() for low in compiled]
-    # Two engines give two programs of one name: pairs, not a dict.
-    return [(re.match(r"HloModule (\S+?),", t).group(1), t) for t in texts]
+    # Two engines give two programs of one name: a list, not a dict.
+    return [
+        (re.match(r"HloModule (\S+?),", t).group(1), t, low)
+        for t, low in zip(texts, compiled)
+    ]
 
 
 @pytest.mark.parametrize("module,op", _named_by_trace_metrics())
 def test_trace_metric_names_exist_in_the_compiled_programs(
     step_programs, module, op
 ):
-    hits = [t for name, t in step_programs if name.startswith(module)]
+    hits = [t for name, t, _ in step_programs if name.startswith(module)]
     assert hits, (
-        f"no program named {module}*: {sorted(n for n, _ in step_programs)}"
+        f"no program named {module}*: {sorted(n for n, *_ in step_programs)}"
     )
     if op is not None:
         # The program of whichever engine runs that op (GPT-2-shaped or
@@ -681,3 +686,32 @@ def test_trace_metric_names_exist_in_the_compiled_programs(
         ]
         assert holding, f"no instruction named {op}* in {module}"
         assert all("tpu_custom_call" in t for t in holding)
+
+
+def test_the_chip_compiler_aliases_every_donated_cache_leaf(step_programs):
+    """What the CPU tests show of the engine's donation
+    (``tests/test_engine_programs.py``) holds for the chip's compiler too:
+    each ``refill_step`` / ``decode_block`` / ``mixed_step`` compiled for
+    ``v5e:2x2`` aliases every cache leaf it was asked to donate (pools,
+    counters, expert counts, recurrent state) to an output, and was asked
+    to donate no table."""
+    from learning_jax_sharding_tpu.analysis.donation import report_from_lowered
+
+    seen = 0
+    for name, text, low in step_programs:
+        if name.split(".")[0] not in (
+            "jit_refill_step", "jit_decode_block", "jit_mixed_step"
+        ):
+            continue
+        seen += 1
+        asked = [
+            i for i in report_from_lowered(low, text)["inputs"] if i["donated"]
+        ]
+        assert asked and all(i["verdict"] == "donated" for i in asked), (
+            name, [i for i in asked if i["verdict"] != "donated"]
+        )
+        # The tables ride as a list of their own, beside the donated tree.
+        (tables,) = [a for a in low.args_info[0] if isinstance(a, list)]
+        assert tables and not any(i.donated for i in tables), name
+    assert seen == 7
+

@@ -424,8 +424,9 @@ class TestDisaggregatedHandoff:
             r.engine for r in dec
             if r.engine.registry.counter("engine_kv_ingests_total").value
         )
+        # (cache, its block tables: none, contiguous rows), then the rows.
         args = eng.program("kv_ingest").last_args()
-        rows, shardings = args[1], eng.kv_row_shardings()
+        rows, shardings = args[2], eng.kv_row_shardings()
         jax.tree.map(
             lambda x, s: None if x.sharding == s else pytest.fail(
                 f"ingested row sharding {x.sharding} != cache row {s}"
