@@ -139,6 +139,19 @@ from learning_jax_sharding_tpu.telemetry.registry import labeled_name
 #: must never guess.
 _RECOVERABLE_DISPATCH = (InjectedFault, FloatingPointError)
 
+#: The phases of the request clock (``ContinuousEngine._tick``): every
+#: second of a request between its admission and its retirement is in
+#: exactly one, by what the dispatch whose readback just returned gave
+#: it. Before its first token: ``refill`` (the dispatch carried at least
+#: one of its chunk rows) or ``refill_wait`` (none). Holding one:
+#: ``decode`` (the dispatch gave it tokens) or ``stall`` (nothing: the
+#: split engine's refill dispatch). ``queue`` is in front of them
+#: (``queue_wait``, ``requeue_wait_s``); the seconds of an admission that
+#: a preemption threw away move to a further series, ``redone``.
+#: Pinned by ``tests/test_engine_spans.py``.
+_PHASES = ("refill_wait", "refill", "stall", "decode")
+_REFILL_WAIT, _REFILL, _STALL, _DECODE, _REDONE = range(5)
+
 def _dispatch_span(kind):
     """Wrap a dispatch method in the tracer span ``engine.<kind>``. The
     event is kept only when the method dispatched a program of that
@@ -208,6 +221,12 @@ class _Request:
     ingested: bool = False                # admitted via kv_ingest: the prefill
     #                                       happened on another replica
     tenant: str | None = None             # cost-attribution / SLO label
+    # The request clock's books of what a preemption threw away: the
+    # seconds of its abandoned admissions, the instant of the last
+    # preemption while it waits in the queue, the seconds of such waits.
+    redone_s: float = 0.0
+    preempt_t: float | None = None
+    requeue_wait_s: float = 0.0
 
 
 class ContinuousEngine:
@@ -375,8 +394,13 @@ class ContinuousEngine:
       (arrival → first generated token visible on the host),
       ``tpot_p50/p99`` (per-request mean inter-token time after the
       first), ``itl_p50/p99`` (raw host-visibility gaps — block-granular
-      by design: tokens land ``decode_block_steps`` at a time), and
-      ``queue_wait_p50/p99`` (arrival → slot admission).
+      by design: tokens land ``decode_block_steps`` at a time),
+      ``queue_wait_p50/p99`` (arrival → slot admission), and the request
+      clock's split of the two (``_PHASES``): ``decode_per_token_p50/p99``
+      + ``stall_per_token_p50/p99`` (a request's TPOT is the sum of its
+      two; stall: seconds a token it held a first token and a dispatch
+      gave it nothing) and ``refill_wait_p50/p99`` (admitted, waiting
+      for a refill dispatch to take one of its chunks).
 
     TELEMETRY (round 6): the engine meters into a
     :class:`~learning_jax_sharding_tpu.telemetry.MetricsRegistry`
@@ -1119,8 +1143,6 @@ class ContinuousEngine:
             "engine_ttft_seconds", "arrival to first visible token")
         self._h_tpot = r.histogram(
             "engine_tpot_seconds", "per-request mean inter-token seconds")
-        self._h_itl = r.histogram(
-            "engine_itl_seconds", "raw host-visibility gaps")
         self._h_wait = r.histogram(
             "engine_queue_wait_seconds", "arrival to slot admission")
         self._h_e2e = r.histogram(
@@ -1220,6 +1242,32 @@ class ContinuousEngine:
             )
             for phase in ("decode", "refill")
         } if self._moe_counted else {}
+        # The request clock (_tick): slot-seconds and readbacks by the
+        # phase they put a request in, counted where they are assigned,
+        # so a window's edge loses nothing. ``redone`` counts AGAIN what
+        # a preemption threw away (those seconds were first counted in
+        # the phase they ran in): the four phases less ``redone`` are
+        # the seconds retired requests kept.
+        self._c_phase = [
+            (
+                r.counter(
+                    labeled_name(
+                        "engine_request_phase_seconds_total", phase=phase
+                    ),
+                    "slot-seconds of admitted requests by the phase the "
+                    "dispatch just read back put them in"),
+                r.counter(
+                    labeled_name(
+                        "engine_request_phase_dispatches_total", phase=phase
+                    ),
+                    "readbacks that put a request in the phase, summed "
+                    "over requests"),
+            )
+            for phase in (*_PHASES, "redone")
+        ]
+        # Requests by phase over the readbacks since the last
+        # engine.dispatch event (which reports and clears them).
+        self._ph_event = [0] * len(_PHASES)
         self._span_names: dict[tuple[str, str], str] = {}  # (phase, family)
         # Goodput ledger (round 14): exhaustive wall-clock attribution
         # for the engine loop. step() is the top-level frame (its
@@ -1278,7 +1326,9 @@ class ContinuousEngine:
     # engine.consume            sched       consume     token / first-token /
     #                                                   retire loop after it
     # engine.plan               sched       plan        the horizon planner
-    # engine.telemetry          telemetry   telemetry   counters, recorder, SLO
+    # engine.telemetry          telemetry   telemetry   counters, recorder, SLO,
+    #                                                   the request clock's
+    #                                                   books (_flush_ticks)
     # engine.recovery / engine.kv_handoff / engine.swap: their bucket
     #
     # ``<family>`` is the ``Program``'s. ``engine.refill`` /
@@ -1317,10 +1367,10 @@ class ContinuousEngine:
     def _book_moe(self, phase, stats):
         """Add one readback's expert counts ``(3,)`` (a dropless-expert
         config's programs return them; already on the host) to
-        ``phase``'s counters."""
-        with self.ledger.measure("telemetry", span="engine.telemetry"):
-            for counter, n in zip(self._c_moe[phase], stats.tolist()):
-                counter.inc(n)
+        ``phase``'s counters (``_flush_ticks``, inside its caller's
+        telemetry frame)."""
+        for counter, n in zip(self._c_moe[phase], stats.tolist()):
+            counter.inc(n)
 
     @contextlib.contextmanager
     def _led_device(self, prog: Program | None = None, family=None,
@@ -1429,6 +1479,17 @@ class ContinuousEngine:
         # lose a row's counter reset (review finding, round 5).
         self._needs_reset = np.zeros((b,), bool)
         self._reset_to = np.zeros((b,), np.int32)
+        # The request clock (_tick): seconds and readbacks of the slot's
+        # request by phase (_PHASES) since its admission here at
+        # _ph_admit, accounted up to _ph_last. Plain lists: the clock
+        # runs on the host right after a blocking readback, where a loop
+        # over 16-32 slots costs a third of the same books kept in numpy
+        # arrays (PERF.md, Findings, PR 37).
+        self._ph_s = [[0.0] * len(_PHASES) for _ in range(b)]
+        self._ph_n = [[0] * len(_PHASES) for _ in range(b)]
+        self._ph_last = [0.0] * b
+        self._ph_admit = [0.0] * b
+        self._ticks: list[tuple] = []      # readbacks noted, not yet booked
         # Retired-request → slot map while the slot's KV is still intact
         # (export window for the disaggregated handoff); entries drop the
         # moment the slot is reused by a later admission/ingestion.
@@ -2419,6 +2480,9 @@ class ContinuousEngine:
             }
             self._slot_req[slot] = r
             self._req[slot] = rid
+            # An ingested row's clock opens HERE: ``decode`` and ``stall``
+            # only, from its ingestion (the hand-off is the router's leg).
+            self._open_clock(slot, now)
             self._plen[slot] = int(p.size)
             self._pending[slot] = np.zeros((0,), np.int32)
             self._emitted[slot] = 1
@@ -2726,6 +2790,134 @@ class ContinuousEngine:
             "pid": pid,
         }
 
+    # --- the request clock -------------------------------------------------
+    #
+    # One clock a request: every second between its admission and its
+    # retirement is in exactly one of _PHASES. The clock ticks once a
+    # readback, at the ``now`` the dispatch functions stamp first tokens
+    # and retirements with, BEFORE their consume loop: so the interval
+    # since the previous readback, host time in front of the dispatch
+    # included, goes whole to the phase THIS dispatch put the request
+    # in, and for every retired request, to float rounding,
+    #
+    #   refill_wait_s + refill_s == first_token_t - (its last admission)
+    #   stall_s + decode_s       == finish_t - first_token_t
+    #                            == tpot x (generated - 1)
+    #
+    # (an ingested row: from its ingestion here; the hand-off is the
+    # router's leg). A request that fails or is preempted between two
+    # readbacks is accounted up to that instant (_settle); what a
+    # preemption throws away moves to ``redone_s`` and the wait for the
+    # next admission to ``requeue_wait_s``, so that
+    # e2e == queue_wait + redone_s + requeue_wait_s + the four phases.
+    # A tick only NOTES the readback (``_tick``: its instant, who held a
+    # first token, who rode); the books are written by ``_flush_ticks``
+    # at the head of the next telemetry frame that reads or closes a
+    # request's books (a retirement, a failure, the dispatch's own
+    # books), so the clock opens no frame of its own: a frame costs more
+    # than the loop it would wrap (PERF.md, Findings, PR 37).
+    # tests/test_engine_spans.py pins the names and the identities;
+    # scripts/engine_breakdown.py prints them from a bundle.
+
+    def _open_clock(self, slot, now):
+        """``slot`` admits (or ingests) a request at ``now``."""
+        self._flush_ticks()
+        self._ph_s[slot] = [0.0] * len(_PHASES)
+        self._ph_n[slot] = [0] * len(_PHASES)
+        self._ph_last[slot] = self._ph_admit[slot] = now
+
+    def _tick(self, now, carried=(), advanced=None, moe=()):
+        """A dispatch's readback returned at ``now``: every live slot's
+        seconds since its last tick go to the phase the dispatch put its
+        request in. ``carried``: the slots whose chunk rows rode the
+        dispatch (anything that answers ``slot in carried``);
+        ``advanced``: the slots it could give tokens to, ``(slots,)``
+        bool, or True for every holder of a first token. Read against
+        ``_active`` as it stands NOW, before the readback's first tokens
+        are stamped: a slot without a first token is refilling or waiting
+        to, one with it decodes or stalls. ``moe``: the readback's
+        ``(phase, expert counts)``. Noted here, booked by
+        ``_flush_ticks``."""
+        first = self._active.tolist()
+        if advanced is not None:
+            advanced = first if advanced is True else advanced.tolist()
+        self._ticks.append((now, first, carried, advanced, moe))
+
+    def _flush_ticks(self):
+        """Write the books of the readbacks noted since the last flush.
+        Inside the caller's telemetry frame, and before anything frees
+        or fills a slot: the slots live now are those that were live at
+        each of them."""
+        ticks = self._ticks
+        if not ticks:
+            return
+        n_phases = len(_PHASES)
+        seconds, hits = [0.0] * n_phases, [0] * n_phases
+        last, ph_s, ph_n = self._ph_last, self._ph_s, self._ph_n
+        live = [slot for slot, rid in enumerate(self._req) if rid >= 0]
+        for now, first, carried, advanced, moe in ticks:
+            for phase, stats in moe:
+                self._book_moe(phase, stats)
+            for slot in live:
+                if first[slot]:
+                    p = _DECODE if advanced and advanced[slot] else _STALL
+                else:
+                    p = _REFILL if slot in carried else _REFILL_WAIT
+                dt = now - last[slot]
+                last[slot] = now
+                ph_s[slot][p] += dt
+                ph_n[slot][p] += 1
+                seconds[p] += dt
+                hits[p] += 1
+        ticks.clear()
+        for p, n in enumerate(hits):
+            if n:
+                c_seconds, c_hits = self._c_phase[p]
+                c_seconds.inc(seconds[p])
+                c_hits.inc(n)
+                self._ph_event[p] += n
+
+    def _settle(self, slot, now):
+        """Account ``slot``'s request up to ``now`` between two readbacks
+        (it fails, or is preempted): it was waiting, for a refill turn or
+        behind one. No dispatch is counted."""
+        self._flush_ticks()
+        p = _STALL if self._active[slot] else _REFILL_WAIT
+        dt = now - self._ph_last[slot]
+        self._ph_s[slot][p] += dt
+        self._ph_last[slot] = now
+        self._c_phase[p][0].inc(dt)
+
+    def _request_phases(self, slot, r, now):
+        """The request clock's fields of ``r`` (in ``slot``, accounted up
+        to ``now``) for its ``engine.retire`` / ``engine.request_failed``
+        event and its ``latency_stats()`` record. ``*_unix`` put the
+        admission the phases count from and the first token on the
+        recorder's ``t`` clock."""
+        refill_wait_s, refill_s, stall_s, decode_s = self._ph_s[slot]
+        _, refills, stalls, decodes = self._ph_n[slot]
+        gaps = self._emitted[slot] - 1
+        first_token_t = r.first_token_t
+        unix = time.time() - time.perf_counter()
+        return dict(
+            queue_wait=r.admit_t - r.arrival_t,
+            tpot=(
+                (now - first_token_t) / gaps
+                if gaps > 0 and first_token_t is not None else None
+            ),
+            refill_wait_s=refill_wait_s, refill_s=refill_s,
+            stall_s=stall_s, decode_s=decode_s,
+            refill_dispatches=refills, stall_dispatches=stalls,
+            decode_dispatches=decodes,
+            stall_per_token_s=stall_s / gaps if gaps > 0 else None,
+            decode_per_token_s=decode_s / gaps if gaps > 0 else None,
+            redone_s=r.redone_s, requeue_wait_s=r.requeue_wait_s,
+            admit_unix=self._ph_admit[slot] + unix,
+            first_token_unix=(
+                first_token_t + unix if first_token_t is not None else None
+            ),
+        )
+
     def _retire(self, slot, now, retired):
         r = self._slot_req[slot]
         r.tokens = np.asarray(self._out[slot], np.int32)
@@ -2734,28 +2926,25 @@ class ContinuousEngine:
         times = self._ttimes[slot]
         gaps = [b - a for a, b in zip(times, times[1:])]
         self._itl.extend(gaps)
-        for g in gaps:
-            self._h_itl.observe(g)
-        rec = dict(
-            rid=r.rid,
-            prompt_len=int(r.prompt.size),
-            generated=n,
-            queue_wait=r.admit_t - r.arrival_t,
-            ttft=(
-                r.first_token_t - r.arrival_t
-                if r.first_token_t is not None else None
-            ),
-            e2e=now - r.arrival_t,
-            tpot=(
-                (now - r.first_token_t) / (n - 1) if n > 1 else None
-            ),
-        )
-        self._completed.append(rec)
         # Histograms carry the same observations for export; the exact
         # percentiles in latency_stats() stay sample-based (pinned). All
         # of this booking is the observability tax — it lands in the
         # ledger's telemetry bucket so perf_goodput.py can pin it.
         with self.ledger.measure("telemetry", span="engine.telemetry"):
+            self._flush_ticks()
+            phases = self._request_phases(slot, r, now)
+            rec = dict(
+                rid=r.rid,
+                prompt_len=int(r.prompt.size),
+                generated=n,
+                ttft=(
+                    r.first_token_t - r.arrival_t
+                    if r.first_token_t is not None else None
+                ),
+                e2e=now - r.arrival_t,
+                **phases,
+            )
+            self._completed.append(rec)
             self._c_finished.inc()
             self._c_tokens.inc(n)
             self._h_wait.observe(rec["queue_wait"])
@@ -2764,10 +2953,16 @@ class ContinuousEngine:
                 self._h_ttft.observe(rec["ttft"])
             if rec["tpot"] is not None:
                 self._h_tpot.observe(rec["tpot"])
-            self.tracer.async_end("request", r.rid, generated=n)
+            self.tracer.async_end(
+                "request", r.rid, generated=n,
+                refill_wait_s=phases["refill_wait_s"],
+                refill_s=phases["refill_s"], stall_s=phases["stall_s"],
+                decode_s=phases["decode_s"],
+            )
             self.recorder.record(
                 "engine.retire", rid=r.rid, slot=slot, generated=n,
                 ttft=rec["ttft"], e2e=rec["e2e"], version=r.version,
+                **phases,
             )
             if self.slo is not None:
                 ten = r.tenant
@@ -2782,7 +2977,7 @@ class ContinuousEngine:
                 for g in gaps:
                     self.slo.observe("itl", g, tenant=ten)
             if self.trace_sink is not None:
-                self._record_trace_legs(r, now, generated=n)
+                self._record_trace_legs(r, now, generated=n, phases=phases)
                 if self.trace_sink.auto_complete:
                     self.trace_sink.complete(
                         r.rid, status="ok", finish_t=now,
@@ -2807,6 +3002,7 @@ class ContinuousEngine:
 
     def _record_trace_legs(
         self, r, now, *, generated=0, wasted=False, status="ok",
+        phases=None,
     ):
         """Append THIS engine's spans of ``r``'s journey to the trace
         sink, from the request's own stamps. The queue leg opens at
@@ -2818,9 +3014,17 @@ class ContinuousEngine:
         (they sum separately in the critical path). Ingested rows emit
         only a decode leg — their queue/prefill ran on the prefill
         replica and the handoff leg is the router's to record (it alone
-        saw both ends of the transfer)."""
+        saw both ends of the transfer). ``phases`` (the request clock's
+        fields, ``_request_phases``) hands the ``prefill`` leg its
+        ``refill_wait_s`` and the ``decode`` leg its ``stall_s``: the
+        store's critical path reports that measured stall beside its
+        remainder."""
         ts = self.trace_sink
         rep = self.trace_replica
+        waited = {} if phases is None else {
+            "refill_wait_s": phases["refill_wait_s"]
+        }
+        stalled = {} if phases is None else {"stall_s": phases["stall_s"]}
         q0 = r.enqueue_t if r.enqueue_t is not None else r.arrival_t
         if r.admit_t is None:
             # Never admitted here: all wait, no compute to waste.
@@ -2830,7 +3034,7 @@ class ContinuousEngine:
             ts.leg(
                 r.rid, "decode", q0, now, replica=rep,
                 generated=generated, version=r.version,
-                wasted=wasted, status=status,
+                wasted=wasted, status=status, **stalled,
             )
             return
         ts.leg(r.rid, "queue", q0, r.admit_t, replica=rep)
@@ -2839,25 +3043,29 @@ class ContinuousEngine:
             # Died mid-prefill (chaos kill before the first token).
             ts.leg(
                 r.rid, "prefill", r.admit_t, now, replica=rep,
-                version=r.version, wasted=wasted, status=status,
+                version=r.version, wasted=wasted, status=status, **waited,
             )
             return
         ts.leg(
             r.rid, "prefill", r.admit_t, ft, replica=rep,
-            first_token_t=ft, version=r.version, wasted=wasted,
+            first_token_t=ft, version=r.version, wasted=wasted, **waited,
         )
         if now > ft:
             ts.leg(
                 r.rid, "decode", ft, now, replica=rep,
                 generated=generated, version=r.version,
-                wasted=wasted, status=status,
+                wasted=wasted, status=status, **stalled,
             )
 
-    def _fail_request(self, r, status, error, *, now=None, tokens=None):
+    def _fail_request(
+        self, r, status, error, *, now=None, tokens=None, slot=None,
+    ):
         """Retire ``r`` with a terminal non-ok status: surfaced through
         ``pop_finished`` as a :class:`RequestFailure` — the recovery
         policies' one exit path (deadline, quarantine, malformed,
-        shutdown)."""
+        shutdown). ``slot``: the slot it fails in (``_fail_slot``); its
+        request clock is then accounted up to ``now`` and written to the
+        event."""
         now = time.perf_counter() if now is None else now
         r.status = status
         r.error = error
@@ -2868,9 +3076,13 @@ class ContinuousEngine:
             self._c_req_failed.inc()
             if status == "rerouted":
                 self._c_rerouted.inc()
+            phases = None
+            if slot is not None:
+                self._settle(slot, now)
+                phases = self._request_phases(slot, r, now)
             self.recorder.record(
                 "engine.request_failed", rid=r.rid, status=status,
-                error=error,
+                error=error, **(phases or {}),
             )
             if r.admit_t is not None:
                 # async_begin was issued at first admission; close the
@@ -2885,6 +3097,7 @@ class ContinuousEngine:
                 self._record_trace_legs(
                     r, now,
                     wasted=(status == "rerouted"), status=status,
+                    phases=phases,
                 )
                 if self.trace_sink.auto_complete and status != "rerouted":
                     self.trace_sink.complete(
@@ -2901,7 +3114,7 @@ class ContinuousEngine:
         the caller sees how far the request got)."""
         r = self._slot_req[slot]
         self._fail_request(
-            r, status, error, now=now,
+            r, status, error, now=now, slot=slot,
             tokens=np.asarray(self._out[slot], np.int32),
         )
         if self._paged:
@@ -2983,6 +3196,8 @@ class ContinuousEngine:
         next dispatch creates a new one."""
         with self.ledger.measure("recovery", span="engine.recovery"):
             self._c_dispatch_faults.inc()
+            self._flush_ticks()
+            self._ph_event = [0] * len(_PHASES)   # no engine.dispatch event follows
             self.recorder.record(
                 "engine.dispatch_fault",
                 error=type(e).__name__, message=str(e),
@@ -3050,6 +3265,16 @@ class ContinuousEngine:
         self._queue.appendleft(r)
         self.tracer.instant("request.preempted", rid=r.rid, slot=slot)
         self.recorder.record("engine.preempt", rid=r.rid, slot=slot)
+        # The request clock: this admission's seconds are work to redo.
+        # They stay counted in the phases they ran in, and count again
+        # under ``redone``.
+        r.preempt_t = time.perf_counter()
+        self._settle(slot, r.preempt_t)
+        redone_s = sum(self._ph_s[slot])
+        r.redone_s += redone_s
+        c_seconds, c_hits = self._c_phase[_REDONE]
+        c_seconds.inc(redone_s)
+        c_hits.inc(sum(self._ph_n[slot]))
         if self._paged:
             self._release(slot, register=False)
         self._slot_req[slot] = None
@@ -3157,6 +3382,10 @@ class ContinuousEngine:
                     }
                     self._slot_req[slot] = r
                     self._req[slot] = r.rid
+                    self._open_clock(slot, now)
+                    if r.preempt_t is not None:
+                        r.requeue_wait_s += now - r.preempt_t
+                        r.preempt_t = None
                     self._aidx[slot] = (
                         self._adapter_pool.slot_of(r.adapter)
                         if r.adapter is not None else 0
@@ -3379,7 +3608,9 @@ class ContinuousEngine:
                 self._pending[slot] = self._pending[slot][n:]
                 if self._pending[slot].size == 0 and self._req[slot] >= 0:
                     seg_completes.append(slot)
-            segs.append((tok_new, seg_completes, last_row, prog.family, moe))
+            segs.append(
+                (tok_new, seg_completes, last_row, prog.family, moe, took)
+            )
             self._c_prefill_tok.inc(sum(took.values()))
             self._c_refill_slots.inc(chunk.size)
             self._c_chunk_rows.inc(len(firsts) + len(extra))
@@ -3388,9 +3619,9 @@ class ContinuousEngine:
                 self._c_ssm_resets.inc(n_reset)
         if not segs:
             return False
-        for i, (tok_new, seg_completes, last_row, seg_fam, moe) in enumerate(
-            segs
-        ):
+        for i, (
+            tok_new, seg_completes, last_row, seg_fam, moe, took
+        ) in enumerate(segs):
             with self._led_device(
                 family=seg_fam, in_flight=len(segs) - 1 - i
             ):
@@ -3398,8 +3629,7 @@ class ContinuousEngine:
                 # program, a dropless-expert config's counts.
                 tok_new, *moe = (np.asarray(x) for x in (tok_new, *moe))
             now = time.perf_counter()       # its host-visibility time
-            if moe:
-                self._book_moe("refill", moe[0])
+            self._tick(now, carried=took, moe=[("refill", m) for m in moe])
             with self._led_consume():
                 # A slot's first token: the pick of its LAST chunk's row.
                 self._first_tokens(
@@ -3552,6 +3782,7 @@ class ContinuousEngine:
                 ]
             now = time.perf_counter()
             was_active = self._active.copy()
+            self._tick(now, advanced=True)
             with self._led_consume():
                 for buffer, counts, acc, prop in segs:
                     self._c_spec_acc.inc(int(acc.sum()))
@@ -3592,9 +3823,10 @@ class ContinuousEngine:
                 segs = [np.asarray(t) for t in segs]   # ONE sync
                 moe_segs = jax.device_get(moe_segs)    # the same programs' counts
             now = time.perf_counter()
-            for moe in moe_segs:
-                self._book_moe("decode", moe)
             was_active = self._active.copy()
+            self._tick(
+                now, advanced=True, moe=[("decode", m) for m in moe_segs]
+            )
             with self._led_consume():
                 for toks in segs:
                     for slot in range(b):
@@ -3910,7 +4142,8 @@ class ContinuousEngine:
                     ):
                         seg_completes.append(slot)
             segs.append(
-                (first_tok, buffer, counts, acc, prop, seg_completes)
+                (first_tok, buffer, counts, acc, prop, seg_completes,
+                 set(np.flatnonzero(lengths).tolist()))
             )
         if not segs:
             return False
@@ -3927,13 +4160,16 @@ class ContinuousEngine:
             self._c_adapter_rows.inc(
                 int(((self._aidx > 0) & occ).sum()) * len(segs)
             )
-        for i, (first_tok, buffer, counts, acc, prop, seg_completes) in (
-            enumerate(segs)
-        ):
+        for i, (
+            first_tok, buffer, counts, acc, prop, seg_completes, carried
+        ) in enumerate(segs):
             left = len(segs) - 1 - i
             with self._led_device(family=prog.family, in_flight=left):
                 first_np = np.asarray(first_tok)   # each link's own sync
             now = time.perf_counter()
+            # Each request by what IT got from the link: a chunk, a token
+            # (the rows decoding at chain start), or nothing.
+            self._tick(now, carried=carried, advanced=was_active)
             if self._speculative:
                 with self._led_device(family=prog.family, in_flight=left):
                     counts_np = np.asarray(counts)
@@ -4202,6 +4438,12 @@ class ContinuousEngine:
             self._c_spec_acc.inc(int(acc_np[:n_live].sum()))
             self._c_spec_prop.inc(int(props_np[:n_live].sum()))
         now = time.perf_counter()
+        # ONE readback shows every link's tokens: one tick, a request
+        # carried if any link held one of its chunks.
+        self._tick(
+            now, carried=set(np.flatnonzero(lens.any(axis=0)).tolist()),
+            advanced=was_active,
+        )
         with self._led_consume():
             for i in range(n_live):
                 first_np = toks_np[i]
@@ -4508,9 +4750,16 @@ class ContinuousEngine:
         One ``engine.dispatch`` flight-recorder event per dispatch: its
         seconds, leaves, arrays and tokens are the growth of the cumulative
         counters since the previous event; ``starved_s`` is the
-        empty-device time that ended at this dispatch's enqueue."""
+        empty-device time that ended at this dispatch's enqueue;
+        ``carried`` / ``waiting`` / ``stalled`` are the request clock's
+        (``_tick``): requests with a chunk row in the dispatch, admitted
+        requests without a first token and without one, and requests
+        holding a first token that it gave nothing (summed over its
+        readbacks where it has several): the cause of each request's
+        phase, by dispatch."""
         dt = time.perf_counter() - t0
         with self.ledger.measure("telemetry", span="engine.telemetry"):
+            self._flush_ticks()
             seconds, dispatches = {
                 "refill": (self._c_refill_s, self._c_refill_n),
                 "decode": (self._c_decode_s, self._c_decode_n),
@@ -4536,9 +4785,12 @@ class ContinuousEngine:
                     now[field] = sum(
                         series[i].value for series in self._c_moe.values()
                     )
+            by_phase, self._ph_event = self._ph_event, [0] * len(_PHASES)
             self.recorder.record(
                 "engine.dispatch", family=self._last_family, phase=kind,
                 step=self._step_n, rows=rows, compiled=self._compiled,
+                carried=by_phase[_REFILL], waiting=by_phase[_REFILL_WAIT],
+                stalled=by_phase[_STALL],
                 **{f: v - self._booked[f] for f, v in now.items()},
             )
             self._booked = now
@@ -4568,6 +4820,11 @@ class ContinuousEngine:
         out.update(pcts([c["tpot"] for c in comp], "tpot"))
         out.update(pcts(self._itl, "itl"))
         out.update(pcts([c["e2e"] for c in comp], "e2e"))
+        # The request clock: a request's TPOT is its decode plus its
+        # stall seconds a token; its time from admission to first token
+        # is refill plus the wait for a refill turn.
+        for name in ("decode_per_token", "stall_per_token", "refill_wait"):
+            out.update(pcts([c[f"{name}_s"] for c in comp], name))
         refill_s = self._win_delta(self._c_refill_s)
         decode_s = self._win_delta(self._c_decode_s)
         mixed_s = self._win_delta(self._c_mixed_s)

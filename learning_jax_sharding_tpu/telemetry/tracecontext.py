@@ -12,8 +12,10 @@ yields
 
 * a **critical-path decomposition** — queue → prefill → handoff →
   decode, with ``stall`` as the remainder the named stages cannot cover
-  (requeue gaps, swap drains, rerouted recompute) and ``wasted`` as the
-  work thrown away by failovers;
+  (requeue gaps, swap drains, rerouted recompute), ``stall_measured_s``
+  / ``refill_wait_s`` as what the engines' request clocks measured
+  INSIDE the decode and prefill legs, and ``wasted`` as the work thrown
+  away by failovers;
 * per-stage histograms in the owning registry
   (``trace_stage_seconds{stage="queue"}`` …), rendered/merged by the
   labeled-registry plumbing like every other fleet metric;
@@ -230,12 +232,15 @@ class TraceStore:
         stages: dict[str, float] = {}
         wasted = 0.0
         ttft = None
+        measured = {"stall_s": 0.0, "refill_wait_s": 0.0}
         for s in spans:
             dur = s["t1"] - s["t0"]
             if s["attrs"].get("wasted"):
                 wasted += dur
                 continue
             stages[s["stage"]] = stages.get(s["stage"], 0.0) + dur
+            for key in measured:
+                measured[key] += s["attrs"].get(key) or 0.0
             if s["stage"] == "prefill" and s["attrs"].get("first_token_t"):
                 t = s["attrs"]["first_token_t"] - arrival
                 ttft = t if ttft is None else min(ttft, t)
@@ -250,6 +255,12 @@ class TraceStore:
             "e2e_s": e2e,
             "ttft_s": ttft,
             "stages": stages,
+            # What the engines' request clocks MEASURED inside the named
+            # legs (``decode``'s seconds behind another request's refill,
+            # ``prefill``'s wait for a refill turn), beside the ``stall``
+            # remainder no leg covers.
+            "stall_measured_s": measured["stall_s"],
+            "refill_wait_s": measured["refill_wait_s"],
             "wasted_s": wasted,
             "legs": len(spans),
             "reroutes": sum(

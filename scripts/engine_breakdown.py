@@ -20,7 +20,16 @@ Its input is a post-mortem bundle, ``engine.dump_diagnostics(outdir)``:
   over ``engine_step_seconds_total``, with the wait, enqueue and h2d
   shares; how many cache blocks the decode-attention kernel keeps in
   flight (``engine_decode_attn_pages_in_flight``) and the bytes of cache
-  the step programs update in place (``engine_cache_donated_bytes``).
+  the step programs update in place (``engine_cache_donated_bytes``);
+* from the registry, the request clock's slot-seconds and readbacks by
+  phase (``engine_request_phase_seconds_total{phase=...}``,
+  ``..._dispatches_total``), and
+  from the ``engine.retire`` events, its table: p50 and
+  p95 of each phase of a request in ms (``refill_wait``, ``refill``,
+  ``stall``, ``decode``) and a token (a request's TPOT is its decode plus
+  its stall seconds a token), how many requests sat through how many
+  refill and stall dispatches, the request at the 95th percentile of
+  TPOT, and the five slowest requests by TPOT with their phases.
 
 With ``--xplane`` (a ``jax.profiler`` capture taken while that engine
 served, a file or a directory holding one) it also places the bundle's
@@ -32,7 +41,9 @@ seconds the host counted beside the idle the device planes show, and
 the idle gaps by the innermost ``engine.*`` span at their middle, then
 by the innermost runtime event inside it; and the device time of the
 kernels a trace names by scope (``ssm.*``, ``moe.*``, ``attn.*``), by the
-program that ran them.
+program that ran them; and where on the capture each of the five slowest
+requests was admitted, got its first token and retired (their
+``admit_unix`` / ``first_token_unix`` / ``t`` on the same clock).
 
 Usage:
     python scripts/engine_breakdown.py BUNDLE_DIR [--xplane PATH] [--json]
@@ -45,6 +56,7 @@ import bisect
 import collections
 import glob
 import json
+import math
 import os
 import pathlib
 import re
@@ -74,6 +86,9 @@ CARRY_SUMMED = ("carried_rows",)
 #: per distinct leaf width), which every ``table_leaves`` leaf shares.
 PUSH_SUMMED = ("table_arrays",)
 MOE = "engine_moe_"
+#: The request clock's phases (``models/serving.py::_PHASES``), fields
+#: ``<phase>_s`` of an ``engine.retire`` event since PR 37.
+PHASES = ("refill_wait", "refill", "stall", "decode")
 #: Gaps shorter than this are launch latency between ops, not the host.
 SMALL_GAP_NS = 20_000.0
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
@@ -85,6 +100,7 @@ def load_bundle(bundle: str | os.PathLike) -> dict:
     trace = json.loads((root / "trace.json").read_text())
     return {
         "dispatches": [e for e in events if e["kind"] == "engine.dispatch"],
+        "retired": [e for e in events if e["kind"] == "engine.retire"],
         "registry": json.loads((root / "registry.json").read_text()),
         "epoch_unix_ns": trace["otherData"]["epoch_unix_ns"],
     }
@@ -134,6 +150,102 @@ def starved_by_span(registry: dict) -> dict:
             registry.get("engine_enqueue_seconds_total", 0.0)
         ),
         "h2d_share_pct": share(registry.get("engine_h2d_seconds_total", 0.0)),
+    }
+
+
+def phase_counters(registry: dict) -> dict[str, dict]:
+    """The request clock's counters of a registry snapshot, cumulative
+    since the engine was built: slot-seconds and readbacks by the phase
+    they put a request in (``redone``: counted again when a preemption
+    threw them away). Empty for a bundle from before PR 37."""
+    out: dict[str, dict] = {}
+    for key, value in registry.items():
+        m = re.fullmatch(
+            r'engine_request_phase_(seconds|dispatches)_total\{phase="(\w+)"\}', key
+        )
+        if m:
+            out.setdefault(m.group(2), {})[m.group(1)] = value
+    return out
+
+
+def _percentile(values, q: float) -> float:
+    """``q``-th percentile, linear between order statistics."""
+    xs = sorted(values)
+    pos = (len(xs) - 1) * q / 100.0
+    lo = math.floor(pos)
+    return xs[lo] + (xs[min(lo + 1, len(xs) - 1)] - xs[lo]) * (pos - lo)
+
+
+def _request_row(e: dict) -> dict:
+    """One request for the table: its phases in ms and its counts."""
+    row = {
+        "rid": e["rid"], "generated": e["generated"],
+        "tpot_ms": 1e3 * e["tpot"],
+        **{f"{p}_ms": 1e3 * e[f"{p}_s"] for p in PHASES},
+    }
+    for key in (
+        "refill_dispatches", "stall_dispatches", "decode_dispatches",
+        "admit_unix", "first_token_unix",
+    ):
+        row[key] = e[key]
+    row["retire_unix"] = e["t"]
+    return row
+
+
+def request_table(
+    retired: list[dict], dispatches: list[dict] = (), slowest: int = 5
+) -> dict | None:
+    """The request clock's table from the ``engine.retire`` events that
+    carry it (None for a bundle from before PR 37, or one without a
+    request of two tokens): ``phases_ms`` and ``per_token_ms`` (p50, p95
+    of each phase, and of TPOT = decode + stall a token),
+    ``refill_dispatches`` / ``stall_dispatches`` (requests by count),
+    ``at_tpot_p95`` (the request nearest the 95th percentile of TPOT) and
+    the ``slowest`` requests by TPOT. A request that sat through a
+    dispatch that compiled is counted (``compiled``) and left out, as
+    such a dispatch is in the family table."""
+    compiles = sorted(e["t"] for e in dispatches if e["compiled"])
+    reqs, compiled = [], 0
+    for e in retired:
+        if e.get("stall_per_token_s") is None:
+            continue
+        i = bisect.bisect_left(compiles, e["admit_unix"])
+        if i < len(compiles) and compiles[i] <= e["t"]:
+            compiled += 1
+        else:
+            reqs.append(e)
+    if not reqs:
+        return None
+
+    def p50_p95(values):
+        values = list(values)
+        return {"p50": _percentile(values, 50), "p95": _percentile(values, 95)}
+
+    by_tpot = sorted(reqs, key=lambda e: e["tpot"])
+    return {
+        "requests": len(reqs),
+        "compiled": compiled,
+        "phases_ms": {
+            p: p50_p95(1e3 * e[f"{p}_s"] for e in reqs) for p in PHASES
+        },
+        "per_token_ms": {
+            "tpot": p50_p95(1e3 * e["tpot"] for e in reqs),
+            **{
+                p: p50_p95(1e3 * e[f"{p}_per_token_s"] for e in reqs)
+                for p in ("stall", "decode")
+            },
+        },
+        **{
+            key: {
+                str(k): n for k, n in
+                sorted(collections.Counter(e[key] for e in reqs).items())
+            }
+            for key in ("refill_dispatches", "stall_dispatches")
+        },
+        "at_tpot_p95": _request_row(
+            by_tpot[round((len(by_tpot) - 1) * 0.95)]
+        ),
+        "slowest": [_request_row(e) for e in by_tpot[:-slowest - 1:-1]],
     }
 
 
@@ -274,6 +386,20 @@ def place_on_capture(bundle: dict, capture: dict) -> dict:
         "host_starved_s": sum(e["starved_s"] for e in inside),
         "by_family": by_family(inside),
     }
+    table = request_table(bundle.get("retired", ()), bundle["dispatches"])
+    if table:
+        # The slowest requests' instants as seconds from the capture's
+        # start (negative or past ``interval_s``: outside it).
+        out["slowest_requests_s"] = [
+            {
+                "rid": r["rid"],
+                **{
+                    key: (profiler_ns(r[f"{key}_unix"]) - lo) / 1e9
+                    for key in ("admit", "first_token", "retire")
+                },
+            }
+            for r in table["slowest"]
+        ]
     if not ops:
         return out
     out["kernel_device_s"] = dict(
@@ -339,6 +465,43 @@ def _print_families(rows: dict[str, dict]) -> None:
         )
 
 
+def _print_requests(table: dict) -> None:
+    def pair(d):
+        return f"{d['p50']:.3f} / {d['p95']:.3f}"
+
+    print(
+        f"requests retired with the request clock ({table['requests']}, and "
+        f"{table['compiled']} that sat through a compiling dispatch, left "
+        "out), p50 / p95:"
+    )
+    print("  ms a request: " + ", ".join(
+        f"{p} {pair(table['phases_ms'][p])}" for p in PHASES
+    ))
+    per = table["per_token_ms"]
+    print(
+        f"  ms a token: tpot {pair(per['tpot'])} = decode "
+        f"{pair(per['decode'])} + stall {pair(per['stall'])} (a request's "
+        "own two add up; the percentiles need not)"
+    )
+    for key in ("refill_dispatches", "stall_dispatches"):
+        print(f"  requests by {key}: " + ", ".join(
+            f"{n} x {k}" for k, n in table[key].items()
+        ))
+
+    def line(r):
+        return (
+            f"rid {r['rid']}: tpot {r['tpot_ms']:.3f} ms over "
+            f"{r['generated']} tokens; " + ", ".join(
+                f"{p} {r[f'{p}_ms']:.1f}" for p in PHASES
+            ) + f" ms; dispatches: refill {r['refill_dispatches']}, stall "
+            f"{r['stall_dispatches']}, decode {r['decode_dispatches']}"
+        )
+
+    print("  at the 95th percentile of tpot: " + line(table["at_tpot_p95"]))
+    for r in table["slowest"]:
+        print("  slowest: " + line(r))
+
+
 def moe_by_phase(registry: dict) -> dict[str, dict]:
     """The dropless-expert counters of a registry snapshot, by phase:
     assignments, expert reads, layer-steps, and tokens per expert read."""
@@ -368,6 +531,8 @@ def main(argv=None) -> dict:
         "moe": moe_by_phase(bundle["registry"]),
         "decode_attn_pages_in_flight": bundle["registry"].get(ATTN_DEPTH),
         "cache_donated_bytes": bundle["registry"].get(DONATED),
+        "requests": request_table(bundle["retired"], bundle["dispatches"]),
+        "request_phases": phase_counters(bundle["registry"]),
     }
     if args.xplane:
         out["capture"] = place_on_capture(bundle, load_capture(args.xplane))
@@ -407,6 +572,15 @@ def main(argv=None) -> dict:
             + (f": {row['tokens_per_expert_read']:.2f} tokens a read"
                if "tokens_per_expert_read" in row else "")
         )
+    if out["request_phases"]:
+        print("request clock, slot-seconds (readbacks) since the engine was "
+              "built: " + ", ".join(
+                  f"{phase} {row.get('seconds', 0.0):.3f} "
+                  f"({row.get('dispatches', 0):.0f})"
+                  for phase, row in out["request_phases"].items()
+              ))
+    if out["requests"]:
+        _print_requests(out["requests"])
     cap = out.get("capture")
     if cap:
         print(
@@ -426,6 +600,12 @@ def main(argv=None) -> dict:
         for key in ("idle_by_span_s", "idle_by_span_and_runtime_event_s"):
             for name, seconds in cap.get(key, {}).items():
                 print(f"  {key[:-2]}: {seconds:.4f} s  {name}")
+        for r in cap.get("slowest_requests_s", ()):
+            print(
+                f"  slowest on the capture: rid {r['rid']} admitted at "
+                f"{r['admit']:.4f} s, first token {r['first_token']:.4f}, "
+                f"retired {r['retire']:.4f} (of {cap['interval_s']:.4f})"
+            )
     return out
 
 

@@ -43,14 +43,20 @@ def _rehearse(cell: str, trace: int) -> dict:
         ("joyai-llm-flash.chat_backlog_2k", 0, {"serve_tok_s", "tpot_p95_ms", "setup_s"}),
         (
             "joyai-llm-flash.chat_backlog_2k", 1,
-            {"moe_tokens_per_expert_read", "refill_host_share_pct", "refill_fill_pct"},
+            {"moe_tokens_per_expert_read", "refill_host_share_pct", "refill_fill_pct",
+             "tpot_stall_p95_ms", "tpot_decode_p95_ms", "wait_host_share_pct"},
         ),
         (
             "nemotron-3-super-120b-a12b.chat_backlog_2k", 1,
-            {"moe_tokens_per_expert_read", "refill_fill_pct", "ssm_carried_rows_pct"},
+            {"moe_tokens_per_expert_read", "refill_fill_pct", "ssm_carried_rows_pct",
+             "wait_host_share_pct"},
         ),
         ("gpt2-xl.chat_backlog", 0, {"serve_tok_s", "tpot_p95_ms", "setup_s"}),
-        ("gpt2-xl.chat_backlog", 1, {"refill_host_share_pct", "refill_fill_pct"}),
+        (
+            "gpt2-xl.chat_backlog", 1,
+            {"refill_host_share_pct", "refill_fill_pct", "tpot_stall_p95_ms",
+             "tpot_decode_p95_ms", "wait_host_share_pct"},
+        ),
     ],
 )
 def test_a_serving_cell_rehearses_to_a_correct_line(cell, trace, reports):

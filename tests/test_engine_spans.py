@@ -6,7 +6,9 @@ profiler's host timeline. The same frames carry the empty-device clock
 (``engine_device_starved_seconds_total``) and feed the per-dispatch
 ``engine.dispatch`` flight-recorder event. These tests pin the names, the
 partition, the identities between counters, events and request records,
-and that every benchmark metric file reads something a served engine has.
+that every benchmark metric file reads something a served engine has,
+and the request clock (PR 37): every second of a request between its
+admission and its retirement in one phase, two identities a request.
 """
 
 import collections
@@ -114,12 +116,14 @@ def served():
     prompts = _prompts(cfg, 14, 6)
     outs = _drain(eng, params, prompts)
     return {
-        "eng": eng, "prompts": prompts, "outs": outs, "start": start,
+        "eng": eng, "cfg": cfg, "params": params, "prompts": prompts,
+        "outs": outs, "start": start,
         "end": eng.registry.snapshot(),
         "buckets": eng.ledger.window_buckets(),
         "reconcile": eng.ledger.reconcile(),
         "events": [e for e in eng.tracer.events if e["ph"] == "X"],
         "dispatches": eng.recorder.events("engine.dispatch"),
+        "retired": eng.recorder.events("engine.retire"),
     }
 
 
@@ -149,6 +153,45 @@ def _exclusive(events):
         assert own[id(ev)] >= -1e-3, (ev["name"], own[id(ev)])
         by_name[ev["name"]] += own[id(ev)]
     return by_name, tops
+
+
+PHASES = ("refill_wait", "refill", "stall", "decode")
+PHASE_SECONDS = 'engine_request_phase_seconds_total{phase="%s"}'
+PHASE_DISPATCHES = 'engine_request_phase_dispatches_total{phase="%s"}'
+
+
+def _check_identities(retired):
+    """The request clock's two identities (and what follows from them) for
+    every ``engine.retire`` event, to float rounding. A dispatch path that
+    skipped its tick would leave its seconds in no phase and fail the
+    second or the first."""
+    assert retired
+    for e in retired:
+        to_first_token = e["ttft"] - e["queue_wait"]     # from the FIRST admission
+        assert (
+            e["redone_s"] + e["requeue_wait_s"] + e["refill_wait_s"] + e["refill_s"]
+        ) == pytest.approx(to_first_token, abs=1e-9), e
+        assert e["stall_s"] + e["decode_s"] == pytest.approx(
+            e["e2e"] - e["ttft"], abs=1e-9
+        ), e
+        assert e["first_token_unix"] - e["admit_unix"] == pytest.approx(
+            e["refill_wait_s"] + e["refill_s"], abs=1e-4
+        ), e                     # from the LAST one, on the recorder's clock
+        # ... and the event is written after the request finished.
+        assert e["t"] - e["first_token_unix"] >= e["stall_s"] + e["decode_s"] - 1e-4
+        gaps = e["generated"] - 1
+        if gaps:
+            assert e["tpot"] * gaps == pytest.approx(
+                e["stall_s"] + e["decode_s"], abs=1e-9
+            )
+            assert e["stall_per_token_s"] + e["decode_per_token_s"] == (
+                pytest.approx(e["tpot"], abs=1e-12)
+            )
+            assert e["decode_dispatches"] >= 1
+        else:
+            assert e["tpot"] is e["stall_per_token_s"] is e["decode_per_token_s"] is None
+        assert e["refill_dispatches"] >= 1
+        assert min(e[f"{p}_s"] for p in PHASES) >= 0.0
 
 
 # --- (a) the spans partition step() and add up to the ledger's buckets -------
@@ -209,19 +252,48 @@ def test_an_undispatched_step_leaves_no_dispatch_span():
     assert not eng.recorder.events("engine.dispatch")
 
 
-def test_mixed_engine_books_each_dispatch_under_the_program_that_ran():
+@pytest.fixture(scope="module")
+def served_mixed():
+    """A mixed engine. One drain at a link a dispatch; then, a budget of
+    one token a dispatch, a prompt that arrives while another request
+    decodes (it waits for a refill turn); then a drain at a two-link
+    horizon (``multi_step``: one readback shows two links)."""
     cfg, params, eng = _setup(mixed=True)
     _drain(eng, params, _prompts(cfg, 5, 4))
-    names = collections.Counter(
-        e["name"] for e in eng.tracer.events if e["ph"] == "X"
+    got = {
+        "eng": eng,
+        "names": collections.Counter(
+            e["name"] for e in eng.tracer.events if e["ph"] == "X"
+        ),
+        "kinds": collections.Counter(
+            e["phase"] for e in eng.recorder.events("engine.dispatch")
+        ),
+        "reconcile": eng.ledger.reconcile(),
+    }
+    budget, eng.token_budget = eng.token_budget, 1
+    first, late = _prompts(cfg, 6, 2)
+    eng.add_request(first[:5])
+    eng.step(params)                      # its first token
+    got["late_rid"] = eng.add_request(late)
+    eng.step(params)                      # decodes one; nothing left to refill with
+    got["budget_dispatch"] = eng.recorder.events("engine.dispatch")[-1]
+    while eng.has_work():
+        eng.step(params)
+    eng.token_budget, eng.horizon = budget, 2
+    _drain(eng, params, _prompts(cfg, 7, 3))
+    got["horizon_kinds"] = collections.Counter(
+        e["family"] for e in eng.recorder.events("engine.dispatch")
     )
-    kinds = collections.Counter(
-        e["phase"] for e in eng.recorder.events("engine.dispatch")
-    )
+    got["retired"] = eng.recorder.events("engine.retire")
+    return got
+
+
+def test_mixed_engine_books_each_dispatch_under_the_program_that_ran(served_mixed):
+    names, kinds = served_mixed["names"], served_mixed["kinds"]
     for kind in ("refill", "decode", "mixed"):
         assert names[f"engine.{kind}"] == kinds[kind]
     assert kinds["mixed"] and names["engine.enqueue.mixed_step"]
-    assert eng.ledger.reconcile()["ok"]
+    assert served_mixed["reconcile"]["ok"]
 
 
 def test_span_names_leave_the_buckets_alone():
@@ -285,6 +357,13 @@ def test_a_disabled_tracer_costs_no_span_and_keeps_the_books():
     snap = eng.registry.snapshot()
     assert snap[STARVED] > 0 and snap["engine_h2d_seconds_total"] > 0
     assert eng.recorder.events("engine.dispatch")
+    retired = eng.recorder.events("engine.retire")
+    assert len(retired) == 3
+    _check_identities(retired)
+    for phase in ("refill", "decode"):
+        assert 0 < snap[PHASE_SECONDS % phase] == pytest.approx(
+            sum(e[f"{phase}_s"] for e in retired), rel=1e-9
+        )
 
 
 # --- (b) the empty-device clock ------------------------------------------------
@@ -374,6 +453,11 @@ def test_the_clock_stops_while_a_chained_dispatch_is_in_flight():
     assert marks and set(marks) == {0}
     assert any(n == 2 for _, n in waits), "the chain never had two in flight"
     assert not any(running for running, n in waits if n > 0)
+    # The request clock ticks at each segment's readback: the prompt's two
+    # dispatches are two refill ticks, and the identities hold.
+    retired = eng.recorder.events("engine.retire")
+    assert [e["refill_dispatches"] for e in retired] == [2, 2]
+    _check_identities(retired)
 
 
 # --- (c) one engine.dispatch event per dispatch --------------------------------
@@ -509,10 +593,10 @@ def test_profiler_capture_holds_the_engine_spans(tmp_path):
     assert abs(other["epoch_unix_ns"] / 1e9 - eng.recorder.events()[0]["t"]) < 600
 
 
-def test_the_breakdown_tool_places_a_bundle_on_a_capture(tmp_path, capsys):
-    """``scripts/engine_breakdown.py`` is the reader of the dispatch
-    events, the labelled starved series and the clock anchors: it finds
-    in a bundle exactly the dispatches of the steps a capture caught."""
+@pytest.fixture(scope="module")
+def captured(tmp_path_factory):
+    """An engine's bundle and a capture of three of its steps."""
+    tmp_path = tmp_path_factory.mktemp("captured")
     cfg, params, eng = _setup()
     _drain(eng, params, _prompts(cfg, 1, 2))          # warm, before it
     for p in _prompts(cfg, 2, 2):
@@ -527,9 +611,19 @@ def test_the_breakdown_tool_places_a_bundle_on_a_capture(tmp_path, capsys):
         jax.profiler.stop_trace()
     while eng.has_work():                             # ... and after it
         eng.step(params)
-    bundle = eng.dump_diagnostics(tmp_path / "bundle")
+    return {
+        "cfg": cfg, "eng": eng, "xplane": str(tmp_path / "xplane"),
+        "bundle": str(eng.dump_diagnostics(tmp_path / "bundle")),
+    }
+
+
+def test_the_breakdown_tool_places_a_bundle_on_a_capture(captured, capsys):
+    """``scripts/engine_breakdown.py`` is the reader of the dispatch
+    events, the labelled starved series and the clock anchors: it finds
+    in a bundle exactly the dispatches of the steps a capture caught."""
+    cfg, eng, bundle = captured["cfg"], captured["eng"], captured["bundle"]
     out = engine_breakdown.main(
-        [str(bundle), "--xplane", str(tmp_path / "xplane"), "--json"]
+        [bundle, "--xplane", captured["xplane"], "--json"]
     )
     assert json.loads(capsys.readouterr().out) == out
     cap = out["capture"]
@@ -567,6 +661,70 @@ def test_the_breakdown_tool_places_a_bundle_on_a_capture(tmp_path, capsys):
     assert "starved by span: " in printed
     assert "chunk rows a dispatch, fill " in printed
     assert f"table pushes of {cfg.num_layers} leaves in 1 arrays" in printed
+
+
+def test_the_breakdown_tool_prints_the_request_table(captured, capsys):
+    """The request clock's reader: from a bundle's ``engine.retire``
+    events the phases' percentiles, the requests by dispatch count and
+    the slowest by TPOT; with a capture, those on its clock."""
+    retired = captured["eng"].recorder.events("engine.retire")
+    out = engine_breakdown.main(
+        [captured["bundle"], "--xplane", captured["xplane"], "--json"]
+    )
+    capsys.readouterr()
+    table = out["requests"]
+    # The warm drain's two sat through compiling dispatches: left out, as
+    # a dispatch that compiled is in the family table.
+    assert len(retired) == 4 and (table["requests"], table["compiled"]) == (2, 2)
+    retired = retired[2:]
+    assert set(table["phases_ms"]) == set(PHASES)
+    for phase in PHASES:
+        values = sorted(1e3 * e[f"{phase}_s"] for e in retired)
+        assert values[0] <= table["phases_ms"][phase]["p50"] <= (
+            table["phases_ms"][phase]["p95"]
+        ) <= values[-1]
+    for key in ("refill_dispatches", "stall_dispatches"):
+        assert sum(table[key].values()) == 2
+        assert sum(int(k) * n for k, n in table[key].items()) == sum(
+            e[key] for e in retired
+        )
+    slowest = table["slowest"]
+    assert [r["tpot_ms"] for r in slowest] == sorted(
+        (1e3 * e["tpot"] for e in retired), reverse=True
+    )
+    for r in slowest + [table["at_tpot_p95"]]:
+        assert r["stall_ms"] + r["decode_ms"] == pytest.approx(
+            r["tpot_ms"] * (r["generated"] - 1), rel=1e-9
+        )
+    # On the capture: admitted in its first step, then a first token
+    # inside it; retired after it (three steps do not finish them).
+    placed = out["capture"]["slowest_requests_s"]
+    assert [r["rid"] for r in placed] == [r["rid"] for r in slowest]
+    assert sorted(r["rid"] for r in placed) == [2, 3]
+    for r in placed:
+        assert 0 <= r["admit"] < r["first_token"] < out["capture"]["interval_s"]
+        assert r["first_token"] < r["retire"]
+    # The registry's phase counters: every retired request's seconds (no
+    # request is live, none was preempted).
+    snap = captured["eng"].registry.snapshot()
+    assert set(out["request_phases"]) == {*PHASES, "redone"}
+    for phase in PHASES:
+        row = out["request_phases"][phase]
+        assert row["seconds"] == snap[PHASE_SECONDS % phase] == pytest.approx(
+            sum(e[f"{phase}_s"] for e in captured["eng"].recorder.events("engine.retire")),
+            rel=1e-9, abs=1e-12,
+        )
+    engine_breakdown.main([captured["bundle"], "--xplane", captured["xplane"]])
+    printed = capsys.readouterr().out
+    assert "request clock, slot-seconds (readbacks) since the engine was built: " in printed
+    assert "ms a token: tpot " in printed and "requests by stall_dispatches: " in printed
+    assert "and 2 that sat through a compiling dispatch, left out" in printed
+    assert printed.count("  slowest: rid ") == 2
+    assert printed.count("slowest on the capture: rid ") == 2
+    # A bundle from before the request clock has no table.
+    assert engine_breakdown.request_table(
+        [{"kind": "engine.retire", "rid": 0, "generated": 6, "ttft": 0.1, "e2e": 0.2}]
+    ) is None
 
 
 @pytest.mark.parametrize("arrays", [None, 2.0], ids=["before_pr32", "with_arrays"])
@@ -640,6 +798,7 @@ def _metric_files(readers):
     "spec",
     _metric_files({
         "registry", "ledger_share", "trace_roofline_counted", "trace_roofline_calls",
+        "recorder_field",
     }),
 )
 def test_metric_file_reads_what_a_served_engine_has(served, served_moe, spec):
@@ -647,6 +806,11 @@ def test_metric_file_reads_what_a_served_engine_has(served, served_moe, spec):
     if spec["reader"] == "ledger_share":
         report = served["eng"].ledger.window_report()
         assert set(params["buckets"]) <= set(report["buckets"])
+    elif spec["reader"] == "recorder_field":
+        # The event kind exists and every event of it carries the field.
+        events = served["eng"].recorder.events(params["kind"])
+        assert events and all(params["field"] in e for e in events)
+        assert any(e[params["field"]] is not None for e in events)
     elif spec["reader"] == "trace_roofline_calls":
         # Rows a call: a dispatch of the family books its token slots.
         mine = [e for e in served["dispatches"] if e["family"] == params["family"]]
@@ -697,6 +861,7 @@ def served_moe():
         "eng": eng, "cfg": cfg, "prompts": prompts, "outs": outs,
         "start": start, "end": eng.registry.snapshot(),
         "dispatches": eng.recorder.events("engine.dispatch"),
+        "retired": eng.recorder.events("engine.retire"),
     }
 
 
@@ -786,6 +951,7 @@ def served_ssm():
         "eng": eng, "cfg": cfg, "prompts": prompts, "outs": outs, "start": {},
         "end": eng.registry.snapshot(),
         "dispatches": eng.recorder.events("engine.dispatch"),
+        "retired": eng.recorder.events("engine.retire"),
     }
 
 
@@ -898,3 +1064,189 @@ def test_the_breakdown_tool_times_the_kernels_a_trace_names_by_scope():
         "jit_refill_step | ssm.chunk_scan": (pytest.approx(3e-7), 1),
         "jit_refill_step | moe.experts": (pytest.approx(5e-8), 1),
     }
+
+
+# --- (h) the request clock: every second of a request in one phase ---------------
+
+RETIRE_FIELDS = {
+    "rid", "slot", "generated", "ttft", "e2e", "version", "queue_wait", "tpot",
+    "refill_wait_s", "refill_s", "stall_s", "decode_s", "refill_dispatches",
+    "stall_dispatches", "decode_dispatches", "stall_per_token_s",
+    "decode_per_token_s", "redone_s", "requeue_wait_s", "admit_unix",
+    "first_token_unix",
+}
+
+
+@pytest.mark.parametrize(
+    "engine", ["served", "served_moe", "served_ssm", "served_mixed"]
+)
+def test_a_retired_requests_phases_add_up_to_its_latency(engine, request):
+    """``refill_wait_s + refill_s`` is admission to first token and
+    ``stall_s + decode_s`` first token to retirement, for every request
+    of the split paged engines (dense, latent + experts, state space) and
+    of a mixed one (a link a dispatch, a budget that makes a prompt wait,
+    a two-link horizon)."""
+    retired = request.getfixturevalue(engine)["retired"]
+    _check_identities(retired)
+    assert all(set(e) >= RETIRE_FIELDS for e in retired)
+    assert not any(e["redone_s"] or e["requeue_wait_s"] for e in retired)
+    if engine != "served_mixed":
+        # A split engine's refill dispatch carries every admitted prompt.
+        assert not any(e["refill_wait_s"] for e in retired)
+
+
+def test_a_mixed_dispatch_books_each_request_by_what_it_got(served_mixed):
+    """With one token a dispatch to spend, the link that advances the
+    decoding request carries nothing of the prompt that just arrived: it
+    waits (``refill_wait``), and nobody stalls."""
+    ev = served_mixed["budget_dispatch"]
+    assert (ev["phase"], ev["carried"], ev["waiting"], ev["stalled"]) == (
+        "mixed", 0, 1, 0
+    )
+    (late,) = [
+        e for e in served_mixed["retired"] if e["rid"] == served_mixed["late_rid"]
+    ]
+    assert late["refill_wait_s"] > 0 and late["refill_dispatches"] >= 1
+    # ... and a two-link horizon reads back once for both links.
+    assert served_mixed["horizon_kinds"]["multi_step"]
+
+
+@pytest.fixture(scope="module")
+def pressed(served):
+    """The ``served`` engine again, after its measured drain. First a long
+    prompt that arrives while a request decodes: ONE refill dispatch (two
+    chunk rows) stalls it. Then two prompts whose decode does not fit the
+    pool together (11 pages of 8; each needs 6): one is preempted once."""
+    eng, params, cfg = served["eng"], served["params"], served["cfg"]
+    rng = np.random.default_rng(11)
+
+    def prompt(n):
+        return rng.integers(1, cfg.vocab_size, size=(n,)).astype(np.int32)
+
+    def snap():
+        return eng.registry.snapshot(), eng.recorder.events()
+
+    got = {"start": snap()}
+    got["early"] = eng.add_request(prompt(5))
+    eng.step(params)                                  # its first token
+    got["before"] = snap()
+    got["late"] = eng.add_request(prompt(16))
+    eng.step(params)                                  # the late prompt, whole
+    got["after"] = snap()
+    got["held"] = np.asarray([eng._ph_s[s] for s in range(2) if eng._req[s] >= 0]).sum(axis=0)
+    while eng.has_work():
+        eng.step(params)
+    got["drained"] = snap()
+    for _ in range(2):
+        eng.add_request(prompt(40))
+    while eng.has_work():
+        eng.step(params)
+    got["end"] = snap()
+    eng.pop_finished()
+    return got
+
+
+def _since(got, a, b, kind=None):
+    """Registry growth and the recorder's new events between two snaps."""
+    (reg_a, ev_a), (reg_b, ev_b) = got[a], got[b]
+    events = ev_b[len(ev_a):]
+    if kind is not None:
+        events = [e for e in events if e["kind"] == kind]
+    return (lambda name: reg_b[name] - reg_a.get(name, 0.0)), events
+
+
+def test_a_late_prompt_stalls_the_rows_already_decoding(pressed):
+    grew, (dispatch,) = _since(pressed, "before", "after", "engine.dispatch")
+    assert (dispatch["phase"], dispatch["chunk_rows"]) == ("refill", 2)
+    # carried + waiting: the requests admitted without a first token.
+    assert (dispatch["carried"], dispatch["waiting"], dispatch["stalled"]) == (1, 0, 1)
+    assert dispatch["rows"] == dispatch["stalled"]
+    _, retired = _since(pressed, "start", "drained", "engine.retire")
+    early, late = (
+        next(e for e in retired if e["rid"] == pressed[k]) for k in ("early", "late")
+    )
+    # The early request's stall IS that dispatch's tick: the one interval
+    # the clock gave ``stall`` while it was the only holder of a first token.
+    assert early["stall_dispatches"] == 1
+    assert early["stall_s"] == pytest.approx(grew(PHASE_SECONDS % "stall"), rel=1e-12)
+    assert early["stall_s"] > 0
+    assert grew(PHASE_DISPATCHES % "stall") == 1
+    assert (late["refill_dispatches"], late["stall_dispatches"]) == (1, 0)
+    assert late["refill_s"] == pytest.approx(grew(PHASE_SECONDS % "refill"), rel=1e-12)
+    assert late["refill_wait_s"] == late["stall_s"] == 0.0
+    _check_identities(retired)
+
+
+def test_the_phase_counters_grow_by_what_requests_were_given(served, pressed):
+    # A drain: everything the counters gained is on the retire events ...
+    for phase in PHASES:
+        assert _delta(served, PHASE_SECONDS % phase) == pytest.approx(
+            sum(e[f"{phase}_s"] for e in served["retired"]), rel=1e-9, abs=1e-12
+        )
+    for phase, field in (
+        ("refill", "refill_dispatches"), ("stall", "stall_dispatches"),
+        ("decode", "decode_dispatches"),
+    ):
+        assert 0 < _delta(served, PHASE_DISPATCHES % phase) == sum(
+            e[field] for e in served["retired"]
+        )
+    # ... and so are the dispatch events' causes, request by request.
+    for cause, field in (("carried", "refill_dispatches"), ("stalled", "stall_dispatches")):
+        assert sum(e[cause] for e in served["dispatches"]) == sum(
+            e[field] for e in served["retired"]
+        )
+    assert not any(e["waiting"] for e in served["dispatches"])
+    # Mid-flight: plus what the live requests hold.
+    grew, retired = _since(pressed, "start", "after", "engine.retire")
+    assert not retired and pressed["held"].sum() > 0
+    for i, phase in enumerate(PHASES):
+        assert grew(PHASE_SECONDS % phase) == pytest.approx(
+            pressed["held"][i], rel=1e-9, abs=1e-12
+        )
+
+
+def test_a_preempted_requests_identities_hold_from_its_last_admission(pressed):
+    grew, events = _since(pressed, "drained", "end")
+    (preempt,) = [e for e in events if e["kind"] == "engine.preempt"]
+    retired = [e for e in events if e["kind"] == "engine.retire"]
+    assert len(retired) == 2
+    _check_identities(retired)
+    (again,) = [e for e in retired if e["rid"] == preempt["rid"]]
+    (other,) = [e for e in retired if e["rid"] != preempt["rid"]]
+    assert again["redone_s"] > 0 and again["requeue_wait_s"] >= 0
+    assert other["redone_s"] == other["requeue_wait_s"] == 0.0
+    # 40 tokens are five chunks: five refill dispatches beside the other
+    # prompt, three alone (two rows a dispatch); the preempted request's
+    # five of its first admission are in ``redone`` only.
+    assert (other["refill_dispatches"], again["refill_dispatches"]) == (5, 3)
+    assert again["admit_unix"] > other["admit_unix"]
+    # The counters keep the redone seconds in the phase they ran in and
+    # count them again under ``redone``.
+    kept = sum(e[f"{p}_s"] for e in retired for p in PHASES)
+    assert grew(PHASE_SECONDS % "redone") == pytest.approx(again["redone_s"], rel=1e-9)
+    assert sum(grew(PHASE_SECONDS % p) for p in PHASES) == pytest.approx(
+        kept + again["redone_s"], rel=1e-9
+    )
+    assert grew(PHASE_DISPATCHES % "redone") == 5
+
+
+def test_a_failed_request_is_accounted_up_to_its_failure(pressed, served):
+    """``drain_requests`` fails a request between two readbacks: its event
+    carries the clock up to that instant (the tail is a wait)."""
+    eng, params, cfg = served["eng"], served["params"], served["cfg"]
+    rid = eng.add_request(_prompts(cfg, 21, 1)[0][:5])
+    eng.step(params)                                  # its first token
+    before = eng.registry.snapshot()
+    eng.drain_requests()
+    (failed,) = [
+        e for e in eng.recorder.events("engine.request_failed") if e["rid"] == rid
+    ]
+    eng.pop_finished()
+    assert failed["status"] == "rerouted" and failed["refill_dispatches"] == 1
+    assert failed["refill_wait_s"] == 0.0 and failed["decode_s"] == 0.0
+    after = eng.registry.snapshot()
+    assert 0 < failed["stall_s"] == pytest.approx(
+        after[PHASE_SECONDS % "stall"] - before[PHASE_SECONDS % "stall"], rel=1e-12
+    )
+    assert failed["stall_dispatches"] == 0
+    assert not eng.has_work()

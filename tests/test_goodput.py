@@ -186,9 +186,10 @@ class TestTraceStore:
         ts = TraceStore(registry=MetricsRegistry())
         ts.mint(1, arrival_t=10.0)
         ts.leg(1, "queue", 10.0, 11.0, replica="p0")
-        ts.leg(1, "prefill", 11.0, 12.5, replica="p0", first_token_t=12.5)
+        ts.leg(1, "prefill", 11.0, 12.5, replica="p0", first_token_t=12.5,
+               refill_wait_s=0.25)
         ts.leg(1, "handoff", 12.5, 12.7)
-        ts.leg(1, "decode", 12.8, 14.0, replica="d0")
+        ts.leg(1, "decode", 12.8, 14.0, replica="d0", stall_s=0.4)
         ts.complete(1, finish_t=14.2)
         cp = ts.critical_path(1)
         assert cp["e2e_s"] == pytest.approx(4.2)
@@ -200,17 +201,23 @@ class TestTraceStore:
         # stall = e2e − named stages: the 0.1 gap before decode plus the
         # 0.2 tail after it.
         assert cp["stages"]["stall"] == pytest.approx(0.3)
+        # ... beside what the engines' request clocks measured INSIDE the
+        # legs (a decode leg's seconds behind another request's refill).
+        assert cp["stall_measured_s"] == pytest.approx(0.4)
+        assert cp["refill_wait_s"] == pytest.approx(0.25)
 
     def test_wasted_legs_sum_separately(self):
         ts = TraceStore()
         ts.mint(1, arrival_t=0.0)
-        ts.leg(1, "prefill", 0.0, 1.0, wasted=True)    # failover threw it
+        ts.leg(1, "prefill", 0.0, 1.0, wasted=True,    # failover threw it
+               refill_wait_s=0.5)
         ts.leg(1, "prefill", 1.0, 1.5, first_token_t=1.5)
         ts.complete(1, finish_t=2.0)
         cp = ts.critical_path(1)
         assert cp["wasted_s"] == pytest.approx(1.0)
         assert cp["stages"]["prefill"] == pytest.approx(0.5)
         assert cp["stages"]["stall"] == pytest.approx(1.5)
+        assert cp["refill_wait_s"] == cp["stall_measured_s"] == 0.0
         assert cp["legs"] == 2
 
     def test_events_count_reroutes_and_pin_versions(self):
@@ -362,6 +369,12 @@ class TestEngineLedger:
             assert cp["stages"]["decode"] > 0.0
             assert cp["ttft_s"] is not None and cp["ttft_s"] > 0.0
             assert cp["e2e_s"] >= cp["ttft_s"]
+            # The request clock's numbers ride the legs: a split engine's
+            # prompts never wait for a refill turn, and a decode leg holds
+            # its stall.
+            assert cp["refill_wait_s"] == 0.0
+            assert 0.0 <= cp["stall_measured_s"] <= cp["stages"]["decode"]
+        assert any(cp["stall_measured_s"] > 0.0 for cp in cps)
 
     def test_ledger_series_reach_prometheus(self, served):
         eng, _ = served

@@ -262,6 +262,13 @@ class TestEngineTelemetry:
         # Same observations landed in the export histograms.
         assert snap["engine_ttft_seconds"]["count"] == len(prompts)
         assert snap["engine_e2e_seconds"]["count"] == len(prompts)
+        # README's Prometheus latency series: one sample a retired request
+        # (a TPOT needs two tokens); token gaps have no histogram.
+        assert snap["engine_queue_wait_seconds"]["count"] == len(prompts)
+        assert snap["engine_tpot_seconds"]["count"] == sum(
+            len(o) - len(p) > 1 for o, p in zip(outs, prompts)
+        )
+        assert "engine_itl_seconds" not in snap
 
     def test_request_timeline_events(self, served):
         eng, prompts, _ = served
