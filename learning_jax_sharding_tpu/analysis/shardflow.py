@@ -225,6 +225,7 @@ _UNARY = {
     "integer_pow", "square", "abs", "real", "imag", "conj",
     "convert_element_type", "copy", "stop_gradient", "reduce_precision",
     "erf_inv", "population_count", "clz", "bitcast_convert_type",
+    "name",     # jax.ad_checkpoint.checkpoint_name: an identity with a tag
 }
 
 #: Reductions keep the *partial* abstraction regardless of monoid — the
@@ -472,7 +473,7 @@ class _Interp:
         partial = frozenset().union(*(s.partial for s in specs))
         if partial and eqn.primitive.name not in (
             "add", "add_any", "sub", "neg", "mul", "div",
-            "convert_element_type", "copy", "stop_gradient",
+            "convert_element_type", "copy", "stop_gradient", "name",
         ):
             for i, s in enumerate(specs):
                 if s.partial:

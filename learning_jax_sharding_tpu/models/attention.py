@@ -30,6 +30,7 @@ from typing import Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from learning_jax_sharding_tpu.ops.attention import (
     causal_mask,
@@ -362,10 +363,19 @@ class MultiHeadAttention(nn.Module):
             v = self._proj("value", self.kv_heads)(x)
         # Projections emerge (B, S, N*H); constrain before the head split
         # (the reference constrains the same three activations,
-        # `case6_attention.py:105-116`, but names dim 1 'embed').
-        q = nn.with_logical_constraint(q, (BATCH, SEQ, HEADS))
-        k = nn.with_logical_constraint(k, (BATCH, SEQ, HEADS))
-        v = nn.with_logical_constraint(v, (BATCH, SEQ, HEADS))
+        # `case6_attention.py:105-116`, but names dim 1 'embed'). Named
+        # HERE, whole lane tiles wide, for a rematerialized block to keep
+        # (utils.memory.REMAT_GROUPS; an identity elsewhere): split by head
+        # a 64-wide minor axis is padded to 128 lanes in HBM.
+        q = checkpoint_name(
+            nn.with_logical_constraint(q, (BATCH, SEQ, HEADS)), "attn_q"
+        )
+        k = checkpoint_name(
+            nn.with_logical_constraint(k, (BATCH, SEQ, HEADS)), "attn_k"
+        )
+        v = checkpoint_name(
+            nn.with_logical_constraint(v, (BATCH, SEQ, HEADS)), "attn_v"
+        )
 
         q = q.reshape(b, s, self.num_heads, self.head_dim)
         k = k.reshape(b, s, self.kv_heads, self.head_dim)

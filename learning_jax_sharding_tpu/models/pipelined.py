@@ -33,6 +33,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from learning_jax_sharding_tpu.models.transformer import (
     TransformerBlock,
     TransformerConfig,
+    block_remat_policies,
     make_norm,
 )
 from learning_jax_sharding_tpu.parallel.logical import (
@@ -291,8 +292,16 @@ class PipelinedTransformer:
 
             if self.config.remat:
                 # Recompute each layer's activations in the backward pipeline
-                # instead of holding M microbatches' worth of them live.
-                apply_layer = jax.checkpoint(apply_layer)
+                # instead of holding M microbatches' worth of them live. One
+                # policy for the scanned layers, from the stack's resolver
+                # (no train step here says what is free: nothing is kept
+                # unless ``remat_policy`` names a policy).
+                apply_layer = jax.checkpoint(
+                    apply_layer,
+                    policy=block_remat_policies(
+                        self.config, h.shape[0], h.shape[1], uniform=True
+                    )[0],
+                )
 
             def body(h, layer_params):
                 return apply_layer(layer_params, h), None

@@ -48,6 +48,7 @@ from typing import Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from learning_jax_sharding_tpu.parallel.logical import BATCH, EMBED, MLP, SEQ
 
@@ -456,7 +457,11 @@ class ShortConv(nn.Module):
         m, k, s = self.features, self.kernel, x.shape[1]
         x = nn.with_logical_constraint(x, (BATCH, SEQ, EMBED))
         with jax.named_scope("conv.in_proj"):
-            bcu = self._dense(3 * m, (EMBED, MLP), "in_proj")(x)
+            # A residual a rematerialized block may keep
+            # (utils.memory.REMAT_GROUPS); an identity elsewhere.
+            bcu = checkpoint_name(
+                self._dense(3 * m, (EMBED, MLP), "in_proj")(x), "conv_in_proj"
+            )
         taps, _ = _Conv(k, m, self.param_dtype, use_bias=False, name="conv")()
         with jax.named_scope("conv.taps"):
             gate_b, gate_c, u = bcu[..., :m], bcu[..., m:2 * m], bcu[..., 2 * m:]

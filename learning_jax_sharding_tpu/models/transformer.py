@@ -47,6 +47,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
+from jax.ad_checkpoint import checkpoint_name
 
 from learning_jax_sharding_tpu.models.attention import (
     LatentAttention,
@@ -64,15 +65,20 @@ from learning_jax_sharding_tpu.parallel.logical import (
 
 
 def resolve_remat_policy(name: Optional[str]):
-    """Named ``jax.checkpoint`` policies for block rematerialization.
+    """Named ``jax.checkpoint`` policies for block rematerialization, the
+    same for every block.
 
-    ``None``/``"nothing"`` — save nothing, recompute everything (the
+    ``"nothing"`` — save nothing, recompute everything (the
     ``jax.checkpoint`` default; minimum memory, ~1/3 extra FLOPs);
     ``"dots"`` — save matmul outputs, recompute only elementwise/softmax work
     (most of the memory win at a fraction of the recompute);
     ``"dots_no_batch"`` — save only batch-free matmuls (i.e. none in a
     transformer block: everything carries the batch dim, so this is the
     conservative middle ground XLA offload papers use).
+
+    ``None`` is none of these: "keep what the chip has room for", a policy
+    by block that :func:`block_remat_policies` resolves from the train
+    step's memory. Here it maps, like ``"nothing"``, to no policy.
     """
     if name is None or name == "nothing":
         return None
@@ -86,6 +92,40 @@ def resolve_remat_policy(name: Optional[str]):
             f"'dots', or 'dots_no_batch'"
         )
     return policies[name]
+
+
+def block_remat_policies(
+    cfg: "TransformerConfig", batch: int, seq: int, *, uniform: bool = False
+) -> list:
+    """The ``jax.checkpoint`` policy of each block under ``cfg.remat``: THE
+    resolver of every rematerialization site (the unrolled stack, the
+    scanned one, ``models/pipelined.py``'s stage).
+
+    An explicit ``cfg.remat_policy`` is every block's. ``None`` keeps what
+    fits: the train step that traces this model says what its device has
+    left (``utils.memory.remat_scope``, set by ``make_train_step``), and
+    ``utils.memory.remat_plan`` picks, from that and the traced ``batch`` x
+    ``seq``, which of a block's NAMED residuals
+    (``utils.memory.REMAT_GROUPS``) ``save_only_these_names`` keeps. With no
+    scope around the trace (a bare ``apply``), or a device whose memory is
+    unknown (the emulated CPU mesh), nothing is kept: full recomputation.
+    ``uniform``: one plan for all blocks (a scanned stack traces one).
+    """
+    if cfg.remat_policy is not None:
+        return [resolve_remat_policy(cfg.remat_policy)] * cfg.num_layers
+    from learning_jax_sharding_tpu.utils.memory import current_remat_scope
+
+    scope = current_remat_scope()
+    if scope is None:
+        return [None] * cfg.num_layers
+    plan = scope.resolve(cfg, batch, seq, uniform=uniform)
+    # One policy object a distinct set of names: the stack builds one
+    # rematerialized block class for each.
+    by_names = {
+        names: jax.checkpoint_policies.save_only_these_names(*names)
+        for names in set(plan.names) if names
+    }
+    return [by_names.get(names) for names in plan.names]
 
 
 class _CompressedDense(nn.Module):
@@ -222,11 +262,18 @@ class FeedForward(nn.Module):
                 x.astype(self.dtype), q4_up, s_up, q4_dn, s_dn, group=g
             )
             return nn.with_logical_constraint(out, (BATCH, SEQ, EMBED))
+        # The activation's inputs are residuals a rematerialized block may
+        # keep (utils.memory.REMAT_GROUPS); a name is an identity elsewhere.
         h = self._dense(self.hidden, (EMBED, MLP), "up")(x)
-        h = nn.with_logical_constraint(h, (BATCH, SEQ, HIDDEN))
+        h = checkpoint_name(
+            nn.with_logical_constraint(h, (BATCH, SEQ, HIDDEN)), "ff_up"
+        )
         if self.gated:
             g = self._dense(self.hidden, (EMBED, MLP), "gate")(x)
-            h = nn.silu(nn.with_logical_constraint(g, (BATCH, SEQ, HIDDEN))) * h
+            g = checkpoint_name(
+                nn.with_logical_constraint(g, (BATCH, SEQ, HIDDEN)), "ff_gate"
+            )
+            h = nn.silu(g) * h
         elif self.activation == "relu2":
             h = jnp.square(nn.relu(h))
         elif self.activation == "gelu":
@@ -509,6 +556,9 @@ class TransformerBlock(nn.Module):
 
     def _finish(self, x, attn_out, deterministic, chunk_lengths):
         """The second half of a block: residual add, norm, feed-forward."""
+        # A rematerialized block may keep the operator's output
+        # (utils.memory.REMAT_GROUPS); a name is an identity elsewhere.
+        attn_out = checkpoint_name(attn_out, "operator_out")
         # The block boundary: residual add + norm — ONE fused HBM pass
         # under fused_norm, the plain pair otherwise (identical math).
         h, x = self._norm("ln_ff")(attn_out, x)
@@ -603,10 +653,16 @@ class TransformerConfig:
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
-    remat: bool = False              # rematerialize each block's activations
-    remat_policy: Optional[str] = None  # what remat SAVES: None/'nothing'
-                                     # (recompute all), 'dots', 'dots_no_batch'
-                                     # (see resolve_remat_policy)
+    remat: bool = False              # rematerialize each block's activations:
+                                     # keep what the chip has room for and
+                                     # recompute the rest in the backward
+    remat_policy: Optional[str] = None  # what remat SAVES: None = the named
+                                     # residuals that fit the train step's
+                                     # free memory (block_remat_policies;
+                                     # nothing where no step says what is
+                                     # free); 'nothing' (recompute all),
+                                     # 'dots', 'dots_no_batch' for every block
+                                     # (resolve_remat_policy)
     remat_attention: bool = False    # rematerialize only the O(S²) attention
                                      # internals (cheap; lifts the batch cap)
     scan_layers: bool = False        # one nn.scan'd stacked block instead of
@@ -758,8 +814,8 @@ class TransformerConfig:
     ssm_chunk: int = 128
 
     def __post_init__(self):
-        # Fail fast on typos; 'nothing' IS the default, so only a policy that
-        # changes behavior demands remat=True.
+        # Fail fast on typos; 'nothing' is what remat=False ignores anyway,
+        # so only a policy that changes behavior demands remat=True.
         if resolve_remat_policy(self.remat_policy) is not None and not self.remat:
             raise ValueError(
                 "remat_policy is set but remat=False — the policy would "
@@ -1322,7 +1378,7 @@ class Transformer(nn.Module):
                 block_cls = nn.remat(
                     TransformerBlock,
                     prevent_cse=False,
-                    policy=resolve_remat_policy(cfg.remat_policy),
+                    policy=block_remat_policies(cfg, b, s, uniform=True)[0],
                     static_argnums=(2,),
                 )
             stack = nn.scan(
@@ -1337,25 +1393,34 @@ class Transformer(nn.Module):
                 x, deterministic
             )
         else:
-            block_cls = TransformerBlock
+            remat_cls = {}
             if cfg.remat and not cfg.decode:
-                # Trade FLOPs for HBM: recompute each block's activations in
-                # the backward instead of storing them (SURVEY.md's remat
-                # note; key to fitting long sequences). deterministic is arg 2
-                # (self=0) and must stay untraced — nn.Dropout branches on it.
-                block_cls = nn.remat(
-                    TransformerBlock,
-                    static_argnums=(2,),
-                    policy=resolve_remat_policy(cfg.remat_policy),
-                )
+                # Trade FLOPs for HBM: recompute in the backward what a block
+                # does not keep (its input, and the named residuals its
+                # policy saves: block_remat_policies) instead of storing
+                # everything (SURVEY.md's remat note; key to fitting long
+                # sequences). The blocks are unrolled, so each may keep what
+                # the plan gives it. deterministic is arg 2 (self=0) and must
+                # stay untraced — nn.Dropout branches on it.
+                policies = block_remat_policies(cfg, b, s)
+                remat_cls = {
+                    policy: nn.remat(
+                        TransformerBlock, static_argnums=(2,), policy=policy,
+                    )
+                    for policy in set(policies)
+                }
             for i in range(cfg.num_layers):
                 if cfg.decode:
                     # chunk_lengths rides only the decode path (remat wraps
                     # the training call and pins its positional signature).
-                    x = block_cls(**fields_of(i), name=f"block_{i}")(
+                    x = TransformerBlock(**fields_of(i), name=f"block_{i}")(
                         x, deterministic, chunk_lengths
                     )
                 else:
+                    block_cls = (
+                        remat_cls[policies[i]] if remat_cls
+                        else TransformerBlock
+                    )
                     x = block_cls(**fields_of(i), name=f"block_{i}")(
                         x, deterministic
                     )

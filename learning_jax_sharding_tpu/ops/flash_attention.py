@@ -28,6 +28,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -435,15 +436,12 @@ def _bwd_dq_kernel(
 
 
 def _bwd(scale, causal, window, block_q, block_k, interpret, group, residuals, do):
-    q, k, v, out, lse = residuals
+    # ``delta_i = Σ_h do_ih · o_ih`` comes in with the residuals: a tiny
+    # elementwise reduction the caller makes wherever ``out`` lives.
+    q, k, v, lse, delta = residuals
     bn, s_q, h = q.shape
     s_kv = k.shape[1]
     nq, nk = pl.cdiv(s_q, block_q), pl.cdiv(s_kv, block_k)
-
-    # delta_i = Σ_h do_ih · o_ih — tiny elementwise reduction, jnp handles it.
-    delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
-    )
 
     # Banded grids mirror the forward (see the banded-grid comment block):
     # dkv sweeps only the q blocks attending into its k block, dq only the
@@ -557,40 +555,108 @@ def _auto_block(s: int, cap: int = 1024) -> int:
     return blk
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11)
-)
+def _q_rows(x, n_kv):
+    """(B, S, N, H) → (B·N_kv, S·group, H): each (batch, kv-head) slice is
+    independent; under GQA the group's query heads FOLD INTO THE ROW DIM
+    (row r = position r // group), so k/v enter at their native N_kv heads
+    — no repeat_kv materialization, and dk/dv reduce over the group for
+    free in the kernel's q-row sweep. MHA is the group == 1 case."""
+    b, s, n, h = x.shape
+    return (
+        x.reshape(b, s, n_kv, n // n_kv, h)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(b * n_kv, s * (n // n_kv), h)
+    )
+
+
+def _q_unrows(x, b, n):
+    bn_kv, rows, h = x.shape
+    n_kv = bn_kv // b
+    group = n // n_kv
+    return (
+        x.reshape(b, n_kv, rows // group, group, h)
+        .transpose(0, 2, 1, 3, 4)
+        .reshape(b, rows // group, n, h)
+    )
+
+
+def _kv_rows(x):
+    b, s, n, h = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * n, s, h)
+
+
+def _kv_unrows(x, b):
+    bn, s, h = x.shape
+    return x.reshape(b, bn // b, s, h).transpose(0, 2, 1, 3)
+
+
+def _attend(q, k, v, scale, causal, window, block_q, block_k, interpret):
+    """The forward kernel over ``(B, S, N, H)`` operands: ``out`` in their
+    layout, ``lse`` and the operands in the kernels' row layout."""
+    b, _, n, _ = q.shape
+    n_kv = k.shape[2]
+    rows = _q_rows(q, n_kv), _kv_rows(k), _kv_rows(v)
+    out, lse = _fwd(
+        *rows, scale=scale, causal=causal, window=window,
+        block_q=block_q, block_k=block_k, interpret=interpret,
+        group=n // n_kv,
+    )
+    return _q_unrows(out, b, n), lse, rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash(q, k, v, scale, causal, window, block_q, block_k,
-           bwd_block_q, bwd_block_k, interpret, group):
-    out, _ = _fwd(
-        q, k, v, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret, group=group,
+           bwd_block_q, bwd_block_k, interpret):
+    out, _, _ = _attend(
+        q, k, v, scale, causal, window, block_q, block_k, interpret
     )
     return out
 
 
 def _flash_fwd(q, k, v, scale, causal, window, block_q, block_k,
-               bwd_block_q, bwd_block_k, interpret, group):
-    out, lse = _fwd(
-        q, k, v, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=interpret, group=group,
+               bwd_block_q, bwd_block_k, interpret):
+    out, lse, (qr, kr, vr) = _attend(
+        q, k, v, scale, causal, window, block_q, block_k, interpret
     )
-    return out, (q, k, v, out, lse)
+    # The backward reads ``out`` only for ``delta``, a sum over H it can take
+    # in any layout, so the residual is the (B, S, N·H) form the output
+    # projection multiplies anyway: whole lane tiles, where the kernel's own
+    # (rows, 64) is padded to 128 lanes in HBM, twice its bytes in every
+    # block that keeps it. ``lse`` is kept without its trailing 1 for the
+    # same reason (128 x its bytes). Both carry names
+    # (utils.memory.REMAT_GROUPS): under a ``jax.checkpoint`` whose policy
+    # saves them the saved values ARE the backward's residuals and the
+    # forward kernel is dead code in the recomputation. The names sit in the
+    # VJP's forward rule alone: the primal (serving) never sees them.
+    b, s_q, n, h = out.shape
+    kept = checkpoint_name(out.reshape(b, s_q, n * h), "flash_out")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
+    return kept.reshape(out.shape), (qr, kr, vr, kept, lse)
 
 
 def _flash_bwd(
     scale, causal, window, block_q, block_k, bwd_block_q, bwd_block_k,
-    interpret, group, residuals, do,
+    interpret, residuals, do,
 ):
+    qr, kr, vr, out, lse = residuals
+    b, _, n, _ = do.shape
+    n_kv = kr.shape[0] // b
+    delta = jnp.sum(
+        do.astype(jnp.float32) * out.reshape(do.shape).astype(jnp.float32),
+        axis=-1, keepdims=True,
+    )
     # The backward's optimal tiles differ from the forward's (it holds
     # more live tensors per block: do, lse, delta, two accumulators) —
     # tunable independently; None inherits the forward tiles.
-    return _bwd(
+    dq, dk, dv = _bwd(
         scale, causal, window,
         block_q if bwd_block_q is None else bwd_block_q,
         block_k if bwd_block_k is None else bwd_block_k,
-        interpret, group, residuals, do,
+        interpret, n // n_kv,
+        (qr, kr, vr, lse[..., None], _q_rows(delta, n_kv)),
+        _q_rows(do, n_kv),
     )
+    return _q_unrows(dq, b, n), _kv_unrows(dk, b), _kv_unrows(dv, b)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -671,35 +737,15 @@ def flash_attention(
             )
     scale = h**-0.5 if scale is None else scale
 
-    # (B, S, N, H) → (B·N_kv, S·group, H): each (batch, kv-head) slice is
-    # independent; under GQA the group's query heads FOLD INTO THE ROW DIM
-    # (row r = position r // group), so k/v enter at their native N_kv heads
-    # — no repeat_kv materialization, and dk/dv reduce over the group for
-    # free in the kernel's q-row sweep. MHA is the group == 1 case.
-    def q_rows(x):
-        return (
-            x.reshape(b, s_q, n_kv, group, h)
-            .transpose(0, 2, 1, 3, 4)
-            .reshape(b * n_kv, rows_q, h)
-        )
-
-    def kv_rows(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * n_kv, s_kv, h)
-
     for bwd_blk, rows in ((bwd_block_q, rows_q), (bwd_block_k, s_kv)):
         if bwd_blk is not None and rows % bwd_blk:
             raise ValueError(
                 f"sequence rows ({rows}) must be divisible by the backward "
                 f"block size ({bwd_blk})"
             )
-    out = _flash(
-        q_rows(q), kv_rows(k), kv_rows(v), scale, causal, window,
-        block_q, block_k, bwd_block_q, bwd_block_k, interpret, group,
-    )
-    return (
-        out.reshape(b, n_kv, s_q, group, h)
-        .transpose(0, 2, 1, 3, 4)
-        .reshape(b, s_q, n, h)
+    return _flash(
+        q, k, v, scale, causal, window,
+        block_q, block_k, bwd_block_q, bwd_block_k, interpret,
     )
 
 

@@ -529,9 +529,10 @@ def fit(
                     # dispatch — and a wedged sync is flagged.
                     metrics.log(i + 1, loss=loss)
                     cache_after = cache_size(step_fn.jitted)
-                    if cache_after is not None and (
+                    compiled = cache_after is not None and (
                         cache_before is None or cache_after > cache_before
-                    ):
+                    )
+                    if compiled:
                         frame.rebucket("compile")
                 # The OBSERVED loss: the chaos seam can corrupt the host
                 # reading (the spike drill) without touching device state.
@@ -541,6 +542,19 @@ def fit(
                     )
                 with led.measure("telemetry"):
                     rec.record("train_step", step=i + 1, loss=loss_f)
+                    if registry is not None and (compiled or i == start_step):
+                        # What remat=True keeps, resolved while the step was
+                        # traced (make_train_step): 0 / 0 where it keeps
+                        # nothing (no remat, the emulated CPU mesh).
+                        plan = step_fn.remat.plan
+                        registry.gauge(
+                            "train_remat_saved_bytes",
+                            "named residuals the rematerialized blocks keep, a device",
+                        ).set(plan.saved_bytes if plan else 0)
+                        registry.gauge(
+                            "train_remat_budget_bytes",
+                            "bytes the train step found free for them, a device",
+                        ).set(plan.budget_bytes if plan else 0)
                     if routing and registry is not None:
                         # Outputs of the step whose loss was just read.
                         for key in ("held_assignments", "experts_touched"):

@@ -23,6 +23,8 @@ mesh/rules handled by one context helper instead of repeated ``with`` pairs.
 from __future__ import annotations
 
 import functools
+import logging
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -33,13 +35,21 @@ from flax.training import train_state
 from jax.sharding import Mesh, NamedSharding
 
 from learning_jax_sharding_tpu.parallel.logical import (
+    BATCH,
+    MLP,
     Rules,
     Unstepped,
     activate,
     tree_shardings,
 )
+from learning_jax_sharding_tpu.utils.memory import (
+    RematScope,
+    device_memory_bytes,
+    remat_scope,
+)
 
 TrainState = train_state.TrainState
+log = logging.getLogger(__name__)
 
 
 def default_loss(y: jax.Array, batch: Any) -> jax.Array:
@@ -183,6 +193,28 @@ def sharded_train_state(
     return state, state_shardings
 
 
+def _mesh_shards(mesh: Mesh, rules: Rules, logical_axis: str) -> int:
+    """How many ways ``rules`` split ``logical_axis`` over ``mesh``."""
+    from flax.linen import partitioning as nn_partitioning
+
+    (axes,) = nn_partitioning.logical_to_mesh_axes((logical_axis,), tuple(rules))
+    if axes is None:
+        return 1
+    names = (axes,) if isinstance(axes, str) else axes
+    return math.prod(mesh.shape[a] for a in names)
+
+
+def _device_bytes_of(tree: Any, shardings: Any) -> float:
+    """Bytes ONE device holds of ``tree`` (arrays or tracers) placed by
+    ``shardings``: every leaf's shard, from shapes alone."""
+    return float(sum(
+        math.prod(sh.shard_shape(leaf.shape)) * leaf.dtype.itemsize
+        for leaf, sh in zip(
+            jax.tree.leaves(tree), jax.tree.leaves(shardings), strict=True
+        )
+    ))
+
+
 def make_train_step(
     state_shardings: Any,
     x_sharding: NamedSharding,
@@ -259,6 +291,16 @@ def make_train_step(
     with it: no sync of their own. Not with ``grad_accum_steps`` or
     ``aux_loss_collection``.
 
+    A model built with ``remat=True`` (and no explicit ``remat_policy``)
+    keeps of each block what this step has room for: while the step is
+    traced it tells the model, through ``utils.memory.remat_scope``, what its
+    device holds (``utils.memory.device_memory_bytes``: 0 of budget on the
+    emulated CPU mesh), the bytes of ``state`` a device is given and how the
+    mesh divides the batch and the widths. The returned callable's ``remat``
+    (a ``utils.memory.RematScope``) holds the resolved ``plan`` after the
+    first call (names by block, bytes kept, budget, predicted peak), which
+    is also logged once at INFO.
+
     ``steps_per_call``: run this many FULL optimizer steps per jitted call
     (a ``lax.scan``); the batch then carries a leading ``(steps_per_call,)``
     dim of per-step batches and the returned loss is the per-step
@@ -276,7 +318,24 @@ def make_train_step(
             "grad_accum_steps or aux_loss_collection"
         )
 
+    # What the model under ``remat=True`` resolves its plan from; the state's
+    # bytes are read off the traced state, so every trace of ``step`` (the
+    # call's, a ``.lower()``) sees the same budget.
+    remat = RematScope(
+        device_bytes=device_memory_bytes(mesh.devices.flat[0]),
+        n_data_shards=_mesh_shards(mesh, rules, BATCH),
+        n_model_shards=_mesh_shards(mesh, rules, MLP),
+    )
+
     def step(state: TrainState, batch: Any):
+        remat.state_bytes = _device_bytes_of(state, state_shardings)
+        with remat_scope(remat):
+            out = _step(state, batch)
+        if remat.plan is not None:
+            log.info("train step: %s", remat.plan.summary())
+        return out
+
+    def _step(state: TrainState, batch: Any):
         def loss_of_params(params, batch, micro_idx=0):
             kwargs: dict[str, Any] = dict(apply_kwargs or {})
             if dropout_rng is not None:
@@ -401,6 +460,7 @@ def make_train_step(
             return jitted(state, batch)
 
     run.jitted = jitted  # expose for lowering/HLO inspection
+    run.remat = remat    # .plan: what remat=True keeps, once traced
     return run
 
 

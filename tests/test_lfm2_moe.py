@@ -280,7 +280,8 @@ def test_a_bf16_router_and_a_dropped_renorm_eps_fail(monkeypatch):
 
 def test_fit_books_the_routing_counters_and_never_moves_the_selection_bias():
     """Three steps of ``fit(registry=, step_kwargs={"routing_stats": True})``
-    under ``default_optimizer`` (AdamW with weight decay). The counters:
+    under ``default_optimizer`` (AdamW with weight decay), every block
+    rematerialized. The counters:
     assignments to held experts, held experts touched and the largest load,
     counted on the device inside the step. The selection bias: seeded, it
     moves picks, and it is the ONE leaf the three steps leave as it was (no
@@ -291,7 +292,7 @@ def test_fit_books_the_routing_counters_and_never_moves_the_selection_bias():
     from learning_jax_sharding_tpu.telemetry import MetricsRegistry
     from learning_jax_sharding_tpu.training.loop import TrainLoopConfig, fit
 
-    cfg, steps = _config(), 3
+    cfg, steps = _config(remat=True), 3
     module = Transformer(cfg)
     loop = TrainLoopConfig(
         steps=steps, global_batch_size=B, learning_rate=1e-2, weight_decay=0.1, seed=1
@@ -309,6 +310,10 @@ def test_fit_books_the_routing_counters_and_never_moves_the_selection_bias():
     assert 0 < snap["train_moe_expert_max_load"] <= tokens
     # Near the uniform expectation (half the experts are held): seeded weights.
     assert 0.25 < assigned / (steps * layers * tokens * k) < 0.75
+    # What ``remat=True`` kept (PR 38): on the emulated CPU mesh the step
+    # finds no memory it could count on, so nothing, of a budget of nothing.
+    assert snap["train_remat_saved_bytes"] == 0
+    assert snap["train_remat_budget_bytes"] == 0
 
     assert _unstepped_paths() == {
         f"['block_{i}']['moe']['bias']" for i in range(2, 6)

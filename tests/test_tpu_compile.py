@@ -715,3 +715,108 @@ def test_the_chip_compiler_aliases_every_donated_cache_leaf(step_programs):
         assert tables and not any(i.donated for i in tables), name
     assert seen == 7
 
+
+
+def test_the_train_step_keeps_what_its_remat_plan_says(topo):
+    """The same train step compiled for one described chip under
+    ``remat_policy="nothing"`` and under the plan a budget gives
+    (``utils.memory.remat_plan``; the budget set outright, the test's handle:
+    a step finds its own from the device). The plan's program holds ONE
+    forward flash kernel a layer where "nothing" holds two, and its
+    temporaries grow with the plan's bytes and never by more (a residual
+    kept in a padded layout would: the kernel's own ``lse`` is 128 x its
+    bytes in HBM, its ``out`` twice). By less than all of them at these
+    sizes: a block's own residuals are alive in its backward whatever is
+    kept, and with 3 blocks that is a third of the plan, the loss head's
+    peak beside it (at the cells' 36 blocks the builder's compile read
+    1.92 GB of growth for a plan of 2.04 GB: PERF.md, PR 38)."""
+    import flax.linen as nn
+    import numpy as np
+    from flax.training.train_state import TrainState
+    from jax.sharding import Mesh
+
+    from learning_jax_sharding_tpu.models.transformer import (
+        Transformer,
+        TransformerConfig,
+        fused_next_token_loss,
+    )
+    from learning_jax_sharding_tpu.ops.flash_attention import make_flash_attn_fn
+    from learning_jax_sharding_tpu.parallel import mesh_sharding
+    from learning_jax_sharding_tpu.parallel.logical import (
+        RULES_DP_TP,
+        activate,
+        tree_shardings,
+    )
+    from learning_jax_sharding_tpu.training.loop import (
+        TrainLoopConfig,
+        default_optimizer,
+    )
+    from learning_jax_sharding_tpu.training.pipeline import make_train_step
+    from learning_jax_sharding_tpu.utils.memory import block_residual_bytes
+
+    layers = 3
+    mesh = Mesh(np.asarray([topo.devices[0]]).reshape(1, 1), ("data", "model"))
+    cfg = TransformerConfig(
+        vocab_size=512, num_layers=layers, features=256, num_heads=4,
+        head_dim=64, hidden=512, max_seq_len=1024, dtype=BF16,
+        param_dtype=F32, remat=True,
+        attn_fn=make_flash_attn_fn(interpret=False),
+    )
+    optimizer = default_optimizer(TrainLoopConfig(steps=10, global_batch_size=8))
+    tokens = jax.ShapeDtypeStruct((8, 1024), I32)
+    sizes = block_residual_bytes(cfg, 0, 8 * 1024)
+    kept_names = ("flash_out", "flash_lse", "attn_q", "attn_k", "attn_v")
+    budget = layers * sum(sizes[n] for n in kept_names)
+
+    def compiled(cfg, budget):
+        module = Transformer(cfg)
+
+        def init(key, x):
+            return TrainState.create(
+                apply_fn=module.apply, tx=optimizer,
+                params=module.init({"params": key}, x)["params"],
+            )
+
+        with activate(mesh, RULES_DP_TP):
+            abstract = jax.eval_shape(init, jax.random.key(0), tokens)
+            state_sh = tree_shardings(abstract, mesh, RULES_DP_TP)
+            state = jax.tree.map(
+                lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                nn.meta.unbox(abstract), state_sh,
+            )
+            batch_sh = mesh_sharding(mesh, "data", None)
+            batch = {
+                k: jax.ShapeDtypeStruct(tokens.shape, I32, sharding=batch_sh)
+                for k in ("inputs", "targets")
+            }
+            step = make_train_step(
+                state_sh, {k: batch_sh for k in batch}, mesh, RULES_DP_TP,
+                loss_fn=fused_next_token_loss, loss_needs_params=True,
+                apply_kwargs={"return_hidden": True},
+            )
+            step.remat.budget_bytes = budget
+            program = step.jitted.lower(state, batch).compile()
+        text = program.as_text()
+        kernels = [
+            line for line in text.splitlines()
+            if re.match(r"\s*%attn[\w.\-]* = .*custom-call", line)
+        ]
+        assert kernels and all("tpu_custom_call" in k for k in kernels)
+        # The forward kernel is the one that returns the float32 lse.
+        forward = [k for k in kernels if "f32[" in k.split("custom-call(")[0]]
+        return program.memory_analysis().temp_size_in_bytes, forward, kernels, step.remat
+
+    nothing = compiled(dataclasses.replace(cfg, remat_policy="nothing"), budget)
+    fitted = compiled(cfg, budget)
+    assert nothing[3].plan is None              # an explicit policy asks no plan
+    plan = fitted[3].plan
+    assert plan.names == (kept_names,) * layers
+    assert plan.saved_bytes == pytest.approx(budget)
+    # The described device's kind says what it holds: a step would find a
+    # budget of its own here (the emulated CPU mesh finds none).
+    assert fitted[3].device_bytes == 16e9
+    # Forward, recomputed forward, dK/dV, dQ a layer; then no second forward.
+    assert (len(nothing[1]), len(nothing[2])) == (2 * layers, 4 * layers)
+    assert (len(fitted[1]), len(fitted[2])) == (layers, 3 * layers)
+    grown = fitted[0] - nothing[0]
+    assert 0.25 * budget <= grown <= 1.25 * budget, (grown, budget)
