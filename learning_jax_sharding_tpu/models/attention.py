@@ -25,7 +25,7 @@ JoyAI-LLM-Flash, ``TransformerConfig.latent_kv_rank``).
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import flax.linen as nn
 import jax
@@ -131,17 +131,20 @@ def row_update_masked(
     return jax.vmap(one)(buf, chunk, idx, lengths)
 
 
-def paged_slots(table, idx, s: int, page: int, chunk_lengths, length: int):
+def paged_slots(
+    table, idx, s: int, page: int, chunk_lengths, length: int,
+    ring: bool = False,
+):
     """Where a chunk's ``s`` positions land in a page pool: cache position
-    ``idx_b + t`` lives at ``(table[b, pos // page], pos % page)``. Invalid
-    positions (padding past a row's ``chunk_lengths``, or past ``length``
-    without them) are redirected to the reserved scratch page 0, so masked
-    writes can never touch live pages. Returns ``(pages, slots)``, ``(B, S)``
-    each."""
+    ``idx_b + t`` lives at ``(table[b, pos // page], pos % page)`` (a RING
+    table: ``table[b, (pos // page) mod R]``). Invalid positions (padding
+    past a row's ``chunk_lengths``, or past ``length`` without them) are
+    redirected to the reserved scratch page 0, so masked writes can never
+    touch live pages. Returns ``(pages, slots)``, ``(B, S)`` each."""
     pos = idx[:, None] + jnp.arange(s)[None, :]
-    pages = jnp.take_along_axis(
-        table, jnp.minimum(pos // page, table.shape[1] - 1), axis=1
-    )
+    col = pos // page
+    col = col % table.shape[1] if ring else jnp.minimum(col, table.shape[1] - 1)
+    pages = jnp.take_along_axis(table, col, axis=1)
     if chunk_lengths is not None:
         valid = jnp.arange(s)[None, :] < chunk_lengths[:, None]
     else:
@@ -199,6 +202,12 @@ class MultiHeadAttention(nn.Module):
     num_kv_heads: Optional[int] = None   # < num_heads → GQA; 1 → MQA
     rope: bool = False                   # rotary positions on q/k
     rope_theta: float = 10_000.0
+    rotary_dim: Optional[int] = None     # rotate the first this many values
+                                         # of a head, pass the rest (None: all)
+    rope_yarn: Optional[Any] = None      # ops.rope.Yarn: scaled frequencies
+    gate: bool = False                   # per-head sigmoid output gate from
+                                         # the attention's input (``gate``:
+                                         # one scalar a head a token)
     qk_norm: bool = False                # RMSNorm over each head's values of
                                          # q and of k (one learned head_dim
                                          # vector each: q_norm, k_norm),
@@ -410,8 +419,9 @@ class MultiHeadAttention(nn.Module):
                     positions = idx + jnp.arange(s)
             else:
                 positions = jnp.arange(s)
-            q = apply_rope(q, positions, self.rope_theta)
-            k = apply_rope(k, positions, self.rope_theta)
+            rot = dict(rotary_dim=self.rotary_dim, yarn=self.rope_yarn)
+            q = apply_rope(q, positions, self.rope_theta, **rot)
+            k = apply_rope(k, positions, self.rope_theta, **rot)
 
         if self.decode:
             out = self._cached_attention(q, k, v, chunk_lengths)
@@ -448,6 +458,12 @@ class MultiHeadAttention(nn.Module):
                     causal=self.causal,
                 )
         out = nn.with_logical_constraint(out, (BATCH, SEQ, HEADS, KV))
+        if self.gate:
+            # sigmoid(x W_gate): one scalar a head, from the same input as q.
+            g = self._dense(self.num_heads, (EMBED, HEADS), "gate")(x)
+            out = out * jax.nn.sigmoid(g.astype(jnp.float32)).astype(
+                out.dtype
+            )[..., None]
         out = out.reshape(b, s, self.inner_dim)
 
         # Output projection (N*H, M) with logical (HEADS, EMBED)
@@ -616,6 +632,12 @@ class MultiHeadAttention(nn.Module):
         # Each position's k and v share one cache row, k | v on the minor
         # axis: at head size 64 that is one full 128-lane row, where two
         # (…, 64) buffers would each be padded to 128 lanes in HBM.
+        # A sliding-window layer of a paged cache keeps a RING of pages a
+        # slot instead of the page list all other layers share: slot s owns
+        # pages 1 + s·R … R + s·R of a pool of its own (page 0 is scratch),
+        # logical block j of its cache lives in the ring's page j mod R, and
+        # the table never changes (the host allocates nothing for it).
+        ring = paged and self.window is not None
         if paged:
             if not ragged:
                 raise ValueError("decode_paged requires decode_ragged")
@@ -625,12 +647,24 @@ class MultiHeadAttention(nn.Module):
                     f"decode_paged needs decode_block_k (page size) "
                     f"dividing max_decode_len ({length}); got {page}"
                 )
-            pool = self.decode_page_count
+            if ring:
+                from learning_jax_sharding_tpu.ops.decode_attention import (
+                    ring_pages,
+                )
+
+                r = ring_pages(self.window, page)
+                pool = b * r + 1
+                block_table = self.variable(
+                    "cache", "ring_table",
+                    lambda: 1 + jnp.arange(b * r, dtype=jnp.int32).reshape(b, r),
+                )
+            else:
+                pool = self.decode_page_count
+                block_table = self.variable(
+                    "cache", "block_table", jnp.zeros, (b, length // page),
+                    jnp.int32,
+                )
             kv_shape, sc_shape = (pool, n_kv, page, 2 * h), (pool, n_kv, page)
-            block_table = self.variable(
-                "cache", "block_table", jnp.zeros, (b, length // page),
-                jnp.int32,
-            )
         else:
             kv_shape, sc_shape = (b, n_kv, length, 2 * h), (b, n_kv, length)
         cached_kv = self.variable(
@@ -679,7 +713,7 @@ class MultiHeadAttention(nn.Module):
             # Scatter a sequence-major chunk through the block table
             # (paged_slots: masked positions go to scratch page 0).
             pages, slots = paged_slots(
-                block_table.value, idx, s, page, chunk_lengths, length
+                block_table.value, idx, s, page, chunk_lengths, length, ring
             )
             # chunk (B, N_kv, S, ...) → (B, S, N_kv, ...): advanced indices
             # on pool axes 0 and 2 put the (B, S) index shape in front.
@@ -728,6 +762,8 @@ class MultiHeadAttention(nn.Module):
         table_args = {}
         if paged:
             table_args = dict(block_table=block_table.value)
+            if ring:
+                table_args["ring"] = True
         # window/block_k pass at CALL time either way: the module is the
         # single source of truth, so a mesh-aware wrapper built without them
         # cannot silently drop the sliding window.

@@ -275,15 +275,13 @@ def config_from_hf_lfm2_moe(hf_config: Any, **overrides) -> TransformerConfig:
     feed-forward and layer are checked against ``modeling_lfm2.py``,
     ``tests/test_lfm2_moe.py``).
     """
-    from learning_jax_sharding_tpu.models.transformer import OPERATOR_KINDS
-
     c = hf_config
     layer_types = tuple(c.layer_types)
-    unknown = sorted(set(layer_types) - set(OPERATOR_KINDS))
+    kinds = ("conv", "full_attention")           # the family's operators
+    unknown = sorted(set(layer_types) - set(kinds))
     if unknown:
         raise ValueError(
-            f"layer_types holds unknown operators {unknown} (known: "
-            f"{OPERATOR_KINDS})"
+            f"layer_types holds unknown operators {unknown} (known: {kinds})"
         )
     unsupported = {
         "layer_types/num_hidden_layers": len(layer_types) != c.num_hidden_layers,
@@ -330,6 +328,126 @@ def config_from_hf_lfm2_moe(hf_config: Any, **overrides) -> TransformerConfig:
         moe_routed_scaling=float(c.routed_scaling_factor),
         moe_renorm_eps=1e-6,
         tie_embeddings=bool(getattr(c, "tie_word_embeddings", True)),
+        dtype=jnp.float32,
+        param_dtype=jnp.float32,
+    )
+    defaults.update(overrides)
+    return TransformerConfig(**defaults)
+
+
+def config_from_hf_laguna(hf_config: Any, **overrides) -> TransformerConfig:
+    """TransformerConfig for a ``laguna`` ``config.json`` (poolside Laguna):
+    per layer an attention KIND from ``layer_types`` (``full_attention``:
+    grouped-query attention over the whole history with partial YaRN rotary;
+    ``sliding_attention``: over the last ``sliding_window`` positions with
+    full rotary at its own theta), each with its head count from
+    ``num_attention_heads_per_layer`` and a per-head sigmoid output gate
+    (``gating``); then a feed-forward by ``mlp_layer_types``: a dense SwiGLU
+    of ``intermediate_size`` (``dense``) or ``num_experts`` softmax-routed
+    SwiGLU experts of ``moe_intermediate_size``, top ``num_experts_per_tok``
+    NOT renormalised, times ``moe_routed_scaling_factor``, beside one shared
+    SwiGLU expert of ``shared_expert_intermediate_size`` (``sparse``);
+    RMSNorm, no bias, an untied head.
+
+    The head counts are the KINDS' (one for each): a list that gives two
+    layers of one kind different counts is refused, as are dense layers
+    anywhere but in front, another gate than one scalar a head, biases, a
+    tied head, router weights applied on the input, a shared expert that is
+    not a whole number of routed experts wide, and rotary settings the
+    program does not compute (the ``sliding_attention`` kind's must be
+    unscaled and whole).
+    """
+    from learning_jax_sharding_tpu.ops.rope import Yarn
+
+    c = hf_config
+    kinds = tuple(c.layer_types)
+    heads = tuple(c.num_attention_heads_per_layer)
+    mlp = tuple(c.mlp_layer_types)
+    unknown = sorted(set(kinds) - {"full_attention", "sliding_attention"})
+    if unknown:
+        raise ValueError(
+            f"layer_types holds kinds {unknown} that laguna does not have "
+            "(full_attention, sliding_attention)"
+        )
+    by_kind = {k: {n for k2, n in zip(kinds, heads) if k2 == k} for k in set(kinds)}
+    dense = mlp.count("dense")
+    rope = c.rope_parameters
+    full, sliding = rope.get("full_attention", {}), rope.get("sliding_attention", {})
+    gating = getattr(c, "gating", False)
+    unsupported = {
+        "layer_types/num_hidden_layers": len(kinds) != c.num_hidden_layers,
+        "num_attention_heads_per_layer": len(heads) != c.num_hidden_layers
+        or any(len(n) != 1 for n in by_kind.values()),
+        "mlp_layer_types": len(mlp) != c.num_hidden_layers
+        or set(mlp) - {"dense", "sparse"} or "dense" in mlp[dense:],
+        "gating": gating not in (True, "per-head"),
+        "attention_bias": bool(c.attention_bias),
+        "tie_word_embeddings": bool(c.tie_word_embeddings),
+        "moe_apply_router_weight_on_input": bool(
+            getattr(c, "moe_apply_router_weight_on_input", False)
+        ),
+        "norm_topk_prob": bool(getattr(c, "norm_topk_prob", False)),
+        "shared_expert_intermediate_size": c.shared_expert_intermediate_size
+        % c.moe_intermediate_size,
+        "rope_parameters.full_attention": full.get("rope_type", "default")
+        not in ("default", "yarn"),
+        "rope_parameters.sliding_attention": sliding
+        and (sliding.get("rope_type", "default") != "default"
+             or sliding.get("partial_rotary_factor", 1) != 1),
+    }
+    bad = sorted(k for k, v in unsupported.items() if v)
+    if bad:
+        raise ValueError(
+            f"unsupported laguna settings: {bad} (the program computes one "
+            "head count a kind, a sigmoid gate a head, leading dense layers, "
+            "softmax routing without renormalisation, YaRN or plain rotary "
+            "on the global layers and plain whole rotary on the window ones)"
+        )
+    import jax.numpy as jnp
+
+    head_dim = c.head_dim
+    partial = full.get("partial_rotary_factor", 1.0)
+    yarn = None
+    if full.get("rope_type") == "yarn":
+        yarn = Yarn(
+            factor=float(full["factor"]),
+            original_max_positions=int(full["original_max_position_embeddings"]),
+            beta_fast=float(full.get("beta_fast", 32)),
+            beta_slow=float(full.get("beta_slow", 1)),
+            attention_factor=full.get("attention_factor"),
+        )
+    window = "sliding_attention" in kinds
+    defaults = dict(
+        vocab_size=c.vocab_size,
+        num_layers=c.num_hidden_layers,
+        layer_types=kinds,
+        features=c.hidden_size,
+        num_heads=next(iter(by_kind.get("full_attention", {c.num_attention_heads}))),
+        window_num_heads=next(iter(by_kind["sliding_attention"])) if window else None,
+        num_kv_heads=c.num_key_value_heads,
+        head_dim=head_dim,
+        hidden=c.intermediate_size,
+        max_seq_len=c.max_position_embeddings,
+        use_bias=False,
+        norm="rmsnorm",
+        norm_eps=c.rms_norm_eps,
+        rope=True,
+        rope_theta=float(full.get("rope_theta", 10_000.0)),
+        rotary_dim=int(head_dim * partial) if partial < 1 else None,
+        rope_yarn=yarn,
+        window=c.sliding_window if window else None,
+        window_rope_theta=float(sliding["rope_theta"]) if window else None,
+        attn_gate=True,
+        causal=True,
+        ff_gated=True,
+        first_k_dense=dense,
+        num_experts=c.num_experts,
+        moe_top_k=c.num_experts_per_tok,
+        moe_routing="softmax_dropless",
+        moe_hidden=c.moe_intermediate_size,
+        moe_shared_experts=c.shared_expert_intermediate_size
+        // c.moe_intermediate_size,
+        moe_routed_scaling=float(c.moe_routed_scaling_factor),
         dtype=jnp.float32,
         param_dtype=jnp.float32,
     )

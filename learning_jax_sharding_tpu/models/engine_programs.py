@@ -183,8 +183,8 @@ def _row_chain(rows: jax.Array, offsets: jax.Array):
 def _take_rows(cache: Any, rows: jax.Array, offsets: jax.Array) -> Any:
     """The cache as a refill dispatch's CHUNK ROWS see it: row ``r`` carries
     a chunk of slot ``rows[r]`` that starts ``offsets[r]`` tokens past what
-    the slot has consumed, so it takes that slot's ``block_table`` and its
-    counters moved on by the offset. Page pools (and ``moe_stats``) are
+    the slot has consumed, so it takes that slot's ``block_table`` (and a
+    window layer's ``ring_table``) and its counters moved on by the offset. Page pools (and ``moe_stats``) are
     shared by all rows and pass through: every layer writes its chunk into
     the pool before it attends through the table, so a row reads in each
     layer what an earlier row of the same slot wrote in that layer. A
@@ -196,7 +196,7 @@ def _take_rows(cache: Any, rows: jax.Array, offsets: jax.Array) -> Any:
 
     def leaf(path, x):
         key = getattr(path[-1], "key", None)
-        if key == "block_table" or key in _SLOT_STATE_KEYS:
+        if key in ("block_table", "ring_table") or key in _SLOT_STATE_KEYS:
             return x[rows]
         if key in _SLOT_COUNTER_KEYS:
             return x[rows] + offsets.astype(x.dtype)
@@ -223,7 +223,7 @@ def _put_rows(
 
     def leaf(path, old, new):
         key = getattr(path[-1], "key", None)
-        if key in ("block_table", _ROW_CARRY_KEY):
+        if key in ("block_table", "ring_table", _ROW_CARRY_KEY):
             return old
         if key in _SLOT_COUNTER_KEYS:
             return old.at[rows].max(new)

@@ -35,6 +35,8 @@ Two routing rules live here (``TransformerConfig.moe_routing``):
   no drop; expert compute through ``ops/moe_experts.py``. Used by
   JoyAI-LLM-Flash (``benchmark/configs/joyai-llm-flash.json``) on one chip;
   not sharded over experts yet.
+* ``"softmax_dropless"`` — the same layer with softmax scores, no bias and
+  no renormalisation (``DroplessMoE(score="softmax")``; Laguna).
 """
 
 from __future__ import annotations
@@ -328,7 +330,7 @@ class DroplessMoE(nn.Module):
     do not): the others are routed nowhere, read no expert and get the
     shared expert's output only (which their row discards).
 
-    Variants (the ``nemotron_h`` family uses all three):
+    Variants (the ``nemotron_h`` family uses the first three):
 
     * ``held=(first, count)``: this chip HOLDS experts ``[first, first +
       count)`` of ``num_experts`` — its share of an expert-parallel group.
@@ -344,6 +346,13 @@ class DroplessMoE(nn.Module):
 
           y = (sum_i w_i E_i(x W_down_latent)) W_up_latent + E_shared(x)
 
+    * ``score="softmax"``: the Qwen-MoE rule with ``norm_topk_prob`` false
+      (the ``laguna`` family)::
+
+          p = softmax(x W_r)                    float32, over all E
+          picks = top_k(p);  w_i = routed_scaling * p_i     (no bias, no
+                                                            renormalisation)
+
     Initialisers: with ``expert_init_scale`` a number the expert tensors
     take each expert's OWN fan-in, ``down`` times that number (a benchmark
     lowers it where its float32 reference cannot follow the program's
@@ -357,8 +366,8 @@ class DroplessMoE(nn.Module):
     no gain for the mean of what they read (``transformer.zero_mean``).
 
     Parameters: ``router/kernel`` ``(M, E)``, ``bias`` ``(E,)`` (the
-    selection bias, a leaf of this module so that its path ends in
-    ``['bias']``), ``gate`` / ``up`` ``(E_held, W, H)``, ``down``
+    selection bias of the sigmoid rule, a leaf of this module so that its
+    path ends in ``['bias']``; the softmax rule has none), ``gate`` / ``up`` ``(E_held, W, H)``, ``down``
     ``(E_held, H, W)`` (``W`` = ``latent`` or ``M``), ``latent_down`` /
     ``latent_up`` kernels, and ``shared/{gate,up,down}/kernel`` of width
     ``shared_hidden`` (default ``shared_experts * H``). All in
@@ -387,6 +396,7 @@ class DroplessMoE(nn.Module):
     shared_experts: int = 0
     shared_hidden: int | None = None
     routed_scaling: float = 1.0
+    score: str = "sigmoid"          # or "softmax": no bias, no renormalisation
     renorm_eps: float = 1e-20
     bias_init_std: float = 0.0
     held: tuple | None = None
@@ -427,24 +437,35 @@ class DroplessMoE(nn.Module):
                 ),
                 name="router",
             )
-            bias_init = (
-                nn.initializers.normal(self.bias_init_std)
-                if self.bias_init_std else nn.initializers.zeros_init()
-            )
-            bias = self.param(
-                "bias", with_unstepped_partitioning(bias_init, (EXPERT,)),
-                (e,), self.param_dtype,
-            )
-            scores = jax.nn.sigmoid(
-                router(x.astype(self.router_dtype)).reshape(t, e)
-            )
-            _, idx = jax.lax.top_k(
-                scores + bias.astype(self.router_dtype), self.top_k
-            )
-            picked = jnp.take_along_axis(scores, idx, axis=-1)
-            weights = self.routed_scaling * picked / (
-                jnp.sum(picked, axis=-1, keepdims=True) + self.renorm_eps
-            )
+            if self.score == "softmax":
+                scores = jax.nn.softmax(
+                    router(x.astype(self.router_dtype)).reshape(t, e), axis=-1
+                )
+                picked, idx = jax.lax.top_k(scores, self.top_k)
+                weights = self.routed_scaling * picked
+            elif self.score == "sigmoid":
+                bias_init = (
+                    nn.initializers.normal(self.bias_init_std)
+                    if self.bias_init_std else nn.initializers.zeros_init()
+                )
+                bias = self.param(
+                    "bias", with_unstepped_partitioning(bias_init, (EXPERT,)),
+                    (e,), self.param_dtype,
+                )
+                scores = jax.nn.sigmoid(
+                    router(x.astype(self.router_dtype)).reshape(t, e)
+                )
+                _, idx = jax.lax.top_k(
+                    scores + bias.astype(self.router_dtype), self.top_k
+                )
+                picked = jnp.take_along_axis(scores, idx, axis=-1)
+                weights = self.routed_scaling * picked / (
+                    jnp.sum(picked, axis=-1, keepdims=True) + self.renorm_eps
+                )
+            else:
+                raise ValueError(
+                    f"unknown score {self.score!r}: 'sigmoid' or 'softmax'"
+                )
 
         def experts(name, shape, axes, init=self.kernel_init):
             return self.param(

@@ -114,9 +114,11 @@ from learning_jax_sharding_tpu.models.engine_programs import (
     split_cache,
 )
 from learning_jax_sharding_tpu.models.transformer import (
+    DROPLESS_ROUTINGS,
     Transformer,
     TransformerConfig,
 )
+from learning_jax_sharding_tpu.ops.decode_attention import window_band
 from learning_jax_sharding_tpu.parallel.compression import (
     CommCompression,
     get_codec,
@@ -645,13 +647,17 @@ class ContinuousEngine:
         # each of them yet is refused here, by name, never served wrong.
         latent = bool(config.latent_kv_rank)
         moe_counted = (
-            config.num_experts > 0 and config.moe_routing == "sigmoid_dropless"
+            config.num_experts > 0 and config.moe_routing in DROPLESS_ROUTINGS
         )
         ssm = "M" in (config.layer_pattern or "")
+        # Sliding-window layers keep a RING of pages a slot on a paged
+        # engine (models/attention.py): a page is overwritten once the
+        # window has passed it.
+        window = config.window is not None
         # A reason a kind: the first kind the config has names the refusal.
         kinds = {
-            "state-space layers": ssm, "latent attention": latent,
-            "dropless experts": moe_counted,
+            "window layers": window, "state-space layers": ssm,
+            "latent attention": latent, "dropless experts": moe_counted,
         }
         if any(kinds.values()):
             what = next(k for k, has in kinds.items() if has)
@@ -692,6 +698,51 @@ class ContinuousEngine:
                     "the other chips' parts",
                 ),
             }
+            if what == "window layers":
+                # A window layer's ring page is overwritten as soon as the
+                # window has passed it: what assumes a page outlives it
+                # does not hold.
+                refused.update({
+                    "draft_config (speculative decoding)": (
+                        speculative, "a rejected draft rewinds cache_index, "
+                        "and the ring is sized for what one dispatch writes "
+                        "of a slot before it reads: the draft's rounds and a "
+                        "rewind were never fitted to it",
+                    ),
+                    "prefix_cache": (
+                        prefix_cache, "a ring page holds a slot's last "
+                        "positions only and is overwritten as the slot "
+                        "moves on: a window layer's K and V of a prefix do "
+                        "not outlive its request to be shared",
+                    ),
+                    "mixed / horizon": (
+                        mixed or horizon > 1, "the fused step families "
+                        "schedule rows by a token budget, not by the bound "
+                        "on what one dispatch writes of a slot that the ring "
+                        "size rests on",
+                    ),
+                    "kv_cache_dtype=int8": (
+                        config.kv_cache_dtype == jnp.int8, "an int8 pool's "
+                        "scale arrays ride beside its pages, and a ring of "
+                        "them was never built or checked",
+                    ),
+                    "dequantize": (
+                        bool(dequantize), "the per-head gate and the expert "
+                        "matrices have no quantized form",
+                    ),
+                    "a mesh of more than one device": (
+                        mesh.size > 1, "an expert layer computes every "
+                        "expert on one device, and the rings were never "
+                        "sharded over heads",
+                    ),
+                })
+                if refill_chunk > config.window:
+                    raise ValueError(
+                        f"refill_chunk ({refill_chunk}) must not exceed the "
+                        f"window ({config.window}) with window layers: a "
+                        "wider chunk overwrites ring pages its own first "
+                        "queries still read"
+                    )
             for name, (asked, why) in refused.items():
                 if asked:
                     raise ValueError(
@@ -846,6 +897,15 @@ class ContinuousEngine:
         self._latent = latent
         self._moe_counted = moe_counted
         self._ssm = ssm
+        self._window = config.window
+        # The rows of one refill dispatch a slot may take: as many chunks
+        # as its window holds, so a window layer's ring
+        # (ops.decode_attention.ring_pages) covers what the rows write
+        # before the first of them reads.
+        self._rows_a_slot = (
+            batch_size if config.window is None
+            else max(1, config.window // refill_chunk)
+        )
         self._paged = paged
         self._paged_pages = paged_pages
         self._page_size = page_size
@@ -859,8 +919,8 @@ class ContinuousEngine:
             # A one-mixer-a-layer config refills as a latent one does: the
             # head on one position a row, and no contiguous rows to hand off.
             apply, d_apply, adapter=adapter_pool is not None,
-            head_on_last=latent or config.layer_pattern is not None,
-            kv_rows=not (latent or config.layer_pattern is not None),
+            head_on_last=latent or window or config.layer_pattern is not None,
+            kv_rows=not (latent or window or config.layer_pattern is not None),
             moe_counted=moe_counted, mixed=bool(mixed), paged=paged,
             prefix_cache=prefix_cache, temperature=temperature, top_k=top_k,
             top_p=top_p, min_p=min_p, vocab_limit=vocab_limit,
@@ -1208,6 +1268,20 @@ class ContinuousEngine:
             "of the loop form's DMA ring, 0 where the pipeline-emitter form "
             "runs (ops.decode_attention.pages_in_flight: fixed when the "
             "programs are traced, from the cache's shape and dtype)")
+        self._c_window_pages = r.counter(
+            "engine_decode_window_pages_total",
+            "over decode row-steps, the pages of ONE window layer's ring "
+            "the step's window band covers (the blocks the kernel reads: "
+            "ops.decode_attention.window_band); the same in every window "
+            "layer; 0 without a window")
+        self._g_ring_bytes = r.gauge(
+            "engine_window_ring_bytes",
+            "bytes of the window layers' page rings (slots x ring pages + a "
+            "scratch page, each layer); 0 without window layers")
+        self._g_global_bytes = r.gauge(
+            "engine_global_pool_bytes",
+            "bytes of the page pools the host allocator serves (every "
+            "layer's that is not a window layer's); 0 unpaged")
         self._g_donated_bytes = r.gauge(
             "engine_cache_donated_bytes",
             "bytes of the cache leaves the step programs update in place "
@@ -2218,6 +2292,12 @@ class ContinuousEngine:
     # --- disaggregated prefill/decode handoff (round 11) -------------------
 
     def _check_handoff_supported(self, what: str):
+        if self._window is not None:
+            raise ValueError(
+                f"{what}: engines with window layers are not supported — a "
+                "window layer's ring holds a slot's last pages at positions "
+                "modulo the ring, not the row's history"
+            )
         if self._ssm:
             raise ValueError(
                 f"{what}: engines with state-space layers are not supported "
@@ -2292,6 +2372,17 @@ class ContinuousEngine:
         self._g_donated_bytes.set(sum(
             x.nbytes for key, x in leaves if key != "block_table"
         ))
+        if self._paged:
+            # A layer's pool is a ring where its table is one.
+            flat = [
+                (p[:-1], getattr(p[-1], "key", None), x)
+                for p, x in jax.tree_util.tree_flatten_with_path(self._cache)[0]
+            ]
+            rings = {at for at, key, _ in flat if key == "ring_table"}
+            pools = [(at in rings, x.nbytes) for at, key, x in flat
+                     if key in _PAGE_LEAF_KEYS]
+            self._g_ring_bytes.set(sum(n for ring, n in pools if ring))
+            self._g_global_bytes.set(sum(n for ring, n in pools if not ring))
         if self._ssm:
             self._g_ssm_bytes.set(sum(
                 x.nbytes for key, x in leaves if key in _SLOT_STATE_KEYS
@@ -2572,6 +2663,12 @@ class ContinuousEngine:
         return True
 
     def _check_tier_supported(self, what: str):
+        if self._window is not None:
+            raise ValueError(
+                f"{what}: engines with window layers are not tiered — a "
+                "window layer's ring page is overwritten as its slot moves "
+                "on, so no page of it stands for a prefix"
+            )
         if self._ssm:
             raise ValueError(
                 f"{what}: engines with state-space layers are not tiered — "
@@ -3244,6 +3341,14 @@ class ContinuousEngine:
         k = len(out) - ctx
         self._c_decode_steps.inc(k)
         self._c_decode_ctx.inc(k * ctx + k * (k - 1) // 2)
+        if self._window is not None and self._paged:
+            # Step i fed position ctx - 1 + i and read its window band.
+            page, w = self._page_size, self._window
+            self._c_window_pages.inc(sum(
+                last - first for first, last in (
+                    window_band(ctx - 1 + i, 1, w, page) for i in range(k)
+                )
+            ))
 
     def _rid_arr(self):
         return jnp.asarray(np.maximum(self._req, 0), jnp.int32)
@@ -3464,7 +3569,7 @@ class ContinuousEngine:
         b, c = self._b, self._refill_chunk
         spare = b - len(firsts)
         extra = []
-        if not (self._paged and c > 1 and spare):
+        if not (self._paged and c > 1 and spare and self._rows_a_slot > 1):
             return extra
         left = {
             slot: -(-(self._pending[slot].size - n) // c)
@@ -3473,7 +3578,7 @@ class ContinuousEngine:
         for slot in sorted(left, key=lambda s: (left[s], s)):
             if not spare:
                 break
-            k = min(left[slot], spare)
+            k = min(left[slot], spare, self._rows_a_slot - 1)
             size = self._pending[slot].size
             consumed = self._plen[slot] - size
             try:

@@ -30,9 +30,13 @@ Block kinds (a config picks one attention and, per layer, one feed-forward):
   ``x + Mixer(norm(x))``): ``M`` a Mamba-2 layer (``models/ssm.py``), ``E``
   an expert layer, ``*`` attention (the ``nemotron_h`` family);
 * or, with ``layer_types``, the block's OPERATOR by layer: ``"conv"`` a gated
-  short convolution (``models/ssm.py::ShortConv``) or ``"full_attention"``,
-  each followed by the layer's feed-forward as above (the ``lfm2_moe``
-  family: q / k RMSNorm a head, a head tied to the embedding).
+  short convolution (``models/ssm.py::ShortConv``), ``"full_attention"`` or
+  ``"sliding_attention"`` (attention over the last ``window`` positions,
+  with heads and rotary of its own: ``window_num_heads``,
+  ``window_rope_theta``), each followed by the layer's feed-forward as above
+  (the ``lfm2_moe`` family: q / k RMSNorm a head, a head tied to the
+  embedding; the ``laguna`` family: window and global layers, a per-head
+  output gate, partial YaRN rotary on the global ones).
 
 Everything is dtype-parameterized: bf16 compute / fp32 params is the TPU MXU
 sweet spot and the default for benchmarks.
@@ -401,7 +405,10 @@ class TransformerBlock(nn.Module):
     num_kv_heads: Optional[int] = None
     rope: bool = False
     rope_theta: float = 10_000.0
+    rotary_dim: Optional[int] = None
+    rope_yarn: Optional[Any] = None
     window: Optional[int] = None
+    attn_gate: bool = False
     dropout_rate: float = 0.0
     causal: bool = True
     use_bias: bool = False
@@ -458,7 +465,9 @@ class TransformerBlock(nn.Module):
     moe_bias_init_std: float = 0.0
     moe_expert_init_scale: Optional[float] = None
     operator: str = "full_attention"   # or "conv": ShortConv in the
-                                  # attention's place (norm ``ln_conv``)
+                                  # attention's place (norm ``ln_conv``); a
+                                  # "sliding_attention" layer is attention
+                                  # with its window, heads and rotary set
     conv_kernel: int = 3
     qk_norm: bool = False
 
@@ -528,7 +537,10 @@ class TransformerBlock(nn.Module):
             num_kv_heads=self.num_kv_heads,
             rope=self.rope,
             rope_theta=self.rope_theta,
+            rotary_dim=self.rotary_dim,
+            rope_yarn=self.rope_yarn,
             window=self.window,
+            gate=self.attn_gate,
             dropout_rate=self.dropout_rate,
             causal=self.causal,
             use_bias=self.use_bias,
@@ -562,7 +574,7 @@ class TransformerBlock(nn.Module):
         # The block boundary: residual add + norm — ONE fused HBM pass
         # under fused_norm, the plain pair otherwise (identical math).
         h, x = self._norm("ln_ff")(attn_out, x)
-        if self.num_experts > 0 and self.moe_routing == "sigmoid_dropless":
+        if self.num_experts > 0 and self.moe_routing in DROPLESS_ROUTINGS:
             from learning_jax_sharding_tpu.models.moe import DroplessMoE
 
             valid = None
@@ -577,6 +589,7 @@ class TransformerBlock(nn.Module):
                 top_k=self.moe_top_k,
                 shared_experts=self.moe_shared_experts,
                 routed_scaling=self.moe_routed_scaling,
+                score=DROPLESS_ROUTINGS[self.moe_routing],
                 held=self.moe_held,
                 renorm_eps=self.moe_renorm_eps,
                 bias_init_std=self.moe_bias_init_std,
@@ -621,8 +634,13 @@ class TransformerBlock(nn.Module):
         return (x, None) if self.scan else x
 
 
-#: ``TransformerConfig.layer_types``' entries (the ``lfm2`` family's key).
-OPERATOR_KINDS = ("conv", "full_attention")
+#: ``TransformerConfig.layer_types``' entries (the ``lfm2`` and ``laguna``
+#: families' key).
+OPERATOR_KINDS = ("conv", "full_attention", "sliding_attention")
+
+#: The expert layers without capacity (``models/moe.py::DroplessMoE``), by
+#: ``TransformerConfig.moe_routing``: the score each computes.
+DROPLESS_ROUTINGS = {"sigmoid_dropless": "sigmoid", "softmax_dropless": "softmax"}
 
 #: ``TransformerConfig.layer_pattern``'s characters (the ``nemotron_h``
 #: family's ``hybrid_override_pattern``; its "-", a dense MLP layer, is not
@@ -644,7 +662,20 @@ class TransformerConfig:
     num_kv_heads: Optional[int] = None  # < num_heads → GQA; 1 → MQA
     rope: bool = False               # rotary positions instead of the learned table
     rope_theta: float = 10_000.0
+    rotary_dim: Optional[int] = None  # a head's first this many values are
+                                     # rotated, the rest pass (None: all)
+    rope_yarn: Optional[Any] = None  # ops.rope.Yarn scaling of the rotation
     window: Optional[int] = None     # causal sliding-window attention size
+                                     # (every layer's; with layer_types
+                                     # holding "sliding_attention", those
+                                     # layers' only)
+    window_num_heads: Optional[int] = None  # a sliding layer's query heads
+                                     # (None: num_heads)
+    window_rope_theta: Optional[float] = None  # a sliding layer's rotary
+                                     # base; it rotates every value of a head,
+                                     # unscaled (None: as the other layers)
+    attn_gate: bool = False          # per-head sigmoid gate on attention's
+                                     # output, from the attention's input
     hidden: int = 3072
     max_seq_len: int = 1024
     dropout_rate: float = 0.0
@@ -758,7 +789,9 @@ class TransformerConfig:
                                      # MoEFeedForward) or "sigmoid_dropless"
                                      # (DroplessMoE: sigmoid scores, selection
                                      # bias, renormalised x moe_routed_scaling,
-                                     # gated experts, shared experts)
+                                     # gated experts, shared experts) or
+                                     # "softmax_dropless" (softmax scores, no
+                                     # bias, not renormalised)
     moe_hidden: Optional[int] = None  # expert width (None: hidden)
     moe_shared_experts: int = 0      # always-on experts beside the routed
     moe_routed_scaling: float = 1.0
@@ -791,10 +824,11 @@ class TransformerConfig:
                                      # it (its box: parallel.logical.Unstepped)
     # --- the block's operator by layer (TransformerBlock.operator) ---------
     layer_types: Optional[tuple] = None  # one entry a layer: "conv" (gated
-                                     # short convolution, models/ssm.py) or
-                                     # "full_attention"; None: attention in
-                                     # every block. The feed-forward follows
-                                     # first_k_dense / num_experts as ever
+                                     # short convolution, models/ssm.py),
+                                     # "full_attention" or "sliding_attention";
+                                     # None: attention in every block. The
+                                     # feed-forward follows first_k_dense /
+                                     # num_experts as ever
     conv_kernel: int = 3             # the short convolution's taps
     qk_norm: bool = False            # RMSNorm over each head of q and of k
                                      # (one learned vector each), before RoPE
@@ -821,10 +855,10 @@ class TransformerConfig:
                 "remat_policy is set but remat=False — the policy would "
                 "be silently ignored; set remat=True (or drop the policy)"
             )
-        if self.moe_routing not in ("softmax_capacity", "sigmoid_dropless"):
+        if self.moe_routing not in ("softmax_capacity", *DROPLESS_ROUTINGS):
             raise ValueError(
                 f"unknown moe_routing {self.moe_routing!r}: "
-                f"'softmax_capacity' or 'sigmoid_dropless'"
+                f"'softmax_capacity' or one of {sorted(DROPLESS_ROUTINGS)}"
             )
         if self.moe_expert_act not in ("silu_gated", "relu2"):
             raise ValueError(
@@ -887,6 +921,17 @@ class TransformerConfig:
                     "a 'conv' layer has no cached form: the short "
                     "convolution's last inputs are not a slot's state yet"
                 )
+            if "sliding_attention" in self.layer_types and not self.window:
+                raise ValueError(
+                    "a 'sliding_attention' layer needs window (its size)"
+                )
+        if self.window_num_heads is not None and (
+            self.layer_types is None
+            or "sliding_attention" not in self.layer_types
+        ):
+            raise ValueError(
+                "window_num_heads is a 'sliding_attention' layer's head count"
+            )
         if self.latent_kv_rank and not (
             self.latent_q_rank and self.qk_nope_dim and self.qk_rope_dim
             and self.v_head_dim
@@ -954,8 +999,8 @@ class TransformerConfig:
                 for i in range(self.num_layers)
             )
             attn_layers = sum(
-                self._operator(i) == "full_attention"
-                for i in range(self.num_layers)
+                self._heads(i) / self.num_heads
+                for i in range(self.num_layers) if self._operator(i) != "conv"
             )
         matmul_params = blocks + self.features * self.vocab_size   # lm_head
         qk_v = (
@@ -967,6 +1012,21 @@ class TransformerConfig:
         ) * (0.5 if self.causal else 1.0)
         per_token = 6 * matmul_params + 3 * attn_per_token
         return float(per_token) * batch * seq
+
+    def _heads(self, layer: int) -> int:
+        """Query heads of block ``layer``."""
+        if self._operator(layer) == "sliding_attention":
+            return self.window_num_heads or self.num_heads
+        return self.num_heads
+
+    def _attn_params(self, layer: int) -> int:
+        """Block ``layer``'s attention projections and its output gate."""
+        n = self._heads(layer)
+        if n == self.num_heads:
+            base = self._attn_proj_params
+        else:
+            base = dataclasses.replace(self, num_heads=n)._attn_proj_params
+        return base + (self.features * n if self.attn_gate else 0)
 
     @property
     def _attn_proj_params(self) -> int:
@@ -995,7 +1055,7 @@ class TransformerConfig:
         projections, or the short convolution's ``in_proj`` and ``out_proj``."""
         if self._operator(layer) == "conv":
             return 4 * self.features * self.features
-        return self._attn_proj_params
+        return self._attn_params(layer)
 
     def _ff_params(self, layer: int, *, activated: bool = False) -> int:
         """Feed-forward matmul parameters of block ``layer``: all of them
@@ -1074,7 +1134,10 @@ class TransformerConfig:
                 total += self.conv_kernel * m
             elif self.qk_norm:
                 total += 2 * self.head_dim
-            if self.num_experts and i >= self.first_k_dense:
+            if (
+                self.num_experts and i >= self.first_k_dense
+                and self.moe_routing == "sigmoid_dropless"
+            ):
                 total += self.num_experts            # the selection bias
         heads = 1 if self.tie_embeddings else 2
         return total + heads * self.vocab_size * m + m
@@ -1288,7 +1351,10 @@ class Transformer(nn.Module):
             num_kv_heads=cfg.num_kv_heads,
             rope=cfg.rope,
             rope_theta=cfg.rope_theta,
+            rotary_dim=cfg.rotary_dim,
+            rope_yarn=cfg.rope_yarn,
             window=cfg.window,
+            attn_gate=cfg.attn_gate,
             hidden=cfg.hidden,
             dropout_rate=cfg.dropout_rate,
             causal=cfg.causal,
@@ -1346,6 +1412,16 @@ class Transformer(nn.Module):
                 fields = {**fields, "num_experts": 0}
             if cfg.layer_types is not None:
                 fields = {**fields, "operator": cfg.layer_types[i]}
+                if "sliding_attention" in cfg.layer_types:
+                    # The window, the heads and the rotary are the kind's.
+                    sliding = cfg.layer_types[i] == "sliding_attention"
+                    fields["window"] = cfg.window if sliding else None
+                    if sliding:
+                        fields.update(
+                            num_heads=cfg.window_num_heads or cfg.num_heads,
+                            rope_theta=cfg.window_rope_theta or cfg.rope_theta,
+                            rotary_dim=None, rope_yarn=None,
+                        )
             return fields
 
         if cfg.layer_pattern is not None:

@@ -67,7 +67,11 @@ fold, ``m / l / acc``) and share everything below:
   int8 bytes are what crosses HBM — the upcast never materializes.
 * a sliding window additionally advances the FIRST block read
   (``kstart = (index - window + 1) // block_k``; the row's blocks start
-  there), so SWA decode touches only the window band.
+  there), so SWA decode touches only the window band. A window layer of a
+  paged cache may keep a RING of ``R`` pages a row (``ring=True``,
+  :func:`ring_pages`): logical block ``j`` is read and written at
+  ``table[row, j mod R]``, whatever form the call takes, and such a call
+  runs under a name of its own (``attn.window_cached_attention``).
 * chunk queries (prefill / speculative verification) are tiled over the
   grid's leading dimension in ``block_q``-row tiles, each stopping at its
   own causal frontier — long prompts stay inside VMEM; in the emitter form
@@ -107,6 +111,33 @@ _TRACE_NAME = "attn._blocked_cached_attention"
 # ``[c_kv | k_rope]`` is both the key (all of it) and the value (its first
 # ``latent_v`` lanes) — benchmark/metrics/mla_decode_attn_roofline.json.
 _LATENT_TRACE_NAME = "attn.mla_cached_attention"
+# A sliding-window layer's call (benchmark/metrics/swa_decode_attn_roofline.json):
+# its own name, so the window layers' time and the global layers' read apart.
+_WINDOW_TRACE_NAME = "attn.window_cached_attention"
+
+
+def ring_pages(window: int, page: int) -> int:
+    """Pages of a window layer's RING a slot: logical block ``j`` of its
+    cache lives in ring page ``j mod R``. A query at ``p`` reads positions
+    ``(p - window, p]``, and a dispatch writes at most ``window`` positions
+    of a slot before its first query reads (the engine's bound: a refill
+    chunk is no wider than the window, a slot takes at most ``window //
+    chunk`` rows of one dispatch, a decode step writes one position), so
+    the ring must hold every page that ``2 * window - 1`` consecutive
+    positions touch, and no page of them may stand for two blocks."""
+    span = 2 * window - 1
+    return -(-(span - 1) // page) + 1
+
+
+def window_band(index, s: int, window: int, block_k: int):
+    """``(first block, blocks through the last query)`` a row of a
+    sliding-window call reads: the scalars the call computes for a chunk of
+    ``s`` queries whose first sits at ``index`` (before it clamps them to
+    its buffer). Plain integer arithmetic, for the engine to count what
+    decode read."""
+    last = (index + s + block_k - 1) // block_k
+    first = (index - (window - 1)) // block_k
+    return first * (first > 0), last
 
 
 def auto_block_k(length: int, cap: int = 256) -> int:
@@ -322,7 +353,7 @@ def _loop_kernel(
     #                       whole cache, in HBM), [kvn_ref,] o_ref, [okv_hbm
     #                       (the same buffer, aliased),] scratch
     scale: float, block_k: int, group: int, qb: int, s: int,
-    window, fold: bool, paged: bool, depth: int,
+    window, fold: bool, paged: bool, depth: int, ringed: bool = False,
 ):
     """The LOOP form: the grid is ``(q tiles, rows)`` and each program loops
     over the blocks its row holds, fetching them with its own DMAs into a
@@ -356,9 +387,13 @@ def _loop_kernel(
             s_ref[5, b_] > 0, last_block(b_, q_, s_ref) - s_ref[0, b_] + 1, 0
         )
 
+    def page_of(b_, blk_):
+        # A ring table maps logical block j to its page j mod R.
+        return t_ref[b_, jax.lax.rem(blk_, t_ref.shape[1]) if ringed else blk_]
+
     def block_copy(b_, blk_, slot):
         if paged:
-            src = kv_hbm.at[t_ref[b_, blk_]]
+            src = kv_hbm.at[page_of(b_, blk_)]
         else:
             src = kv_hbm.at[
                 b_, :, pl.ds(pl.multiple_of(blk_ * block_k, block_k), block_k)
@@ -455,7 +490,7 @@ def _loop_kernel(
                 ring[slot, :, pl.ds(sub, tile)] = merged
                 stage[k] = merged
                 if paged:
-                    dst = okv_hbm.at[t_ref[bi, blk], :, pl.ds(sub, tile)]
+                    dst = okv_hbm.at[page_of(bi, blk), :, pl.ds(sub, tile)]
                 else:
                     dst = okv_hbm.at[
                         bi, :,
@@ -538,6 +573,7 @@ def decode_attention(
     block_k: int | None = None,
     block_q: int = _BLOCK_Q,
     latent_v: int | None = None,
+    ring: bool = False,
     interpret: bool | None = None,
 ):
     """Attend chunk queries against the valid prefix of a KV cache.
@@ -592,6 +628,11 @@ def decode_attention(
             page. Unallocated
             entries are never read (per-row frontier clamping) but should
             point at a reserved scratch page for masked writes.
+        ring: the block table is a RING of ``R`` pages a row (a sliding-
+            window layer's, :func:`ring_pages`): logical block ``t`` lives
+            in page ``block_table[b, t mod R]``, so the row's logical
+            capacity is not the table's width. The caller keeps every
+            block the window band and the chunk touch within ``R``.
         row_enable: per-row ``(B,)`` mask (nonzero = attend). A row with 0
             gets NO step of the work list: none of its blocks is read, its
             folded write does not happen, and its output is zeros. For
@@ -619,13 +660,14 @@ def decode_attention(
     return _decode_attention(
         q, kv_cache, index, k_scale, v_scale, kv_new, ks_new, vs_new,
         write_enable, block_table, row_enable, window=window, scale=scale,
-        block_k=block_k, block_q=block_q, latent_v=latent_v,
+        block_k=block_k, block_q=block_q, latent_v=latent_v, ring=ring,
         interpret=interpret,
     )
 
 
 def _emitter_specs(
     *, b, n_kv, gq, h, h_out, hk, block_k, qb, s, paged, quantized, fold,
+    ring=False,
 ):
     """``(in_specs, out_specs)`` of the emitter form: every index map reads
     the work list, so a grid step's blocks are what that step needs."""
@@ -643,7 +685,11 @@ def _emitter_specs(
         return lambda qi, w, *pf: (step_of(w, pf[0])[0], 0, *tail)
 
     def block_at(bi, lb, pf, tail):
-        return (pf[1][bi, lb], 0, 0, *tail) if paged else (bi, 0, lb, *tail)
+        if not paged:
+            return (bi, 0, lb, *tail)
+        # A ring table maps logical block j to its page j mod R.
+        col = jax.lax.rem(lb, pf[1].shape[1]) if ring else lb
+        return (pf[1][bi, col], 0, 0, *tail)
 
     def clamped(tail):
         # A q tile whose causal frontier comes before the row's last block
@@ -689,13 +735,14 @@ def _emitter_specs(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "window", "scale", "block_k", "block_q", "latent_v", "interpret"
+        "window", "scale", "block_k", "block_q", "latent_v", "ring",
+        "interpret",
     ),
 )
 def _decode_attention(
     q, kv_cache, index, k_scale, v_scale, kv_new, ks_new, vs_new,
     write_enable, block_table, row_enable, *, window, scale, block_k,
-    block_q, latent_v, interpret,
+    block_q, latent_v, ring, interpret,
 ):
     b, s, n, h = q.shape
     latent = latent_v is not None
@@ -715,6 +762,8 @@ def _decode_attention(
         block_k = page
         length = block_table.shape[1] * page   # logical per-row capacity
         bk = b
+        if ring and window is None:
+            raise ValueError("a ring of pages serves a sliding window only")
     else:
         bk, n_kv, length, hk = kv_cache.shape
     if latent:
@@ -748,10 +797,15 @@ def _decode_attention(
     block_k = auto_block_k(length) if block_k is None else block_k
     if length % block_k:
         raise ValueError(f"cache length {length} not divisible by block_k {block_k}")
-    nk = length // block_k
+    # A ring's logical blocks run past its table: nothing to clamp to.
+    nk = jnp.iinfo(jnp.int32).max // block_k if ring else length // block_k
     # q rows tile in whole queries (qb of them → gq = qb·group rows) so a
     # tile's causal frontier is well-defined; single-token decode is one tile.
+    # A tile that is not the whole chunk holds whole sublane tiles of rows
+    # (a group of 6 takes 20 queries, 120 rows, not 21).
     qb = min(s, max(1, block_q // group))
+    while qb < s and (qb * group) % 8 and qb > 1:
+        qb -= 1
     gq = qb * group
     nq = pl.cdiv(s, qb)
 
@@ -765,6 +819,8 @@ def _decode_attention(
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
     # Block numbers stay inside the buffer (and the table): the index maps
     # take addresses from them even for a row at or past its capacity.
+    # (window_band's arithmetic, written out: the program of a call
+    # without a window stays as it was.)
     valid_blocks = jnp.clip((idx + s + block_k - 1) // block_k, 1, nk)
     if window is not None:
         kstart = jnp.maximum(0, (idx - (window - 1)) // block_k)
@@ -885,12 +941,15 @@ def _decode_attention(
                 pltpu.VMEM((2, n_kv, tile, hk), kv_cache.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
             ]
-        kernel = functools.partial(_loop_kernel, depth=depth, **statics)
+        kernel = functools.partial(
+            _loop_kernel, depth=depth, ringed=ring, **statics
+        )
         grid = (nq, b)
     else:
         in_specs, out_specs = _emitter_specs(
             b=b, n_kv=n_kv, gq=gq, h=h, h_out=h_out, hk=hk, block_k=block_k,
             qb=qb, s=s, paged=paged, quantized=quantized, fold=fold,
+            ring=ring,
         )
         if quantized:
             operands += [k_scale, v_scale]
@@ -922,7 +981,11 @@ def _decode_attention(
         input_output_aliases=aliases,
         interpret=interpret,
     )
-    with jax.named_scope(_LATENT_TRACE_NAME if latent else _TRACE_NAME):
+    name = (
+        _LATENT_TRACE_NAME if latent
+        else _TRACE_NAME if window is None else _WINDOW_TRACE_NAME
+    )
+    with jax.named_scope(name):
         result = call(*prefetch_args, *operands)
 
     out = result[0] if fold else result

@@ -19,6 +19,22 @@ Faults (a training cell over ``DroplessMoE``):
 ``dropped_expert``      held expert 3's term left out of the combine.
 ``dropped_renorm_eps``  the picks' sum renormalised with 1e-20, not 1e-6.
 
+Faults of a serving cell over window layers (the ``laguna`` family): the
+program's own, or the REFERENCE's (``ref_*``: the harness compares a sound
+program against a reference that computes the model wrongly in that way,
+which reads as the program being wrong in the opposite one):
+
+``ring_off_by_one``     the kernel reads a window layer's logical block j
+                        from ring page (j + 1) mod R; writes go where they
+                        belong.
+``ref_window_ignored``  the window layers attend the whole history.
+``ref_gate_dropped``    no per-head output gate.
+``ref_full_rotary``     the global layers rotate all 128 dims of a head.
+``ref_fp8_weights``     every weight matrix rounded to fp8 e4m3 under a
+                        scale of its own as a block reads it (an expert's
+                        matrices one at a time): the precision below the
+                        stated bf16.
+
 ``REPLAY=<k>`` in the environment overrides ``check.replay_steps``.
 """
 import os
@@ -78,6 +94,59 @@ elif fault == "dropped_renorm_eps":
         renorm_eps: float = 1e-20
 
     moe.DroplessMoE = NoEps
+elif fault == "ring_off_by_one":
+    from learning_jax_sharding_tpu.ops import decode_attention as kernel
+
+    real_kernel = kernel.decode_attention
+
+    def shifted(*args, block_table=None, ring=False, **kw):
+        if ring:
+            block_table = jnp.roll(block_table, -1, axis=1)
+        return real_kernel(*args, block_table=block_table, ring=ring, **kw)
+
+    kernel.decode_attention = shifted
+elif fault.startswith("ref_"):
+    from benchmark.families import laguna, laguna_reference
+
+    real_reference = laguna.reference_fn
+
+    def faulty(dims):
+        if fault == "ref_window_ignored":
+            dims = {**dims, "window": 1 << 30}
+        elif fault == "ref_full_rotary":
+            dims = {**dims, "full_rope": {**dims["full_rope"], "rotary_dim": dims["head_dim"]}}
+        run = real_reference(dims)
+
+        def wrapped(params, tokens):
+            if fault == "ref_gate_dropped":
+                # sigmoid(0) = 1/2 a head, undone by twice the output
+                # projection: a layer without its gate.
+                params = jax.tree_util.tree_map_with_path(
+                    lambda p, x: (
+                        jnp.zeros_like(x) if "'gate'" in jax.tree_util.keystr(p[-2:])
+                        and "'attn'" in jax.tree_util.keystr(p) else
+                        2 * x if "['attn']['out']" in jax.tree_util.keystr(p) else x
+                    ), params,
+                )
+            return run(params, tokens)
+
+        return wrapped
+
+    if fault == "ref_fp8_weights":
+        # Each weight matrix as a block reads it (an expert's at a time):
+        # a rounded copy of the whole tree would not fit beside the served
+        # one on the chip.
+        real_f32 = laguna_reference._f32
+
+        def fp8(x):
+            x = real_f32(x)
+            if x.ndim < 2:
+                return x
+            scale = jnp.max(jnp.abs(x)) / 448.0
+            return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
+
+        laguna_reference._f32 = fp8
+    laguna.reference_fn = faulty
 elif fault != "none":
     raise SystemExit(f"unknown fault {fault}")
 

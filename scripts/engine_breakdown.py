@@ -20,7 +20,11 @@ Its input is a post-mortem bundle, ``engine.dump_diagnostics(outdir)``:
   over ``engine_step_seconds_total``, with the wait, enqueue and h2d
   shares; how many cache blocks the decode-attention kernel keeps in
   flight (``engine_decode_attn_pages_in_flight``) and the bytes of cache
-  the step programs update in place (``engine_cache_donated_bytes``);
+  the step programs update in place (``engine_cache_donated_bytes``); on an
+  engine with window layers, the ring pages one window layer read a decode
+  row-step (``engine_decode_window_pages_total`` over
+  ``engine_decode_steps_total``) and the bytes of the window rings and of
+  the global pools (``engine_window_ring_bytes``, ``engine_global_pool_bytes``);
 * from the registry, the request clock's slot-seconds and readbacks by
   phase (``engine_request_phase_seconds_total{phase=...}``,
   ``..._dispatches_total``), and
@@ -69,6 +73,13 @@ STEP = "engine_step_seconds_total"
 ATTN_DEPTH = "engine_decode_attn_pages_in_flight"
 #: Gauge since PR 36: bytes of the cache leaves the step programs donate.
 DONATED = "engine_cache_donated_bytes"
+#: An engine with window layers: ring pages one window layer read over
+#: decode row-steps, and the bytes of the rings and of the pools
+#: the host allocator serves.
+WINDOW_PAGES = "engine_decode_window_pages_total"
+DECODE_STEPS = "engine_decode_steps_total"
+RING_BYTES = "engine_window_ring_bytes"
+POOL_BYTES = "engine_global_pool_bytes"
 SUMMED = (
     "starved_s", "enqueue_s", "wait_s", "h2d_s", "table_leaves",
     "prefill_tokens", "decode_steps", "context_tokens",
@@ -92,6 +103,20 @@ PHASES = ("refill_wait", "refill", "stall", "decode")
 #: Gaps shorter than this are launch latency between ops, not the host.
 SMALL_GAP_NS = 20_000.0
 _DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+
+
+def window_pages(reg: dict) -> dict | None:
+    """The window layers' decode reads and the cache split by kind, or None
+    on an engine without window layers (or one whose bundle predates the
+    gauges)."""
+    if not reg.get(RING_BYTES):
+        return None
+    steps = reg.get(DECODE_STEPS) or 0
+    return {
+        "pages_per_decode_step": reg.get(WINDOW_PAGES, 0) / steps if steps else None,
+        "ring_bytes": reg[RING_BYTES],
+        "pool_bytes": reg.get(POOL_BYTES, 0),
+    }
 
 
 def load_bundle(bundle: str | os.PathLike) -> dict:
@@ -531,6 +556,7 @@ def main(argv=None) -> dict:
         "moe": moe_by_phase(bundle["registry"]),
         "decode_attn_pages_in_flight": bundle["registry"].get(ATTN_DEPTH),
         "cache_donated_bytes": bundle["registry"].get(DONATED),
+        "window": window_pages(bundle["registry"]),
         "requests": request_table(bundle["retired"], bundle["dispatches"]),
         "request_phases": phase_counters(bundle["registry"]),
     }
@@ -557,6 +583,16 @@ def main(argv=None) -> dict:
         print(
             f"decode attention: {depth:.0f} cache blocks in flight"
             + (" (the loop form)" if depth else " (the emitter form)")
+        )
+    win = out["window"]
+    if win is not None:
+        per = win["pages_per_decode_step"]
+        print(
+            "window layers: "
+            + (f"{per:.3f} ring pages read a decode row-step (one layer), "
+               if per is not None else "")
+            + f"rings {win['ring_bytes'] / 1e9:.3f} GB beside global pools "
+            f"{win['pool_bytes'] / 1e9:.3f} GB"
         )
     donated = out["cache_donated_bytes"]
     if donated is not None:

@@ -51,6 +51,12 @@ def _rehearse(cell: str, trace: int) -> dict:
             {"moe_tokens_per_expert_read", "refill_fill_pct", "ssm_carried_rows_pct",
              "wait_host_share_pct"},
         ),
+        ("laguna-xs.2.code_mixed_8k", 0, {"serve_tok_s", "setup_s"}),
+        (
+            "laguna-xs.2.code_mixed_8k", 1,
+            {"moe_tokens_per_expert_read", "refill_fill_pct", "swa_pages_per_decode_token",
+             "wait_host_share_pct"},
+        ),
         ("gpt2-xl.chat_backlog", 0, {"serve_tok_s", "tpot_p95_ms", "setup_s"}),
         (
             "gpt2-xl.chat_backlog", 1,
@@ -68,7 +74,11 @@ def test_a_serving_cell_rehearses_to_a_correct_line(cell, trace, reports):
     assert not {
         "mla_decode_attn_roofline", "moe_expert_roofline", "decode_attn_roofline",
         "ssm_state_update_roofline", "ssm_chunk_scan_roofline", "latent_moe_expert_roofline",
+        "swa_decode_attn_roofline", "global_decode_attn_roofline",
     } & set(line["metrics"])
+    if "swa_pages_per_decode_token" in reports:
+        # A window of 16 over pages of 8: a band covers 2 or 3 pages.
+        assert 2.0 <= line["metrics"]["swa_pages_per_decode_token"]["value"] <= 3.0
     if "ssm_carried_rows_pct" in reports:
         # PR 30's packing is on: long prompts' further chunks ride along.
         assert 0.0 < line["metrics"]["ssm_carried_rows_pct"]["value"] < 100.0

@@ -32,6 +32,7 @@ from learning_jax_sharding_tpu.ops.decode_attention import (
     auto_block_k,
     decode_attention,
     pages_in_flight,
+    ring_pages,
 )
 from learning_jax_sharding_tpu.ops.flash_attention import flash_attention
 from learning_jax_sharding_tpu.ops.fused_norm import fused_residual_norm
@@ -117,7 +118,7 @@ _B, _N, _H, _L = 8, 12, 64, 1024
 
 def _decode_case(
     *, s=1, page=None, fold=False, int8=False, window=None,
-    b=_B, n=_N, pool=None, n_kv=None, h=_H, row_enable=False,
+    b=_B, n=_N, pool=None, n_kv=None, h=_H, row_enable=False, ring=False,
 ):
     """(fn, shapes) for one decode_attention variant: at the 125M widths,
     or with ``b``/``n``/``pool`` (and ``n_kv`` KV heads of ``h``) at a
@@ -147,7 +148,8 @@ def _decode_case(
         shapes += [((b,), I32)]
     if page is not None:
         names += ["block_table"]
-        shapes += [((b, _L // page), I32)]
+        width = ring_pages(window, page) if ring else _L // page
+        shapes += [((b, width), I32)]
     if row_enable:
         names += ["row_enable"]
         shapes += [((b,), I32)]
@@ -156,7 +158,7 @@ def _decode_case(
         kw = dict(zip(names, args))
         return decode_attention(
             kw.pop("q"), kw.pop("kv_cache"), kw.pop("index"),
-            window=window, interpret=False, **kw,
+            window=window, ring=ring, interpret=False, **kw,
         )
 
     return fn, shapes
@@ -357,6 +359,22 @@ def test_decode_attention_gqa16_head128(one_chip, s):
         b=32, n=32, n_kv=2, h=128, page=128, pool=512, s=s, fold=s == 1,
     )
     assert _in_flight(shapes, 128) == 8      # the loop form: 131 KB a page
+    assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
+
+
+# laguna-xs.2.code_mixed_8k's window layers: 64 slots, 64 (or the global
+# layers' 48) query heads on 8 KV heads of 128, a window of 512 over pages of
+# 128, a ring of 9 pages a slot: groups of 8 and 6 (a chunk tile of 20
+# queries, 120 rows: whole sublane tiles).
+@pytest.mark.parametrize("n", [64, 48])
+@pytest.mark.parametrize("s", [1, 128], ids=["fold", "chunk128"])
+def test_window_ring_decode_attention(one_chip, n, s):
+    fn, shapes = _decode_case(
+        b=64, n=n, n_kv=8, h=128, page=128, pool=64 * 9 + 1, s=s,
+        fold=s == 1, row_enable=s > 1, window=512, ring=True,
+    )
+    assert ring_pages(512, 128) == 9
+    assert _in_flight(shapes, 128) == 8      # the loop form: 512 KB a page
     assert _compiles_to_kernel(fn, one_chip, *shapes) == 1
 
 
@@ -587,6 +605,47 @@ def step_programs(topo):
                     ints(b), rng,
                 ))
 
+        # Window and global layers (the laguna family at small widths, heads
+        # of 128 so that the ring's pool takes the loop form): the window
+        # layers' call ``attn.window_cached_attention`` beside the global
+        # ones' ``attn._blocked_cached_attention``, a per-head gate, partial
+        # YaRN rotary, softmax-routed experts.
+        from learning_jax_sharding_tpu.ops.rope import Yarn
+
+        window_cfg = TransformerConfig(
+            vocab_size=512, num_layers=3, features=128, num_heads=6,
+            num_kv_heads=1, head_dim=128, hidden=256, max_seq_len=1024,
+            dtype=BF16, param_dtype=BF16, norm="rmsnorm", rope=True,
+            rotary_dim=64, rope_yarn=Yarn(factor=4, original_max_positions=256),
+            layer_types=("full_attention", "sliding_attention", "full_attention"),
+            window=256, window_num_heads=8, window_rope_theta=1e4,
+            attn_gate=True, ff_gated=True, first_k_dense=1, num_experts=8,
+            moe_top_k=2, moe_hidden=128, moe_routing="softmax_dropless",
+            moe_shared_experts=1,
+        )
+        window_params = on_chip(jax.eval_shape(lambda: nn.meta.unbox(
+            Transformer(window_cfg).init(
+                {"params": jax.random.key(0)}, np.zeros((2, 8), np.int32)
+            )["params"]
+        )))
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            eng = ContinuousEngine(
+                window_cfg, mesh, RULES_TP_SERVING, batch_size=b,
+                max_new_tokens=8, refill_chunk=chunk, paged_pages=17,
+                page_size=chunk, inference_dtype=BF16,
+            )
+            first = (window_params, None, ints(b, chunk), ints(b), ints(b), rng)
+            with activate(mesh, RULES_TP_SERVING):
+                cache = on_chip(jax.eval_shape(eng.program("first_refill").fn, *first)[1])
+                compiled.append(eng.program("refill_step").fn.lower(
+                    window_params, None, *split_cache(cache), ints(b, chunk),
+                    ints(b), flags, ints(b), ints(b), rng, ints(b), ints(b),
+                ))
+                compiled.append(eng.program("decode_block").fn.lower(
+                    window_params, *split_cache(cache), ints(b), ints(b),
+                    ints(b), ints(b), rng,
+                ))
+
         # The train step as benchmark/train.py builds it, its state abstract.
         train_cfg = dataclasses.replace(
             cfg, param_dtype=F32, decode_attention="dense", max_seq_len=1024,
@@ -713,7 +772,7 @@ def test_the_chip_compiler_aliases_every_donated_cache_leaf(step_programs):
         # The tables ride as a list of their own, beside the donated tree.
         (tables,) = [a for a in low.args_info[0] if isinstance(a, list)]
         assert tables and not any(i.donated for i in tables), name
-    assert seen == 7
+    assert seen == 9
 
 
 
