@@ -152,6 +152,51 @@ def paged_slots(
     return jnp.where(valid, pages, 0), pos % page
 
 
+def paged_scatter(
+    pool, chunk, table, idx, page: int, chunk_lengths, length: int,
+    ring: bool = False,
+):
+    """Write a sequence-major chunk ``(B, N_kv, S, ...)`` into a page pool
+    ``(P, N_kv, page, ...)`` position by position (:func:`paged_slots`:
+    masked positions go to scratch page 0). Any start, any width. The
+    indices sit on pool axes 0 and 2 with the heads between them, so the
+    compiler relays out the whole pool around the scatter."""
+    pages, slots = paged_slots(table, idx, chunk.shape[2], page,
+                               chunk_lengths, length, ring)
+    # (B, N_kv, S, ...) -> (B, S, N_kv, ...): advanced indices on pool axes
+    # 0 and 2 put the (B, S) index shape in front.
+    return pool.at[pages, :, slots].set(jnp.moveaxis(chunk, 2, 1))
+
+
+def paged_page_write(
+    pool, chunk, table, idx, page: int, chunk_lengths, ring: bool = False,
+):
+    """:func:`paged_scatter` for a chunk of whole pages whose every row
+    starts on a page (``idx % page == 0``, ``S % page == 0``): the chunk
+    goes in as ``B * S / page`` whole pages on the pool's LEADING axis, one
+    scatter the compiler does in place. Chunk page ``j`` of row ``b`` lands
+    on ``table[b, idx_b // page + j]`` (a ring table: that column mod
+    ``R``); a page holding none of the row's ``chunk_lengths`` positions
+    goes to scratch page 0.
+
+    A row's last page may be valid in part: its positions at and past the
+    row's length take the chunk's padding. Nothing reads them before they
+    are written again: attention stops at a row's length and the next
+    token's write lands there first; in a ring they are positions of the
+    chunk's own span, which the ring is sized to take whole."""
+    b, n_kv, s = chunk.shape[:3]
+    n = s // page
+    col = idx[:, None] // page + jnp.arange(n)[None, :]
+    col = col % table.shape[1] if ring else jnp.minimum(col, table.shape[1] - 1)
+    live = jnp.arange(n)[None, :] * page < chunk_lengths[:, None]
+    ids = jnp.where(live, jnp.take_along_axis(table, col, axis=1), 0)
+    rest = chunk.shape[3:]
+    pages = jnp.moveaxis(chunk.reshape(b, n_kv, n, page, *rest), 2, 1)
+    return pool.at[ids.reshape(b * n)].set(
+        pages.reshape(b * n, n_kv, page, *rest), mode="drop"
+    )
+
+
 def repeat_kv(kv: jax.Array, num_heads: int) -> jax.Array:
     """Broadcast grouped k/v heads ``(B, S, N_kv, H)`` to ``num_heads``.
 
@@ -270,6 +315,11 @@ class MultiHeadAttention(nn.Module):
     # B × max_decode_len. Page 0 is a reserved scratch target for masked
     # writes; this module never touches the table.
     decode_page_count: int = 0
+    # Every multi-token chunk starts on a page at every row (the serving
+    # engine's refill program vouches for it): a chunk of whole pages is
+    # written as whole pages on the pool's leading axis
+    # (``paged_page_write``) instead of position by position.
+    decode_paged_aligned: bool = False
 
     @property
     def inner_dim(self) -> int:
@@ -709,16 +759,23 @@ class MultiHeadAttention(nn.Module):
                 )
             return row_update(buf, chunk, idx, seq_dim=2)
 
+        # Whole pages where the caller vouches that every row starts on a
+        # page and the chunk's width is whole pages; position by position
+        # everywhere else.
+        whole_pages = (
+            paged and self.decode_paged_aligned and s % page == 0
+            and chunk_lengths is not None
+        )
+
         def paged_write(pool_buf, chunk):
-            # Scatter a sequence-major chunk through the block table
-            # (paged_slots: masked positions go to scratch page 0).
-            pages, slots = paged_slots(
-                block_table.value, idx, s, page, chunk_lengths, length, ring
+            table = block_table.value
+            if whole_pages:
+                return paged_page_write(
+                    pool_buf, chunk, table, idx, page, chunk_lengths, ring
+                )
+            return paged_scatter(
+                pool_buf, chunk, table, idx, page, chunk_lengths, length, ring
             )
-            # chunk (B, N_kv, S, ...) → (B, S, N_kv, ...): advanced indices
-            # on pool axes 0 and 2 put the (B, S) index shape in front.
-            upd = jnp.moveaxis(chunk, 2, 1)
-            return pool_buf.at[pages, :, slots].set(upd)
 
         def write(var, chunk):
             if paged:

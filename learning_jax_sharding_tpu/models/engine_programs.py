@@ -327,6 +327,11 @@ class _Bodies:
 
     apply: Callable
     d_apply: Callable | None     # the draft's; None = not speculative
+    # The two as ``refill_step`` runs them (``build_programs``'
+    # ``page_applies``): forms whose paged layers write a chunk as whole
+    # pages where every chunk row starts on a page; else the same two.
+    r_apply: Callable
+    r_d_apply: Callable | None
     head_on_last: bool   # refill runs the head on a row's last position only
     temperature: float
     top_k: int | None
@@ -389,28 +394,31 @@ class _Bodies:
     # --- the split families: jitted as they stand (XLA's module takes a
     # bound method's name) ------------------------------------------------
 
-    def _refill(self, params, d_params, cache, chunk, lengths, rid, rng):
+    def _refill(
+        self, apply, d_apply, params, d_params, cache, chunk, lengths, rid,
+        rng,
+    ):
         # Run the chunk through the target (and the draft, whose cache
         # must mirror the target's valid prefix for verification); the
         # pick is each row's first generated token — position 0 of its
         # stream.
         last = jnp.maximum(lengths - 1, 0)
-        if self.d_apply is not None:
+        if d_apply is not None:
             t_cache, d_cache = cache
-            logits, t_cache = self.apply(params, t_cache, chunk, lengths)
-            _, d_cache = self.d_apply(d_params, d_cache, chunk, lengths)
+            logits, t_cache = apply(params, t_cache, chunk, lengths)
+            _, d_cache = d_apply(d_params, d_cache, chunk, lengths)
             cache = (t_cache, d_cache)
         elif self.head_on_last:
             # The head on each row's last valid position only: at this
             # family's vocabulary (129,280) the chunk's full (B, S, V)
             # logits are 2.1 GB in float32 and 2.2 TFLOP a dispatch.
             # GPT-2-shaped configs keep the program their goldens pin.
-            logits, cache = self.apply(
+            logits, cache = apply(
                 params, cache, chunk, lengths, logit_positions=last
             )
             last = jnp.zeros_like(last)      # logits are (B, 1, V)
         else:
-            logits, cache = self.apply(params, cache, chunk, lengths)
+            logits, cache = apply(params, cache, chunk, lengths)
         pick = jnp.take_along_axis(logits, last[:, None, None], axis=1)[:, 0]
         tok = self.sample_rows(pick, rng, rid, jnp.zeros_like(rid))
         return tok, cache
@@ -432,8 +440,8 @@ class _Bodies:
         # to the three helpers: both take the same rows.)
         cache = _reset_rows(cache, reset_mask, reset_to)
         tok, out = self._refill(
-            params, d_params, _take_rows(cache, rows, offsets), chunk,
-            lengths, rid[rows], rng,
+            self.r_apply, self.r_d_apply, params, d_params,
+            _take_rows(cache, rows, offsets), chunk, lengths, rid[rows], rng,
         )
         return tok, _put_rows(cache, out, rows, offsets)
 
@@ -442,7 +450,10 @@ class _Bodies:
         # as refill_step minus the reset (Flax creates the zeroed caches —
         # make_cached_apply treats a None cache as the creating call).
         cache = (None, None) if self.d_apply is not None else None
-        return self._refill(params, d_params, cache, chunk, lengths, rid, rng)
+        return self._refill(
+            self.apply, self.d_apply, params, d_params, cache, chunk, lengths,
+            rid, rng,
+        )
 
     def decode_block(self, params, cache, tok, active, remaining, rid, rng):
         """``decode_block_steps`` tokens per call, scanned ON DEVICE — the
@@ -998,8 +1009,8 @@ def _kv_programs():
 
 
 def build_programs(
-    apply, d_apply=None, *, adapter=False, head_on_last=False, kv_rows=True,
-    moe_counted=False,
+    apply, d_apply=None, *, page_applies=None, adapter=False,
+    head_on_last=False, kv_rows=True, moe_counted=False,
     mixed=False, paged=False, prefix_cache=False, temperature=0.0,
     top_k=None, top_p=None, min_p=None, vocab_limit=None, max_new_tokens,
     eos_id=None, decode_block_steps, num_draft=4,
@@ -1007,7 +1018,10 @@ def build_programs(
     """The program table of one engine mode, in the order every report
     lists it. ``apply`` / ``d_apply`` are the target's and the draft's
     ``make_cached_apply`` (a draft makes the engine speculative);
-    ``adapter``: a multi-LoRA pool rides the fused programs;
+    ``page_applies``: the two again, built with ``decode_paged_aligned``,
+    for ``refill_step`` alone, where every chunk row of a refill dispatch
+    starts on a page (None: it runs ``apply`` / ``d_apply``); ``adapter``:
+    a multi-LoRA pool rides the fused programs;
     ``head_on_last``: refill runs the head on each row's last valid
     position only (latent-attention and one-mixer-a-layer configs);
     ``kv_rows``: every cache leaf is contiguous ``(B, L, N_kv, H)`` K,V
@@ -1021,8 +1035,10 @@ def build_programs(
     ``first_refill`` of them). A mixed engine gets ONE fused step and ONE
     horizon scan: speculation picks the body, the pool picks the apply."""
     speculative = d_apply is not None
+    r_apply, r_d_apply = page_applies or (apply, d_apply)
     b = _Bodies(
-        apply=apply, d_apply=d_apply, head_on_last=head_on_last,
+        apply=apply, d_apply=d_apply, r_apply=r_apply, r_d_apply=r_d_apply,
+        head_on_last=head_on_last,
         temperature=temperature,
         top_k=top_k, top_p=top_p, min_p=min_p, vocab_limit=vocab_limit,
         max_new_tokens=max_new_tokens, eos_id=eos_id,

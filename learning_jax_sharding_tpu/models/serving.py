@@ -852,6 +852,32 @@ class ContinuousEngine:
                 dequant_dtype=cfg.param_dtype,
             ))
 
+        # A refill dispatch's chunk rows start where a slot's consumed
+        # prompt ends: at its admission index (0, or whole shared pages)
+        # plus whole chunks. With chunks of whole pages every row starts on
+        # a page, and refill_step writes K|V into the pools as whole pages
+        # (models.attention.paged_page_write). A mixed engine's token budget
+        # cuts chunks anywhere and its refill_step continues them; a latent
+        # cache keeps its own write.
+        whole_pages = (
+            paged and not mixed and not latent
+            and refill_chunk % page_size == 0
+        )
+        page_applies = None
+        if whole_pages:
+            page_applies = tuple(
+                None if c is None else make_cached_apply(
+                    Transformer(
+                        dataclasses.replace(c, decode_paged_aligned=True)
+                    ),
+                    dequantize=dq, dequant_dtype=c.param_dtype,
+                )
+                for c, dq in (
+                    (cfg, bool(dequantize) and not fused),
+                    (d_cfg, draft_dequantize),
+                )
+            )
+
         # --- engine configuration and compiled programs -------------------
         self._mesh, self._rules = mesh, rules
         self._cfg, self._d_cfg = cfg, d_cfg
@@ -907,6 +933,7 @@ class ContinuousEngine:
             else max(1, config.window // refill_chunk)
         )
         self._paged = paged
+        self._whole_pages = whole_pages
         self._paged_pages = paged_pages
         self._page_size = page_size
         self._prefix = prefix_cache
@@ -918,7 +945,8 @@ class ContinuousEngine:
         self._programs = build_programs(
             # A one-mixer-a-layer config refills as a latent one does: the
             # head on one position a row, and no contiguous rows to hand off.
-            apply, d_apply, adapter=adapter_pool is not None,
+            apply, d_apply, page_applies=page_applies,
+            adapter=adapter_pool is not None,
             head_on_last=latent or window or config.layer_pattern is not None,
             kv_rows=not (latent or window or config.layer_pattern is not None),
             moe_counted=moe_counted, mixed=bool(mixed), paged=paged,
@@ -1247,6 +1275,21 @@ class ContinuousEngine:
         self._c_chunk_rows = r.counter(
             "engine_refill_chunk_rows_total",
             "rows of refill dispatches that carried prompt tokens")
+        # A paged engine only: how its pools took the refill chunks.
+        self._c_page_writes = {
+            form: r.counter(
+                labeled_name("engine_refill_page_writes_total", form=form),
+                help_,
+            )
+            for form, help_ in (
+                ("whole_page", "pages a paged engine's refill_step wrote "
+                 "whole on the pool's leading axis (the pages holding a "
+                 "row's chunk; each into every paged attention layer)"),
+                ("scatter", "prompt positions a paged engine's refill "
+                 "programs scattered one by one (refill_step where its "
+                 "rows may start inside a page, mixed_step, multi_step)"),
+            )
+        }
         # A state-space config only (models/ssm.py): the second kind of
         # state in the cache tree, by slot.
         self._c_ssm_carried = r.counter(
@@ -3659,6 +3702,13 @@ class ContinuousEngine:
             for slot, off, n in (
                 *((slot, 0, n) for slot, n in firsts.items()), *extra
             ):
+                start = self._plen[slot] - self._pending[slot].size + off
+                if self._whole_pages and start % self._page_size:
+                    raise RuntimeError(
+                        f"a chunk row of slot {slot} starts at {start}, "
+                        f"inside a page of {self._page_size}: refill_step "
+                        "writes whole pages"
+                    )
                 r = next(free) if off else slot
                 rows[r], offsets[r], lengths[r] = slot, off, n
                 chunk[r, :n] = self._pending[slot][off:off + n]
@@ -3719,6 +3769,12 @@ class ContinuousEngine:
             self._c_prefill_tok.inc(sum(took.values()))
             self._c_refill_slots.inc(chunk.size)
             self._c_chunk_rows.inc(len(firsts) + len(extra))
+            if self._whole_pages:
+                self._c_page_writes["whole_page"].inc(
+                    int((-(-lengths // self._page_size)).sum())
+                )
+            elif self._paged:
+                self._c_page_writes["scatter"].inc(int(lengths.sum()))
             if self._ssm:
                 self._c_ssm_carried.inc(len(extra))
                 self._c_ssm_resets.inc(n_reset)
@@ -4253,6 +4309,8 @@ class ContinuousEngine:
         if not segs:
             return False
         self._c_prefill_tok.inc(refill_scheduled)
+        if self._paged:
+            self._c_page_writes["scatter"].inc(refill_scheduled)
         self.recorder.record(
             "engine.mixed_schedule", links=len(segs),
             decode_rows=n_active, refill_tokens=refill_scheduled,
@@ -4519,6 +4577,8 @@ class ContinuousEngine:
         self._c_multi_n.inc()
         self._c_multi_links.inc(n_live)
         self._c_prefill_tok.inc(refill_scheduled)
+        if self._paged:
+            self._c_page_writes["scatter"].inc(refill_scheduled)
         if self._adapter_pool is not None:
             self._c_adapter_n.inc(n_live)
             self._c_adapter_rows.inc(

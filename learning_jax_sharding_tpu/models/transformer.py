@@ -437,6 +437,7 @@ class TransformerBlock(nn.Module):
                                   # serving; see models.attention)
     decode_paged: bool = False    # paged KV pools + host-owned block tables
     decode_page_count: int = 0
+    decode_paged_aligned: bool = False
     quantization: Optional[str] = None   # "int4" → fused-kernel projections
     quantization_group: int = 128
     quantized_matmul_fn: Optional[Callable] = None
@@ -557,6 +558,7 @@ class TransformerBlock(nn.Module):
             decode_ragged=self.decode_ragged,
             decode_paged=self.decode_paged,
             decode_page_count=self.decode_page_count,
+            decode_paged_aligned=self.decode_paged_aligned,
             quantization=self.quantization,
             quantization_group=self.quantization_group,
             quantized_matmul_fn=self.quantized_matmul_fn,
@@ -749,6 +751,14 @@ class TransformerConfig:
                                      # (models/serving.py)
     decode_page_count: int = 0       # physical pages per layer pool, incl.
                                      # the reserved scratch page 0
+    decode_paged_aligned: bool = False  # every multi-token chunk starts on
+                                     # a page at every row: a paged layer
+                                     # writes a chunk of whole pages as whole
+                                     # pages (models.attention.
+                                     # paged_page_write). The serving engine
+                                     # sets it for its refill program alone,
+                                     # whose chunk rows start where a slot's
+                                     # consumed prompt ends
     quantization: Optional[str] = None  # "int4": every projection consumes a
                                      # quantize_tree(bits=4) tree verbatim
                                      # through the fused dequant-matmul
@@ -970,6 +980,8 @@ class TransformerConfig:
                     "decode_page_count must be >= 2 (page 0 is the "
                     "reserved scratch page)"
                 )
+        elif self.decode_paged_aligned:
+            raise ValueError("decode_paged_aligned requires decode_paged")
 
     def train_step_flops(self, batch: int, seq: int) -> float:
         """Analytic model FLOPs of one train step (fwd + bwd ≈ 3× fwd).
@@ -1252,6 +1264,7 @@ class MixerBlock(nn.Module):
                 decode_ragged=cfg.decode_ragged,
                 decode_paged=cfg.decode_paged,
                 decode_page_count=cfg.decode_page_count,
+                decode_paged_aligned=cfg.decode_paged_aligned,
                 name="attn",
             )(h, deterministic=deterministic, chunk_lengths=chunk_lengths)
         return nn.with_logical_constraint(x + out, (BATCH, SEQ, EMBED))
@@ -1378,6 +1391,7 @@ class Transformer(nn.Module):
             decode_ragged=cfg.decode_ragged,
             decode_paged=cfg.decode_paged,
             decode_page_count=cfg.decode_page_count,
+            decode_paged_aligned=cfg.decode_paged_aligned,
             quantization=cfg.quantization,
             quantization_group=cfg.quantization_group,
             quantized_matmul_fn=cfg.quantized_matmul_fn,

@@ -423,3 +423,66 @@ def test_the_cache_moving_programs_donate_too(make_engine, tiny_params):
     eng.fill_page(key, page)
     assert all(x.is_deleted() for x in _leaves(before, tables=False))
     assert not any(x.is_deleted() for x in jax.tree.leaves(eng._cache))
+
+
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr inside it (a jitted call's,
+    a scan's body, a Pallas kernel's)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else (value,):
+                if isinstance(sub, ClosedJaxpr):
+                    n += _equations(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    n += _equations(sub)
+    return n
+
+
+#: ``refill_step``'s equations a layer of ``TINY`` (4 rows, chunks of 16,
+#: pages of 8) while every paged layer scattered its chunk position by
+#: position: read off the program before the whole-page write existed.
+SCATTER_EQUATIONS_A_LAYER = 326
+
+
+def test_refill_step_traces_the_same_handful_of_ops_a_layer(mesh):
+    """What set-up pays to trace and lower ``refill_step`` grows with the
+    layers by what one layer's ops are, and the whole-page write keeps that
+    no larger than the scatter did: no kernel, loop or per-row op a layer
+    (a per-row or per-page form multiplies a layer's ops by the rows; each
+    of xl's 48 layers pays it again)."""
+    from learning_jax_sharding_tpu.models.engine_programs import split_cache
+    from learning_jax_sharding_tpu.parallel.logical import activate
+
+    b, chunk = 4, 16
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    rng = jax.eval_shape(lambda: jax.random.key(0))
+    counted = {}
+    for layers in (2, 8):
+        cfg = dataclasses.replace(TINY, num_layers=layers)
+        eng = ContinuousEngine(
+            cfg, mesh, RULES_TP_SERVING, batch_size=b, max_new_tokens=4,
+            refill_chunk=chunk, paged_pages=9, page_size=8,
+        )
+        assert eng._whole_pages
+        params = jax.eval_shape(lambda: nn.meta.unbox(
+            Transformer(cfg).init(
+                {"params": jax.random.key(0)}, np.zeros((2, 8), np.int32)
+            )["params"]
+        ))
+        with activate(mesh, RULES_TP_SERVING):
+            cache = jax.eval_shape(
+                eng.program("first_refill").fn,
+                params, None, ints(b, chunk), ints(b), ints(b), rng,
+            )[1]
+            jaxpr = jax.make_jaxpr(eng.program("refill_step").fn)(
+                params, None, *split_cache(cache), ints(b, chunk), ints(b),
+                jax.ShapeDtypeStruct((b,), jnp.bool_), ints(b), ints(b), rng,
+                ints(b), ints(b),
+            )
+        counted[layers] = _equations(jaxpr.jaxpr)
+    a_layer = (counted[8] - counted[2]) / 6
+    assert a_layer <= SCATTER_EQUATIONS_A_LAYER + 8, counted

@@ -398,3 +398,188 @@ class TestPagedKVCache:
         )
         with pytest.raises(ValueError, match="cannot shard the batch"):
             eng_dp(params, prompts[:1])
+
+
+# --- how a refill chunk goes into the pools --------------------------------
+
+
+def _pool_and_chunk(case, leaf):
+    """A pool, a chunk and where it goes, as a refill dispatch gives them:
+    every row's chunk starts on a page. ``(pool, chunk, table, idx,
+    lengths, ring)`` with pages of 4 and chunks of two pages."""
+    from learning_jax_sharding_tpu.ops.decode_attention import ring_pages
+
+    rng = np.random.default_rng(["aligned", "mixed_lengths", "ring"].index(case))
+    page, s, n_kv, rows = 4, 8, 2, 5
+    if case == "ring":
+        r = ring_pages(8, page)          # a window of 8: 4 pages a slot
+        table = 1 + np.arange(rows * r, dtype=np.int32).reshape(rows, r)
+        pool_pages = rows * r + 1
+        idx = page * rng.integers(0, 30, size=rows)   # turns of the ring
+    else:
+        cols, pool_pages = 8, 48          # 32 positions a row
+        # Rows own distinct pages; some pages belong to no row.
+        owned = rng.permutation(np.arange(1, pool_pages))[: rows * cols]
+        table = owned.reshape(rows, cols).astype(np.int32)
+        idx = page * rng.integers(0, cols - s // page + 1, size=rows)
+    if case == "aligned":
+        lengths = np.full(rows, s)
+    else:
+        lengths = np.array([0, s, 3, 5, 1])   # empty, whole, partial pages
+    rest = (6,) if leaf == "kv" else ()       # k | v lanes, or int8 scales
+    pool = rng.standard_normal((pool_pages, n_kv, page, *rest)).astype(np.float32)
+    chunk = rng.standard_normal((rows, n_kv, s, *rest)).astype(np.float32)
+    return (
+        pool, chunk, jnp.asarray(table), jnp.asarray(idx, jnp.int32),
+        jnp.asarray(lengths, jnp.int32), case == "ring",
+    )
+
+
+@pytest.mark.parametrize("leaf", ["kv", "int8_scales"])
+@pytest.mark.parametrize("case", ["aligned", "mixed_lengths", "ring"])
+def test_whole_page_write_matches_the_scatter(case, leaf):
+    """A refill chunk written as whole pages on the pool's leading axis
+    leaves the pool as the position-by-position scatter does wherever
+    anything reads: every position below a row's new length, every page no
+    row owns. What differs is the padding past a row's length inside the
+    row's own last page, and scratch page 0; a row of length 0 touches
+    nothing but page 0."""
+    from learning_jax_sharding_tpu.models.attention import (
+        paged_page_write,
+        paged_scatter,
+    )
+
+    pool, chunk, table, idx, lengths, ring = _pool_and_chunk(case, leaf)
+    page, s = pool.shape[2], chunk.shape[2]
+    want = np.asarray(jax.jit(
+        paged_scatter, static_argnames=("page", "length", "ring")
+    )(pool, chunk, table, idx, page=page, chunk_lengths=lengths,
+      length=table.shape[1] * page, ring=ring))
+    got = np.asarray(jax.jit(
+        paged_page_write, static_argnames=("page", "ring")
+    )(pool, chunk, table, idx, page=page, chunk_lengths=lengths, ring=ring))
+
+    table, idx, lengths = map(np.asarray, (table, idx, lengths))
+    may_differ = np.zeros(pool.shape[:1] + pool.shape[2:3], bool)  # (P, page)
+    may_differ[0] = True
+    may_touch = {0}
+    for b in range(len(idx)):
+        n = int(lengths[b])
+        for t in range(n):           # what the row writes: the chunk itself
+            col = (idx[b] + t) // page
+            pid = table[b, col % table.shape[1] if ring else col]
+            np.testing.assert_array_equal(
+                got[pid, :, (idx[b] + t) % page], chunk[b, :, t]
+            )
+        for t in range(n, -(-n // page) * page):   # its last page's padding
+            col = (idx[b] + t) // page
+            pid = table[b, col % table.shape[1] if ring else col]
+            may_differ[pid, (idx[b] + t) % page] = True
+        may_touch |= {
+            int(table[b, (idx[b] // page + j) % table.shape[1]])
+            for j in range(-(-n // page))
+        }
+    keep = ~may_differ            # by (page, slot): heads moved behind
+    np.testing.assert_array_equal(
+        np.moveaxis(got, 2, 1)[keep], np.moveaxis(want, 2, 1)[keep]
+    )
+    touched = {
+        int(p) for p in np.flatnonzero(
+            (got != pool).reshape(len(pool), -1).any(axis=1)
+        )
+    }
+    assert touched <= may_touch, (touched, may_touch)
+    assert s % page == 0 and (idx % page == 0).all()
+
+
+def _page_writes(eng):
+    snap = eng.engine.registry.snapshot()
+    return {
+        form: snap[f'engine_refill_page_writes_total{{form="{form}"}}']
+        for form in ("whole_page", "scatter")
+    }
+
+
+@pytest.mark.parametrize("kind", ["plain", "prefix_cache", "speculative", "int8_kv"])
+def test_whole_page_refill_matches_the_unpaged_engine(setup, mesh22, kind):
+    """A split paged engine whose refill chunk is whole pages (16 tokens,
+    pages of 8, as xl's 128 over 64) writes its refill chunks as whole
+    pages: the tokens are the contiguous engine's, admissions at a shared
+    prefix's page included, and every row starts on a page (the engine
+    checks each row's start as it packs the dispatch)."""
+    cfg, params, _ = setup
+    cfg = dataclasses.replace(cfg, decode_attention="blocked")
+    if kind == "int8_kv":
+        cfg = dataclasses.replace(cfg, kv_cache_dtype=jnp.int8)
+    rng = np.random.default_rng(21)
+    base = rng.integers(1, cfg.vocab_size, size=(20,)).astype(np.int32)
+    queue = [
+        rng.integers(1, cfg.vocab_size, size=(n,)).astype(np.int32)
+        for n in (16, 37, 5, 48, 21)
+    ] + [base, base.copy(), np.concatenate([base, base[:7]])]
+    kw, call = {}, {}
+    if kind == "prefix_cache":
+        kw = dict(prefix_cache=True)
+    if kind == "speculative":
+        kw = dict(
+            draft_config=dataclasses.replace(DRAFT_CFG, decode_attention="blocked"),
+            num_draft=2,
+        )
+        call = dict(draft_params=_draft_params())
+    plain = make_continuous_engine(
+        cfg, mesh22, RULES_TP_SERVING, batch_size=2, max_new_tokens=NEW,
+        refill_chunk=4,
+    )
+    paged = make_continuous_engine(
+        cfg, mesh22, RULES_TP_SERVING, batch_size=2, max_new_tokens=NEW,
+        refill_chunk=16, paged_pages=17, page_size=8, **kw,
+    )
+    ref = plain(params, queue)
+    got = paged(params, queue, **call)
+    for r, g in zip(ref, got):
+        np.testing.assert_array_equal(g, r)
+    writes = _page_writes(paged)
+    assert writes["whole_page"] > 0 and writes["scatter"] == 0
+    if kind == "prefix_cache":
+        assert paged.last_stats["prefix_hits"] >= 1
+
+
+def test_page_write_counter_by_form(setup, mesh22):
+    """``engine_refill_page_writes_total{form=}``: a split engine's aligned
+    refill dispatch counts ``rows x S / page`` whole pages (prompts of
+    whole chunks fill every row); a mixed engine, whose token budget cuts
+    chunks anywhere, scatters every prompt position it refills."""
+    cfg, params, _ = setup
+    cfg = dataclasses.replace(cfg, decode_attention="blocked")
+    rng = np.random.default_rng(22)
+    queue = [
+        rng.integers(1, cfg.vocab_size, size=(n,)).astype(np.int32)
+        for n in (16, 48, 32)
+    ]
+    args = dict(batch_size=2, max_new_tokens=NEW, paged_pages=17, page_size=8)
+    split = make_continuous_engine(
+        cfg, mesh22, RULES_TP_SERVING, refill_chunk=16, **args
+    )
+    split(params, queue)
+    snap = split.engine.registry.snapshot()
+    rows = snap["engine_refill_chunk_rows_total"]
+    assert rows == sum(len(p) for p in queue) // 16
+    assert _page_writes(split) == {"whole_page": rows * 16 // 8, "scatter": 0}
+
+    mixed = make_continuous_engine(
+        cfg, mesh22, RULES_TP_SERVING, refill_chunk=16, mixed=True, **args
+    )
+    mixed(params, queue)
+    snap = mixed.engine.registry.snapshot()
+    assert snap["engine_mixed_dispatches_total"] > 0
+    assert _page_writes(mixed) == {
+        "whole_page": 0, "scatter": snap["engine_prefill_tokens_total"],
+    }
+    # A chunk that is not whole pages keeps the scatter on a split engine.
+    narrow = make_continuous_engine(
+        cfg, mesh22, RULES_TP_SERVING, refill_chunk=12, **args
+    )
+    narrow(params, queue)
+    assert _page_writes(narrow) == {
+        "whole_page": 0, "scatter": sum(len(p) for p in queue),
+    }

@@ -775,6 +775,56 @@ def test_the_chip_compiler_aliases_every_donated_cache_leaf(step_programs):
     assert seen == 9
 
 
+def test_refill_step_writes_its_chunk_into_the_pools_in_place(step_programs):
+    """No ``refill_step`` compiled for ``v5e:2x2`` relays out a paged pool:
+    the GPT-2, state-space and window engines write a chunk as whole pages
+    on the pool's leading axis. A position-by-position scatter on pool
+    axes 0 and 2 had the compiler copy every attention layer's whole pool
+    to another layout and back around it, in every refill dispatch. (The
+    compiler also stages a pool through VMEM and writes it back, copies
+    that keep the layout: they are not what this pins. The latent engine
+    keeps its own write; at these widths it relays out nothing.)"""
+    from learning_jax_sharding_tpu.models.engine_programs import (
+        _PAGE_LEAF_KEYS,
+    )
+
+    def relayouts(line, pools):
+        # A copy (an async one, or a fusion the compiler named for one)
+        # whose result holds a pool in another layout than its own.
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if not m or not (
+            m.group(1).startswith("copy")
+            or m.group(3) in ("copy", "copy-start")
+        ):
+            return False
+        return any(
+            layout != pools[dims]
+            for dims, layout in re.findall(r"\w+(\[[\d,]+\])\{([\d,]+)", m.group(2))
+            if dims in pools
+        )
+
+    seen = 0
+    for name, text, low in step_programs:
+        if not name.startswith("jit_refill_step"):
+            continue
+        seen += 1
+        # Pool dims -> the row-major layout every argument arrives in.
+        pools = {
+            "[" + ",".join(map(str, leaf.shape)) + "]":
+            ",".join(map(str, reversed(range(len(leaf.shape)))))
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                low.args_info
+            )[0]
+            if getattr(path[-1], "key", None) in _PAGE_LEAF_KEYS
+        }
+        assert pools, name
+        moved = [
+            line.strip()[:200] for line in text.splitlines()
+            if relayouts(line, pools)
+        ]
+        assert not moved, (name, sorted(pools), moved[:4])
+    assert seen == 4
+
 
 def test_the_train_step_keeps_what_its_remat_plan_says(topo):
     """The same train step compiled for one described chip under
